@@ -1,5 +1,5 @@
 # Build/test fan-out (capability parity: reference top-level Makefile:1-9).
-.PHONY: all test e2e e2e-kind bench bench-http bench-gas bench-gang bench-configs bench-serving bench-rebalance bench-chaos bench-decisions bench-forecast bench-ha bench-twin bench-shard test-serving test-obs test-rebalance test-faults test-decisions test-gang test-forecast test-ha test-slo test-shard test-record test-control test-admission test-explain test-solveobs bench-control bench-admission bench-replay bench-ledger test-fuzz fuzz-smoke test-wirec trace-lint pascheck obs-smoke lint image clean dryrun
+.PHONY: all test e2e e2e-kind chip-smoke bench bench-http bench-gas bench-gang bench-configs bench-serving bench-rebalance bench-chaos bench-decisions bench-forecast bench-ha bench-twin bench-shard test-serving test-obs test-rebalance test-faults test-decisions test-gang test-forecast test-ha test-slo test-shard test-record test-control test-admission test-explain test-solveobs bench-control bench-admission bench-replay bench-ledger test-fuzz fuzz-smoke test-wirec trace-lint pascheck obs-smoke lint image clean dryrun
 
 all: test
 
@@ -17,6 +17,16 @@ e2e-kind:
 		python .github/e2e/run_e2e.py ); rc=$$?; \
 		bash .github/scripts/e2e_teardown_cluster.sh; exit $$rc
 
+# the first command on a machine with a chip: one process holds the TPU,
+# serves TAS (10k nodes) and GAS (2k x 8) in threads, drives its own
+# sockets, compares every answer with the host-only reference, and exits
+# non-zero unless the device path really ran (chip_smoke.py).  The compile
+# cache lives where JAX_COMPILATION_CACHE_DIR says, else in ./.jax_cache
+chip-smoke:
+	python chip_smoke.py
+
+# every section is a child process, one at a time (one process per chip);
+# exits non-zero when any section failed or was not run
 bench:
 	python bench.py
 
@@ -264,12 +274,14 @@ obs-smoke:
 bench-configs:
 	python -m benchmarks.configs
 
+# multi-chip parity checks on a virtual 8-device CPU mesh; the same checks
+# run on real chips as chip_smoke.py's mesh phase (>= 4 devices) or
+# `python __graft_entry__.py --chips`
 dryrun:
-	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-		python -c "import __graft_entry__; __graft_entry__.dryrun_multichip(8)"
+	python __graft_entry__.py 8
 
 lint:
-	python -m compileall -q platform_aware_scheduling_tpu tests bench.py __graft_entry__.py
+	python -m compileall -q platform_aware_scheduling_tpu tests benchmarks bench.py chip_smoke.py __graft_entry__.py
 
 image:
 	docker build -f deploy/images/Dockerfile.tas -t pas-tpu-tas .

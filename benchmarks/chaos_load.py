@@ -372,7 +372,11 @@ def run(num_nodes: int = 256, requests: int = 400) -> Dict:
 
 
 def main() -> None:
+    from benchmarks import children
+
+    identity = children.hold_chip("benchmarks.chaos_load")
     result = run()
+    result["platform"] = identity["platform"]
     lk = result["leader_kill"]
     print(
         f"chaos: availability clean={result['clean']['availability']} "

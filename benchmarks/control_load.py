@@ -74,8 +74,11 @@ def compact(out: Dict) -> Dict:
 
 
 def main() -> int:
+    from benchmarks import children
+
+    identity = children.hold_chip("benchmarks.control_load")
     out = run()
-    print(json.dumps(compact(out), indent=1))
+    print(json.dumps({**compact(out), "platform": identity["platform"]}, indent=1))
     ok = out["all_strictly_better"] and out["diurnal_quiet"]["ok"]
     if not ok:
         print(

@@ -36,6 +36,7 @@ import json
 import sys
 from typing import Dict, List
 
+from benchmarks import children
 from benchmarks.http_load import _PATHS, _best_of, _spawn_service, drive, make_bodies
 from platform_aware_scheduling_tpu.extender.server import HTTPRequest
 from platform_aware_scheduling_tpu.forecast import Forecaster
@@ -316,6 +317,7 @@ def overhead(
     requests: int = 240,
     warmup: int = 5,
     repeats: int = 2,
+    platform: str = "tpu",
 ) -> Dict:
     """Forecast on-vs-off serving p99 at cluster scale (the acceptance
     bar: the off path is the pre-forecast path, and fits off the request
@@ -325,8 +327,8 @@ def overhead(
     )
     out: Dict = {"num_nodes": num_nodes}
     for label, forecast in (("on", True), ("off", False)):
-        proc, port = _spawn_service(
-            num_nodes, device=True, forecast=forecast
+        proc, port, out["platform"] = _spawn_service(
+            num_nodes, device=True, forecast=forecast, platform=platform
         )
         try:
             side: Dict = {}
@@ -358,18 +360,31 @@ def overhead(
     return out
 
 
+def scenarios() -> Dict:
+    """The two placement-quality A/Bs; both compute on the device in this
+    process."""
+    return {"trending": trending_ab(), "spike": spike_ab()}
+
+
 def run(num_nodes: int = 10_000, with_overhead: bool = True) -> Dict:
-    out: Dict = {
-        "trending": trending_ab(),
-        "spike": spike_ab(),
-    }
+    """Scenarios + the on-vs-off overhead.  The overhead A/B launches
+    device services, so this process stays off JAX and the scenarios run
+    as a child of their own first (benchmarks/children.py)."""
+    out = children.run_child(["-m", "benchmarks.forecast_load", "--scenarios"])
     if with_overhead:
         out["overhead"] = overhead(num_nodes=num_nodes)
     return out
 
 
 def main() -> None:
+    if sys.argv[1:] == ["--scenarios"]:
+        identity = children.hold_chip("forecast scenarios")
+        result = scenarios()
+        result["platform"] = identity["platform"]
+        print(json.dumps(result))
+        return
     result = run()
+    children.assert_launcher("benchmarks.forecast_load")
     trending, spike = result["trending"], result["spike"]
     print(
         f"forecast: trending violated-at-bind snapshot="

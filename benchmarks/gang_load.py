@@ -266,7 +266,11 @@ def run() -> Dict:
 
 
 def main() -> int:
+    from benchmarks import children
+
+    identity = children.hold_chip("benchmarks.gang_load")
     result = run()
+    result["platform"] = identity["platform"]
     print(json.dumps(result, indent=2))
     on, off = result["gang_on"], result["gang_off"]
     ok = not on["deadlock"] and off["deadlock"]

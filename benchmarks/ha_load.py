@@ -172,7 +172,11 @@ def run(
 
 
 def main() -> None:
+    from benchmarks import children
+
+    identity = children.hold_chip("benchmarks.ha_load")
     result = run()
+    result["platform"] = identity["platform"]
     fo = result["failover"]
     print(
         f"ha: c=8 over {result['replicas']} replicas rps "

@@ -39,12 +39,12 @@ Round-3 verdict additions:
 from __future__ import annotations
 
 import json
-import os
 import socket
 import threading
 import time
 from typing import Dict, List
 
+from benchmarks import children
 from platform_aware_scheduling_tpu.extender.server import Server
 from platform_aware_scheduling_tpu.ops.state import TensorStateMirror
 from platform_aware_scheduling_tpu.tas.cache import AutoUpdatingCache
@@ -430,18 +430,29 @@ def _serve_forever(
     decisions_enabled: bool = True,
     forecast: bool = False,
     flight: bool = False,
+    platform: str = "tpu",
 ) -> None:
-    """Subprocess entry: start the service, print ``READY <port>``, block.
-    The server gets its own process (and GIL) — in-process serving would
-    let the measuring threads contend with the handler threads and charge
-    the contention to the server under test.  ``builder`` defaults to the
-    TAS service; benchmarks/gas_load.py reuses this with its own.
+    """Subprocess entry: start the service, print ``READY <port>
+    <platform>``, block.  The server gets its own process (and GIL) —
+    in-process serving would let the measuring threads contend with the
+    handler threads and charge the contention to the server under test.
+    ``builder`` defaults to the TAS service; benchmarks/gas_load.py reuses
+    this with its own.
+
+    A ``device`` service holds the chip: it places the compile cache and
+    refuses any platform but ``platform`` (benchmarks/children.py).  The
+    host control never touches JAX's backend and says ``host``.
 
     GC posture (applies to BOTH sides of the A/B): the same serving
     tuning the production mains apply (utils/gctuning.py)."""
     from platform_aware_scheduling_tpu.utils import decisions, devicewatch
     from platform_aware_scheduling_tpu.utils.gctuning import tune_for_serving
 
+    served_on = "host"
+    if device:
+        served_on = children.hold_chip(
+            "a bench service labelled device", platform
+        )["platform"]
     # decision provenance on/off — the decision_overhead A/B flips this
     # per service subprocess (mirrors --decisionLog on the real mains)
     decisions.DECISIONS.configure(enabled=decisions_enabled)
@@ -458,9 +469,10 @@ def _serve_forever(
             forecast=forecast,
             flight=flight,
         )
-    devicewatch.DeviceWatcher(period_s=2.0).start()
+    if device:
+        devicewatch.DeviceWatcher(period_s=2.0).start()
     tune_for_serving()
-    print(f"READY {server.port}", flush=True)
+    print(f"READY {server.port} {served_on}", flush=True)
     threading.Event().wait()
 
 
@@ -472,12 +484,17 @@ def _spawn_service(
     decisions_enabled: bool = True,
     forecast: bool = False,
     flight: bool = False,
+    platform: str = "tpu",
 ) -> tuple:
-    """(process, port) for an isolated service subprocess running
-    ``python -m <module> --serve`` (shared by the GAS A/B)."""
+    """(process, port, platform it serves on) for an isolated service
+    subprocess running ``python -m <module> --serve`` (shared by the GAS
+    A/B).  A TPU service needs the chip, so its launcher must never have
+    touched JAX."""
     import subprocess
     import sys
 
+    if device and platform == "tpu":
+        children.assert_launcher("the process spawning a device service")
     proc = subprocess.Popen(
         [
             sys.executable,
@@ -490,18 +507,20 @@ def _spawn_service(
             "1" if decisions_enabled else "0",
             "1" if forecast else "0",
             "1" if flight else "0",
+            platform,
         ],
         stdout=subprocess.PIPE,
         text=True,
         # resolve `-m benchmarks.*` from the repo root regardless of the
         # caller's cwd (bench.py supports being launched anywhere)
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        cwd=children.REPO_ROOT,
     )
     line = proc.stdout.readline().strip()
     if not line.startswith("READY "):
         proc.terminate()
         raise RuntimeError(f"service failed to start: {line!r}")
-    return proc, int(line.split()[1])
+    _ready, port, served_on = line.split()
+    return proc, int(port), served_on
 
 
 def _best_of(a: Dict, b: Dict) -> Dict:
@@ -519,6 +538,7 @@ def run(
     concurrency_sweep: tuple = (1, 8),
     warmup: int = 5,
     repeats: int = 2,
+    platform: str = "tpu",
 ) -> Dict:
     """The full A/B: device fastpath vs host control, same harness, both
     wire modes, Prioritize and Filter, hit and miss tiers, across the
@@ -535,7 +555,11 @@ def run(
     names = node_names(num_nodes)
     out: Dict = {"num_nodes": num_nodes}
     for label, device in (("device", True), ("control", False)):
-        proc, port = _spawn_service(num_nodes, device=device)
+        proc, port, served_on = _spawn_service(
+            num_nodes, device=device, platform=platform
+        )
+        if device:
+            out["platform"] = served_on
         n_req = device_requests if device else control_requests
         try:
             side: Dict = {}
@@ -646,6 +670,7 @@ def serving_scaling(
     repeats: int = 2,
     concurrency_sweep: tuple = (1, 8),
     servings: tuple = ("threaded", "async"),
+    platform: str = "tpu",
 ) -> Dict:
     """Head-to-head c=1 → c=8 scaling curve: threaded front-end vs the
     event-loop micro-batching one (serving/), device fastpath on both
@@ -660,7 +685,9 @@ def serving_scaling(
     bodies = make_bodies(names, "nodenames")
     out: Dict = {"num_nodes": num_nodes}
     for serving in servings:
-        proc, port = _spawn_service(num_nodes, device=True, serving=serving)
+        proc, port, out["platform"] = _spawn_service(
+            num_nodes, device=True, serving=serving, platform=platform
+        )
         try:
             side: Dict = {}
             for conc in concurrency_sweep:
@@ -704,7 +731,7 @@ def filter_floor_breakdown(num_nodes: int = 10_000, reps: int = 30) -> Dict:
     The filter MISS tier sits ~25-30x because the CONTROL's filter has no
     sort (~25 ms at 10k nodes) while the device side still pays an
     irreducible floor.  This measures that floor stage by stage, in-process
-    (no HTTP) plus the HTTP transport floor via a live socket:
+    (no HTTP; this process holds the chip):
 
       * ``parse_us`` — native scan of a 10k-name NodeNames body
         (wirec.parse_prioritize);
@@ -721,10 +748,8 @@ def filter_floor_breakdown(num_nodes: int = 10_000, reps: int = 30) -> Dict:
         Prioritize analog;
       * ``nodes_hit_verb_us`` — the full-Nodes HIT path (span memcmp +
         cached bytes), the floor behind the filter_nodes configs;
-      * ``http_floor_us`` — p50 of POSTing the same bodies to
-        /scheduler/bind on the live service (TAS Bind is an immediate 404
-        after the server ingests the body: transport + framing cost with
-        ZERO scheduling work);
+      * the transport floor (``http_floor_us``) is :func:`http_floor`'s,
+        measured from a launcher process of its own;
       * ``control_filter_ms`` — the host control's per-request filter
         work at the same size, for the ratio.
 
@@ -844,28 +869,47 @@ def filter_floor_breakdown(num_nodes: int = 10_000, reps: int = 30) -> Dict:
         ctl.filter(req(nodes_body))
     out["control_filter_ms"] = round((time.perf_counter() - t0) / 3 * 1e3, 3)
 
-    # transport floor: same bytes, zero scheduling work (Bind -> 404)
-    proc, port = _spawn_service(num_nodes, device=True)
+    out["notes"] = (
+        "floor = http transport (http_floor) + parse + partition/encode; "
+        "control has no sort so the miss-tier ratio is capped at "
+        "control_filter_ms over this floor"
+    )
+    return out
+
+
+def http_floor(
+    num_nodes: int = 10_000, reps: int = 30, platform: str = "tpu"
+) -> Dict:
+    """``http_floor_us``: the transport floor under the Filter floor — p50
+    of POSTing rotated full-size bodies to /scheduler/bind on a live
+    device service (TAS Bind is an immediate 404 after the server ingests
+    the body: transport + framing cost with ZERO scheduling work).  It
+    launches the service, so it is its own process, apart from
+    :func:`filter_floor_breakdown` which computes in-process."""
+    bodies = make_bodies(
+        node_names(num_nodes), "nodenames", rotate_span=True, count=reps,
+        rotate_offset=reps,
+    )
+    proc, port, served_on = _spawn_service(
+        num_nodes, device=True, platform=platform
+    )
     try:
         floor = drive(
             port,
-            miss_bodies[: min(reps, len(miss_bodies))],
-            min(reps, len(miss_bodies)),
+            bodies,
+            len(bodies),
             concurrency=1,
             path="/scheduler/bind",
             min_payload=0,
             expect_status=404,
         )
-        out["http_floor_us"] = round(floor["p50_ms"] * 1e3, 1)
     finally:
         proc.terminate()
         proc.wait(timeout=10)
-    out["notes"] = (
-        "floor = http transport + parse + partition/encode; control has "
-        "no sort so the miss-tier ratio is capped at control_filter_ms "
-        "over this floor"
-    )
-    return out
+    return {
+        "http_floor_us": round(floor["p50_ms"] * 1e3, 1),
+        "platform": served_on,
+    }
 
 
 def decision_overhead(
@@ -873,6 +917,7 @@ def decision_overhead(
     requests: int = 240,
     warmup: int = 5,
     repeats: int = 2,
+    platform: str = "tpu",
 ) -> Dict:
     """Decision-provenance A/B (ISSUE 6 acceptance): serving p99 with the
     decision log ON vs OFF — same device service, same bodies, same
@@ -888,8 +933,9 @@ def decision_overhead(
     bodies = make_bodies(names, "nodenames")
     out: Dict = {"num_nodes": num_nodes}
     for label, enabled in (("on", True), ("off", False)):
-        proc, port = _spawn_service(
-            num_nodes, device=True, decisions_enabled=enabled
+        proc, port, out["platform"] = _spawn_service(
+            num_nodes, device=True, decisions_enabled=enabled,
+            platform=platform,
         )
         try:
             side: Dict = {}
@@ -970,6 +1016,7 @@ def record_overhead(
     requests: int = 400,
     warmup: int = 5,
     repeats: int = 3,
+    platform: str = "tpu",
 ) -> Dict:
     """Flight-recorder A/B (ISSUE 13 acceptance: recorder-on p99 within
     5% of off): serving p99 with --flightRecorder on vs off — same
@@ -995,8 +1042,8 @@ def record_overhead(
     for _rep in range(max(repeats, 1)):
         pair: Dict[str, Dict[str, Dict]] = {}
         for label, enabled in (("on", True), ("off", False)):
-            proc, port = _spawn_service(
-                num_nodes, device=True, flight=enabled
+            proc, port, out["platform"] = _spawn_service(
+                num_nodes, device=True, flight=enabled, platform=platform
             )
             try:
                 side = out[label]
@@ -1055,8 +1102,15 @@ def record_overhead(
         out[f"pair_ratios_{verb}_p99"] = [round(r, 3) for r in ratios]
     # the hermetic companion number: on shared/noisy machines the wire
     # A/B's spawn variance can exceed the recorder's whole cost, so the
-    # in-process delta is the authoritative per-request figure
-    out["inprocess"] = record_inprocess_overhead(num_nodes)
+    # in-process delta is the authoritative per-request figure.  It
+    # computes on the device, so it runs as a child of its own, after the
+    # last service has released the chip — this process only launches
+    out["inprocess"] = children.run_child(
+        [
+            "-m", "benchmarks.http_load", "--record-inprocess",
+            str(num_nodes), platform,
+        ]
+    )
     return out
 
 
@@ -1123,7 +1177,10 @@ def record_inprocess_overhead(
 if __name__ == "__main__":
     import sys
 
-    if len(sys.argv) > 1 and sys.argv[1] == "--serve":
+    # `--serve` and `--record-inprocess` hold the chip; every other entry
+    # only launches services and must stay off JAX (children.py)
+    mode = sys.argv[1] if len(sys.argv) > 1 else ""
+    if mode == "--serve":
         _serve_forever(
             int(sys.argv[2]),
             sys.argv[3] == "1",
@@ -1133,20 +1190,35 @@ if __name__ == "__main__":
             ),
             forecast=(sys.argv[6] == "1" if len(sys.argv) > 6 else False),
             flight=(sys.argv[7] == "1" if len(sys.argv) > 7 else False),
+            platform=sys.argv[8] if len(sys.argv) > 8 else "tpu",
         )
-    elif len(sys.argv) > 1 and sys.argv[1] == "--record":
+    elif mode == "--record-inprocess":
+        identity = children.hold_chip(
+            "record_inprocess_overhead",
+            sys.argv[3] if len(sys.argv) > 3 else "tpu",
+        )
+        result = record_inprocess_overhead(int(sys.argv[2]))
+        result["platform"] = identity["platform"]
+        print(json.dumps(result))
+    elif mode == "--floor":
         nodes = int(sys.argv[2]) if len(sys.argv) > 2 else 10_000
-        print(json.dumps(record_overhead(num_nodes=nodes), indent=2))
-    elif len(sys.argv) > 1 and sys.argv[1] == "--decisions":
-        nodes = int(sys.argv[2]) if len(sys.argv) > 2 else 10_000
-        print(json.dumps(decision_overhead(num_nodes=nodes), indent=2))
-    elif len(sys.argv) > 1 and sys.argv[1] == "--scaling":
-        nodes = int(sys.argv[2]) if len(sys.argv) > 2 else 2_000
-        print(json.dumps(serving_scaling(num_nodes=nodes), indent=2))
-    elif len(sys.argv) > 1 and sys.argv[1] == "--floor":
-        nodes = int(sys.argv[2]) if len(sys.argv) > 2 else 10_000
-        print(json.dumps(filter_floor_breakdown(nodes), indent=2))
+        identity = children.hold_chip("filter_floor_breakdown")
+        result = filter_floor_breakdown(nodes)
+        result["platform"] = identity["platform"]
+        print(json.dumps(result, indent=2))
     else:
-        nodes = int(sys.argv[1]) if len(sys.argv) > 1 else 10_000
-        result = run(num_nodes=nodes)
+        entries = {
+            "--record": (record_overhead, 10_000),
+            "--decisions": (decision_overhead, 10_000),
+            "--scaling": (serving_scaling, 2_000),
+            "--http-floor": (http_floor, 10_000),
+        }
+        if mode in entries:
+            fn, nodes = entries[mode]
+            if len(sys.argv) > 2:
+                nodes = int(sys.argv[2])
+        else:
+            fn, nodes = run, int(mode) if mode else 10_000
+        result = fn(num_nodes=nodes)
+        children.assert_launcher(f"benchmarks.http_load {mode}".strip())
         print(json.dumps(result, indent=2))

@@ -394,6 +394,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser.add_argument("--no-overhead", action="store_true",
                         help="skip the instrumented-vs-off pin")
     args = parser.parse_args(argv)
+    from benchmarks import children
+
+    identity = children.hold_chip("benchmarks.perf_ledger")
     if args.write:
         measurement = measure(num_nodes=args.nodes)
         anchor = write_anchor(measurement)
@@ -402,6 +405,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     out = report(
         num_nodes=args.nodes, include_overhead=not args.no_overhead
     )
+    out["platform"] = identity["platform"]
     print(json.dumps(out, indent=2))
     if args.strict and out["flagged"]:
         return 1

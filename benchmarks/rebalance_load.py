@@ -258,7 +258,11 @@ def run(
 
 
 def main() -> None:
+    from benchmarks import children
+
+    identity = children.hold_chip("benchmarks.rebalance_load")
     result = run()
+    result["platform"] = identity["platform"]
     active, label_only = result["active"], result["label_only"]
     print(
         f"rebalance: active converged in {active['cycles_to_zero']} cycles "

@@ -126,20 +126,29 @@ def _serve_main(role: str, num_nodes: int, partitions: int, index: int):
     block (the http_load protocol — each owner gets its own process and
     GIL so aggregate rps measures real parallelism, not thread
     interleaving)."""
+    from benchmarks import children
     from platform_aware_scheduling_tpu.utils import devicewatch
     from platform_aware_scheduling_tpu.utils.gctuning import tune_for_serving
 
+    identity = children.hold_chip(f"shard service {role}/{index}")
     devicewatch.install_cost_hooks()
     server, _ = build_shard_service(
         num_nodes, partitions, None if role == "full" else index
     )
     tune_for_serving()
-    print(f"READY {server.port}", flush=True)
+    print(f"READY {server.port} {identity['platform']}", flush=True)
     threading.Event().wait()
 
 
-def _spawn(role: str, num_nodes: int, partitions: int, index: int):
-    """(process, port) for one isolated service subprocess."""
+def _spawn(role: str, num_nodes: int, partitions: int, index: int, chip: int):
+    """(process, port) for one isolated service subprocess pinned to chip
+    ``chip`` of this host (libtpu's one-process-per-chip environment) —
+    a service that cannot get its chip exits instead of serving from the
+    CPU."""
+    env = dict(os.environ)
+    env["TPU_VISIBLE_CHIPS"] = str(chip)
+    env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+    env["TPU_PROCESS_BOUNDS"] = "1,1,1"
     proc = subprocess.Popen(
         [
             sys.executable,
@@ -153,6 +162,7 @@ def _spawn(role: str, num_nodes: int, partitions: int, index: int):
         ],
         stdout=subprocess.PIPE,
         text=True,
+        env=env,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )
     line = proc.stdout.readline().strip()
@@ -189,9 +199,28 @@ def run(
     concurrency: int = CONCURRENCY,
 ) -> Dict:
     """The multi-process shard tier: 1 full-world replica vs
-    ``partitions`` partition-owner subprocesses at ``num_nodes``."""
+    ``partitions`` partition-owner subprocesses at ``num_nodes``.  All
+    ``partitions + 1`` services compute on a device at the same moment,
+    so the host needs that many chips; on fewer this reports ``not_run``
+    — it never puts an owner on the CPU."""
+    from benchmarks import children
     from benchmarks.http_load import _PATHS, drive, make_bodies, node_names
     from platform_aware_scheduling_tpu.shard.partition import PartitionMap
+
+    identity = children.probe_devices()
+    needed = partitions + 1
+    if identity["platform"] != "tpu" or identity["count"] < needed:
+        return {
+            "bench": "shard_load",
+            "num_nodes": num_nodes,
+            "partitions": partitions,
+            "platform": identity["platform"],
+            "not_run": (
+                f"needs {needed} device processes at once, one chip each; "
+                f"this host has {identity['count']} x "
+                f"{identity['platform']} ({identity['kind']})"
+            ),
+        }
 
     names = node_names(num_nodes)
     # the parent computes each owner's slice with the same pure math the
@@ -201,11 +230,13 @@ def run(
     path = _PATHS["filter"]
     procs: List[subprocess.Popen] = []
     try:
-        base_proc, base_port = _spawn("full", num_nodes, partitions, -1)
+        base_proc, base_port = _spawn(
+            "full", num_nodes, partitions, -1, chip=partitions
+        )
         procs.append(base_proc)
         owners = []
         for p in range(partitions):
-            proc, port = _spawn("owner", num_nodes, partitions, p)
+            proc, port = _spawn("owner", num_nodes, partitions, p, chip=p)
             procs.append(proc)
             owners.append((p, port))
 
@@ -319,6 +350,7 @@ def run(
         "bench": "shard_load",
         "num_nodes": num_nodes,
         "partitions": partitions,
+        "platform": identity["platform"],
         "refresh_passes": REFRESH_PASSES,
         "baseline": {
             **baseline,
@@ -349,6 +381,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     partitions = int(argv[argv.index("--partitions") + 1]) \
         if "--partitions" in argv else PARTITIONS
     out = run(num_nodes=num_nodes, partitions=partitions)
+    if "not_run" in out:
+        print(f"shard: NOT RUN — {out['not_run']}", file=sys.stderr)
+        print(json.dumps(out, sort_keys=True))
+        return 1
     print(
         f"shard: {out['partitions']} owners @ {out['num_nodes']} nodes — "
         f"aggregate filter {out['aggregate_requests_per_s']} rps vs "

@@ -164,7 +164,11 @@ def run(
 
 
 def main() -> int:
+    from benchmarks import children
+
+    identity = children.hold_chip("benchmarks.twin_load")
     result = run()
+    result["platform"] = identity["platform"]
     compact = {
         name: ("pass" if entry["passed"] else f"FAIL {entry.get('failing')}")
         for name, entry in result["matrix"].items()

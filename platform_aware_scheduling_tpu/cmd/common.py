@@ -13,7 +13,7 @@ import argparse
 import threading
 from typing import Optional
 
-from platform_aware_scheduling_tpu.utils import devicewatch, klog
+from platform_aware_scheduling_tpu.utils import backend, devicewatch, klog
 
 
 def add_profile_flag(parser: argparse.ArgumentParser) -> None:
@@ -882,11 +882,24 @@ def maybe_start_profiler(port: int) -> bool:
         return False
 
 
-def install_cost_visibility() -> None:
-    """Install the one-shot per-kernel cost-analysis capture
-    (utils/devicewatch.py).  Call BEFORE assembly — the capture hangs
-    off each watched kernel's FIRST compile, which assembly's warm pass
-    triggers."""
+def prepare_device_runtime() -> None:
+    """Everything a service main does about the device BEFORE assembly
+    (whose warm pass runs the first compiles): place the persistent
+    compile cache (utils/backend.py — JAX_COMPILATION_CACHE_DIR when set,
+    else the fixed in-checkout path), log and export which device and
+    which wire path this replica actually runs on, and install the
+    one-shot per-kernel cost-analysis capture (utils/devicewatch.py),
+    which hangs off each watched kernel's FIRST compile."""
+    from platform_aware_scheduling_tpu.native import wirec_origin
+
+    cache_dir = backend.enable_compile_cache()
+    backend.export_device_identity()
+    origin = wirec_origin()
+    klog.v(1).info_s(
+        f"compile cache: {cache_dir}; wire path: "
+        + (f"native _wirec ({origin})" if origin else "pure Python"),
+        component="extender",
+    )
     devicewatch.install_cost_hooks()
 
 

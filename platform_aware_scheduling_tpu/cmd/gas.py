@@ -82,9 +82,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     kube_client = common.wrap_kube_client(
         get_kube_client(args.kubeConfig), retry_policy, breakers
     )
-    # before the extender warms its device binpack kernels (cost capture
-    # rides each kernel's first compile)
-    common.install_cost_visibility()
+    # before the extender warms its device binpack kernels: compile
+    # cache, device identity, cost capture (rides each first compile)
+    common.prepare_device_runtime()
     extender = GASExtender(kube_client, retry_policy=retry_policy)
     # admission plane (--admission=on): queue-only here — no gang
     # tracker, so backfill runs size-only and preemption never attaches

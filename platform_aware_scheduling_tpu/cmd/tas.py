@@ -333,9 +333,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     # riding the fault-tolerant client built above
     leadership = common.build_lease_elector(args, kube_client)
     gang_journal = common.build_gang_journal(args, kube_client, breakers)
-    # cost-analysis capture hangs off each kernel's FIRST compile, which
-    # assemble's warm pass triggers — install before assembly
-    common.install_cost_visibility()
+    # compile cache, device identity and the cost capture all precede
+    # the first compile, which assemble's warm pass triggers
+    common.prepare_device_runtime()
     gang_tracker = common.build_gang_tracker(args, kube_client)
     cache, mirror, extender, controller, _, stop = assemble(
         kube_client,

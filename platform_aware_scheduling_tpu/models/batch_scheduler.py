@@ -20,7 +20,8 @@ hand-written collective forms live in parallel/sharded.py.
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from functools import partial
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +32,7 @@ from platform_aware_scheduling_tpu.ops.assign import (
     auction_assign_kernel,
     greedy_assign_kernel,
 )
+from platform_aware_scheduling_tpu.ops.pallas_assign import greedy_assign_pallas
 from platform_aware_scheduling_tpu.ops.rules import (
     OP_GREATER_THAN,
     OP_LESS_THAN,
@@ -95,29 +97,61 @@ def score_and_filter(state: ClusterState, pods: PendingPods):
     return violating, score, eligible
 
 
-@jax.jit
-def scheduling_step(state: ClusterState, pods: PendingPods) -> ScheduleOutput:
-    """One full solve over the pending set."""
-    violating, score, eligible = score_and_filter(state, pods)
-    # All three assignment kernels are exact greedy-in-order.  Measured on
-    # v5e at 1k x 10k: the Pallas kernel (~6 ms; capacity resident in VMEM,
-    # one launch) beats the XLA scan (~12 ms; P dispatch-bound steps), which
-    # beats the auction under heavy contention (62 rounds, ~36 ms — though
-    # auction wins when pods' rankings are mostly distinct).  Pallas lowers
-    # only on TPU; elsewhere the scan runs.
-    # (single-chip only: a hand-written pallas_call does not auto-partition
-    # under GSPMD — the multi-chip path uses the scan / parallel/sharded.py)
-    if jax.default_backend() == "tpu" and jax.device_count() == 1:
-        from platform_aware_scheduling_tpu.ops.pallas_assign import (
-            greedy_assign_pallas,
-        )
+ASSIGNER_PALLAS = "pallas"
+ASSIGNER_SCAN = "scan"
+#: the one platform the hand-written Pallas (Mosaic) kernel lowers on
+PALLAS_PLATFORM = "tpu"
 
+
+def choose_assigner(*operands) -> str:
+    """Which exact greedy assigner a solve over ``operands`` runs, decided
+    from the operands themselves: the Pallas kernel when every array sits
+    on ONE TPU device, else the XLA scan.  A hand-written pallas_call
+    lowers only on TPU and does not auto-partition under GSPMD, so
+    mesh-sharded operands take the scan (or parallel/sharded.py) — and an
+    unsharded solve keeps the Pallas kernel however many chips the host
+    exposes.  Operands seen as tracers (a caller's own jit) carry no
+    placement: the scan runs unless the caller passes the choice it made
+    from its concrete inputs."""
+    devices = set()
+    for leaf in jax.tree.leaves(operands):
+        if isinstance(leaf, jax.core.Tracer):
+            return ASSIGNER_SCAN
+        if isinstance(leaf, jax.Array):
+            devices |= leaf.devices()
+    if not devices:  # host operands land on the default device
+        devices = {jax.devices()[0]}
+    if len(devices) == 1 and next(iter(devices)).platform == PALLAS_PLATFORM:
+        return ASSIGNER_PALLAS
+    return ASSIGNER_SCAN
+
+
+@partial(jax.jit, static_argnames=("assigner",))
+def _scheduling_step(
+    state: ClusterState, pods: PendingPods, assigner: str
+) -> ScheduleOutput:
+    violating, score, eligible = score_and_filter(state, pods)
+    # Both assigners are exact greedy-in-order and return identical
+    # results: the Pallas kernel keeps capacity resident in VMEM for one
+    # launch, the scan pays P dispatch-bound steps.
+    if assigner == ASSIGNER_PALLAS:
         assignment = greedy_assign_pallas(score, eligible, state.capacity)
     else:
         assignment = greedy_assign_kernel(score, eligible, state.capacity)
     return ScheduleOutput(
         assignment=assignment, violating=violating, score=score, eligible=eligible
     )
+
+
+def scheduling_step(
+    state: ClusterState, pods: PendingPods, assigner: Optional[str] = None
+) -> ScheduleOutput:
+    """One full solve over the pending set.  ``assigner`` defaults to
+    :func:`choose_assigner` over the operands; a caller that traces this
+    inside its own program passes the value it chose outside the trace."""
+    if assigner is None:
+        assigner = choose_assigner(state, pods)
+    return _scheduling_step(state, pods, assigner=assigner)
 
 
 def observed_scheduling_step(
@@ -140,10 +174,10 @@ def observed_scheduling_step(
         if obs is None:
             return scheduling_step(state, pods)
         timer = obs.begin("batch_solve")
-    before = scheduling_step._cache_size()
+    before = _scheduling_step._cache_size()
     out = scheduling_step(state, pods)
     timer.mark(
-        "compile" if scheduling_step._cache_size() > before else "execute"
+        "compile" if _scheduling_step._cache_size() > before else "execute"
     )
     jax.block_until_ready(out.assignment.node_for_pod)
     timer.mark("execute")

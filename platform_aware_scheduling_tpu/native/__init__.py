@@ -33,6 +33,17 @@ _SRC = os.path.join(_DIR, "wirec.c")
 _lock = threading.Lock()
 _loaded = False
 _module = None
+#: how _module got here: "built" (compiled by this process), "loaded" (an
+#: artifact for this exact source was already on disk), "override"
+#: (PAS_TPU_WIREC_SO); None while unavailable or not yet asked for
+_origin = None
+
+
+def wirec_origin():
+    """``"built"`` / ``"loaded"`` / ``"override"`` for the module
+    :func:`get_wirec` serves, or None when the wire path is pure Python —
+    the one signal that tells a native deployment from a degraded one."""
+    return _origin if get_wirec() is not None else None
 
 
 def _so_path() -> str:
@@ -112,7 +123,7 @@ def get_wirec(allow_build: bool = True):
 
     Set ``PAS_TPU_NO_NATIVE=1`` to force the pure-Python paths (used by the
     test matrix to keep both variants covered)."""
-    global _loaded, _module
+    global _loaded, _module, _origin
     if os.environ.get("PAS_TPU_NO_NATIVE") == "1":
         return None
     if _loaded:
@@ -135,6 +146,7 @@ def get_wirec(allow_build: bool = True):
             spec.loader.exec_module(module)
             _loaded = True
             _module = module
+            _origin = "override"
             return _module
         try:
             so = _so_path()
@@ -142,7 +154,8 @@ def get_wirec(allow_build: bool = True):
             _loaded = True
             _module = None
             return None
-        if not os.path.exists(so) and (not allow_build or not _build(so)):
+        prebuilt = os.path.exists(so)
+        if not prebuilt and (not allow_build or not _build(so)):
             _loaded = True
             _module = None
             return None
@@ -154,4 +167,6 @@ def get_wirec(allow_build: bool = True):
             module = None
         _loaded = True
         _module = module
+        if module is not None:
+            _origin = "loaded" if prebuilt else "built"
         return _module

@@ -21,10 +21,13 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from platform_aware_scheduling_tpu.ops import i64
 
-NO_CARD = jnp.int32(-1)
+# a host scalar: a jnp value here would initialize the JAX backend — and
+# take the chip — in every process that merely imports this module
+NO_CARD = np.int32(-1)
 
 
 class BinpackRequest(NamedTuple):

@@ -21,18 +21,11 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from platform_aware_scheduling_tpu.ops import i64
 from platform_aware_scheduling_tpu.ops.assign import AssignResult
-
-try:  # pallas is TPU/Mosaic; interpret mode covers CPU tests
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
 
 LANE = 128
 NEG_INF_I32 = -(2**31)  # python int: jnp constants may not be captured by kernels

@@ -27,17 +27,12 @@ Four building blocks, each the multi-chip form of an ops/ kernel:
 
 from __future__ import annotations
 
-import inspect
 from functools import partial
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 ships it under experimental only
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from platform_aware_scheduling_tpu.ops import i64
@@ -50,13 +45,6 @@ from platform_aware_scheduling_tpu.ops.rules import (
 )
 from platform_aware_scheduling_tpu.parallel.mesh import NODE_AXIS, POD_AXIS
 
-# "skip the static replication/varying-axes check" spells check_vma in
-# current jax and check_rep before the rename — resolve once at import
-_SHARD_MAP_NOCHECK = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(shard_map).parameters
-    else {"check_rep": False}
-)
 
 
 def sharded_violations(mesh: Mesh, metric_values: i64.I64, metric_present, rules: RuleSet):
@@ -181,8 +169,7 @@ def sharded_prioritize_ring(mesh: Mesh, value: i64.I64, valid, op_id):
             return (blk_hi, blk_lo, blk_tie, counts), None
 
         # node-varying zeros derived from a sharded value (tie_loc) so the
-        # scan carry rep matches on every jax version; current jax would
-        # spell this lax.pcast(..., to="varying"), older jax has no pcast
+        # scan carry's varying axes match what the body produces
         zero_counts = tie_loc * jnp.int32(0)
         init = (key_loc.hi, key_loc.lo, tie_loc, zero_counts)
         (_, _, _, ranks), _ = jax.lax.scan(hop, init, None, length=n_shards)
@@ -242,7 +229,7 @@ def sharded_greedy_assign(
         # `assigned` is replicated by construction (every chip replays the
         # same decision from the same gathered candidates); the static
         # varying-axes check can't see that
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )
     def _impl(s, elig, cap):
         n_loc = cap.shape[-1]
@@ -391,7 +378,7 @@ def sharded_auction_assign(
         out_specs=(P(), P(NODE_AXIS)),
         # choice is replicated by construction (every chip reduces the
         # same gathered candidates); the static check can't see that
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )
     def _impl(s, elig, cap):
         n_loc = cap.shape[-1]
@@ -550,14 +537,12 @@ def sharded_sinkhorn_assign(
 
         # log_v is per-node (varying over the shard axis); log_u is built
         # from psums and stays replicated
-        # derive both zero carries from already-collective values so every
-        # jax version's replication tracker assigns them the same rep the
-        # scan body produces: log_u from the psum-built has_eligible
-        # (replicated over both axes, like -row_lse), log_v from the
-        # node-sharded capacity (node-varying, like col_lse).  Bare
-        # jnp.zeros carries would trip the scan carry rep check on either
-        # side; newer jax spells the cast lax.pcast, older jax has no
-        # such API, multiplying by zero works on both.
+        # derive both zero carries from already-collective values so the
+        # varying-axes tracker assigns them what the scan body produces:
+        # log_u from the psum-built has_eligible (replicated over both
+        # axes, like -row_lse), log_v from the node-sharded capacity
+        # (node-varying, like col_lse).  Bare jnp.zeros carries would trip
+        # the scan carry check on either side.
         init = (
             has_eligible.astype(jnp.float32) * jnp.float32(0.0),
             cap_f * jnp.float32(0.0),

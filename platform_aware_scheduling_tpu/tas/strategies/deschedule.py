@@ -131,6 +131,9 @@ class Strategy:
                 )
             return violating
         except Exception as exc:
+            trace.COUNTERS.inc(
+                "pas_device_path_errors_total", labels={"site": "deschedule"}
+            )
             klog.error("device deschedule failed, host fallback: %s", exc)
             return None
 

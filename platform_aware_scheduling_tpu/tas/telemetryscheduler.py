@@ -299,6 +299,10 @@ class MetricsExtender:
                     },
                 )
         except Exception as exc:  # warming must never break the writer
+            trace.COUNTERS.inc(
+                "pas_device_path_errors_total",
+                labels={"site": "warm_fastpath"},
+            )
             klog.error("fastpath warm failed: %s", exc)
 
     def warm_forecast_rankings(self) -> None:
@@ -332,6 +336,10 @@ class MetricsExtender:
                         )],
                     )
         except Exception as exc:  # warming must never break the refresher
+            trace.COUNTERS.inc(
+                "pas_device_path_errors_total",
+                labels={"site": "warm_forecast"},
+            )
             klog.error("forecast ranking warm failed: %s", exc)
 
     # -- readiness (utils/health.py) -------------------------------------------
@@ -440,6 +448,10 @@ class MetricsExtender:
             for compiled, view in filter_policies.values():
                 solves += self.fastpath.warm_violations(compiled, view)
         except Exception as exc:
+            trace.COUNTERS.inc(
+                "pas_device_path_errors_total",
+                labels={"site": "warm_batch"},
+            )
             klog.error("batch warm failed, per-request path serves: %s", exc)
         return solves
 
@@ -1067,6 +1079,10 @@ class MetricsExtender:
             # device trouble (XlaRuntimeError, OOM, ...) must never fail
             # the verb: degrade to the exact path, whose host fallback
             # owns the response — same invariant Prioritize keeps
+            trace.COUNTERS.inc(
+                "pas_device_path_errors_total",
+                labels={"site": "filter_probe"},
+            )
             klog.error("filter cache probe failed, exact path: %s", exc)
             return None
 
@@ -1755,6 +1771,10 @@ class MetricsExtender:
                 if explained is not None:
                     return explained[1]
             except Exception as exc:
+                trace.COUNTERS.inc(
+                    "pas_device_path_errors_total",
+                    labels={"site": "filter_violations"},
+                )
                 klog.error("device filter failed, host fallback: %s", exc)
         return {
             name: detail[1]

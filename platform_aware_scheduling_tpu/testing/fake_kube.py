@@ -533,6 +533,25 @@ class FakeKubeClient:
         with self._lock:
             self._metrics.setdefault(metric_name, {})[node_name] = item
 
+    def replace_node_metric(
+        self, metric_name: str, values: Dict[str, str], timestamp: str
+    ) -> None:
+        """Swap a metric's whole per-node map in ONE step: a concurrent
+        ``get_node_custom_metric`` sees the old map or the new one, never
+        a half-written refresh (chip_smoke.py rewrites 10k nodes per
+        metric while the service's own refresh loop is polling)."""
+        items = {
+            node_name: {
+                "describedObject": {"kind": "Node", "name": node_name, "apiVersion": "/v1"},
+                "metric": {"name": metric_name},
+                "timestamp": timestamp,
+                "value": value,
+            }
+            for node_name, value in values.items()
+        }
+        with self._lock:
+            self._metrics[metric_name] = items
+
     def clear_node_metric(self, metric_name: str, node_name: Optional[str] = None) -> None:
         with self._lock:
             if node_name is None:

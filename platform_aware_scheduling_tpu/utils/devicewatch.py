@@ -124,8 +124,6 @@ def capture_kernel_cost(
         with _cost_lock:
             _cost_captured.discard(name)  # a later backend may succeed
         return False
-    if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     c = counters if counters is not None else trace.COUNTERS
     labels = {"kernel": name}
     exported = False

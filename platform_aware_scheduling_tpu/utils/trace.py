@@ -86,6 +86,7 @@ declare("pas_prioritize_native_total", "counter", "Prioritize requests answered 
 declare("pas_prioritize_native_host_total", "counter", "Prioritize requests on the native wire path answered with exact host semantics (host-only policy/metric, or after a device failure).")
 declare("pas_prioritize_exact_total", "counter", "Prioritize requests served by the exact Python path.")
 declare("pas_prioritize_host_fallback_total", "counter", "Device-path failures degraded to host semantics (events; overlaps the partition counters).")
+declare("pas_device_path_errors_total", "counter", "Device-path failures caught off the Prioritize verb and served by the host path or the next pass (label: site in warm_fastpath/warm_forecast/warm_batch/filter_probe/filter_violations/deschedule).")
 declare("pas_fastpath_response_hit_total", "counter", "Prioritize response-reuse cache hits (span memcmp).")
 declare("pas_fastpath_response_miss_total", "counter", "Prioritize response-reuse cache misses.")
 declare("pas_filter_cache_hit_total", "counter", "Filter response cache hits.")
@@ -137,7 +138,8 @@ declare("pas_workqueue_done_total", "counter", "Items finished processing (label
 declare("pas_informer_relists_total", "counter", "Informer list/re-list passes started (label: informer).")
 declare("pas_informer_watch_errors_total", "counter", "Informer watch streams that broke and forced a re-list (label: informer).")
 declare("pas_informer_synced", "gauge", "1 once the informer's initial list has delivered (label: informer).")
-# device & compile visibility (utils/devicewatch.py)
+# device & compile visibility (utils/backend.py, utils/devicewatch.py)
+declare("pas_device_info", "gauge", "Devices JAX found at start-up (labels: platform, kind; value = device count) — a replica that fell back to the CPU shows platform=\"cpu\".")
 declare("pas_device_memory_in_use_bytes", "gauge", "Device memory currently allocated (label: device; absent on backends without memory_stats).")
 declare("pas_device_memory_peak_bytes", "gauge", "Peak device memory watermark (label: device).")
 declare("pas_device_memory_limit_bytes", "gauge", "Device memory ceiling (label: device).")
@@ -653,7 +655,7 @@ JIT_WATCHES: List[_JitWatch] = []
 
 def watch_jit(name: str, fn, counters: Optional[CounterSet] = None):
     """Wrap a jitted callable with the retrace shim; a callable without a
-    jit cache (older jax, plain function) passes through untouched."""
+    jit cache (a plain function) passes through untouched."""
     if not hasattr(fn, "_cache_size"):
         return fn
     watch = _JitWatch(name, fn, counters if counters is not None else COUNTERS)

@@ -7,13 +7,15 @@ from benchmarks import configs
 
 class TestConfigBenches:
     def test_config1_runs_and_reports(self):
-        out = configs.config1_single_metric(num_nodes=3)
+        out = configs.config1_single_metric(num_nodes=3, platform="cpu")
+        assert out["platform"] == "cpu"
         assert out["device_p99_ms"] > 0
         assert out["control_p99_ms"] > 0
         assert "speedup_p99" in out
 
     def test_config2_runs_and_reports(self):
         out = configs.config2_multi_metric(num_nodes=64, num_pods=8)
+        assert out["assigner"] == "scan"  # CPU operands never pick Pallas
         assert out["device_ms_per_solve"] > 0
         assert out["control_ms_per_solve"] > 0
         assert "speedup" in out

@@ -4,6 +4,10 @@ The full-scale A/B runs in bench.py on real hardware; these tests pin the
 harness itself: alias derivation from the actual sweep (the round-4 judge
 hit a KeyError driving ``concurrency_sweep=(1,)``), the repeat-spread
 field, and the >=100-request control sample.
+
+Every spawned service is told ``platform="cpu"``: the harness default is
+``"tpu"`` and a service that finds another platform than the one named
+refuses to start (benchmarks/children.py).
 """
 
 from benchmarks import http_load
@@ -19,7 +23,9 @@ class TestHttpLoadHarness:
             concurrency_sweep=(1,),
             warmup=2,
             repeats=1,
+            platform="cpu",
         )
+        assert out["platform"] == "cpu"
         assert out["speedup_p99"] > 0
         assert "speedup_p99_miss" in out
         assert "speedup_p99_filter" in out
@@ -38,6 +44,7 @@ class TestHttpLoadHarness:
             concurrency_sweep=(1,),
             warmup=1,
             repeats=2,
+            platform="cpu",
         )
         entry = out["device"]["prioritize_nodenames_c1"]
         assert len(entry["repeat_p99_ms"]) == 2
@@ -65,11 +72,17 @@ class TestHttpLoadHarness:
             "warm_verb_total_us",
             "warm_prioritize_verb_us",
             "control_filter_ms",
-            "http_floor_us",
         ):
             assert out[key] > 0, key
         # the verb includes parse + partition/encode (plus probe overhead)
         assert out["verb_total_us"] >= out["partition_encode_us"] * 0.5
+
+    def test_http_floor_small(self):
+        """The transport floor is measured from a launched service, apart
+        from the in-process breakdown above."""
+        out = http_load.http_floor(num_nodes=64, reps=5, platform="cpu")
+        assert out["http_floor_us"] > 0
+        assert out["platform"] == "cpu"
 
     def test_serving_scaling_small(self):
         """The threaded-vs-async head-to-head harness end to end at tiny
@@ -81,7 +94,9 @@ class TestHttpLoadHarness:
             warmup=2,
             repeats=1,
             concurrency_sweep=(1, 2),
+            platform="cpu",
         )
+        assert out["platform"] == "cpu"
         for mode in ("threaded", "async"):
             assert out[mode]["c1"]["p99_ms"] > 0
             assert out[mode]["c2"]["p99_ms"] > 0
@@ -100,7 +115,9 @@ class TestHttpLoadHarness:
             concurrency_sweep=(1,),
             warmup=1,
             repeats=1,
+            platform="cpu",
         )
+        assert out["platform"] == "cpu"
         assert out["speedup_p99_gas_filter"] > 0
         assert "gas_filter_c1" in out["device"]
         assert "gas_filter_c8" not in out["device"]
