@@ -26,8 +26,10 @@ One process holds the TPU, serves in threads, and drives its own sockets:
   mesh         with >= 4 devices: ``__graft_entry__.multichip_on_chips``
 
 All data comes from ``--seed``.  Any phase's failure is a non-zero exit and
-no result line.  The last stdout line of a pass is one JSON object:
-``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}, ...}``.
+no result line.  A pass ends with two stdout lines: ``[summary]`` followed
+by one JSON object (phases, assigner, seed, observations), then — last, and
+with exactly these keys, because the driver's check parses it —
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
 
 ``--rehearse-cpu`` is for debugging the script itself in a sandbox without a
 chip: the same flow at a tiny size on the CPU backend, Pallas in interpret
@@ -801,6 +803,20 @@ def phase_counters(tas, identity, observations):
 # -- main ----------------------------------------------------------------------------
 
 
+def verdict_line(ok: bool, identity: dict) -> str:
+    """The last stdout line: exactly ``ok`` and ``device`` (``platform``,
+    ``kind``, ``count`` as JAX reports them) — everything else the run
+    learned goes on the ``[summary]`` line before it."""
+    return json.dumps({
+        "ok": bool(ok),
+        "device": {
+            "platform": str(identity["platform"]),
+            "kind": str(identity["kind"]),
+            "count": int(identity["count"]),
+        },
+    })
+
+
 def cache_entries(path: str) -> int:
     try:
         return len(os.listdir(path))
@@ -939,15 +955,14 @@ def main(argv=None) -> int:
         say(f"  {key}: {json.dumps(value)}")
 
     passed = not args.rehearse_cpu
-    print(json.dumps({
-        "ok": passed,
-        **({"rehearsal": True} if args.rehearse_cpu else {}),
-        "device": identity,
+    say("[summary] " + json.dumps({
+        "rehearsal": args.rehearse_cpu,
         "phases": phases,
         "assigner": assigner,
         "seed": args.seed,
         "observations": observations,
-    }), flush=True)
+    }))
+    say(verdict_line(passed, identity))
     return 0 if passed else EXIT_REHEARSAL
 
 

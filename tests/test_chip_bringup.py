@@ -72,6 +72,25 @@ class TestNoSilentCpu:
         assert "platform='cpu'" in proc.stderr
         assert '"ok"' not in proc.stdout  # no result line of any kind
 
+    def test_verdict_line_has_exactly_the_contract_keys(self):
+        """The driver parses the smoke's last stdout line and refuses any
+        key beyond ``ok`` and ``device{platform, kind, count}`` (how this
+        PR's first submission was refused): the rest of what the run
+        learned goes on the ``[summary]`` line before it."""
+        sys.path.insert(0, REPO)
+        import chip_smoke
+
+        line = chip_smoke.verdict_line(True, backend.device_identity())
+        assert "\n" not in line
+        verdict = json.loads(line)
+        assert set(verdict) == {"ok", "device"}
+        assert verdict["ok"] is True
+        assert set(verdict["device"]) == {"platform", "kind", "count"}
+        assert verdict["device"]["platform"] == jax.devices()[0].platform
+        assert verdict["device"]["kind"] == jax.devices()[0].device_kind
+        assert verdict["device"]["count"] == len(jax.devices())
+        assert type(verdict["device"]["count"]) is int
+
     def test_require_platform_names_what_it_found(self):
         assert backend.require_platform("test", "cpu")["platform"] == "cpu"
         with pytest.raises(backend.NoAcceleratorError, match="'cpu'"):
