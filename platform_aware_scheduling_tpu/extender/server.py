@@ -323,14 +323,19 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
                 except OSError:
                     return
             # -- read the body ----------------------------------------------
-            while len(buf) < length:
-                try:
-                    chunk = sock.recv(self.rbufsize)
-                except (TimeoutError, OSError):
-                    return
-                if not chunk:
-                    return
-                buf += chunk
+            if len(buf) < length:
+                # a body that outlasts the first recv (the Nodes wire's
+                # 6 MB): annotated, so that a profiled window shows the
+                # receive; the span's read stage below covers it by hand
+                with trace.stage("read"):
+                    while len(buf) < length:
+                        try:
+                            chunk = sock.recv(self.rbufsize)
+                        except (TimeoutError, OSError):
+                            return
+                        if not chunk:
+                            return
+                        buf += chunk
             body = bytes(buf[:length])
             del buf[:length]
             # -- dispatch + respond ------------------------------------------
@@ -341,18 +346,27 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
                 method=method, path=path, headers=headers, body=body,
                 span=span,
             )
-            try:
-                response = type(self).route(request)
-            except Exception as exc:
-                klog.error("handler raised: %r", exc)
-                span.set("error", repr(exc))
-                response = HTTPResponse(status=500)
+            # read + handle + write_arm + write tile the span (handle and
+            # write_arm on sampled spans); handle contains the verb's own
+            # stages, so it is never annotated
+            with span.stage("handle", leaf=False, sampled=True):
+                try:
+                    response = type(self).route(request)
+                except Exception as exc:
+                    klog.error("handler raised: %r", exc)
+                    span.set("error", repr(exc))
+                    response = HTTPResponse(status=500)
             response.headers.setdefault("X-Request-ID", request_id)
             close = (
                 version == "HTTP/1.0"
                 or lowered.get("connection", "").lower() == "close"
             )
-            sock.settimeout(WRITE_TIMEOUT_S)
+            # arming the timeout is an ioctl: the GIL is released for it and
+            # has to be won back from whatever the verb woke (informers,
+            # the refresh thread) — a stage of its own, so that the stages
+            # still tile the span and write keeps its meaning
+            with span.stage("write_arm", sampled=True):
+                sock.settimeout(WRITE_TIMEOUT_S)
             t_write = time.perf_counter()
             try:
                 sock.sendall(render_response(response, close))
@@ -817,6 +831,7 @@ class Server:
         in p99 (the Go reference gets the equivalent from net/http's
         optimized server for free)."""
         server = self
+        trace.watch_gc()
 
         class Handler(_FastHTTPHandler):
             route = staticmethod(server.route)

@@ -33,7 +33,7 @@ from platform_aware_scheduling_tpu.ops.binpack import (
     BinpackRequest,
     binpack_kernel,
 )
-from platform_aware_scheduling_tpu.utils import decisions
+from platform_aware_scheduling_tpu.utils import decisions, trace
 
 import jax.numpy as jnp
 
@@ -181,22 +181,28 @@ class GASUsageMirror:
         with self._lock:
             return dict(self._res_index)
 
-    def snapshot(self):
+    def snapshot(self, span=trace.NULL_SPAN):
         """(device state over ALL interned rows, node_index, flags) — device
-        arrays memoized per version."""
+        arrays memoized per version; a restage is the span's
+        ``state_upload`` stage (the host copies and the eight uploads)."""
         with self._lock:
             if self._device is None or self._device[0] != self._version:
-                used_hi, used_lo = i64.split_int64_np(self._used)
-                cap_hi, cap_lo = i64.split_int64_np(self._cap)
-                state = BinpackNodeState(
-                    used=i64.I64(hi=jnp.asarray(used_hi), lo=jnp.asarray(used_lo)),
-                    capacity=i64.I64(hi=jnp.asarray(cap_hi), lo=jnp.asarray(cap_lo)),
-                    cap_present=jnp.asarray(self._cap_present.copy()),
-                    card_valid=jnp.asarray(self._card_valid.copy()),
-                    card_real=jnp.asarray(self._card_real.copy()),
-                    card_order=jnp.asarray(self._card_order.copy()),
-                )
-                self._device = (self._version, state)
+                with span.stage("state_upload"):
+                    used_hi, used_lo = i64.split_int64_np(self._used)
+                    cap_hi, cap_lo = i64.split_int64_np(self._cap)
+                    state = BinpackNodeState(
+                        used=i64.I64(
+                            hi=jnp.asarray(used_hi), lo=jnp.asarray(used_lo)
+                        ),
+                        capacity=i64.I64(
+                            hi=jnp.asarray(cap_hi), lo=jnp.asarray(cap_lo)
+                        ),
+                        cap_present=jnp.asarray(self._cap_present.copy()),
+                        card_valid=jnp.asarray(self._card_valid.copy()),
+                        card_real=jnp.asarray(self._card_real.copy()),
+                        card_order=jnp.asarray(self._card_order.copy()),
+                    )
+                    self._device = (self._version, state)
             return (
                 self._device[1],
                 dict(self._node_index),
@@ -265,6 +271,7 @@ class DeviceBinpacker:
         pod: Pod,
         node_names: Sequence[str],
         with_reasons: bool = False,
+        span=trace.NULL_SPAN,
     ) -> Optional[List[bool]]:
         """Per-node fit verdicts, or None when the pod has no per-card
         demand (the host loop decides cheaply).  With ``with_reasons``
@@ -282,7 +289,9 @@ class DeviceBinpacker:
             # the host loop decides cheaply — no point shipping tensors
             return None
         if self.mirror is not None:
-            fits, codes = self._fit_mirror(requests, shares, resources, node_names)
+            fits, codes = self._fit_mirror(
+                requests, shares, resources, node_names, span
+            )
         else:
             fits, codes = self._fit_staged(requests, shares, resources, node_names)
         return (fits, codes) if with_reasons else fits
@@ -318,12 +327,22 @@ class DeviceBinpacker:
                 del self._fits_cache[self.FITS_CACHE_SIZE:]
         return fits
 
-    def _fit_mirror(self, requests, shares, resources, node_names):
+    def _fit_mirror(
+        self, requests, shares, resources, node_names, span=trace.NULL_SPAN
+    ):
         mirror = self.mirror
-        with mirror._lock:
+        # informer deliveries restage rows under this lock: the wait is
+        # what a release or a resync costs this request
+        with span.stage("mirror_wait"):
+            mirror._lock.acquire()
+        try:
             for name in resources:  # unknown request resources: intern (all-absent)
                 mirror._intern_resource(name)
-            state, node_index, known, has_gpus, res_index = mirror.snapshot()
+            state, node_index, known, has_gpus, res_index = mirror.snapshot(
+                span
+            )
+        finally:
+            mirror._lock.release()
         max_gpus = max((k for _, k in shares), default=0)
         k_pad = _bucket(max(max_gpus, 1), MIN_GPUS)
         signature = (
@@ -335,27 +354,31 @@ class DeviceBinpacker:
 
         def compute() -> np.ndarray:
             r_pad = state.capacity.hi.shape[-1]
-            request, staged_k_pad = stage_request(
-                requests, shares, res_index, r_pad
-            )
-            return np.asarray(
-                binpack_kernel(state, request, staged_k_pad).fits
-            )
+            with span.stage("req_upload"):
+                request, staged_k_pad = stage_request(
+                    requests, shares, res_index, r_pad
+                )
+            # dispatch to readback: np.asarray is what waits for the device
+            with span.stage("solve"):
+                return np.asarray(
+                    binpack_kernel(state, request, staged_k_pad).fits
+                )
 
         fits_all = self._all_rows_fits(state, signature, compute)
-        out = [False] * len(node_names)
-        codes = [decisions.CODE_GAS_CAPACITY] * len(node_names)
-        for pos, name in enumerate(node_names):
-            row = node_index.get(name)
-            if row is None or not known[row]:
-                codes[pos] = decisions.CODE_GAS_UNKNOWN_NODE
-                continue  # pre-failed
-            if not has_gpus[row]:
-                codes[pos] = decisions.CODE_GAS_NO_GPUS
-                continue
-            out[pos] = bool(fits_all[row])
-            if out[pos]:
-                codes[pos] = decisions.CODE_ELIGIBLE
+        with span.stage("rows"):
+            out = [False] * len(node_names)
+            codes = [decisions.CODE_GAS_CAPACITY] * len(node_names)
+            for pos, name in enumerate(node_names):
+                row = node_index.get(name)
+                if row is None or not known[row]:
+                    codes[pos] = decisions.CODE_GAS_UNKNOWN_NODE
+                    continue  # pre-failed
+                if not has_gpus[row]:
+                    codes[pos] = decisions.CODE_GAS_NO_GPUS
+                    continue
+                out[pos] = bool(fits_all[row])
+                if out[pos]:
+                    codes[pos] = decisions.CODE_ELIGIBLE
         return out, codes
 
     # -- per-request staging path (control) ------------------------------------

@@ -211,7 +211,10 @@ class GASExtender:
                     None, len(args.node_names or ())
                 )
             admission_codes: Dict[str, int] = {}
-            with span.stage("kernel"):
+            # kernel contains lock_wait, mirror_wait, state_upload,
+            # req_upload, solve, rows and verdict: a container, so never
+            # annotated
+            with span.stage("kernel", leaf=False):
                 result = self._filter_nodes(
                     args, span=span, codes_out=admission_codes
                 )
@@ -261,8 +264,9 @@ class GASExtender:
                 klog.error("cannot decode request %s", exc)
             if args is None:
                 return HTTPResponse(status=404)
-            with span.stage("kernel"):
-                result = self._bind_node(args)
+            # kernel contains pod_get, lock_wait, book, api_write, record
+            with span.stage("kernel", leaf=False):
+                result = self._bind_node(args, span=span)
             status = 404 if result.error else 200
             with span.stage("encode"):
                 body = result.to_json()
@@ -299,34 +303,45 @@ class GASExtender:
             klog.error(error)
             return FilterResult(error=error)
         summary = request_summary(args.pod)
-        with self._rwmutex:
+        # the wait for the verbs' mutex (the same stage on Bind), apart
+        # from what is done under it: acquired by hand so that the stage
+        # ends where the lock is held
+        with span.stage("lock_wait"):
+            self._rwmutex.acquire()
+        try:
             if self._device is not None:
                 try:
                     res = self._device.batch_fit(
-                        args.pod, args.node_names, with_reasons=True
+                        args.pod, args.node_names, with_reasons=True,
+                        span=span,
                     )
                 except Exception as exc:
                     klog.error("device binpack failed, host fallback: %s", exc)
                     res = None
                 if res is not None:
-                    fits, codes = res
-                    span.set("path", "device")
-                    trace.COUNTERS.inc("pas_gas_filter_device_total")
-                    node_names = [n for n, ok in zip(args.node_names, fits) if ok]
-                    failed = {
-                        n: decisions.gas_reason(code, summary)
-                        for n, ok, code in zip(args.node_names, fits, codes)
-                        if not ok
-                    }
-                    if codes_out is not None:
-                        for n, ok, code in zip(
-                            args.node_names, fits, codes
-                        ):
-                            if not ok:
-                                codes_out[n] = code
-                    self._record_filter_decision(
-                        span, args.pod, args.node_names, failed, codes
-                    )
+                    with span.stage("verdict"):
+                        fits, codes = res
+                        span.set("path", "device")
+                        trace.COUNTERS.inc("pas_gas_filter_device_total")
+                        node_names = [
+                            n for n, ok in zip(args.node_names, fits) if ok
+                        ]
+                        failed = {
+                            n: decisions.gas_reason(code, summary)
+                            for n, ok, code in zip(
+                                args.node_names, fits, codes
+                            )
+                            if not ok
+                        }
+                        if codes_out is not None:
+                            for n, ok, code in zip(
+                                args.node_names, fits, codes
+                            ):
+                                if not ok:
+                                    codes_out[n] = code
+                        self._record_filter_decision(
+                            span, args.pod, args.node_names, failed, codes
+                        )
                     return FilterResult(
                         node_names=node_names, failed_nodes=failed, error=""
                     )
@@ -362,6 +377,8 @@ class GASExtender:
                 span, args.pod, args.node_names, failed, codes
             )
             return FilterResult(node_names=node_names, failed_nodes=failed, error="")
+        finally:
+            self._rwmutex.release()
 
     def _admission_review(
         self,
@@ -491,34 +508,43 @@ class GASExtender:
 
     # -- bind (scheduler.go:385-445) --------------------------------------------
 
-    def _bind_node(self, args: BindingArgs) -> BindingResult:
+    def _bind_node(
+        self, args: BindingArgs, span=trace.NULL_SPAN
+    ) -> BindingResult:
         try:
-            pod = self.cache.fetch_pod(args.pod_namespace, args.pod_name)
+            with span.stage("pod_get"):
+                pod = self.cache.fetch_pod(args.pod_namespace, args.pod_name)
         except Exception as exc:
             klog.warning("Pod %s couldn't be read or pod vanished", args.pod_name)
             return BindingResult(error=str(exc))
-        with self._rwmutex:
+        with span.stage("lock_wait"):
+            self._rwmutex.acquire()
+        try:
             resources_adjusted = False
             annotation = ""
             try:
-                annotation = self._run_scheduling_logic(pod, args.node)
-                self.cache.adjust_pod_resources_locked(
-                    pod, ADD, annotation, args.node
-                )
+                with span.stage("book"):
+                    annotation = self._run_scheduling_logic(pod, args.node)
+                    self.cache.adjust_pod_resources_locked(
+                        pod, ADD, annotation, args.node
+                    )
                 resources_adjusted = True
-                self._annotate_pod_bind(annotation, pod)
-                self.kube_client.bind_pod(
-                    args.pod_namespace, args.pod_name, args.pod_uid, args.node
-                )
+                with span.stage("api_write"):
+                    self._annotate_pod_bind(annotation, pod)
+                    self.kube_client.bind_pod(
+                        args.pod_namespace, args.pod_name, args.pod_uid,
+                        args.node,
+                    )
                 # outcome feedback: the successful bind closes this pod's
                 # open gas_filter decision records (utils/decisions.py)
-                decisions.DECISIONS.observe_bind(
-                    args.pod_namespace, args.pod_name, args.node
-                )
-                if self.admission is not None:
-                    self.admission.observe_bind(
-                        args.pod_namespace, args.pod_name
+                with span.stage("record"):
+                    decisions.DECISIONS.observe_bind(
+                        args.pod_namespace, args.pod_name, args.node
                     )
+                    if self.admission is not None:
+                        self.admission.observe_bind(
+                            args.pod_namespace, args.pod_name
+                        )
                 return BindingResult()
             except Exception as exc:
                 klog.error("binding failed: %s", exc)
@@ -531,6 +557,8 @@ class GASExtender:
                     except Exception as rollback_exc:
                         klog.error("rollback failed: %s", rollback_exc)
                 return BindingResult(error=str(exc))
+        finally:
+            self._rwmutex.release()
 
     def _annotate_pod_bind(self, annotation: str, pod: Pod) -> None:
         """Write gas-ts + gas-container-cards with a conflict-retry loop

@@ -203,14 +203,17 @@ class Informer:
     def _resync_once(self) -> None:
         with self._store_lock:
             keys = list(self._store.keys())
-        for key in keys:
-            with self._dispatch_lock:
-                with self._store_lock:
-                    current = self._store.get(key)
-                if current is None:
-                    continue
-                if self._passes(current):
-                    self._on_update(current, current)
+        # one annotation for the whole delivery: a profiled window shows
+        # which requests ran beside a resync (utils/trace.py)
+        with trace.stage("inf.sync"):
+            for key in keys:
+                with self._dispatch_lock:
+                    with self._store_lock:
+                        current = self._store.get(key)
+                    if current is None:
+                        continue
+                    if self._passes(current):
+                        self._on_update(current, current)
 
     def _backoff(self) -> float:
         """Delay before the next relist after a watch/list failure."""

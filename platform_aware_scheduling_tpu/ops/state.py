@@ -29,6 +29,7 @@ import numpy as np
 from platform_aware_scheduling_tpu.ops import i64, solveobs
 from platform_aware_scheduling_tpu.ops.rules import OP_IDS, RuleSet
 from platform_aware_scheduling_tpu.tas.policy.v1alpha1 import TASPolicy
+from platform_aware_scheduling_tpu.utils import trace
 
 MIN_NODE_CAPACITY = 64
 MIN_METRIC_CAPACITY = 8
@@ -396,7 +397,10 @@ class TensorStateMirror:
     def on_metric_write(self, metric_name: str, info) -> None:
         """info: NodeMetricsInfo (node -> NodeMetric) or None (registration
         only, autoupdating.go:105-122)."""
-        changed = self._metric_write_locked(metric_name, info)
+        # the mirror's part of a refresh pass's publish, apart from the
+        # warm that _notify sets off (tas/cache.py sums the seconds)
+        with trace.stage("rf.publish"):
+            changed = self._metric_write_locked(metric_name, info)
         if changed:
             self._notify()
 

@@ -190,6 +190,7 @@ class AsyncServer:
         """Same contract as ``Server.start_server``: plain HTTP when
         ``unsafe``, pinned mTLS otherwise; ``block=False`` serves on a
         daemon thread (startup failures re-raise in the caller)."""
+        trace.watch_gc()
         ssl_context = None
         if not unsafe:
             ssl_context = configure_secure_context(cert_file, key_file, ca_file)
@@ -327,32 +328,36 @@ class AsyncServer:
                     span=span,
                 )
                 bare_path = path.partition("?")[0]
-                if bare_path in QUEUE_BYPASS_PATHS:
-                    # observability endpoints bypass the admission queue:
-                    # they must stay readable precisely when the queue is
-                    # saturated (the condition they exist to diagnose),
-                    # and they never touch the device.  The set derives
-                    # from the DEBUG_ENDPOINTS index (extender/server.py)
-                    # so a new debug route cannot silently queue here
-                    try:
-                        response = self._router.route(request)
-                    except Exception as exc:
-                        klog.error("handler raised: %r", exc)
-                        response = HTTPResponse(status=500)
-                elif bare_path in EXECUTOR_DEBUG_PATHS:
-                    # also bypass the queue, but these BLOCK: the
-                    # bounded profile capture sleeps for its window and
-                    # a what-if runs a whole twin replay — run them
-                    # off-loop so the event loop keeps serving meanwhile
-                    try:
-                        response = await asyncio.get_running_loop().run_in_executor(
-                            None, self._router.route, request
-                        )
-                    except Exception as exc:
-                        klog.error("handler raised: %r", exc)
-                        response = HTTPResponse(status=500)
-                else:
-                    response = await self.dispatcher.submit(request)
+                # read + handle + write tile the span (handle on sampled
+                # spans); queue_wait, coalesce and the verb's stages lie
+                # inside it, so it is a container and never annotated
+                with span.stage("handle", leaf=False, sampled=True):
+                    if bare_path in QUEUE_BYPASS_PATHS:
+                        # observability endpoints bypass the admission queue:
+                        # they must stay readable precisely when the queue is
+                        # saturated (the condition they exist to diagnose),
+                        # and they never touch the device.  The set derives
+                        # from the DEBUG_ENDPOINTS index (extender/server.py)
+                        # so a new debug route cannot silently queue here
+                        try:
+                            response = self._router.route(request)
+                        except Exception as exc:
+                            klog.error("handler raised: %r", exc)
+                            response = HTTPResponse(status=500)
+                    elif bare_path in EXECUTOR_DEBUG_PATHS:
+                        # also bypass the queue, but these BLOCK: the
+                        # bounded profile capture sleeps for its window and
+                        # a what-if runs a whole twin replay — run them
+                        # off-loop so the event loop keeps serving meanwhile
+                        try:
+                            response = await asyncio.get_running_loop().run_in_executor(
+                                None, self._router.route, request
+                            )
+                        except Exception as exc:
+                            klog.error("handler raised: %r", exc)
+                            response = HTTPResponse(status=500)
+                    else:
+                        response = await self.dispatcher.submit(request)
                 # every response carries the id — INCLUDING the 503
                 # backpressure rejection the dispatcher answers directly
                 response.headers.setdefault("X-Request-ID", request_id)

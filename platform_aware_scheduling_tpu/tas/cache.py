@@ -260,6 +260,18 @@ class AutoUpdatingCache:
             return [name for name in self._metric_refcounts if name]
 
     def update_all_metrics(self, client: Client) -> None:
+        """One refresh pass.  A pass is no request, so it lands on no
+        span: its seconds go to the four ``pas_refresh_*_seconds_total``
+        counters (fetch + publish + warm <= pass)."""
+        began = time.perf_counter()
+        try:
+            self._refresh_pass(client)
+        finally:
+            self.counters.inc(
+                "pas_refresh_pass_seconds_total", time.perf_counter() - began
+            )
+
+    def _refresh_pass(self, client: Client) -> None:
         with self._mtx:
             names = list(self._metric_refcounts)
         errors: Dict[str, int] = {}  # reason -> count
@@ -420,10 +432,25 @@ class AutoUpdatingCache:
         return max(3.0 * period, 1.0)
 
     def _update_metric(self, client: Client, metric_name: str) -> None:
-        info = client.get_node_metric(metric_name)
-        if self.refresh_filter is not None and info:
-            info = self.refresh_filter(info)
-        self.write_metric(metric_name, info)
+        with trace.stage(
+            "rf.fetch", "pas_refresh_fetch_seconds_total", self.counters
+        ):
+            info = client.get_node_metric(metric_name)
+            if self.refresh_filter is not None and info:
+                info = self.refresh_filter(info)
+        # publish is write_metric through the mirror's publish, less the
+        # warm that publish triggers (warm_fastpath times itself, into the
+        # process-wide set; the mirror annotates its own part rf.publish)
+        warmed = trace.COUNTERS.get("pas_refresh_warm_seconds_total")
+        began = time.perf_counter()
+        try:
+            self.write_metric(metric_name, info)
+        finally:
+            took = time.perf_counter() - began
+            warmed = trace.COUNTERS.get("pas_refresh_warm_seconds_total") - warmed
+            self.counters.inc(
+                "pas_refresh_publish_seconds_total", max(took - warmed, 0.0)
+            )
 
     def periodic_update(
         self,
@@ -440,7 +467,10 @@ class AutoUpdatingCache:
         stop = stop or threading.Event()
         while not stop.is_set():
             self.update_all_metrics(client)
-            stop.wait(period_seconds)
+            # idle by design: a profiled gap that reads rf.wait is the
+            # sync period, not something the host made
+            with trace.stage("rf.wait"):
+                stop.wait(period_seconds)
 
     def start_periodic_update(
         self,

@@ -574,7 +574,7 @@ class PrioritizeFastPath:
         planned_row = -1
         if planned is not None:
             planned_row = table.node_index.get(planned, -1)
-        with self._lock:
+        with span.stage("lookup", sampled=True), self._lock:
             if universe is not None:
                 skeletons = self._prioritize_skeletons
                 for idx, entry in enumerate(skeletons):

@@ -239,6 +239,10 @@ class MetricsExtender:
         fastpath = self.fastpath
         if fastpath is None:
             return
+        with trace.stage("rf.warm", "pas_refresh_warm_seconds_total"):
+            self._warm_fastpath(fastpath)
+
+    def _warm_fastpath(self, fastpath) -> None:
         obs = solveobs.ACTIVE
         warm_t0 = obs.clock() if obs is not None else 0.0
         try:
@@ -657,7 +661,7 @@ class MetricsExtender:
                     # set, so the native fastpath (and its ~1/P-size
                     # problem) serves sharded Filter at full speed
                     # (shard/plane.py remote_holds_possible)
-                    with span.stage("cache_probe"):
+                    with span.stage("cache_probe", leaf=False):
                         probe = self._filter_cache_probe(
                             request, gang_token
                         )
@@ -713,52 +717,56 @@ class MetricsExtender:
                 parsed, violations, use_node_names, gang_version, universe = (
                     probe
                 )
-                self.fastpath.filter_store(
-                    violations, use_node_names, parsed, body,
-                    len(result.failed_nodes), gang_version,
-                    universe=universe,
-                )
-            if decisions.DECISIONS.enabled:
-                path = span.attrs.get("filter_cache", "exact")
-                reason_code = decisions.CODE_RULE_VIOLATION
-                if degraded_action == degraded_mode.ACTION_FAIL_CLOSED:
-                    path = "fail_closed"
-                    reason_code = decisions.CODE_FAIL_CLOSED
-                elif degraded_action == degraded_mode.ACTION_FAIL_OPEN:
-                    path = "fail_open"
-                candidates = self._candidate_names(args)
-                reason_counts = None
-                if gang_codes:
-                    # a gang overlay mixes reason classes in one request:
-                    # count each failed node under its own code so the
-                    # per-reason counters stay exact
-                    reason_counts = {}
-                    for name in result.failed_nodes:
-                        code = gang_codes.get(name, reason_code)
-                        reason_counts[code] = reason_counts.get(code, 0) + 1
-                decisions.DECISIONS.record_filter(
+                with span.stage("store"):
+                    self.fastpath.filter_store(
+                        violations, use_node_names, parsed, body,
+                        len(result.failed_nodes), gang_version,
+                        universe=universe,
+                    )
+            # what is left of the exact path: the always-on observers
+            # (decision record, journal event)
+            with span.stage("record"):
+                if decisions.DECISIONS.enabled:
+                    path = span.attrs.get("filter_cache", "exact")
+                    reason_code = decisions.CODE_RULE_VIOLATION
+                    if degraded_action == degraded_mode.ACTION_FAIL_CLOSED:
+                        path = "fail_closed"
+                        reason_code = decisions.CODE_FAIL_CLOSED
+                    elif degraded_action == degraded_mode.ACTION_FAIL_OPEN:
+                        path = "fail_open"
+                    candidates = self._candidate_names(args)
+                    reason_counts = None
+                    if gang_codes:
+                        # a gang overlay mixes reason classes in one request:
+                        # count each failed node under its own code so the
+                        # per-reason counters stay exact
+                        reason_counts = {}
+                        for name in result.failed_nodes:
+                            code = gang_codes.get(name, reason_code)
+                            reason_counts[code] = reason_counts.get(code, 0) + 1
+                    decisions.DECISIONS.record_filter(
+                        request_id=span.trace_id,
+                        pod_namespace=args.pod.namespace,
+                        pod_name=args.pod.name,
+                        policy=args.pod.get_labels().get(TAS_POLICY_LABEL, ""),
+                        path=path,
+                        candidates=len(candidates),
+                        filtered=len(result.failed_nodes),
+                        violating=dict(result.failed_nodes),
+                        violating_scope="request",
+                        reason_code=reason_code,
+                        reason_counts=reason_counts,
+                    )
+                events.JOURNAL.publish(
+                    "verdict",
+                    "filter",
                     request_id=span.trace_id,
-                    pod_namespace=args.pod.namespace,
-                    pod_name=args.pod.name,
-                    policy=args.pod.get_labels().get(TAS_POLICY_LABEL, ""),
-                    path=path,
-                    candidates=len(candidates),
-                    filtered=len(result.failed_nodes),
-                    violating=dict(result.failed_nodes),
-                    violating_scope="request",
-                    reason_code=reason_code,
-                    reason_counts=reason_counts,
+                    pod=f"{args.pod.namespace}/{args.pod.name}",
+                    data={
+                        "failed": len(result.failed_nodes),
+                        "path": str(span.attrs.get("filter_cache", "exact")),
+                    },
                 )
-            events.JOURNAL.publish(
-                "verdict",
-                "filter",
-                request_id=span.trace_id,
-                pod=f"{args.pod.namespace}/{args.pod.name}",
-                data={
-                    "failed": len(result.failed_nodes),
-                    "path": str(span.attrs.get("filter_cache", "exact")),
-                },
-            )
             return HTTPResponse.json(body)
         finally:
             self.recorder.observe(
@@ -962,43 +970,50 @@ class MetricsExtender:
             return None
         span = trace.of(request)
         try:
-            parsed = wirec.parse_prioritize(request.body)
-            use_node_names = False
-            if not parsed.nodes_present or parsed.num_nodes == 0:
-                if (
-                    self.node_cache_capable
-                    and parsed.node_names_present
-                    and parsed.num_node_names > 0
-                ):
-                    use_node_names = True
-                else:
+            # sampled leaves of the cache_probe container: scan, policy,
+            # intern (every span), lookup, fencode, record
+            with span.stage("scan", sampled=True):
+                parsed = wirec.parse_prioritize(request.body)
+            # from the scan to the universe probe: the policy read, its
+            # compiled form and the violation set with its reasons (on the
+            # host-only path the shortcut's intern lies inside)
+            with span.stage("policy", sampled=True):
+                use_node_names = False
+                if not parsed.nodes_present or parsed.num_nodes == 0:
+                    if (
+                        self.node_cache_capable
+                        and parsed.node_names_present
+                        and parsed.num_node_names > 0
+                    ):
+                        use_node_names = True
+                    else:
+                        return None
+                policy_name = parsed.policy_label
+                if policy_name is None:
                     return None
-            policy_name = parsed.policy_label
-            if policy_name is None:
-                return None
-            try:
-                policy = self.cache.read_policy(
-                    parsed.pod_namespace or "", policy_name
+                try:
+                    policy = self.cache.read_policy(
+                        parsed.pod_namespace or "", policy_name
+                    )
+                except Exception:
+                    return None
+                compiled, view = self._device_policy(policy)
+                if compiled is None or not self._device_filter_ok(compiled):
+                    # host-only policy: the span caches cannot serve (the
+                    # verdict is host-computed), but an interned span still
+                    # spares the exact path its full json.loads
+                    return self._host_filter_shortcut(
+                        wirec, parsed, use_node_names, span
+                    )
+                # one call resolves the violation set AND its decoded per-node
+                # provenance (the shared reason map the wire FailedNodes and
+                # the decision records both reference)
+                explained = self.fastpath.violation_reasons(
+                    compiled, view, policy.name
                 )
-            except Exception:
-                return None
-            compiled, view = self._device_policy(policy)
-            if compiled is None or not self._device_filter_ok(compiled):
-                # host-only policy: the span caches cannot serve (the
-                # verdict is host-computed), but an interned span still
-                # spares the exact path its full json.loads
-                return self._host_filter_shortcut(
-                    wirec, parsed, use_node_names, span
-                )
-            # one call resolves the violation set AND its decoded per-node
-            # provenance (the shared reason map the wire FailedNodes and
-            # the decision records both reference)
-            explained = self.fastpath.violation_reasons(
-                compiled, view, policy.name
-            )
-            if explained is None:
-                return None
-            violations, reasons, _indexes = explained
+                if explained is None:
+                    return None
+                violations, reasons, _indexes = explained
             with span.stage("intern"):
                 universe = self.fastpath.universe_probe(
                     wirec, parsed, use_node_names
@@ -1028,18 +1043,20 @@ class MetricsExtender:
                     universe.uid if universe is not None else None,
                     int(candidates),
                 )
-            cached = self.fastpath.filter_lookup(
-                violations, use_node_names, parsed, gang_version,
-                universe=universe,
-            )
+            with span.stage("lookup", sampled=True):
+                cached = self.fastpath.filter_lookup(
+                    violations, use_node_names, parsed, gang_version,
+                    universe=universe,
+                )
             if cached is not None:
                 body, n_failed = cached
-                span.set("filter_cache", "hit")
-                trace.COUNTERS.inc("pas_filter_cache_hit_total")
-                self._record_device_filter(
-                    span, parsed, policy_name, "cache_hit",
-                    candidates, n_failed, reasons,
-                )
+                with span.stage("record", sampled=True):
+                    span.set("filter_cache", "hit")
+                    trace.COUNTERS.inc("pas_filter_cache_hit_total")
+                    self._record_device_filter(
+                        span, parsed, policy_name, "cache_hit",
+                        candidates, n_failed, reasons,
+                    )
                 return HTTPResponse.json(body)
             if use_node_names and hasattr(wirec, "filter_encode"):
                 # span-cache miss, NodeNames mode: build the response
@@ -1052,21 +1069,23 @@ class MetricsExtender:
                 # counts ONLY once the encode succeeded — a raise here
                 # lands in the outer except -> None -> the caller counts
                 # it a bypass, never miss+bypass
-                body, n_failed = self.fastpath.filter_parsed(
-                    wirec, view, parsed, violations, compiled, policy.name,
-                    reason_table=reason_table,
-                    universe=universe if use_node_names else None,
-                )
-                self.fastpath.filter_store(
-                    violations, use_node_names, parsed, body, n_failed,
-                    gang_version, universe=universe,
-                )
-                span.set("filter_cache", "miss")
-                trace.COUNTERS.inc("pas_filter_cache_miss_total")
-                self._record_device_filter(
-                    span, parsed, policy_name, "native",
-                    candidates, n_failed, reasons,
-                )
+                with span.stage("fencode", sampled=True):
+                    body, n_failed = self.fastpath.filter_parsed(
+                        wirec, view, parsed, violations, compiled, policy.name,
+                        reason_table=reason_table,
+                        universe=universe if use_node_names else None,
+                    )
+                    self.fastpath.filter_store(
+                        violations, use_node_names, parsed, body, n_failed,
+                        gang_version, universe=universe,
+                    )
+                with span.stage("record", sampled=True):
+                    span.set("filter_cache", "miss")
+                    trace.COUNTERS.inc("pas_filter_cache_miss_total")
+                    self._record_device_filter(
+                        span, parsed, policy_name, "native",
+                        candidates, n_failed, reasons,
+                    )
                 return HTTPResponse.json(body)
             # cacheable but missed: the exact path builds (and stores) the
             # response via the returned token — still a miss
@@ -1226,48 +1245,51 @@ class MetricsExtender:
         # parse errors (ValueError/TypeError) propagate to the outer guard
         with span.stage("decode"):
             parsed = wirec.parse_prioritize(request.body)
-        use_node_names = False
-        if not parsed.nodes_present or parsed.num_nodes == 0:
-            if (
-                self.node_cache_capable
-                and parsed.node_names_present
-                and parsed.num_node_names > 0
-            ):
-                use_node_names = True
-            else:
-                return None  # empty-200 quirks belong to the exact path
-        status = 200
-        policy_name = parsed.policy_label
-        if policy_name is None:
-            status = 400  # no label: 400 but still prioritize (-> empty)
-            trace.COUNTERS.inc("pas_prioritize_native_total")
-            return HTTPResponse.json(encode_host_priority_list([]), status)
-        namespace = parsed.pod_namespace or ""
-        try:
-            policy = self.cache.read_policy(namespace, policy_name)
-        except Exception:
-            trace.COUNTERS.inc("pas_prioritize_native_total")
-            return HTTPResponse.json(encode_host_priority_list([]), status)
-        rule = self._scheduling_rule(policy)
-        if rule is None:
-            trace.COUNTERS.inc("pas_prioritize_native_total")
-            return HTTPResponse.json(encode_host_priority_list([]), status)
-        pod = Pod(
-            {"metadata": {"name": parsed.pod_name or "", "namespace": namespace}}
-        )
-        # correlation key for the causal spine: the native path must
-        # stamp the span and publish its verdict exactly like the exact
-        # path below, or /debug/explain loses the score step for every
-        # fastpath-served pod
-        pod_key = f"{namespace}/{parsed.pod_name or ''}"
-        span.set("pod", pod_key)
-        planned = (
-            self.planner.planned_node(pod) if self.planner is not None else None
-        )
-        compiled, view = self._device_policy(policy)
-        candidates = (
-            parsed.num_node_names if use_node_names else parsed.num_nodes
-        )
+        # everything between the parse and the universe probe: the
+        # policy read, its compiled form, the plan
+        with span.stage("policy", sampled=True):
+            use_node_names = False
+            if not parsed.nodes_present or parsed.num_nodes == 0:
+                if (
+                    self.node_cache_capable
+                    and parsed.node_names_present
+                    and parsed.num_node_names > 0
+                ):
+                    use_node_names = True
+                else:
+                    return None  # empty-200 quirks belong to the exact path
+            status = 200
+            policy_name = parsed.policy_label
+            if policy_name is None:
+                status = 400  # no label: 400 but still prioritize (-> empty)
+                trace.COUNTERS.inc("pas_prioritize_native_total")
+                return HTTPResponse.json(encode_host_priority_list([]), status)
+            namespace = parsed.pod_namespace or ""
+            try:
+                policy = self.cache.read_policy(namespace, policy_name)
+            except Exception:
+                trace.COUNTERS.inc("pas_prioritize_native_total")
+                return HTTPResponse.json(encode_host_priority_list([]), status)
+            rule = self._scheduling_rule(policy)
+            if rule is None:
+                trace.COUNTERS.inc("pas_prioritize_native_total")
+                return HTTPResponse.json(encode_host_priority_list([]), status)
+            pod = Pod(
+                {"metadata": {"name": parsed.pod_name or "", "namespace": namespace}}
+            )
+            # correlation key for the causal spine: the native path must
+            # stamp the span and publish its verdict exactly like the exact
+            # path below, or /debug/explain loses the score step for every
+            # fastpath-served pod
+            pod_key = f"{namespace}/{parsed.pod_name or ''}"
+            span.set("pod", pod_key)
+            planned = (
+                self.planner.planned_node(pod) if self.planner is not None else None
+            )
+            compiled, view = self._device_policy(policy)
+            candidates = (
+                parsed.num_node_names if use_node_names else parsed.num_nodes
+            )
         with span.stage("intern"):
             universe = self.fastpath.universe_probe(
                 wirec, parsed, use_node_names
@@ -1287,20 +1309,23 @@ class MetricsExtender:
                 span.set("path", "native")
                 if rank_view is not view:
                     span.set("ranking", "forecast")
-                trace.COUNTERS.inc("pas_prioritize_native_total")
-                self._record_prioritize(
-                    span, namespace, parsed.pod_name or "", policy_name,
-                    "native", rule, int(candidates), planned,
-                    compiled=compiled, view=rank_view,
-                    forecast=rank_view is not view,
-                )
-                events.JOURNAL.publish(
-                    "verdict",
-                    "prioritize",
-                    request_id=span.trace_id,
-                    pod=pod_key,
-                    data={"candidates": int(candidates), "path": "native"},
-                )
+                # the always-on observers of this verb: decision record and
+                # journal event
+                with span.stage("record", sampled=True):
+                    trace.COUNTERS.inc("pas_prioritize_native_total")
+                    self._record_prioritize(
+                        span, namespace, parsed.pod_name or "", policy_name,
+                        "native", rule, int(candidates), planned,
+                        compiled=compiled, view=rank_view,
+                        forecast=rank_view is not view,
+                    )
+                    events.JOURNAL.publish(
+                        "verdict",
+                        "prioritize",
+                        request_id=span.trace_id,
+                        pod=pod_key,
+                        data={"candidates": int(candidates), "path": "native"},
+                    )
                 return HTTPResponse.json(body, status)
             except Exception as exc:
                 trace.COUNTERS.inc("pas_prioritize_host_fallback_total")
@@ -1323,18 +1348,19 @@ class MetricsExtender:
             body = encode_host_priority_list(result)
         # partition counter only once the answer actually exists — an
         # exception above falls to the exact path, which counts itself
-        trace.COUNTERS.inc("pas_prioritize_native_host_total")
-        self._record_prioritize(
-            span, namespace, parsed.pod_name or "", policy_name,
-            "native_host", rule, int(candidates), planned, result=result,
-        )
-        events.JOURNAL.publish(
-            "verdict",
-            "prioritize",
-            request_id=span.trace_id,
-            pod=pod_key,
-            data={"candidates": int(candidates), "path": "native_host"},
-        )
+        with span.stage("record", sampled=True):
+            trace.COUNTERS.inc("pas_prioritize_native_host_total")
+            self._record_prioritize(
+                span, namespace, parsed.pod_name or "", policy_name,
+                "native_host", rule, int(candidates), planned, result=result,
+            )
+            events.JOURNAL.publish(
+                "verdict",
+                "prioritize",
+                request_id=span.trace_id,
+                pod=pod_key,
+                data={"candidates": int(candidates), "path": "native_host"},
+            )
         return HTTPResponse.json(body, status)
 
     def _record_prioritize(

@@ -29,14 +29,23 @@ be answerable in production, not reconstructed from benchmarks:
     tests to prove ``/metrics`` is real Prometheus exposition.
 
 Tracing is always-on: a span costs two ``perf_counter`` reads per stage
-and one short lock acquisition at completion.  This module must stay
-importable without jax (the host layer's rule); everything jax touches is
-imported lazily.
+and one short lock acquisition at completion.  A stage is ONE interval on
+two clocks (:class:`_Stage`): ``perf_counter`` for the span (and, off a
+request, a seconds counter), and — leaves only, while a profile is being
+taken — a ``jax.profiler.TraceAnnotation("pas:<stage>")`` so that a
+profiled window shows the program's stages on the profiler's own clock
+beside the device's operations.  The sub-stages of the sub-millisecond
+verbs are ``sampled``: recorded on one span in :data:`SAMPLE_EVERY`, a
+no-op on the others.  This module must stay importable without jax (the
+host layer's rule); everything jax touches is imported lazily.
 """
 
 from __future__ import annotations
 
+import gc
+import itertools
 import json
+import sys
 import threading
 import time
 import uuid
@@ -118,6 +127,10 @@ declare("pas_state_churn_passes_total", "counter", "Refresh passes whose churn t
 declare("pas_state_churn_rows_changed_total", "counter", "Total node columns changed across all flushed refresh passes.")
 # trace buffer health
 declare("pas_traces_recorded_total", "counter", "Completed spans recorded into the trace ring buffer.")
+# collector pauses (watch_gc: one gc.callbacks entry; a span that a
+# collection ended inside carries the attribute gc_ms)
+declare("pas_gc_pause_seconds_total", "counter", "Seconds the interpreter spent inside garbage collections (every thread is stopped for them).")
+declare("pas_gc_collections_total", "counter", "Garbage collections completed (label: generation).")
 # health & readiness (utils/health.py: /healthz + /readyz on both front-ends)
 declare("pas_ready", "gauge", "Composite readiness: 1 when every /readyz condition holds, else 0.")
 declare("pas_ready_transitions_total", "counter", "Readiness flips (ready <-> not ready) observed across /readyz evaluations.")
@@ -126,6 +139,13 @@ declare("pas_ready_transitions_total", "counter", "Readiness flips (ready <-> no
 declare("pas_telemetry_metric_age_seconds", "gauge", "Seconds since each registered telemetry metric's last successful refresh (label: metric).")
 declare("pas_telemetry_refresh_total", "counter", "Telemetry cache refresh passes completed.")
 declare("pas_telemetry_refresh_errors_total", "counter", "Individual metric fetch failures across refresh passes.")
+# refresh-pass split (tas/cache.py update_all_metrics + the warm it
+# triggers): four families, not one with a stage label — fetch + publish +
+# warm <= pass, the rest being pass accounting and end-of-pass hooks
+declare("pas_refresh_pass_seconds_total", "counter", "Seconds spent inside telemetry refresh passes (whole update_all_metrics, end-of-pass hooks included).")
+declare("pas_refresh_fetch_seconds_total", "counter", "Seconds of refresh passes spent fetching and parsing metrics from the custom-metrics API (per metric, refresh_filter included).")
+declare("pas_refresh_publish_seconds_total", "counter", "Seconds of refresh passes spent in write_metric through the mirror's publish, less the fastpath warm it triggers.")
+declare("pas_refresh_warm_seconds_total", "counter", "Seconds spent in warm_fastpath (ranking precompute, violation sets, response skeletons) — in steady state all on the refresh thread.")
 declare("pas_strategy_evaluations_total", "counter", "Strategy violation evaluations (label: strategy).")
 declare("pas_strategy_violations_total", "counter", "Violating nodes found by strategy evaluations (label: strategy).")
 declare("pas_strategy_enforcements_total", "counter", "Enforcement passes completed without error (label: strategy); pairs with pas_strategy_violations_total for whether they changed anything.")
@@ -268,6 +288,71 @@ COUNTERS = CounterSet()
 
 
 # ---------------------------------------------------------------------------
+# collector pauses
+# ---------------------------------------------------------------------------
+
+# Plain tallies that only the callback writes: it runs wherever an
+# allocation set the collector off — inside CounterSet.inc with its lock
+# held, for one — so it may take no lock this process also takes
+# elsewhere.  Collections never nest, so there is one writer at a time;
+# the exposition moves what has accrued into COUNTERS (_flush_gc).
+_gc_seconds = 0.0
+_gc_counts = [0, 0, 0]  # by generation
+_gc_recent: Tuple[Tuple[float, float], ...] = ()  # last (ended, seconds)
+_gc_last_end = -1.0
+_gc_open = None  # the stage of the collection under way
+_gc_flushed = (0.0, (0, 0, 0))  # what COUNTERS holds of the tallies
+_gc_watch_lock = threading.Lock()
+
+
+def _on_gc(phase: str, info: Dict) -> None:
+    global _gc_seconds, _gc_recent, _gc_last_end, _gc_open
+    if phase == "start":
+        # two callbacks bracket a collection, so no ``with`` block can
+        _gc_open = _Stage("gc").__enter__()
+    elif _gc_open is not None:
+        now = time.perf_counter()
+        seconds = now - _gc_open._t0
+        _gc_open.__exit__(None, None, None)
+        _gc_open = None
+        _gc_seconds += seconds
+        _gc_counts[min(int(info.get("generation", 2)), 2)] += 1
+        _gc_recent = (_gc_recent + ((now, seconds),))[-16:]
+        _gc_last_end = now
+
+
+def _flush_gc() -> None:
+    """Move the collector's tallies into COUNTERS: from the exposition,
+    never from the callback (which may take no lock)."""
+    global _gc_flushed
+    with _gc_watch_lock:
+        seconds, counts = _gc_seconds, tuple(_gc_counts)
+        was_seconds, was_counts = _gc_flushed
+        _gc_flushed = (seconds, counts)
+    if seconds > was_seconds:
+        COUNTERS.inc("pas_gc_pause_seconds_total", seconds - was_seconds)
+    for generation, (now, was) in enumerate(zip(counts, was_counts)):
+        if now > was:
+            COUNTERS.inc(
+                "pas_gc_collections_total",
+                now - was,
+                labels={"generation": str(generation)},
+            )
+
+
+def watch_gc() -> None:
+    """Time every garbage collection from now on (one ``gc.callbacks``
+    entry, idempotent; both front-ends call it as they start): the two
+    ``pas_gc_*`` families on /metrics, a ``pas:gc`` annotation in a
+    profiled window, and ``gc_ms`` on every span a collection ended
+    inside — so the ``slowest`` list of /debug/traces can tell a
+    collector pause from a lock wait or an upload."""
+    with _gc_watch_lock:
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+
+# ---------------------------------------------------------------------------
 # request ids and spans
 # ---------------------------------------------------------------------------
 
@@ -277,24 +362,93 @@ def new_request_id() -> str:
     return uuid.uuid4().hex
 
 
-class _StageTimer:
-    """``with span.stage("decode"):`` — one perf_counter pair."""
+#: jax.profiler.TraceAnnotation once resolved; False when jax has no
+#: profiler; None while unresolved
+_ANNOTATION = None
 
-    __slots__ = ("_span", "_name", "_t0")
 
-    def __init__(self, span: "Span", name: str):
-        self._span = span
+def _annotation():
+    """The profiler's annotation class, or None: while jax is not
+    imported nobody can be profiling (and a host-only process must not
+    pay the import for a stage), and without a profiler there is
+    nothing to annotate."""
+    global _ANNOTATION
+    if "jax" not in sys.modules:
+        return None
+    try:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    except Exception:
+        _ANNOTATION = False
+    return _ANNOTATION or None
+
+
+class _Stage:
+    """``with span.stage("decode"):`` / ``with trace.stage("rf.fetch"):``
+    — one interval on two clocks.  By ``perf_counter`` it lands on the
+    span as ``(name, start, dur)`` and, when a ``counter`` is named, in
+    that seconds counter; a leaf additionally opens the profiler
+    annotation ``pas:<name>`` for the same interval, on the thread that
+    does the work, so a profiled window (perfbench --trace 1,
+    GET /debug/profile, --profilePort) shows it beside the device's
+    operations with nothing converted between clocks.  While no profile
+    is being taken the annotation costs one call that says so.
+    ``leaf=False`` is for a stage that contains others (handle,
+    cache_probe, GAS's kernel): recorded on the span, never annotated — a gap is named by the two annotations that
+    cover most of it, and a container would crowd its own children out."""
+
+    __slots__ = ("_name", "_span", "_counter", "_counters", "_leaf", "_mark", "_t0")
+
+    def __init__(self, name, span=None, counter=None, counters=None, leaf=True):
         self._name = name
+        self._span = span
+        self._counter = counter
+        self._counters = counters
+        self._leaf = leaf
+        self._mark = None
 
     def __enter__(self):
+        if self._leaf:
+            cls = _ANNOTATION
+            if cls is None:
+                cls = _annotation()
+            if cls and cls.is_enabled():  # a profile is being taken
+                self._mark = cls("pas:" + self._name)
         self._t0 = time.perf_counter()
         return self
 
-    def __exit__(self, *exc):
-        self._span.add_stage(
-            self._name, time.perf_counter() - self._t0
-        )
+    def __exit__(self, exc_type, exc, tb):
+        now = time.perf_counter()
+        if self._mark is not None:
+            self._mark.__exit__(None, None, None)
+        t0 = self._t0
+        span = self._span
+        if span is not None:
+            # a span's t0 is its first byte: no stage starts before it
+            span.stages.append((self._name, t0 - span._t0, now - t0))
+        if self._counter is not None:
+            (self._counters or COUNTERS).inc(self._counter, now - t0)
         return False
+
+
+def stage(
+    name: str,
+    counter: Optional[str] = None,
+    counters: Optional[CounterSet] = None,
+) -> _Stage:
+    """A stage off any request (the refresh thread, an informer): no
+    span to land on — the ``pas:<name>`` annotation, plus ``counter``
+    (a ``*_seconds_total`` family, in ``counters`` or the process-wide
+    set) when the interval is to be summed."""
+    return _Stage(name, None, counter, counters)
+
+
+#: one span in this many records the ``sampled`` stages (Span.stage).
+#: Odd, so that a scheduler's alternating verbs (Filter, Prioritize,
+#: Filter, ...) are both sampled.
+SAMPLE_EVERY = 7
+_SPAN_SEQ = itertools.count()
 
 
 class Span:
@@ -315,6 +469,7 @@ class Span:
         "stages",
         "attrs",
         "links",
+        "sampled",
     )
 
     def __init__(
@@ -334,12 +489,25 @@ class Span:
         self.stages: List[Tuple[str, float, float]] = []  # (name, start, dur)
         self.attrs: Dict[str, object] = {}
         self.links: List[str] = []
+        self.sampled = next(_SPAN_SEQ) % SAMPLE_EVERY == 0
 
-    def stage(self, name: str) -> _StageTimer:
-        return _StageTimer(self, name)
+    def stage(self, name: str, leaf: bool = True, sampled: bool = False):
+        """``with span.stage(name):`` — the one way to time an interval of
+        this request (:class:`_Stage`).  ``sampled`` is for the new
+        sub-stages of verbs that take a few hundred microseconds, where
+        every recorded stage is a measurable share of the verb: such a
+        stage is recorded on one span in :data:`SAMPLE_EVERY` and is a
+        no-op on the others, so a stage mean over the ring is a mean over
+        the spans that carry it."""
+        if sampled and not self.sampled:
+            return _NULL_STAGE
+        return _Stage(name, self, leaf=leaf)
 
     def add_stage(self, name: str, seconds: float) -> None:
-        """Record a stage that just ended (start inferred from now)."""
+        """Record a stage that just ended (start inferred from now) —
+        for an interval timed by hand: read, which began before the span
+        existed, and write, as it always was.  It carries no profiler
+        annotation (one cannot be back-dated)."""
         offset = max(0.0, time.perf_counter() - self._t0 - seconds)
         self.stages.append((name, offset, seconds))
 
@@ -353,6 +521,12 @@ class Span:
         self.duration_s = time.perf_counter() - self._t0
         if status is not None:
             self.status = status
+        if _gc_last_end >= self._t0:
+            # a collection ended inside this span: every thread stood
+            # still for it, whichever thread's allocation set it off
+            self.attrs["gc_ms"] = round(
+                sum(s for end, s in _gc_recent if end >= self._t0) * 1e3, 4
+            )
         return self
 
     def stage_seconds(self) -> Dict[str, float]:
@@ -394,7 +568,9 @@ class _NullSpan:
     attrs: Dict[str, object] = {}
     links: List[str] = []
 
-    def stage(self, name: str) -> "_NullStageTimer":
+    def stage(
+        self, name: str, leaf: bool = True, sampled: bool = False
+    ) -> "_NullStageTimer":
         return _NULL_STAGE
 
     def add_stage(self, name: str, seconds: float) -> None:
@@ -695,6 +871,7 @@ def exposition(
     for cs in counter_sets:
         parts.append(cs.prometheus_text(help_texts=helps))
     if include_global:
+        _flush_gc()
         parts.append(COUNTERS.prometheus_text(help_texts=helps))
         for provider in list(EXTRA_PROVIDERS):
             parts.append(provider())

@@ -412,9 +412,11 @@ class TestAccounting:
         before = trace.COUNTERS.get("pas_jax_retrace_total")
         ext1, names1 = build_extender(48, device=True)  # 64-node bucket
         assert ext1.prioritize(req(make_bodies(names1, "nodenames", count=1)[0])).status == 200
-        # 1500 nodes -> a 2048-node capacity bucket: a shape no other
-        # fixture in the suite compiles, so the ranking pass MUST re-lower
-        ext2, names2 = build_extender(1500, device=True)
+        # 3000 nodes -> a 4096-node capacity bucket: a shape no other
+        # fixture in the suite compiles (test_record.py's 2,000 nodes take
+        # the 2048 bucket, and a worker may run that file first), so the
+        # ranking pass MUST re-lower
+        ext2, names2 = build_extender(3000, device=True)
         assert ext2.prioritize(req(make_bodies(names2, "nodenames", count=1)[0])).status == 200
         after = trace.COUNTERS.get("pas_jax_retrace_total")
         assert after > before
