@@ -204,7 +204,7 @@ def main(argv=None) -> int:
             "counters": {
                 name: after.get(name, 0.0) - before.get(name, 0.0)
                 for name in after
-                if name.startswith(("pas_refresh_", "pas_gc_"))},
+                if name.startswith(("pas_refresh_", "pas_gc_", "pas_gas_"))},
         }
         if stage_cost is not None:
             # {span name: {group: [median ms, mean ms of the middle 80%, spans]}}

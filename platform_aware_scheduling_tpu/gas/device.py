@@ -1,12 +1,17 @@
-"""Device side of GAS: persistent usage mirror + request staging for the
+"""Device side of GAS: the resident usage mirror + request packing for the
 batched binpack kernel (ops/binpack.py).
 
 :class:`GASUsageMirror` is the GAS analog of the TAS TensorStateMirror
 (SURVEY §7 step 5): it subscribes to the cluster cache's booking hook and
 the node informer events and keeps ``[nodes, cards, resources]`` usage /
-capacity tensors current incrementally — so a Filter request only stages
-its (tiny) per-container request tensors and gathers candidate rows on
-device, instead of re-walking every node's resource maps in Python.
+capacity tensors current incrementally, in NumPy and ON THE DEVICE.  What
+changes only with the cluster's structure (capacity, the card lanes,
+their first-fit order) is uploaded when a node event, a never-seen card
+or resource, or a padded axis moves it; ``used`` stays resident, and a
+booking travels to it as its changed rows inside the next Filter's own
+call.  So a Filter crosses the host<->device boundary three times —
+one packed buffer in (request + update block), one dispatch, one readback
+of ``fits`` — instead of re-uploading every tensor for one changed row.
 
 Lanes are interned append-only; the first-fit name order the reference
 iterates in (scheduler.go:216-224) is carried as an explicit
@@ -19,8 +24,9 @@ per-request staging otherwise (also the correctness control in tests).
 
 from __future__ import annotations
 
+import contextlib
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -29,9 +35,11 @@ from platform_aware_scheduling_tpu.gas.utils import container_requests
 from platform_aware_scheduling_tpu.kube.objects import Node, Pod
 from platform_aware_scheduling_tpu.ops import i64
 from platform_aware_scheduling_tpu.ops.binpack import (
+    UPDATE_SLOTS,
     BinpackNodeState,
-    BinpackRequest,
     binpack_kernel,
+    pack_request,
+    pack_rows,
 )
 from platform_aware_scheduling_tpu.utils import decisions, trace
 
@@ -49,6 +57,40 @@ def _bucket(n: int, minimum: int) -> int:
     while size < n:
         size *= 2
     return size
+
+
+def _pads(shares) -> Tuple[int, int]:
+    """(t_pad, k_pad): the container and GPU-pick axes a request compiles
+    for."""
+    max_gpus = max((k for _, k in shares), default=0)
+    return (
+        _bucket(len(shares), MIN_CONTAINERS),
+        _bucket(max(max_gpus, 1), MIN_GPUS),
+    )
+
+
+def _to_device(array: np.ndarray):
+    """Every explicit host->device copy of this module: each is one
+    operation of ~0.3 ms on the chip whatever its size, so tests count
+    them here."""
+    return jnp.asarray(array)
+
+
+def _to_device_i64(values: np.ndarray) -> i64.I64:
+    hi, lo = i64.split_int64_np(values)
+    return i64.I64(hi=_to_device(hi), lo=_to_device(lo))
+
+
+def _node_state(used, cap, cap_present, card_valid, card_real, card_order):
+    """Upload NumPy card state (``used``/``cap`` int64) whole: 8 copies."""
+    return BinpackNodeState(
+        used=_to_device_i64(used),
+        capacity=_to_device_i64(cap),
+        cap_present=_to_device(cap_present),
+        card_valid=_to_device(card_valid),
+        card_real=_to_device(card_real),
+        card_order=_to_device(card_order),
+    )
 
 
 class GASUsageMirror:
@@ -69,8 +111,17 @@ class GASUsageMirror:
         self._card_order = np.full((n, c), 2**30, dtype=np.int32)
         self._has_gpus = np.zeros(n, dtype=bool)
         self._known = np.zeros(n, dtype=bool)
+        # _version moves with every change (one device state object per
+        # version: the fits cache's key); _structure only with what is not
+        # ``used`` — node events, a never-seen node/card/resource, growth
         self._version = 0
-        self._device: Optional[Tuple[int, BinpackNodeState]] = None
+        self._structure = 0
+        # rows of ``used`` changed since the resident state was installed
+        self._dirty: Set[int] = set()
+        # (version, structure version, state) resident on the device
+        self._device: Optional[Tuple[int, int, BinpackNodeState]] = None
+        # (structure version, node_index, known, has_gpus, res_index)
+        self._views: Optional[tuple] = None
         cache.on_node_change(self.on_node_change)  # replays cached nodes
         # replays booked nodes + registers atomically under the cache lock,
         # preserving cache→mirror lock order (no ABBA window against the
@@ -105,6 +156,7 @@ class GASUsageMirror:
             self._grow(n=row + 1)
             self._node_index[name] = row
             self._card_index.append({})
+            self._structure += 1
         return row
 
     def _intern_resource(self, name: str) -> int:
@@ -113,12 +165,13 @@ class GASUsageMirror:
             idx = len(self._res_index)
             self._grow(r=idx + 1)
             self._res_index[name] = idx
-            # growing the resource axis invalidates the memoized snapshot:
+            # growing the resource axis invalidates the resident state:
             # a request interning a never-seen resource between cluster
             # events would otherwise get a state whose r_pad is too small
             # for the index this just handed out (IndexError in
-            # stage_request until the next event bumped the version)
+            # pack_request until the next event bumped the version)
             self._version += 1
+            self._structure += 1
         return idx
 
     def _intern_card(self, row: int, card: str) -> int:
@@ -132,6 +185,7 @@ class GASUsageMirror:
             # first-fit order = rank among sorted names of this node's lanes
             for rank, name in enumerate(sorted(cards)):
                 self._card_order[row, cards[name]] = rank
+            self._structure += 1
         return lane
 
     # -- event hooks -----------------------------------------------------------
@@ -140,9 +194,10 @@ class GASUsageMirror:
         """Node added/updated/deleted: restage capacity + card set."""
         with self._lock:
             row = self._intern_node(node.name)
+            self._version += 1
+            self._structure += 1
             if deleted:
                 self._known[row] = False
-                self._version += 1
                 return
             self._known[row] = True
             gpus = gas_logic.get_node_gpu_list(node)
@@ -159,11 +214,13 @@ class GASUsageMirror:
                 self._intern_card(row, card)
             for card, lane in self._card_index[row].items():
                 self._card_valid[row, lane] = card in gpu_set
-            self._version += 1
 
     def on_booking_change(self, node_name: str) -> None:
-        """Booking changed on one node: restage its used tensor row.
-        Called with the cache lock held, so reads are consistent."""
+        """Booking changed on one node: rewrite its row of the NumPy
+        mirror and mark it dirty — no device call from here (Bind's
+        ``book`` stage and the informer thread run this); the next Filter
+        carries the row.  Called with the cache lock held, so reads are
+        consistent."""
         with self._lock:
             row = self._intern_node(node_name)
             used = self.cache.get_node_resource_status(node_name)
@@ -173,80 +230,91 @@ class GASUsageMirror:
                 for name, value in rm.items():
                     idx = self._intern_resource(name)
                     self._used[row, lane, idx] = value
+            self._dirty.add(row)
             self._version += 1
 
     # -- reads -----------------------------------------------------------------
 
-    def resource_index(self) -> Dict[str, int]:
-        with self._lock:
-            return dict(self._res_index)
+    # the four below run under ``_lock``, taken by the Filter that calls them
 
-    def snapshot(self, span=trace.NULL_SPAN):
-        """(device state over ALL interned rows, node_index, flags) — device
-        arrays memoized per version; a restage is the span's
-        ``state_upload`` stage (the host copies and the eight uploads)."""
-        with self._lock:
-            if self._device is None or self._device[0] != self._version:
-                with span.stage("state_upload"):
-                    used_hi, used_lo = i64.split_int64_np(self._used)
-                    cap_hi, cap_lo = i64.split_int64_np(self._cap)
-                    state = BinpackNodeState(
-                        used=i64.I64(
-                            hi=jnp.asarray(used_hi), lo=jnp.asarray(used_lo)
-                        ),
-                        capacity=i64.I64(
-                            hi=jnp.asarray(cap_hi), lo=jnp.asarray(cap_lo)
-                        ),
-                        cap_present=jnp.asarray(self._cap_present.copy()),
-                        card_valid=jnp.asarray(self._card_valid.copy()),
-                        card_real=jnp.asarray(self._card_real.copy()),
-                        card_order=jnp.asarray(self._card_order.copy()),
-                    )
-                    self._device = (self._version, state)
-            return (
-                self._device[1],
+    def views(self):
+        """(node_index, known, has_gpus, res_index): the host copies a
+        Filter reads after it has let go of the lock, memoized per
+        structure version."""
+        if self._views is None or self._views[0] != self._structure:
+            self._views = (
+                self._structure,
                 dict(self._node_index),
                 self._known.copy(),
                 self._has_gpus.copy(),
                 dict(self._res_index),
             )
+        return self._views[1:]
 
+    def resident(self) -> Optional[BinpackNodeState]:
+        """The device state over ALL interned rows, if it is this
+        version's."""
+        dev = self._device
+        return dev[2] if dev is not None and dev[0] == self._version else None
 
-def stage_request(
-    requests, shares, resources_index: Dict[str, int], r_pad: int
-) -> Tuple[BinpackRequest, int]:
-    """Build the padded per-container request tensors."""
-    t_pad = _bucket(len(requests), MIN_CONTAINERS)
-    max_gpus = max((k for _, k in shares), default=0)
-    k_pad = _bucket(max(max_gpus, 1), MIN_GPUS)
-    need = np.zeros((t_pad, r_pad), dtype=np.int64)
-    need_active = np.zeros((t_pad, r_pad), dtype=bool)
-    num_gpus = np.zeros(t_pad, dtype=np.int32)
-    container_active = np.zeros(t_pad, dtype=bool)
-    for t, (per_gpu, k) in enumerate(shares):
-        container_active[t] = True
-        num_gpus[t] = k
-        for name, value in per_gpu.items():
-            idx = resources_index[name]
-            need[t, idx] = value
-            need_active[t, idx] = True
-    need_hi, need_lo = i64.split_int64_np(need)
-    return (
-        BinpackRequest(
-            need=i64.I64(hi=jnp.asarray(need_hi), lo=jnp.asarray(need_lo)),
-            need_active=jnp.asarray(need_active),
-            num_gpus=jnp.asarray(num_gpus),
-            container_active=jnp.asarray(container_active),
-        ),
-        k_pad,
-    )
+    def stage(self) -> Tuple[BinpackNodeState, np.ndarray]:
+        """(base state, update block): what the next solve needs to run on
+        this version's usage.  Up to UPDATE_SLOTS dirty rows ride in the
+        block on the resident state; more, or a moved structure, is a
+        full restage of ``used`` (of everything) under an empty block —
+        the same compiled program either way."""
+        dev = self._device
+        structure_held = dev is not None and dev[1] == self._structure
+        if structure_held and len(self._dirty) <= UPDATE_SLOTS:
+            rows = sorted(self._dirty)
+            trace.COUNTERS.inc("pas_gas_state_incremental_total")
+            trace.COUNTERS.inc("pas_gas_state_rows_applied_total", len(rows))
+            return dev[2], pack_rows(self._used, rows)
+        trace.COUNTERS.inc("pas_gas_state_full_restage_total")
+        if structure_held:
+            base = dev[2]._replace(used=_to_device_i64(self._used))
+        else:
+            # the copies: the CPU backend may alias a NumPy buffer it is handed
+            base = _node_state(
+                self._used,
+                self._cap,
+                self._cap_present.copy(),
+                self._card_valid.copy(),
+                self._card_real.copy(),
+                self._card_order.copy(),
+            )
+        return base, pack_rows(self._used)
+
+    def install(self, base: BinpackNodeState, used: i64.I64) -> BinpackNodeState:
+        """This version's state: ``base`` with the ``used`` its solve
+        returned — or the one already resident (a solve for a second
+        template on an unchanged version must not replace the object the
+        fits cache is keyed by)."""
+        state = self.resident()
+        if state is None:
+            state = base._replace(used=used)
+            self._device = (self._version, self._structure, state)
+            self._dirty.clear()
+        return state
+
+    def forget(self) -> None:
+        """Drop the resident state: the next Filter restages from NumPy."""
+        with self._lock:
+            self._device = None
 
 
 class DeviceBinpacker:
     """Evaluates one pod's fit against many nodes in one XLA pass.
 
-    The mirror path amortizes the device dispatch across a scheduling
-    burst: kube-scheduler filters one pod per request, but the pods of a
+    The mirror path keeps the cluster's card state resident on the
+    device (:class:`GASUsageMirror`): a Filter hands the jitted solve ONE
+    packed host buffer — its request and the usage rows that bookings
+    and releases changed since the last solve — and reads ``fits`` back;
+    the solve returns the updated usage, which stays on the device as
+    the next Filter's base.
+
+    It also amortizes the dispatch across a scheduling burst:
+    kube-scheduler filters one pod per request, but the pods of a
     deployment share a template, and the mirror state only changes when
     a booking/node event lands — so fits are cached per (state version,
     request signature) over ALL interned rows, and a burst of filter
@@ -262,8 +330,8 @@ class DeviceBinpacker:
         self.mirror = GASUsageMirror(cache) if use_mirror else None
         self._fits_lock = threading.Lock()
         # MRU [state, signature, fits-over-all-rows]; keyed by the state
-        # OBJECT identity (snapshot memoizes one state per mirror version,
-        # so identity == version) and the pod's request signature
+        # OBJECT identity (the mirror installs one state per version, so
+        # identity == version) and the pod's request signature
         self._fits_cache: List[list] = []
 
     def batch_fit(
@@ -289,35 +357,34 @@ class DeviceBinpacker:
             # the host loop decides cheaply — no point shipping tensors
             return None
         if self.mirror is not None:
-            fits, codes = self._fit_mirror(
-                requests, shares, resources, node_names, span
-            )
+            fits, codes = self._fit_mirror(shares, resources, node_names, span)
         else:
-            fits, codes = self._fit_staged(requests, shares, resources, node_names)
+            fits, codes = self._fit_staged(shares, resources, node_names)
         return (fits, codes) if with_reasons else fits
 
     # -- persistent-mirror path ------------------------------------------------
 
-    def _all_rows_fits(self, state, signature, compute) -> np.ndarray:
-        """fits over ALL interned rows for this (state, request template),
-        served from the MRU cache when the burst repeats the template;
-        ``compute`` runs only on a miss (a hit skips request staging and
-        the kernel entirely)."""
+    def _cached_fits(self, state, signature) -> Optional[np.ndarray]:
+        """fits over ALL interned rows for this (state, request template)
+        from the MRU cache, when the burst repeats the template: a hit
+        skips request packing and the kernel entirely."""
         with self._fits_lock:
             for idx, entry in enumerate(self._fits_cache):
                 if entry[0] is state and entry[1] == signature:
                     if idx:
                         self._fits_cache.insert(0, self._fits_cache.pop(idx))
                     return entry[2]
-        fits = compute()
-        # purge relative to the mirror's CURRENT memoized state, not this
-        # call's: a straggler that snapshotted a superseded state must not
+        return None
+
+    def _remember_fits(self, state, signature, fits: np.ndarray) -> None:
+        # purge relative to the mirror's CURRENT resident state, not this
+        # call's: a straggler that solved a superseded state must not
         # evict fresh entries or insert one that can never hit again
         # (superseded-state entries would only pin full-cluster device
-        # arrays; snapshot returns ONE state object per mirror version)
+        # arrays; the mirror keeps ONE state object per version)
         with self.mirror._lock:
             dev = self.mirror._device
-            current = dev[1] if dev is not None else state
+            current = dev[2] if dev is not None else state
         with self._fits_lock:
             self._fits_cache = [
                 entry for entry in self._fits_cache if entry[0] is current
@@ -325,46 +392,53 @@ class DeviceBinpacker:
             if state is current:
                 self._fits_cache.insert(0, [state, signature, fits])
                 del self._fits_cache[self.FITS_CACHE_SIZE:]
-        return fits
 
-    def _fit_mirror(
-        self, requests, shares, resources, node_names, span=trace.NULL_SPAN
-    ):
+    def _fit_mirror(self, shares, resources, node_names, span=trace.NULL_SPAN):
         mirror = self.mirror
-        # informer deliveries restage rows under this lock: the wait is
-        # what a release or a resync costs this request
-        with span.stage("mirror_wait"):
-            mirror._lock.acquire()
-        try:
-            for name in resources:  # unknown request resources: intern (all-absent)
-                mirror._intern_resource(name)
-            state, node_index, known, has_gpus, res_index = mirror.snapshot(
-                span
-            )
-        finally:
-            mirror._lock.release()
-        max_gpus = max((k for _, k in shares), default=0)
-        k_pad = _bucket(max(max_gpus, 1), MIN_GPUS)
+        t_pad, k_pad = _pads(shares)
         signature = (
             tuple(
                 (tuple(sorted(per_gpu.items())), k) for per_gpu, k in shares
             ),
             k_pad,
         )
-
-        def compute() -> np.ndarray:
-            r_pad = state.capacity.hi.shape[-1]
-            with span.stage("req_upload"):
-                request, staged_k_pad = stage_request(
-                    requests, shares, res_index, r_pad
-                )
-            # dispatch to readback: np.asarray is what waits for the device
-            with span.stage("solve"):
-                return np.asarray(
-                    binpack_kernel(state, request, staged_k_pad).fits
-                )
-
-        fits_all = self._all_rows_fits(state, signature, compute)
+        with contextlib.ExitStack() as held:
+            # informer deliveries rewrite rows under this lock: the wait is
+            # what a release or a resync costs this request
+            with span.stage("mirror_wait"):
+                mirror._lock.acquire()
+            held.callback(mirror._lock.release)
+            for name in resources:  # unknown request resources: intern (all-absent)
+                mirror._intern_resource(name)
+            node_index, known, has_gpus, res_index = mirror.views()
+            state = mirror.resident()
+            fits_all = (
+                None if state is None else self._cached_fits(state, signature)
+            )
+            if fits_all is None:
+                # stage, dispatch and install under the lock: each version's
+                # ``used`` derives from the one before it, so no solve may
+                # overtake another between taking its base and installing
+                with span.stage("state_upload"):
+                    base, update = mirror.stage()
+                with span.stage("req_upload"):
+                    r_pad = base.capacity.hi.shape[-1]
+                    packed = pack_request(
+                        shares, res_index, t_pad, r_pad, update
+                    )
+                # dispatch to readback: np.asarray is what waits for the device
+                with span.stage("solve"):
+                    result = binpack_kernel(base, packed, k_pad)
+                    state = mirror.install(base, result.used)
+                    held.close()  # the wait itself needs no lock
+                    try:
+                        fits_all = np.asarray(result.fits)
+                    except Exception:
+                        # a failed execution poisons the ``used`` every
+                        # later update would build on
+                        mirror.forget()
+                        raise
+                self._remember_fits(state, signature, fits_all)
         with span.stage("rows"):
             out = [False] * len(node_names)
             codes = [decisions.CODE_GAS_CAPACITY] * len(node_names)
@@ -383,10 +457,10 @@ class DeviceBinpacker:
 
     # -- per-request staging path (control) ------------------------------------
 
-    def _fit_staged(self, requests, shares, resources, node_names):
+    def _fit_staged(self, shares, resources, node_names):
         r_pad = _bucket(len(resources), MIN_RESOURCES)
         res_index = {name: i for i, name in enumerate(resources)}
-        request, k_pad = stage_request(requests, shares, res_index, r_pad)
+        t_pad, k_pad = _pads(shares)
 
         staged = []
         out = [False] * len(node_names)
@@ -433,17 +507,11 @@ class DeviceBinpacker:
                     if idx is not None:
                         used_np[row, ci, idx] = value
 
-        used_hi, used_lo = i64.split_int64_np(used_np)
-        cap_hi, cap_lo = i64.split_int64_np(cap_np)
-        state = BinpackNodeState(
-            used=i64.I64(hi=jnp.asarray(used_hi), lo=jnp.asarray(used_lo)),
-            capacity=i64.I64(hi=jnp.asarray(cap_hi), lo=jnp.asarray(cap_lo)),
-            cap_present=jnp.asarray(cap_present),
-            card_valid=jnp.asarray(card_valid),
-            card_real=jnp.asarray(card_real),
-            card_order=jnp.asarray(card_order),
+        state = _node_state(
+            used_np, cap_np, cap_present, card_valid, card_real, card_order
         )
-        result = binpack_kernel(state, request, k_pad)
+        packed = pack_request(shares, res_index, t_pad, r_pad, pack_rows(used_np))
+        result = binpack_kernel(state, packed, k_pad)
         fits_np = np.asarray(result.fits)
         for row, (pos, *_rest) in enumerate(staged):
             out[pos] = bool(fits_np[row])

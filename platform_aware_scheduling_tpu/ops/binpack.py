@@ -17,7 +17,7 @@ axes.  Values are exact int64 in split (hi, lo) form (ops/i64.py).
 from __future__ import annotations
 
 from functools import partial
-from typing import NamedTuple
+from typing import Dict, NamedTuple, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +57,92 @@ class BinpackNodeState(NamedTuple):
 class BinpackResult(NamedTuple):
     fits: jax.Array  # bool [N]
     cards: jax.Array  # int32 [N, T, K] chosen card index per GPU, -1 = none
+    # the usage the fit ran against, update block applied: only for a
+    # packed request (its caller keeps it on the device as the next base)
+    used: Optional[i64.I64] = None  # [N, C, R]
+
+
+# -- the packed request ------------------------------------------------------
+# One int32 host buffer carries a Filter's whole operand: the request's
+# five tensors, then an update block of UPDATE_SLOTS usage rows (row index
+# + that row's [C, R] hi/lo words) that the kernel scatters into ``used``
+# before fitting.  A host<->device operation costs the same whatever its
+# size (PERF.md), so what the layout saves is their number; packers and
+# unpacker stand together here so that no caller knows it.  uint32 words
+# travel as their int32 bit patterns, bools as 0/1.
+
+UPDATE_SLOTS = 8
+
+
+def pack_rows(used: np.ndarray, rows: Sequence[int] = ()) -> np.ndarray:
+    """The update block: rows ``rows`` (at most UPDATE_SLOTS) of the int64
+    host usage array ``used`` [N, C, R].  Unused slots carry the
+    out-of-range row N, which the scatter drops."""
+    slots = np.full(UPDATE_SLOTS, used.shape[0], dtype=np.int32)
+    slots[: len(rows)] = rows
+    block = np.zeros((UPDATE_SLOTS,) + used.shape[1:], dtype=np.int64)
+    block[: len(rows)] = used[list(rows)]
+    hi, lo = i64.split_int64_np(block)
+    return np.concatenate([slots, hi.ravel(), lo.view(np.int32).ravel()])
+
+
+def pack_request(
+    shares,
+    resources_index: Dict[str, int],
+    t_pad: int,
+    r_pad: int,
+    update: np.ndarray,
+) -> np.ndarray:
+    """The packed operand of :func:`binpack_kernel`: ``shares`` (per
+    container: per-GPU resource map, GPU count) padded to ``t_pad``
+    containers x ``r_pad`` resources, then the block of :func:`pack_rows`."""
+    need = np.zeros((t_pad, r_pad), dtype=np.int64)
+    need_active = np.zeros((t_pad, r_pad), dtype=np.int32)
+    num_gpus = np.zeros(t_pad, dtype=np.int32)
+    container_active = np.zeros(t_pad, dtype=np.int32)
+    for t, (per_gpu, k) in enumerate(shares):
+        container_active[t] = 1
+        num_gpus[t] = k
+        for name, value in per_gpu.items():
+            idx = resources_index[name]
+            need[t, idx] = value
+            need_active[t, idx] = 1
+    hi, lo = i64.split_int64_np(need)
+    return np.concatenate([
+        hi.ravel(), lo.view(np.int32).ravel(), need_active.ravel(),
+        num_gpus, container_active, update,
+    ])
+
+
+def _unpack_request(packed: jax.Array, used: i64.I64) -> tuple:
+    """(BinpackRequest, ``used`` with the update block's rows set) from the
+    buffer of :func:`pack_request`, by static slices."""
+    _, c_pad, r_pad = used.hi.shape
+    block = UPDATE_SLOTS * (1 + 2 * c_pad * r_pad)
+    t_pad = (packed.shape[0] - block) // (3 * r_pad + 2)
+    request_shapes = [(t_pad, r_pad)] * 3 + [(t_pad,)] * 2
+    block_shapes = [(UPDATE_SLOTS,)] + [(UPDATE_SLOTS, c_pad, r_pad)] * 2
+    parts, offset = [], 0
+    for shape in request_shapes + block_shapes:
+        size = int(np.prod(shape))
+        parts.append(packed[offset:offset + size].reshape(shape))
+        offset += size
+    need_hi, need_lo, need_active, num_gpus, container_active = parts[:5]
+    rows, used_hi, used_lo = parts[5:]
+
+    def as_u32(words):
+        return jax.lax.bitcast_convert_type(words, jnp.uint32)
+
+    request = BinpackRequest(
+        need=i64.I64(hi=need_hi, lo=as_u32(need_lo)),
+        need_active=need_active != 0,
+        num_gpus=num_gpus,
+        container_active=container_active != 0,
+    )
+    return request, i64.I64(
+        hi=used.hi.at[rows].set(used_hi, mode="drop"),
+        lo=used.lo.at[rows].set(as_u32(used_lo), mode="drop"),
+    )
 
 
 def _card_fits(
@@ -158,9 +244,18 @@ def _fit_one_node(
 
 @partial(jax.jit, static_argnames=("max_gpus",))
 def binpack_kernel(
-    state: BinpackNodeState, request: BinpackRequest, max_gpus: int
+    state: BinpackNodeState,
+    request: Union[BinpackRequest, jax.Array],
+    max_gpus: int,
 ) -> BinpackResult:
-    """Fit ``request`` against every node at once (the batched Filter)."""
+    """Fit ``request`` against every node at once (the batched Filter).
+    Given the packed buffer of :func:`pack_request` instead of a
+    BinpackRequest, its update block is applied to ``state.used`` first
+    and the result carries the updated ``used``."""
+    used = None
+    if not isinstance(request, BinpackRequest):
+        request, used = _unpack_request(request, state.used)
+        state = state._replace(used=used)
     fits, cards, _ = jax.vmap(
         lambda used, cap, cap_p, ok, order: _fit_one_node(
             used, cap, cap_p, ok, order, request, max_gpus
@@ -172,4 +267,4 @@ def binpack_kernel(
         state.card_valid & state.card_real,
         state.card_order,
     )
-    return BinpackResult(fits=fits, cards=cards)
+    return BinpackResult(fits=fits, cards=cards, used=used)

@@ -110,6 +110,11 @@ declare("pas_wire_intern_misses_total", "counter", "Candidate-span universe-cach
 declare("pas_wire_intern_evictions_total", "counter", "Interned universes evicted past the MRU bound.")
 declare("pas_gas_filter_device_total", "counter", "GAS Filter requests served by the vmapped device binpack.")
 declare("pas_gas_filter_host_total", "counter", "GAS Filter requests served by the host loop.")
+# how the resident usage mirror was brought current for a device solve
+# (gas/device.py GASUsageMirror.stage); the two *_total partition the solves
+declare("pas_gas_state_incremental_total", "counter", "GAS device solves whose usage state was brought current by an update block of changed rows (a zero-row block included).")
+declare("pas_gas_state_full_restage_total", "counter", "GAS device solves that re-uploaded the whole usage tensor (more changed rows than the block holds, or a structure change).")
+declare("pas_gas_state_rows_applied_total", "counter", "Usage rows sent to the device inside update blocks.")
 # JAX compile visibility (watch_jit shim + jax.monitoring listeners)
 declare("pas_jax_kernel_compile_total", "counter", "Lowerings of watched scoring kernels (watch_jit shim).")
 declare("pas_jax_retrace_total", "counter", "Watched-kernel lowerings past each kernel's first compile: unexpected hot-path retraces.")

@@ -2,12 +2,16 @@
 ingestion/replay, device-vs-host binpack equivalence."""
 
 import json
+import sys
+import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
 from platform_aware_scheduling_tpu.extender.server import HTTPRequest
+from platform_aware_scheduling_tpu.gas import device as gas_device
 from platform_aware_scheduling_tpu.gas.cache import Cache, get_key
 from platform_aware_scheduling_tpu.gas.resource_map import ResourceMap
 from platform_aware_scheduling_tpu.gas.scheduler import (
@@ -23,8 +27,11 @@ from platform_aware_scheduling_tpu.gas.utils import (
     has_gpu_resources,
     is_completed_pod,
 )
+from platform_aware_scheduling_tpu.ops import i64
+from platform_aware_scheduling_tpu.ops.binpack import UPDATE_SLOTS, binpack_kernel
 from platform_aware_scheduling_tpu.testing.builders import make_node, make_pod
 from platform_aware_scheduling_tpu.testing.fake_kube import FakeKubeClient
+from platform_aware_scheduling_tpu.utils import trace
 
 
 def post(obj) -> HTTPRequest:
@@ -438,7 +445,7 @@ class TestUsageMirrorSync:
     def test_unknown_request_resource_after_snapshot(self):
         """Interning a never-seen request resource must invalidate the
         memoized snapshot: before the fix the old state (too-small r_pad)
-        made stage_request index out of bounds until the next cluster
+        made pack_request index out of bounds until the next cluster
         event, forcing host fallback on every such request."""
         kube = FakeKubeClient()
         kube.add_node(gpu_node("n1"))
@@ -515,3 +522,304 @@ class TestUsageMirrorSync:
             assert out["NodeNames"] == ["n1"]
         finally:
             cache.stop()
+
+
+class _Readback:
+    """Stands in for one array of a solve's result: counts the copies to
+    the host (``np.asarray`` calls ``__array__``)."""
+
+    def __init__(self, array, name, log):
+        self._array, self._name, self._log = array, name, log
+
+    def __array__(self, dtype=None, copy=None):
+        self._log.append(self._name)
+        return np.asarray(self._array)
+
+
+class TestResidentState:
+    """PR 27: ``used`` stays on the device and a booking travels as its
+    changed rows inside the Filter's own buffer (gas/device.py)."""
+
+    NODES = 12
+
+    @pytest.fixture
+    def cluster(self):
+        rng = np.random.default_rng(27)
+        kube = FakeKubeClient()
+        names = [f"n{i}" for i in range(self.NODES)]
+        for name in names:
+            kube.add_node(gpu_node(
+                name,
+                cards=int(rng.integers(1, 5)),
+                i915=int(rng.integers(2, 9)),
+                millicores=int(rng.integers(500, 4000)),
+                memory=int(rng.integers(500, 8000)),
+            ))
+        cache = Cache(kube, start=False)
+        host = GASExtender(kube, cache=cache, use_device=False)
+        staged = GASExtender(kube, cache=cache, use_device=True,
+                             use_mirror=False)
+        mirrored = GASExtender(kube, cache=cache, use_device=True,
+                               use_mirror=True)
+        cache.start()
+        mirror = mirrored._device.mirror
+        assert wait_until(lambda: int(mirror._known.sum()) == self.NODES)
+        yield kube, cache, names, host, staged, mirrored
+        cache.stop()
+
+    @staticmethod
+    def _counters():
+        return {
+            short: trace.COUNTERS.get(f"pas_gas_state_{short}_total")
+            for short in ("incremental", "full_restage", "rows_applied")
+        }
+
+    def _moved(self, before):
+        after = self._counters()
+        return {name: after[name] - before[name] for name in after}
+
+    @staticmethod
+    def _device_used(mirror):
+        version, _structure, state = mirror._device
+        assert version == mirror._version
+        return i64.to_int64_np(state.used)
+
+    def _agree(self, cluster, pod, tag):
+        """The mirror path against the host loop and the staged control,
+        verdicts and reasons; the device's ``used`` against NumPy's."""
+        _kube, _cache, names, host, staged, mirrored = cluster
+        req = post({"Pod": pod.raw, "NodeNames": names})
+        want = json.loads(host.filter(req).body)
+        assert json.loads(staged.filter(req).body) == want, tag
+        assert json.loads(mirrored.filter(req).body) == want, tag
+        # called directly, a device-path exception is not swallowed into
+        # the host loop
+        got = mirrored._device.batch_fit(pod, names, with_reasons=True)
+        assert got == staged._device.batch_fit(pod, names, with_reasons=True), tag
+        mirror = mirrored._device.mirror
+        with mirror._lock:
+            assert np.array_equal(self._device_used(mirror), mirror._used), tag
+
+    def test_random_walk_agrees_with_host_and_staged(self, cluster):
+        kube, cache, names, _host, _staged, mirrored = cluster
+        mirror = mirrored._device.mirror
+        rng = np.random.default_rng(2027)
+        booked = []
+        before = self._counters()
+
+        def book(step, card=None, extra=None):
+            node = names[int(rng.integers(0, len(names)))]
+            pod = gpu_pod(f"b{step}-{len(booked)}",
+                          millicores=str(int(rng.integers(1, 400))),
+                          node_name=node)
+            if extra:
+                pod.raw["spec"]["containers"][0]["resources"]["requests"][
+                    extra] = "1"
+            card = card or f"card{int(rng.integers(0, 4))}"
+            cache.adjust_pod_resources_locked(pod, True, card, node)
+            booked.append((pod, card, node))
+
+        def release():
+            if booked:
+                pod, card, node = booked.pop(int(rng.integers(0, len(booked))))
+                cache.adjust_pod_resources_locked(pod, False, card, node)
+
+        def node_event(change):
+            structure = mirror._structure
+            change()
+            assert wait_until(lambda: mirror._structure > structure)
+
+        def bigger(name, **more):
+            node = gpu_node(name, cards=4, i915=8, millicores=4000,
+                            memory=8000)
+            node.raw["status"]["allocatable"].update(more)
+            node.metadata["resourceVersion"] = str(1000 + len(booked))
+            return node
+
+        scripted = {
+            8: lambda s: book(s, card="card9"),  # a never-seen card
+            14: lambda s: [book(s) for _ in range(UPDATE_SLOTS + 3)],
+            20: lambda s: book(s, extra="gpu.intel.com/never-booked"),
+            26: lambda s: node_event(lambda: kube.add_node(bigger("n3"))),
+            32: lambda s: node_event(lambda: kube.add_node(
+                bigger("n5", **{"gpu.intel.com/tiles": "4"}))),
+            38: lambda s: node_event(lambda: kube.delete_node("n7")),
+        }
+        for step in range(44):
+            if step in scripted:
+                scripted[step](step)
+            elif rng.random() < 0.6 or not booked:
+                book(step)
+            else:
+                release()
+                book(step)
+            requests = {
+                "gpu.intel.com/i915": str(int(rng.integers(1, 3))),
+                "gpu.intel.com/millicores": str(int(rng.integers(0, 2500))),
+            }
+            if step == 29:  # a request resource no node and no booking has
+                requests["gpu.intel.com/never-seen"] = "1"
+            pod = make_pod(f"probe{step}", container_requests=[requests] * int(
+                rng.integers(1, 3)))
+            self._agree(cluster, pod, f"step {step}")
+        moved = self._moved(before)
+        # the burst, the new card, the new resources and the node events
+        # restage; everything else rides in update blocks
+        assert moved["full_restage"] >= 6
+        assert moved["incremental"] > moved["full_restage"]
+        assert moved["rows_applied"] >= moved["incremental"] / 2
+
+    def test_one_array_in_one_back_in_steady_state(self, cluster, monkeypatch):
+        _kube, cache, names, _host, _staged, mirrored = cluster
+        packer = mirrored._device
+        crossed = {"uploads": 0, "kernel_host_args": [], "readbacks": []}
+        upload, kernel = gas_device._to_device, gas_device.binpack_kernel
+
+        def counted_upload(array):
+            crossed["uploads"] += 1
+            return upload(array)
+
+        def counted_kernel(state, request, max_gpus):
+            crossed["kernel_host_args"].append(sum(
+                isinstance(leaf, np.ndarray)
+                for leaf in jax.tree_util.tree_leaves((state, request))))
+            result = kernel(state, request, max_gpus)
+            return result._replace(
+                fits=_Readback(result.fits, "fits", crossed["readbacks"]),
+                cards=_Readback(result.cards, "cards", crossed["readbacks"]))
+
+        monkeypatch.setattr(gas_device, "_to_device", counted_upload)
+        monkeypatch.setattr(gas_device, "binpack_kernel", counted_kernel)
+
+        def filtered(millicores):
+            crossed.update(uploads=0, kernel_host_args=[], readbacks=[])
+            before = self._counters()
+            pod = gpu_pod("probe", millicores=str(millicores))
+            assert packer.batch_fit(pod, names) is not None
+            return dict(crossed), self._moved(before)
+
+        filtered(100)  # brings the structure to the device: 8 uploads
+        held = gpu_pod("held", millicores="50", node_name=names[0])
+        cache.adjust_pod_resources_locked(held, True, "card0", names[0])
+        for cycle in range(1, 5):  # steady state: a booking and a release
+            pod = gpu_pod(f"c{cycle}", millicores="70", node_name=names[cycle])
+            cache.adjust_pod_resources_locked(pod, True, "card0", names[cycle])
+            cache.adjust_pod_resources_locked(
+                held, False, "card0", names[cycle - 1])
+            held = pod
+            seen, moved = filtered(100)
+            assert seen == {"uploads": 0, "kernel_host_args": [1],
+                            "readbacks": ["fits"]}, (cycle, seen)
+            assert moved == {"incremental": 1, "full_restage": 0,
+                             "rows_applied": 2}
+        # nothing changed, another template: its request crosses, no state
+        seen, moved = filtered(300)
+        assert seen == {"uploads": 0, "kernel_host_args": [1],
+                        "readbacks": ["fits"]}
+        assert moved == {"incremental": 1, "full_restage": 0,
+                         "rows_applied": 0}
+        # nothing changed, a template seen at this version: nothing crosses
+        seen, moved = filtered(300)
+        assert seen == {"uploads": 0, "kernel_host_args": [],
+                        "readbacks": []}
+        assert moved == {"incremental": 0, "full_restage": 0,
+                         "rows_applied": 0}
+
+    def test_every_dirty_count_runs_one_compiled_program(self, cluster):
+        kube, cache, names, _host, _staged, mirrored = cluster
+        mirror = mirrored._device.mirror
+        self._agree(cluster, gpu_pod("warm", millicores="10"), "warm")
+        programs = binpack_kernel._cache_size()
+        serial = 0
+        for dirty in list(range(UPDATE_SLOTS + 2)) + ["structure"]:
+            before = self._counters()
+            if dirty == "structure":
+                structure = mirror._structure
+                node = gpu_node("n4", cards=3, i915=8, millicores=3000)
+                node.metadata["resourceVersion"] = "77"
+                kube.add_node(node)
+                assert wait_until(lambda: mirror._structure > structure)
+                expected = {"incremental": 0, "full_restage": 1,
+                            "rows_applied": 0}
+            else:
+                for node in names[:dirty]:
+                    serial += 1
+                    pod = gpu_pod(f"d{serial}", millicores="5",
+                                  node_name=node)
+                    cache.adjust_pod_resources_locked(pod, True, "card0", node)
+                full = dirty > UPDATE_SLOTS
+                expected = {"incremental": int(not full),
+                            "full_restage": int(full),
+                            "rows_applied": 0 if full else dirty}
+            # one solve on the mirror path (a template of its own: the
+            # zero-row case must miss the fits cache), then the agreement
+            pod = gpu_pod("probe", millicores=str(100 + serial * 7 + len(
+                str(dirty))))
+            assert mirrored._device.batch_fit(pod, names) is not None
+            assert self._moved(before) == expected, dirty
+            self._agree(cluster, pod, f"dirty {dirty}")
+            # the staged control solves 12 rows, the mirror 16: its one
+            # program was compiled by the warm-up above
+            assert binpack_kernel._cache_size() == programs, dirty
+
+    def test_filters_race_a_booker(self, cluster):
+        _kube, cache, names, _host, _staged, mirrored = cluster
+        packer, mirror = mirrored._device, mirrored._device.mirror
+        installed, errors = [], []
+        install = mirror.install
+
+        def recording_install(base, used):
+            state = install(base, used)
+            installed.append(mirror._device[0])
+            return state
+
+        mirror.install = recording_install
+        stop = threading.Event()
+
+        def filtering(offset):
+            try:
+                turn = 0
+                while not stop.is_set():
+                    turn += 1
+                    pod = gpu_pod("probe", millicores=str(
+                        50 + (offset + turn) % 40))
+                    fits = packer.batch_fit(pod, names)
+                    assert fits is not None and len(fits) == len(names)
+            except BaseException as exc:  # noqa: BLE001 — reported below
+                errors.append(exc)
+
+        def booking():
+            try:
+                rng = np.random.default_rng(5)
+                turn = 0
+                while not stop.is_set():
+                    turn += 1
+                    node = names[int(rng.integers(0, len(names)))]
+                    pod = gpu_pod(f"r{turn}", millicores="3", node_name=node)
+                    cache.adjust_pod_resources_locked(pod, True, "card0", node)
+                    if turn % 3:
+                        cache.adjust_pod_resources_locked(
+                            pod, False, "card0", node)
+            except BaseException as exc:  # noqa: BLE001
+                errors.append(exc)
+
+        threads = [threading.Thread(target=filtering, args=(0,)),
+                   threading.Thread(target=filtering, args=(17,)),
+                   threading.Thread(target=booking)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for thread in threads:
+                thread.start()
+            time.sleep(1.5)
+        finally:
+            stop.set()
+            for thread in threads:
+                thread.join(timeout=30)
+            sys.setswitchinterval(interval)
+            mirror.install = install
+        assert not any(thread.is_alive() for thread in threads)
+        assert not errors, errors
+        # a stale solve never replaced a newer version's state
+        assert len(installed) > 3 and installed == sorted(installed)
+        self._agree(cluster, gpu_pod("after", millicores="60"), "after")

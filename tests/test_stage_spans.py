@@ -164,6 +164,35 @@ class TestGasStages:
         ext.filter(after)
         assert "state_upload" in after.span.stage_seconds()
 
+    @pytest.mark.parametrize("change", ["rows", "nothing", "structure"])
+    def test_the_device_stages_stay_on_every_solve(self, gas, change):
+        """``state_upload``, ``req_upload`` and ``solve`` are on every
+        Filter that reaches the device, whichever way its state was
+        brought current (the per-layer metrics gas_*_ms read them)."""
+        kube, cache, ext = gas
+        names = [f"n{i}" for i in range(4)]
+        ext.filter(_request("/scheduler/filter",
+                            {"Pod": _gpu_pod("probe").raw, "NodeNames": names}))
+        mirror = ext._device.mirror
+        if change == "rows":  # travels in the update block
+            booked = _gpu_pod("booked")
+            cache.adjust_pod_resources_locked(booked, True, "card0", "n1")
+        elif change == "structure":  # a full restage
+            structure = mirror._structure
+            node = _gpu_node("n2")
+            node.metadata["resourceVersion"] = "9"
+            kube.add_node(node)
+            _wait_until(lambda: mirror._structure > structure)
+        other = make_pod("probe-2", container_requests=[
+            {"gpu.intel.com/i915": "1", "gpu.intel.com/millicores": "700"}
+        ])
+        request = _request("/scheduler/filter",
+                           {"Pod": other.raw, "NodeNames": names})
+        assert ext.filter(request).status == 200
+        stages = request.span.stage_seconds()
+        assert {"state_upload", "req_upload", "solve"} <= set(stages), stages
+        assert request.span.attrs["path"] == "device"
+
     def test_bind_stages_tile_kernel(self, gas, monkeypatch):
         kube, cache, ext = gas
         pod = _gpu_pod("p")
