@@ -74,7 +74,6 @@ def batched_solve():
     import jax.numpy as jnp
 
     from platform_aware_scheduling_tpu.models.batch_scheduler import (
-        PendingPods,
         choose_assigner,
         scheduling_step,
     )
@@ -93,10 +92,8 @@ def batched_solve():
     # includes it.
     def loop_body(i, carry):
         checksum, cap = carry
-        rolled = PendingPods(
-            metric_row=pods.metric_row,
-            op_id=pods.op_id,
-            candidates=jnp.roll(pods.candidates, i, axis=1),
+        rolled = pods._replace(
+            candidates=jnp.roll(pods.candidates, i, axis=1)
         )
         out = scheduling_step(
             state._replace(capacity=cap), rolled, assigner=assigner

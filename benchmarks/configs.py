@@ -113,12 +113,12 @@ def _host_prioritize_control(state, pods, num_nodes: int, n_pods: int) -> float:
     m_lo = np.asarray(state.metric_values.lo).astype(np.int64)
     matrix = (m_hi << 32) | m_lo
     present = np.asarray(state.metric_present)
-    rules_row = np.asarray(state.dontschedule.metric_row)
-    rules_op = np.asarray(state.dontschedule.op_id)
-    t_hi = np.asarray(state.dontschedule.target.hi).astype(np.int64)
-    t_lo = np.asarray(state.dontschedule.target.lo).astype(np.int64)
+    rules_row = np.asarray(state.dontschedule.metric_row)[0]
+    rules_op = np.asarray(state.dontschedule.op_id)[0]
+    t_hi = np.asarray(state.dontschedule.target.hi)[0].astype(np.int64)
+    t_lo = np.asarray(state.dontschedule.target.lo)[0].astype(np.int64)
     rules_target = (t_hi << 32) | t_lo
-    rules_active = np.asarray(state.dontschedule.active)
+    rules_active = np.asarray(state.dontschedule.active)[0]
     capacity = list(np.asarray(state.capacity))
     pod_rows = np.asarray(pods.metric_row)
     pod_ops = np.asarray(pods.op_id)
@@ -159,7 +159,6 @@ def config2_multi_metric(num_nodes: int = 1000, num_pods: int = 100) -> Dict:
     import jax.numpy as jnp
 
     from platform_aware_scheduling_tpu.models.batch_scheduler import (
-        PendingPods,
         choose_assigner,
         example_inputs,
         scheduling_step,
@@ -175,10 +174,8 @@ def config2_multi_metric(num_nodes: int = 1000, num_pods: int = 100) -> Dict:
     def make_jit(reps):
         def loop_body(i, carry):
             checksum, cap = carry
-            rolled = PendingPods(
-                metric_row=pods.metric_row,
-                op_id=pods.op_id,
-                candidates=jnp.roll(pods.candidates, i, axis=1),
+            rolled = pods._replace(
+                candidates=jnp.roll(pods.candidates, i, axis=1)
             )
             out = scheduling_step(
                 state._replace(capacity=cap), rolled, assigner=assigner
@@ -442,12 +439,12 @@ def _host_fused_control(
     m_lo = np.asarray(state.metric_values.lo).astype(np.int64)
     matrix = (m_hi << 32) | m_lo
     present = np.asarray(state.metric_present)
-    rules_row = np.asarray(state.dontschedule.metric_row)
-    rules_op = np.asarray(state.dontschedule.op_id)
-    t_hi = np.asarray(state.dontschedule.target.hi).astype(np.int64)
-    t_lo = np.asarray(state.dontschedule.target.lo).astype(np.int64)
+    rules_row = np.asarray(state.dontschedule.metric_row)[0]
+    rules_op = np.asarray(state.dontschedule.op_id)[0]
+    t_hi = np.asarray(state.dontschedule.target.hi)[0].astype(np.int64)
+    t_lo = np.asarray(state.dontschedule.target.lo)[0].astype(np.int64)
     rules_target = (t_hi << 32) | t_lo
-    rules_active = np.asarray(state.dontschedule.active)
+    rules_active = np.asarray(state.dontschedule.active)[0]
     capacity = list(np.asarray(state.capacity))
     pod_rows = np.asarray(pods.metric_row)
     pod_ops = np.asarray(pods.op_id)
@@ -507,7 +504,6 @@ def config4_fused(num_nodes: int = 10_000, num_pods: int = 1000) -> Dict:
     import jax
     import jax.numpy as jnp
 
-    from platform_aware_scheduling_tpu.models.batch_scheduler import PendingPods
     from platform_aware_scheduling_tpu.models.fused import fused_schedule
 
     state, pods, req_class, gas, requests, max_gpus, hosts = _fused_problem(
@@ -524,10 +520,8 @@ def config4_fused(num_nodes: int = 10_000, num_pods: int = 1000) -> Dict:
 
     def make_jit(reps):
         def loop_body(i, checksum):
-            rolled = PendingPods(
-                metric_row=pods.metric_row,
-                op_id=pods.op_id,
-                candidates=jnp.roll(pods.candidates, i, axis=1),
+            rolled = pods._replace(
+                candidates=jnp.roll(pods.candidates, i, axis=1)
             )
             out = fused_schedule(
                 state, rolled, req_class, gas, requests, max_gpus

@@ -267,7 +267,10 @@ def assemble(
     enforcer.start_enforcing(cache, sync_period_s, stop=stop)
     if planner is not None:
         planner_informer = planner.watch(kube_client)
-        planner.start(sync_period_s)
+        # the plan's one trigger: the end of a refresh pass, after its
+        # publishes and warms (and the forecaster's refit, subscribed
+        # above), so the plan for a version exists as soon as it serves
+        cache.on_refresh_pass.append(planner.replan)
         threading.Thread(
             target=lambda: (stop.wait(), planner_informer.stop()), daemon=True
         ).start()

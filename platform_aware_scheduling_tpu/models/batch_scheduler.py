@@ -5,7 +5,8 @@ kube-scheduler drives one pod per extender round-trip
 (telemetryscheduler.go:39-59 per request); here the WHOLE pending set is
 solved at once over dense tensors:
 
-  1. dontschedule violations over the metric matrix  (ops/rules.py)
+  1. dontschedule violations over the metric matrix  (ops/rules.py),
+     per policy: a pod is kept off the nodes its OWN policy forbids
   2. per-pod score keys from each pod's scheduleonmetric rule
   3. greedy capacity-constrained assignment           (ops/assign.py)
 
@@ -39,6 +40,7 @@ from platform_aware_scheduling_tpu.ops.rules import (
     RuleSet,
     violated_nodes,
 )
+from platform_aware_scheduling_tpu.utils import trace
 
 
 class ClusterState(NamedTuple):
@@ -46,7 +48,9 @@ class ClusterState(NamedTuple):
 
     metric_values: i64.I64  # [M, N] milli-units
     metric_present: jax.Array  # bool [M, N]
-    dontschedule: RuleSet  # shared violation rules
+    # violation rules, ``[D, R]``: one (padded) list per distinct policy of
+    # the pending set, picked per pod by ``PendingPods.policy``
+    dontschedule: RuleSet
     capacity: jax.Array  # int32 [N] — pods each node may still accept
 
 
@@ -56,11 +60,12 @@ class PendingPods(NamedTuple):
     metric_row: jax.Array  # int32 [P]
     op_id: jax.Array  # int32 [P]
     candidates: jax.Array  # bool [P, N]
+    policy: jax.Array  # int32 [P] — the pod's row of ``dontschedule``
 
 
 class ScheduleOutput(NamedTuple):
     assignment: AssignResult
-    violating: jax.Array  # bool [N]
+    violating: jax.Array  # bool [D, N] — per policy of the pending set
     score: i64.I64  # [P, N] keys used (larger = better)
     eligible: jax.Array  # bool [P, N] — candidates ∩ present ∩ ¬violating
 
@@ -86,14 +91,15 @@ def score_and_filter(state: ClusterState, pods: PendingPods):
     """The non-assignment half of the solve: (violating, score, eligible).
     Separable so alternative assignment solvers (ops/sinkhorn.py) don't pay
     for a greedy solve they discard."""
-    violating = violated_nodes(
+    violating = jax.vmap(violated_nodes, in_axes=(None, None, 0))(
         state.metric_values, state.metric_present, state.dontschedule
-    )
+    )  # [D, N]
+    forbidden = violating[pods.policy]  # [P, N]: each pod's own policy
     score = _score_keys(
         state.metric_values, state.metric_present, pods.metric_row, pods.op_id
     )
     present = state.metric_present[pods.metric_row]  # [P, N]
-    eligible = pods.candidates & present & ~violating[None, :]
+    eligible = pods.candidates & present & ~forbidden
     return violating, score, eligible
 
 
@@ -143,6 +149,11 @@ def _scheduling_step(
     )
 
 
+# the planner pads its pending set to a few fixed sizes (tas/planner.py):
+# a lowering past those is a retrace the compile counters must show
+_scheduling_step = trace.watch_jit("scheduling_step", _scheduling_step)
+
+
 def scheduling_step(
     state: ClusterState, pods: PendingPods, assigner: Optional[str] = None
 ) -> ScheduleOutput:
@@ -174,10 +185,10 @@ def observed_scheduling_step(
         if obs is None:
             return scheduling_step(state, pods)
         timer = obs.begin("batch_solve")
-    before = _scheduling_step._cache_size()
+    before = _scheduling_step.cache_size()
     out = scheduling_step(state, pods)
     timer.mark(
-        "compile" if _scheduling_step._cache_size() > before else "execute"
+        "compile" if _scheduling_step.cache_size() > before else "execute"
     )
     jax.block_until_ready(out.assignment.node_for_pod)
     timer.mark("execute")
@@ -195,7 +206,8 @@ def example_inputs(
     num_pods: int = 16,
     seed: int = 0,
 ):
-    """Small synthetic (state, pods) pair for compile checks and benches."""
+    """Small synthetic (state, pods) pair for compile checks and benches:
+    one policy (D = 1) over every pod."""
     import numpy as np
 
     rng = np.random.default_rng(seed)
@@ -208,12 +220,12 @@ def example_inputs(
         metric_values=i64.I64(hi=jnp.asarray(hi), lo=jnp.asarray(lo)),
         metric_present=jnp.asarray(rng.random((num_metrics, num_nodes)) > 0.05),
         dontschedule=RuleSet(
-            metric_row=jnp.asarray(np.array([0, 1], dtype=np.int32)),
+            metric_row=jnp.asarray(np.array([[0, 1]], dtype=np.int32)),
             op_id=jnp.asarray(
-                np.array([OP_GREATER_THAN, OP_GREATER_THAN], dtype=np.int32)
+                np.array([[OP_GREATER_THAN, OP_GREATER_THAN]], dtype=np.int32)
             ),
-            target=i64.I64(hi=jnp.asarray(t_hi), lo=jnp.asarray(t_lo)),
-            active=jnp.asarray(np.array([True, True])),
+            target=i64.I64(hi=jnp.asarray(t_hi[None]), lo=jnp.asarray(t_lo[None])),
+            active=jnp.asarray(np.array([[True, True]])),
         ),
         capacity=jnp.asarray(
             rng.integers(1, 4, size=num_nodes).astype(np.int32)
@@ -229,5 +241,6 @@ def example_inputs(
             )
         ),
         candidates=jnp.asarray(rng.random((num_pods, num_nodes)) > 0.1),
+        policy=jnp.zeros(num_pods, dtype=jnp.int32),
     )
     return state, pods

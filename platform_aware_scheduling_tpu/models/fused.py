@@ -79,7 +79,7 @@ class FusedOutput(NamedTuple):
     capacity_left: jax.Array  # int32 [N]
     used: i64.I64  # [N, C, R] card usage after all bookings
     fits: jax.Array  # bool [T, N] feasibility AFTER all bookings
-    violating: jax.Array  # bool [N] — TAS dontschedule mask
+    violating: jax.Array  # bool [D, N] — TAS dontschedule mask per policy
 
 
 def _stacked(requests: FusedRequests):
@@ -161,6 +161,7 @@ def shard_fused_inputs(mesh, state, pods, req_class, gas, requests):
         candidates=node_shard(pods.candidates, 1),
         metric_row=jax.device_put(pods.metric_row, rep),
         op_id=jax.device_put(pods.op_id, rep),
+        policy=jax.device_put(pods.policy, rep),
     )
     gas_s = jax.tree.map(lambda x: node_shard(x, 0), gas)
     requests_s = jax.tree.map(lambda x: jax.device_put(x, rep), requests)

@@ -16,7 +16,10 @@
  *                                 -> response bytes: global rank order
  *                                    restricted to the request's candidate
  *                                    set, ordinal 10-i scores, optional
- *                                    batch-plan promotion to rank 1
+ *                                    batch-plan promotion to rank 1 (asked
+ *                                    with promotion=True, it returns
+ *                                    (bytes, what the planned row did):
+ *                                    see emit_ranked)
  *
  * The JSON scanner is strict: any structural surprise raises ValueError and
  * the caller falls back to the exact Python path (which reproduces every
@@ -1325,17 +1328,24 @@ static int put_score(Buf *b, long score) {
  * select_encode_universe compile from, so warm-universe bytes can never
  * drift from the cold path's: candidate mask + global rank order ->
  * "[{fragment}<score>, ...]\n" with optional planned-row promotion to
- * rank 1.  0 on success, -1 on OOM. */
+ * rank 1.  *promotion (when asked for) says what the planned row did:
+ * 0 it is not among the ranked candidates, 1 it led the ranking already,
+ * 2 it was moved to rank 1 past a better-ranked candidate.  0 on success,
+ * -1 on OOM. */
 static int emit_ranked(Buf *out, NameTable *t, const uint8_t *mask,
                        const int64_t *order, Py_ssize_t n_ranked,
-                       Py_ssize_t planned_row) {
+                       Py_ssize_t planned_row, int *promotion) {
     int promote = 0;
     if (planned_row >= 0 && planned_row < t->n_rows && mask[planned_row]) {
         /* planned node goes first iff it appears in the ranked order */
+        int ahead = 0;
         for (Py_ssize_t k = 0; k < n_ranked; k++) {
-            if (order[k] == planned_row) { promote = 1; break; }
+            int64_t row = order[k];
+            if (row == planned_row) { promote = 1 + ahead; break; }
+            if (row >= 0 && row < t->n_rows && mask[row]) ahead = 1;
         }
     }
+    if (promotion) *promotion = promote;
     long rank = 0;
     int first = 1;
     if (buf_put(out, "[", 1) < 0) return -1;
@@ -1378,9 +1388,9 @@ static PyObject *wirec_select_encode(PyObject *mod, PyObject *args) {
     (void)mod;
     PyObject *parsed_obj, *table_obj, *ranked_obj;
     Py_ssize_t planned_row = -1;
-    int use_node_names = 0;
-    if (!PyArg_ParseTuple(args, "OOO|np", &parsed_obj, &table_obj, &ranked_obj,
-                          &planned_row, &use_node_names))
+    int use_node_names = 0, want_promotion = 0, promotion = 0;
+    if (!PyArg_ParseTuple(args, "OOO|npp", &parsed_obj, &table_obj, &ranked_obj,
+                          &planned_row, &use_node_names, &want_promotion))
         return NULL;
     if (!PyObject_TypeCheck(parsed_obj, &ParsedArgs_Type)) {
         PyErr_SetString(PyExc_TypeError, "expected ParsedArgs");
@@ -1448,7 +1458,8 @@ static PyObject *wirec_select_encode(PyObject *mod, PyObject *args) {
 
     out_buf = pool_get(ranked_estimate(t, mask));
     if (!out_buf.data) oom = 1;
-    if (!oom && emit_ranked(out, t, mask, order, n_ranked, planned_row) < 0)
+    if (!oom && emit_ranked(out, t, mask, order, n_ranked, planned_row,
+                            &promotion) < 0)
         oom = 1;
     Py_END_ALLOW_THREADS
 
@@ -1460,6 +1471,7 @@ static PyObject *wirec_select_encode(PyObject *mod, PyObject *args) {
     }
     PyObject *res = PyBytes_FromStringAndSize(out->data, (Py_ssize_t)out->len);
     pool_put(&out_buf);
+    if (res && want_promotion) return Py_BuildValue("(Ni)", res, promotion);
     return res;
 
 error:
@@ -2407,7 +2419,8 @@ done:
     return res;
 }
 
-/* select_encode_universe(universe, table, ranked, planned_row) -> bytes
+/* select_encode_universe(universe, table, ranked, planned_row,
+ *                        promotion=False) -> bytes | (bytes, promotion)
  *
  * The universe twin of select_encode: the candidate mask fills from the
  * cached row map instead of per-name hash lookups; the emit loop is
@@ -2417,8 +2430,9 @@ static PyObject *wirec_select_encode_universe(PyObject *mod, PyObject *args) {
     (void)mod;
     PyObject *universe_obj, *table_obj, *ranked_obj;
     Py_ssize_t planned_row = -1;
-    if (!PyArg_ParseTuple(args, "OOO|n", &universe_obj, &table_obj,
-                          &ranked_obj, &planned_row))
+    int want_promotion = 0, promotion = 0;
+    if (!PyArg_ParseTuple(args, "OOO|np", &universe_obj, &table_obj,
+                          &ranked_obj, &planned_row, &want_promotion))
         return NULL;
     if (!PyObject_TypeCheck(universe_obj, &Universe_Type)) {
         PyErr_SetString(PyExc_TypeError, "expected Universe");
@@ -2463,7 +2477,8 @@ static PyObject *wirec_select_encode_universe(PyObject *mod, PyObject *args) {
     int oom = 0;
     out_buf = pool_get(ranked_estimate(t, mask));
     if (!out_buf.data) oom = 1;
-    if (!oom && emit_ranked(out, t, mask, order, n_ranked, planned_row) < 0)
+    if (!oom && emit_ranked(out, t, mask, order, n_ranked, planned_row,
+                            &promotion) < 0)
         oom = 1;
     pool_put(&mask_buf);
     PyBuffer_Release(&ranked);
@@ -2473,6 +2488,7 @@ static PyObject *wirec_select_encode_universe(PyObject *mod, PyObject *args) {
     }
     PyObject *res = PyBytes_FromStringAndSize(out->data, (Py_ssize_t)out->len);
     pool_put(&out_buf);
+    if (res && want_promotion) return Py_BuildValue("(Ni)", res, promotion);
     return res;
 }
 

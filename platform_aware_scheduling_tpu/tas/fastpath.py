@@ -64,6 +64,20 @@ def _score_suffixes(n: int) -> List[bytes]:
     return _SCORE_SUFFIX
 
 
+def count_plan(promotion: int) -> None:
+    """The planner's counters for one Prioritize answer given with a
+    current plan's node, counted where the promotion is made: 0 the node
+    is not among the ranked candidates (unplanned), 1 it leads the answer
+    and led the ordinal ranking already (promoted), 2 it leads because it
+    was moved past a better-ranked candidate (promoted and reordered)."""
+    if not promotion:
+        trace.COUNTERS.inc("pas_planner_unplanned_total")
+        return
+    trace.COUNTERS.inc("pas_planner_promoted_total")
+    if promotion == 2:
+        trace.COUNTERS.inc("pas_planner_reordered_total")
+
+
 def _response_cache_size(default: int = 32) -> int:
     """PAS_TPU_RESPONSE_CACHE, validated: malformed or non-positive
     values fall back to the default rather than crashing the import or
@@ -176,7 +190,8 @@ class PrioritizeFastPath:
         # by comparing the raw candidate-span bytes — identical span +
         # identical ranking implies a byte-identical response, with zero
         # false positives (no hashing trust).  List of
-        # [ranked, table, planned_row, span_bytes, response], MRU first.
+        # [ranked, table, planned_row, span_bytes, response, promotion]
+        # (what the planned row did in it: count_plan), MRU first.
         self._responses: List[list] = []
         # same idea for Filter: [violation_set, use_nn, span_bytes, body,
         # n_failed, gang_version] — the failed-entry count rides along so
@@ -193,7 +208,7 @@ class PrioritizeFastPath:
         # of a span memcmp, and any state change (new frozenset / new
         # ranking / new reservation version) misses by construction.
         # Entries: [violations, universe, gang_version, body, n_failed]
-        # and [ranked, table, planned_row, universe, body].
+        # and [ranked, table, planned_row, universe, body, promotion].
         self._filter_skeletons: List[list] = []
         self._prioritize_skeletons: List[list] = []
         # merged (telemetry + gang reservation) Filter verdicts, one per
@@ -488,7 +503,7 @@ class PrioritizeFastPath:
                     )
                     with self._lock:
                         self._prioritize_skeletons.insert(
-                            0, [ranked, table, -1, universe, body]
+                            0, [ranked, table, -1, universe, body, 0]
                         )
                         del self._prioritize_skeletons[
                             self.RESPONSE_CACHE_SIZE :
@@ -563,7 +578,23 @@ class PrioritizeFastPath:
         ``universe`` the skeleton layer serves first — identity compares
         only, no span memcmp — and a miss renders through the universe's
         cached row map (``select_encode_universe``, zero hashing); either
-        way the bytes are identical to the span path's."""
+        way the bytes are identical to the span path's.  With a current
+        plan's node (``planned``) the planner's counters say what it did
+        to this answer (:func:`count_plan`)."""
+        response, promotion = self._prioritize_parsed(
+            wirec, compiled, view, parsed, planned, use_node_names, span,
+            universe,
+        )
+        if planned is not None:
+            count_plan(promotion)
+        return response
+
+    def _prioritize_parsed(
+        self, wirec, compiled, view, parsed, planned, use_node_names, span,
+        universe,
+    ) -> Tuple[bytes, int]:
+        """(response, what the planned row did in it: see
+        :func:`count_plan`), from the caches or rendered."""
         table = self._table_for(view)
         with span.stage("kernel"):
             ranked = self._ranking(
@@ -588,7 +619,7 @@ class PrioritizeFastPath:
                             skeletons.insert(0, skeletons.pop(idx))
                         span.set("fastpath", "hit")
                         trace.COUNTERS.inc("pas_fastpath_response_hit_total")
-                        return entry[4]
+                        return entry[4], entry[5]
             responses = self._responses
             for idx, entry in enumerate(responses):
                 if (
@@ -604,46 +635,49 @@ class PrioritizeFastPath:
                         # layer so the next warm request skips the memcmp
                         self._prioritize_skeletons.insert(
                             0,
-                            [ranked, table, planned_row, universe, entry[4]],
+                            [ranked, table, planned_row, universe, *entry[4:]],
                         )
                         del self._prioritize_skeletons[
                             self.RESPONSE_CACHE_SIZE :
                         ]
                     span.set("fastpath", "hit")
                     trace.COUNTERS.inc("pas_fastpath_response_hit_total")
-                    return entry[4]
+                    return entry[4], entry[5]
         span.set("fastpath", "miss")
         trace.COUNTERS.inc("pas_fastpath_response_miss_total")
+        # asked, the encoder also says what it did with the planned row
+        # (wirec.c emit_ranked): 0 without one
         with span.stage("encode"):
             if universe is not None and hasattr(
                 wirec, "select_encode_universe"
             ):
-                response = wirec.select_encode_universe(
-                    universe, table.native(wirec), ranked, planned_row
+                response, promotion = wirec.select_encode_universe(
+                    universe, table.native(wirec), ranked, planned_row, True
                 )
             else:
-                response = wirec.select_encode(
+                response, promotion = wirec.select_encode(
                     parsed, table.native(wirec), ranked, planned_row,
-                    use_node_names,
+                    use_node_names, True,
                 )
         if universe is not None:
             with self._lock:
                 self._prioritize_skeletons.insert(
-                    0, [ranked, table, planned_row, universe, response]
+                    0,
+                    [ranked, table, planned_row, universe, response, promotion],
                 )
                 del self._prioritize_skeletons[self.RESPONSE_CACHE_SIZE :]
-            return response
+            return response, promotion
         # cand_span: the request's raw candidate byte-span (the cache key)
         # — distinct from the trace `span` parameter above
         cand_span = (
             parsed.node_names_span() if use_node_names else parsed.nodes_span()
         )
         if cand_span is not None:
-            entry = [ranked, table, planned_row, cand_span, response]
+            entry = [ranked, table, planned_row, cand_span, response, promotion]
             with self._lock:
                 self._responses.insert(0, entry)
                 del self._responses[self.RESPONSE_CACHE_SIZE :]
-        return response
+        return response, promotion
 
     def prioritize_bytes(
         self,
@@ -676,11 +710,10 @@ class PrioritizeFastPath:
             mask[sentinel] = False
             sel = ranked[mask[ranked]]
             if planned is not None:
-                prow = index.get(planned)
-                if prow is not None:
-                    at = np.nonzero(sel == prow)[0]
-                    if at.size:
-                        sel = np.concatenate(([prow], np.delete(sel, at[0])))
+                at = np.nonzero(sel == index.get(planned, -1))[0]
+                if at.size:
+                    sel = np.concatenate(([sel[at[0]]], np.delete(sel, at[0])))
+                count_plan((2 if at[0] else 1) if at.size else 0)
             return self._encode(table, sel)
 
     @staticmethod

@@ -62,7 +62,7 @@ from platform_aware_scheduling_tpu.ops import solveobs
 from platform_aware_scheduling_tpu.tas.cache import AutoUpdatingCache, CacheMissError
 from platform_aware_scheduling_tpu.tas import degraded as degraded_mode
 from platform_aware_scheduling_tpu.native import get_wirec
-from platform_aware_scheduling_tpu.tas.fastpath import PrioritizeFastPath
+from platform_aware_scheduling_tpu.tas.fastpath import PrioritizeFastPath, count_plan
 from platform_aware_scheduling_tpu.tas.policy.v1alpha1 import TASPolicy, TASPolicyRule
 from platform_aware_scheduling_tpu.tas.strategies import core, dontschedule
 from platform_aware_scheduling_tpu.utils import decisions, events, klog, trace
@@ -1583,7 +1583,9 @@ class MetricsExtender:
             return result
         hosts = [hp.host for hp in result]
         if planned not in hosts:
+            count_plan(0)
             return result
+        count_plan(2 if planned != hosts[0] else 1)
         reordered = [planned] + [h for h in hosts if h != planned]
         return [
             HostPriority(host=h, score=10 - i) for i, h in enumerate(reordered)

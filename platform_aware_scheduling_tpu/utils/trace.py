@@ -115,6 +115,20 @@ declare("pas_gas_filter_host_total", "counter", "GAS Filter requests served by t
 declare("pas_gas_state_incremental_total", "counter", "GAS device solves whose usage state was brought current by an update block of changed rows (a zero-row block included).")
 declare("pas_gas_state_full_restage_total", "counter", "GAS device solves that re-uploaded the whole usage tensor (more changed rows than the block holds, or a structure change).")
 declare("pas_gas_state_rows_applied_total", "counter", "Usage rows sent to the device inside update blocks.")
+# batch planner (tas/planner.py; --batchPlanner): one replan after every
+# refresh pass.  snapshot + solve + publish <= replan; promoted, stale and
+# unplanned partition the Prioritize answers given with the planner on;
+# reordered is the part of promoted in which the plan changed the answer.
+declare("pas_planner_replans_total", "counter", "Batch-planner replans that solved a pending set.")
+declare("pas_planner_replan_seconds_total", "counter", "Seconds spent in those replans, snapshot to published plan.")
+declare("pas_planner_snapshot_seconds_total", "counter", "Replan seconds reading the pending set, the policies and the nodes' room into the solve's operands.")
+declare("pas_planner_solve_seconds_total", "counter", "Replan seconds from the solve's dispatch to the readback of its assignment.")
+declare("pas_planner_publish_seconds_total", "counter", "Replan seconds building and publishing the plan's pod -> node table.")
+declare("pas_planner_pending_pods", "gauge", "Pending pods the last replan solved.")
+declare("pas_planner_promoted_total", "counter", "Prioritize answers that carried a current plan's node to rank 1.")
+declare("pas_planner_reordered_total", "counter", "Of those, answers in which the plan's node was moved past a candidate the ordinal ranking put first: the answers the plan changed.")
+declare("pas_planner_stale_total", "counter", "Prioritize answers for a planned pod whose plan's version was not the mirror's.")
+declare("pas_planner_unplanned_total", "counter", "Prioritize answers with no plan entry for the pod, or whose planned node was not among the candidates.")
 # JAX compile visibility (watch_jit shim + jax.monitoring listeners)
 declare("pas_jax_kernel_compile_total", "counter", "Lowerings of watched scoring kernels (watch_jit shim).")
 declare("pas_jax_retrace_total", "counter", "Watched-kernel lowerings past each kernel's first compile: unexpected hot-path retraces.")

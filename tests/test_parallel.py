@@ -239,6 +239,7 @@ class TestGSPMDFullSolve:
             metric_row=jax.device_put(pods.metric_row, pods_sharding),
             op_id=jax.device_put(pods.op_id, pods_sharding),
             candidates=jax.device_put(pods.candidates, grid_sharded(mesh)),
+            policy=jax.device_put(pods.policy, pods_sharding),
         )
         got = scheduling_step(state_s, pods_s)
         np.testing.assert_array_equal(

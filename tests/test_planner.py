@@ -209,7 +209,7 @@ class TestCapacityFidelity:
             deadline = time.monotonic() + 5
             while time.monotonic() < deadline and "n1" not in planner._node_alloc:
                 time.sleep(0.02)
-            assert planner._node_alloc.get("n1") == 0
+            assert planner._node_alloc.get("n1")[0] == 0  # (pods, cpu, memory)
             planner.pod_added(pending_pod("p0"))
             planner.replan()
             assert planner.planned_node(pending_pod("p0")) == "n2"

@@ -160,6 +160,29 @@ class TestCSurface:
             got = wirec.select_encode_universe(universe, table, ranked, planned)
             assert got == want
 
+    @pytest.mark.parametrize("planned,want", [
+        (-1, 0),  # no plan
+        (27, 0),  # the table does not know the row
+        (24, 0),  # known to the table, not among the candidates sent
+        (4, 1),  # the ordinal ranking's first candidate already
+        (7, 2),  # moved to rank 1 past row 4
+    ])
+    def test_asked_the_encoders_say_what_the_planned_row_did(self, planned, want):
+        names = [f"node-{i}" for i in range(24)]
+        table = wirec.build_table(names + ["node-24"])
+        parsed = wirec.parse_prioritize(nn_body(names))
+        universe, _ = wirec.UniverseCache().intern(parsed, True)
+        ranked = np.array([24, 4, 7] + [r for r in range(24) if r not in (4, 7)],
+                          dtype=np.int64)
+        plain = wirec.select_encode(parsed, table, ranked, planned, True)
+        assert isinstance(plain, bytes)  # not asked: the bytes alone, as ever
+        assert wirec.select_encode(
+            parsed, table, ranked, planned, True, True) == (plain, want)
+        assert wirec.select_encode_universe(
+            universe, table, ranked, planned, True) == (plain, want)
+        first = json.loads(plain)[0]["Host"]
+        assert first == ("node-7" if want == 2 else "node-4")
+
     def test_rows_rebuild_on_table_change(self):
         """Node interning moved (a node joined): the universe's cached
         row map must rebuild against the new table, not splice stale
