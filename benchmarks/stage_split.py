@@ -191,6 +191,8 @@ def main(argv=None) -> int:
         row = {
             "workload": args.workload, "seed": args.seed,
             "platform": devices[0].platform,
+            # first verbs sent: what pas_filter_native_total is a share of
+            "filters": len(records),
             "pods_per_s": len(records) / (window["ended"] - window["began"]),
             "cycle_p50_ms": spans[len(spans) // 2] * 1e3,
             "window_began_wall": wall0,
@@ -204,7 +206,8 @@ def main(argv=None) -> int:
             "counters": {
                 name: after.get(name, 0.0) - before.get(name, 0.0)
                 for name in after
-                if name.startswith(("pas_refresh_", "pas_gc_", "pas_gas_"))},
+                if name.startswith(
+                    ("pas_refresh_", "pas_gc_", "pas_gas_", "pas_filter_"))},
         }
         if stage_cost is not None:
             # {span name: {group: [median ms, mean ms of the middle 80%, spans]}}

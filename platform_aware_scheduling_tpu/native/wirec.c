@@ -414,6 +414,10 @@ typedef struct {
     int nodes_present;     /* "Nodes" was a non-null object with items */
     StrSlice *names;       /* node name slices (Nodes.items[].metadata.name) */
     Py_ssize_t num_names;
+    /* two offsets per Nodes.items entry, grown with ``names``: the item's
+     * opening '{' and one past its closing '}' in the body — what the
+     * Nodes-wire Filter echoes (filter_encode_nodes) */
+    Py_ssize_t *item_spans;
     int node_names_present; /* "NodeNames" was a non-null array */
     StrSlice *nn_names;     /* NodeNames[] string slices */
     Py_ssize_t num_nn_names;
@@ -427,6 +431,7 @@ typedef struct {
 static void ParsedArgs_dealloc(ParsedArgs *self) {
     Py_XDECREF(self->body);
     free(self->names);  /* raw-allocated: grown while the GIL is released */
+    free(self->item_spans);
     free(self->nn_names);
     Py_TYPE(self)->tp_free((PyObject *)self);
 }
@@ -749,22 +754,30 @@ static Py_ssize_t grow_cap(Py_ssize_t cap) {
 }
 
 static int push_name(Scan *sc, ParsedArgs *pa, Py_ssize_t *cap,
-                     const StrSlice *sl) {
+                     const StrSlice *sl, Py_ssize_t begin, Py_ssize_t end) {
     if (pa->num_names == *cap) {
         Py_ssize_t ncap = grow_cap(*cap);
         StrSlice *nn = realloc(pa->names, ncap * sizeof(StrSlice));
         if (!nn) return fail("out of memory");
         pa->names = nn;
+        Py_ssize_t *ns =
+            realloc(pa->item_spans, ncap * 2 * sizeof(Py_ssize_t));
+        if (!ns) return fail("out of memory");
+        pa->item_spans = ns;
         *cap = ncap;
     }
+    pa->item_spans[2 * pa->num_names] = begin;
+    pa->item_spans[2 * pa->num_names + 1] = end;
     pa->names[pa->num_names++] = *sl;
     return 0;
 }
 
 static int scan_node_item(Scan *sc, ParsedArgs *pa, Py_ssize_t *cap) {
-    /* one Nodes.items entry: capture metadata.name, skip the rest */
+    /* one Nodes.items entry: capture metadata.name and the item's own
+     * byte span, skip the rest */
     skip_ws(sc);
     if (sc->i >= sc->n || sc->s[sc->i] != '{') return fail("node not object");
+    Py_ssize_t begin = sc->i;
     sc->i++;
     skip_ws(sc);
     StrSlice name = {0, 0, 0, 0};
@@ -843,7 +856,7 @@ done:
     if (!name.present) {
         name.off = 0; name.len = 0; name.escaped = 0; name.present = 1;
     }
-    return push_name(sc, pa, cap, &name);
+    return push_name(sc, pa, cap, &name, begin, sc->i);
 }
 
 static int push_nn_name(Scan *sc, ParsedArgs *pa, Py_ssize_t *cap,
@@ -1043,6 +1056,7 @@ static PyObject *wirec_parse_prioritize(PyObject *mod, PyObject *arg) {
     pa->nodes_present = 0;
     pa->names = NULL;
     pa->num_names = 0;
+    pa->item_spans = NULL;
     pa->node_names_present = 0;
     pa->nn_names = NULL;
     pa->num_nn_names = 0;
@@ -1483,8 +1497,10 @@ error:
 /* ------------------------------------------------------------------ */
 /* filter_encode                                                       */
 
-/* THE Filter emit loop — the one copy both filter_encode and
- * filter_respond compile from, so warm-universe bytes can never drift
+#define buf_lit(b, lit) buf_put((b), (lit), sizeof(lit) - 1)
+
+/* THE Filter emit loop — the one copy filter_encode, filter_encode_nodes
+ * and filter_respond compile from, so warm-universe bytes can never drift
  * from the cold path's:
  *
  *   {"Nodes": null, "NodeNames": [...passing...],
@@ -1495,16 +1511,45 @@ error:
  * ``raw_ok`` (bytes emit verbatim) with ``enc_ptr``/``enc_len`` holding
  * the pre-JSON-encoded form for non-raw names (may be NULL when every
  * candidate is raw).  ``seen`` is a caller-zeroed per-row dedup
- * scratch; 0 on success with *n_failed_out set, -1 on OOM. */
+ * scratch; 0 on success with *n_failed_out set, -1 on OOM.
+ *
+ * With ``item_spans`` (two ``base`` offsets a candidate, the Nodes wire)
+ * the answer is the exact path's Nodes-mode FilterResult instead:
+ *
+ *   {"Nodes": {"metadata": {}, "items": [<item>, ...]},
+ *    "NodeNames": [...passing..., ""], "FailedNodes": {...}, "Error": ""}\n
+ *
+ * each <item> the passing candidate's own bytes, ``"items": null`` when
+ * none passes, NodeNames closed by the "" the reference's split(" ")
+ * leaves. */
 static int emit_filter(Buf *out, const char *base, const StrSlice *cand,
                        Py_ssize_t num, const Py_ssize_t *rows,
                        const uint8_t *raw_ok, const char **enc_ptr,
                        const Py_ssize_t *enc_len, const uint8_t *vmask,
                        const char **reason_ptr, const Py_ssize_t *reason_len,
-                       uint8_t *seen, Py_ssize_t *n_failed_out) {
+                       uint8_t *seen, const Py_ssize_t *item_spans,
+                       Py_ssize_t *n_failed_out) {
     Py_ssize_t n_failed = 0;
-    if (buf_put(out, "{\"Nodes\": null, \"NodeNames\": [", 30) < 0) return -1;
     int first = 1;
+    if (item_spans) {
+        if (buf_lit(out, "{\"Nodes\": {\"metadata\": {}, \"items\": ") < 0)
+            return -1;
+        for (Py_ssize_t k = 0; k < num; k++) {
+            Py_ssize_t row = rows[k];
+            if (row >= 0 && vmask[row]) continue;
+            if (buf_put(out, first ? "[" : ", ", first ? 1 : 2) < 0 ||
+                buf_put(out, base + item_spans[2 * k],
+                        (size_t)(item_spans[2 * k + 1] - item_spans[2 * k])) < 0)
+                return -1;
+            first = 0;
+        }
+        if (buf_put(out, first ? "null" : "]", first ? 4 : 1) < 0 ||
+            buf_lit(out, "}, \"NodeNames\": [") < 0)
+            return -1;
+        first = 1;
+    } else if (buf_lit(out, "{\"Nodes\": null, \"NodeNames\": [") < 0) {
+        return -1;
+    }
     for (Py_ssize_t k = 0; k < num; k++) {
         Py_ssize_t row = rows[k];
         if (row >= 0 && vmask[row]) continue;  /* violating -> FailedNodes */
@@ -1520,6 +1565,8 @@ static int emit_filter(Buf *out, const char *base, const StrSlice *cand,
             return -1;
         }
     }
+    if (item_spans && buf_put(out, first ? "\"\"" : ", \"\"", first ? 2 : 4) < 0)
+        return -1;
     if (buf_put(out, "], \"FailedNodes\": {", 19) < 0) return -1;
     first = 1;
     for (Py_ssize_t k = 0; k < num; k++) {
@@ -1575,9 +1622,19 @@ static int emit_filter(Buf *out, const char *base, const StrSlice *cand,
  * has no escapes and every byte is in [0x20,0x7e] (exactly the set
  * json.dumps re-emits unchanged); duplicate violating names collapse to
  * one FailedNodes entry at first-occurrence position (dict semantics);
- * names absent from the table never violate (they pass through). */
-static PyObject *wirec_filter_encode(PyObject *mod, PyObject *args) {
-    (void)mod;
+ * names absent from the table never violate (they pass through).
+ *
+ * ``nodes_wire`` (filter_encode_nodes) answers a request that carried
+ * ``Nodes``: the candidates are Nodes.items, and every passing one is
+ * echoed as the slice of the request it arrived in — no decoded object,
+ * nothing re-encoded (emit_filter).  Everything outside the items is
+ * byte-identical to the exact path; an item is JSON-equal to it, not
+ * byte-equal: it keeps the request's separators and escapes, where the
+ * exact path writes json.dumps' own.  Returns None, and the exact path
+ * answers, where the reference's ``available.split(" ")`` would not
+ * give the names back one for one: a candidate named "" or holding a
+ * space. */
+static PyObject *filter_encode_common(PyObject *args, int nodes_wire) {
     PyObject *parsed_obj, *table_obj, *mask_obj, *reasons_obj = Py_None;
     if (!PyArg_ParseTuple(args, "OOO|O", &parsed_obj, &table_obj, &mask_obj,
                           &reasons_obj))
@@ -1600,9 +1657,16 @@ static PyObject *wirec_filter_encode(PyObject *mod, PyObject *args) {
         return NULL;
     }
     const uint8_t *vmask = (const uint8_t *)viol.buf;
-    const StrSlice *cand = pa->nn_names;  /* NodeNames mode only */
-    Py_ssize_t num = pa->num_nn_names;
+    const StrSlice *cand = nodes_wire ? pa->names : pa->nn_names;
+    Py_ssize_t num = nodes_wire ? pa->num_names : pa->num_nn_names;
+    const Py_ssize_t *item_spans = nodes_wire ? pa->item_spans : NULL;
     const char *body = PyBytes_AS_STRING(pa->body);
+    if (nodes_wire && (!pa->nodes_present || num == 0)) {
+        /* the exact path answers 404 here, and the probe never asks */
+        PyBuffer_Release(&viol);
+        PyErr_SetString(PyExc_ValueError, "request carries no Nodes items");
+        return NULL;
+    }
 
     /* per-candidate resolution: row (or -1) and, for slices json.dumps
      * would re-escape, a pre-encoded buffer built under the GIL */
@@ -1629,6 +1693,7 @@ static PyObject *wirec_filter_encode(PyObject *mod, PyObject *args) {
     if (!rows || !raw_ok || !seen) { PyErr_NoMemory(); goto done; }
 
     size_t span_bytes = 0;
+    int split_unsafe = 0;  /* Nodes wire: a name split(" ") would break */
     Py_BEGIN_ALLOW_THREADS
     for (Py_ssize_t k = 0; k < num; k++) {
         const StrSlice *sl = &cand[k];
@@ -1643,10 +1708,15 @@ static PyObject *wirec_filter_encode(PyObject *mod, PyObject *args) {
         if (ok) {
             rows[k] = table_lookup(t, body + sl->off, sl->len);
             span_bytes += (size_t)sl->len;
+            if (nodes_wire &&
+                (sl->len == 0 || memchr(body + sl->off, ' ', (size_t)sl->len)))
+                split_unsafe = 1;
         } else {
             rows[k] = -1;  /* resolved under the GIL below */
             n_enc++;
         }
+        if (nodes_wire)
+            span_bytes += (size_t)(item_spans[2 * k + 1] - item_spans[2 * k]);
     }
     Py_END_ALLOW_THREADS
 
@@ -1665,6 +1735,8 @@ static PyObject *wirec_filter_encode(PyObject *mod, PyObject *args) {
             const char *us = PyUnicode_AsUTF8AndSize(u, &ulen);
             if (!us) { Py_DECREF(u); goto done; }
             rows[k] = table_lookup(t, us, ulen);
+            if (nodes_wire && (ulen == 0 || memchr(us, ' ', (size_t)ulen)))
+                split_unsafe = 1;
             PyObject *e = PyObject_CallMethod(json_mod, "dumps", "O", u);
             Py_DECREF(u);
             if (!e) goto done;
@@ -1677,6 +1749,12 @@ static PyObject *wirec_filter_encode(PyObject *mod, PyObject *args) {
             enc_len[k] = PyBytes_GET_SIZE(eb);
             span_bytes += (size_t)enc_len[k];
         }
+    }
+
+    if (split_unsafe) {
+        Py_INCREF(Py_None);
+        res = Py_None;
+        goto done;
     }
 
     if (reasons_obj != Py_None) {
@@ -1708,7 +1786,7 @@ static PyObject *wirec_filter_encode(PyObject *mod, PyObject *args) {
     if (!out_buf.data) oom = 1;
     if (!oom && emit_filter(out, body, cand, num, rows, raw_ok, enc_ptr,
                             enc_len, vmask, reason_ptr, reason_len, seen,
-                            &n_failed) < 0)
+                            item_spans, &n_failed) < 0)
         oom = 1;
     Py_END_ALLOW_THREADS
 
@@ -1736,6 +1814,16 @@ done:
     PyMem_Free(seen);
     PyBuffer_Release(&viol);
     return res;
+}
+
+static PyObject *wirec_filter_encode(PyObject *mod, PyObject *args) {
+    (void)mod;
+    return filter_encode_common(args, 0);
+}
+
+static PyObject *wirec_filter_encode_nodes(PyObject *mod, PyObject *args) {
+    (void)mod;
+    return filter_encode_common(args, 1);
 }
 
 /* ------------------------------------------------------------------ */
@@ -2396,7 +2484,7 @@ static PyObject *wirec_filter_respond(PyObject *mod, PyObject *args) {
         if (!out_buf.data) oom = 1;
         if (!oom && emit_filter(out, span, u->slices, num, rows, u->raw_ok,
                                 enc_ptr, enc_len, vmask, reason_ptr,
-                                reason_len, seen, &n_failed) < 0)
+                                reason_len, seen, NULL, &n_failed) < 0)
             oom = 1;
         if (oom) PyErr_NoMemory();
         else {
@@ -2506,6 +2594,10 @@ static PyMethodDef wirec_methods[] = {
      "Assemble the NodeNames-mode FilterResult response from a parsed "
      "body, a name table, a per-row violation bitmask, and optional "
      "per-row pre-encoded reason bytes; returns (bytes, n_failed)."},
+    {"filter_encode_nodes", wirec_filter_encode_nodes, METH_VARARGS,
+     "filter_encode for a request that carried Nodes: the passing items "
+     "echoed as slices of the request's bytes; (bytes, n_failed), or None "
+     "where a candidate's name is empty or holds a space."},
     {"filter_respond", wirec_filter_respond, METH_VARARGS,
      "filter_encode over an interned Universe: cached row map, zero "
      "hashing; returns (bytes, n_failed)."},

@@ -947,15 +947,25 @@ class PrioritizeFastPath:
         policy_name: str = "",
         reason_table: Optional[list] = None,
         universe=None,
-    ) -> Tuple[bytes, int]:
-        """Native NodeNames-mode Filter response: candidate row lookup,
-        violation partition, and byte assembly all happen in
-        ``_wirec.filter_encode`` over the parsed body's zero-copy name
-        slices — the Filter analog of :meth:`prioritize_parsed` (byte
-        parity with the exact path pinned by tests/test_wirec.py).  With
-        an interned ``universe``, ``_wirec.filter_respond`` partitions
-        over the universe's cached row map instead (one int32 read per
-        candidate, zero hashing) — identical bytes by construction.
+    ) -> Optional[Tuple[bytes, int]]:
+        """Native Filter response: candidate row lookup, violation
+        partition, and byte assembly all happen in ``_wirec.filter_encode``
+        over the parsed body's zero-copy name slices — the Filter analog of
+        :meth:`prioritize_parsed` (byte parity with the exact path pinned
+        by tests/test_wirec.py).  With an interned ``universe``,
+        ``_wirec.filter_respond`` partitions over the universe's cached
+        row map instead (one int32 read per candidate, zero hashing) —
+        identical bytes by construction.
+
+        The request says which form the answer takes: one that carried
+        ``Nodes`` (the exact path's own test, a non-empty items list) is
+        answered by ``_wirec.filter_encode_nodes``, which echoes every
+        passing ``v1.Node`` as the slice of the request it arrived in —
+        byte-identical to the exact path outside the items, JSON-equal
+        inside them (the request's separators and escapes are kept).  It
+        returns None where it will not vouch for the reference's
+        ``split(" ")`` quirk (an empty name, a name with a space), and
+        the exact path answers.
 
         Returns ``(body, failed count)``.  With ``compiled`` given, the
         FailedNodes values carry the concrete per-rule reason strings
@@ -973,6 +983,10 @@ class PrioritizeFastPath:
                 reasons = self.reason_table(
                     compiled, view, policy_name, violations, rule_map, n_rows
                 )
+        if parsed.nodes_present and parsed.num_nodes > 0:
+            return wirec.filter_encode_nodes(
+                parsed, table.native(wirec), mask, reasons
+            )
         if universe is not None and hasattr(wirec, "filter_respond"):
             return wirec.filter_respond(
                 universe, table.native(wirec), mask, reasons

@@ -24,7 +24,12 @@ telemetryscheduler.go.  Wire behavior is reproduced quirk-for-quirk
     scheduler consumes them and rejects unknown entries);
   * Bind is 404 — TAS does not bind (:179-181).
 
-Two execution paths produce identical wire bytes:
+Two execution paths produce identical wire bytes — but for the objects a
+Nodes-wire Filter echoes: the native path copies each passing ``v1.Node``
+out of the request as it arrived (``_wirec.filter_encode_nodes``), so an
+echoed item is JSON-equal to the exact path's ``json.dumps`` of it, not
+byte-equal, while everything around the items is byte-identical
+(docs/architecture.md "Filter on the two wires"):
 
   * **device path** (default): the jitted kernels of ops/scoring.py over the
     TensorStateMirror — one fused XLA pass instead of the per-node Go loop;
@@ -947,12 +952,16 @@ class MetricsExtender:
 
     def _filter_cache_probe(self, request: HTTPRequest, gang_token=None):
         """Filter response reuse (same burst-amortization as Prioritize's
-        span cache): a cached HTTPResponse on hit; a (parsed, violations,
-        use_node_names, gang_version) token when cacheable but missed
-        (the verb stores its exact Python-built bytes under that key);
-        None when the request isn't cacheable (host-only policy, odd
-        shapes, no native scanner) — the exact path then owns the
-        response alone.
+        span cache): a cached HTTPResponse on hit; on a miss the
+        natively built HTTPResponse, on either wire (NodeNames, or Nodes
+        with the passing objects echoed as slices of the request); a
+        (parsed, violations, use_node_names, gang_version, universe)
+        token when cacheable and missed but the native encoder is absent
+        or will not vouch for the names (one empty, or with a space, on
+        the Nodes wire: the verb stores its exact Python-built bytes
+        under that key); None when the request isn't cacheable
+        (host-only policy, odd shapes, no native scanner) — the exact
+        path then owns the response alone.
 
         Correctness: the key pairs the request's raw candidate-span bytes
         (memcmp, zero false positives) with the IDENTITY of the device
@@ -1058,35 +1067,59 @@ class MetricsExtender:
                         candidates, n_failed, reasons,
                     )
                 return HTTPResponse.json(body)
-            if use_node_names and hasattr(wirec, "filter_encode"):
-                # span-cache miss, NodeNames mode: build the response
-                # natively (row lookup + violation partition + byte
-                # assembly in C) instead of paying the exact path's
-                # full Python decode; the result seeds the span cache.
-                # With an interned universe the partition runs over its
-                # cached row map (filter_respond — zero hashing) and the
-                # body seeds the skeleton layer instead.  The miss
+            if hasattr(
+                wirec,
+                "filter_encode" if use_node_names else "filter_encode_nodes",
+            ):
+                # span-cache miss: build the response natively (row
+                # lookup + violation partition + byte assembly in C)
+                # instead of paying the exact path's full Python decode;
+                # the result seeds the span cache.  On the NodeNames
+                # wire, with an interned universe the partition runs
+                # over its cached row map (filter_respond — zero
+                # hashing) and the body seeds the skeleton layer
+                # instead.  On the Nodes wire the passing v1.Node
+                # objects are echoed as slices of the request's own
+                # bytes; that assembly is the whole of what ``encode``
+                # was there, so it keeps the always-on name (at 6 MB a
+                # request a stage's microsecond is nothing; on the
+                # NodeNames wire ``fencode`` stays sampled).  The miss
                 # counts ONLY once the encode succeeded — a raise here
                 # lands in the outer except -> None -> the caller counts
                 # it a bypass, never miss+bypass
-                with span.stage("fencode", sampled=True):
-                    body, n_failed = self.fastpath.filter_parsed(
+                stage = (
+                    span.stage("fencode", sampled=True)
+                    if use_node_names
+                    else span.stage("encode")
+                )
+                with stage:
+                    answer = self.fastpath.filter_parsed(
                         wirec, view, parsed, violations, compiled, policy.name,
                         reason_table=reason_table,
-                        universe=universe if use_node_names else None,
+                        universe=universe,
                     )
-                    self.fastpath.filter_store(
-                        violations, use_node_names, parsed, body, n_failed,
-                        gang_version, universe=universe,
-                    )
-                with span.stage("record", sampled=True):
-                    span.set("filter_cache", "miss")
-                    trace.COUNTERS.inc("pas_filter_cache_miss_total")
-                    self._record_device_filter(
-                        span, parsed, policy_name, "native",
-                        candidates, n_failed, reasons,
-                    )
-                return HTTPResponse.json(body)
+                    if answer is not None:
+                        body, n_failed = answer
+                        self.fastpath.filter_store(
+                            violations, use_node_names, parsed, body,
+                            n_failed, gang_version, universe=universe,
+                        )
+                if answer is not None:
+                    with span.stage("record", sampled=True):
+                        span.set("filter_cache", "miss")
+                        trace.COUNTERS.inc("pas_filter_cache_miss_total")
+                        wire = "names" if use_node_names else "nodes"
+                        trace.COUNTERS.inc(
+                            "pas_filter_native_total", labels={"wire": wire}
+                        )
+                        self._record_device_filter(
+                            span, parsed, policy_name, "native",
+                            candidates, n_failed, reasons,
+                        )
+                    return HTTPResponse.json(body)
+                # the encoder would not vouch for the reference's
+                # split(" ") quirk on these names (one empty, or with a
+                # space): the exact path answers, as for any miss
             # cacheable but missed: the exact path builds (and stores) the
             # response via the returned token — still a miss
             span.set("filter_cache", "miss")

@@ -101,6 +101,7 @@ declare("pas_fastpath_response_miss_total", "counter", "Prioritize response-reus
 declare("pas_filter_cache_hit_total", "counter", "Filter response cache hits.")
 declare("pas_filter_cache_miss_total", "counter", "Filter cacheable requests that missed the response cache.")
 declare("pas_filter_cache_bypass_total", "counter", "Filter requests not cacheable (host-only policy, odd shapes, no native scanner).")
+declare("pas_filter_native_total", "counter", "Filter requests answered by the native encoder after a response-cache miss (label: wire in nodes/names — Nodes echoed as slices of the request, or NodeNames); over the Filters served it is the native path's share.")
 # interned node-name universes (native/wirec.c UniverseCache via
 # tas/fastpath.py).  hits+misses partition every probe against an
 # available universe cache; evictions count universes dropped past the

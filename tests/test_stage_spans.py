@@ -30,7 +30,7 @@ from platform_aware_scheduling_tpu.tas.metrics import (
 )
 from platform_aware_scheduling_tpu.testing.builders import make_node, make_pod
 from platform_aware_scheduling_tpu.testing.fake_kube import FakeKubeClient
-from platform_aware_scheduling_tpu.utils import trace
+from platform_aware_scheduling_tpu.utils import decisions, trace
 from platform_aware_scheduling_tpu.utils.quantity import Quantity
 from wirehelpers import post_bytes, raw_request, start_async, start_threaded
 
@@ -335,6 +335,62 @@ def test_tas_filter_probe_carries_its_sub_stages(every_span_sampled):
     assert inside <= stages["cache_probe"]
 
 
+@pytest.mark.parametrize("sampled", [False, True])
+def test_the_nodes_wire_filter_is_answered_inside_the_probe(
+    sampled, monkeypatch
+):
+    """A Filter that carried ``Nodes``: with the native scanner the probe
+    answers it (``cache_probe`` holds an always-on ``encode``, the native
+    assembly; no ``decode``), the decision record reads ``native`` and the
+    request counts once as a miss; without it the exact path decodes,
+    and the parsed answer is the same object either way."""
+    monkeypatch.setattr(trace, "SAMPLE_EVERY", 1 if sampled else 10**9)
+    decisions.DECISIONS.clear()
+    ext, names = build_extender(48, device=True)
+    body = make_bodies(names, "nodes", rotate_span=True, count=2)[1]
+    # counter -> the series read (None: the family's sum)
+    COUNTED = {"cache_miss": None, "cache_bypass": None, "cache_hit": None,
+               "native": {"wire": "nodes"}}
+
+    def read():
+        return {name: trace.COUNTERS.get(f"pas_filter_{name}_total",
+                                         labels=labels)
+                for name, labels in COUNTED.items()}
+
+    def serve():
+        span = trace.Span("POST /scheduler/filter")
+        span.sampled = sampled
+        before = read()
+        response = ext.filter(HTTPRequest(
+            method="POST", path="/scheduler/filter",
+            headers={"Content-Type": "application/json"}, body=body,
+            span=span))
+        assert response.status == 200
+        moved = {name: value - before[name] for name, value in read().items()}
+        record = decisions.DECISIONS.snapshot(verb="filter")["records"][0]
+        return response, span, moved, record
+
+    native, span, moved, record = serve()
+    stages = span.stage_seconds()
+    assert "decode" not in stages and "kernel" not in stages, sorted(stages)
+    assert stages["encode"] <= stages["cache_probe"]
+    assert span.attrs["filter_cache"] == "miss"
+    assert moved == {"cache_miss": 1, "cache_bypass": 0, "cache_hit": 0,
+                     "native": 1}
+    assert record["path"] == "native" and record["candidates"] == 48
+
+    monkeypatch.setenv("PAS_TPU_NO_NATIVE", "1")
+    exact, span, moved, record = serve()
+    stages = span.stage_seconds()
+    assert "cache_probe" in stages and "decode" in stages, sorted(stages)
+    assert moved == {"cache_miss": 0, "cache_bypass": 1, "cache_hit": 0,
+                     "native": 0}
+    assert record["path"] == "bypass"
+    assert json.loads(native.body) == json.loads(exact.body)
+    assert json.loads(native.body)["Nodes"]["items"]
+    assert record["filtered"] == len(json.loads(native.body)["FailedNodes"])
+
+
 class TestRefreshPassCounters:
     FAMILIES = ("pass", "fetch", "publish", "warm")
 
@@ -547,6 +603,7 @@ def test_new_families_are_declared():
         "pas_refresh_pass_seconds_total", "pas_refresh_fetch_seconds_total",
         "pas_refresh_publish_seconds_total", "pas_refresh_warm_seconds_total",
         "pas_gc_pause_seconds_total", "pas_gc_collections_total",
+        "pas_filter_native_total",
     }
     for name in expected:
         assert trace.METRICS[name][0] == "counter", name

@@ -7,7 +7,12 @@ is served twice through the REAL verb handlers — once with the native
 scanner available, once with ``get_wirec`` patched to None (the exact
 path that owns every decode-failure/empty-list wire quirk,
 telemetryscheduler.py module doc).  Status and body bytes must match
-exactly, for Prioritize and Filter, in both nodeCacheCapable modes.
+exactly, for Prioritize and Filter, in both nodeCacheCapable modes —
+with one sanctioned difference since PR 30: a Filter that carried
+``Nodes`` and is answered natively echoes each passing ``v1.Node`` as
+the slice of the request it arrived in, so there the answer's frame
+(everything outside the items) must be byte-equal and each item
+JSON-equal (``wirehelpers.split_filter_echo``).
 A body the scanner rejects (strict parse) must therefore produce the
 exact path's answer on BOTH runs — so any scanner-vs-Python divergence
 in acceptance, field resolution, case folding, escape handling, or
@@ -18,7 +23,9 @@ Corpus: >=10,000 cases from a FIXED seed —
     spellings and case variants, duplicate/null fields in document order,
     Nodes/NodeNames/both/neither, escaped + non-ASCII + empty + duplicate
     node names, pods with/without the telemetry-policy label, unknown
-    policies, extra unknown fields, nested metadata oddities;
+    policies, extra unknown fields, nested metadata oddities, node
+    objects whose strings hold ``}`` ``"NodeNames"`` and ``\\"``, values
+    written compact, spaced or indented;
   * byte-level mutations (truncate / flip / insert / delete / splice) of
     the golden request fixtures (tests/golden/*.json) and of generated
     valid bodies — mostly-invalid inputs that must fail IDENTICALLY.
@@ -64,7 +71,9 @@ from platform_aware_scheduling_tpu.tas.cache import AutoUpdatingCache
 from platform_aware_scheduling_tpu.tas.metrics import NodeMetric
 from platform_aware_scheduling_tpu.tas.policy.v1alpha1 import TASPolicy
 from platform_aware_scheduling_tpu.tas.telemetryscheduler import MetricsExtender
+from platform_aware_scheduling_tpu.utils import trace
 from platform_aware_scheduling_tpu.utils.quantity import Quantity
+from wirehelpers import split_filter_echo
 
 pytestmark = pytest.mark.skipif(
     get_wirec() is None, reason="native scanner unavailable (no compiler)"
@@ -74,6 +83,7 @@ GOLDEN_DIR = os.path.join(os.path.dirname(__file__), "golden")
 NUM_NODES = 64
 CASES_GENERATED = 6_000
 CASES_MUTATED = 4_500
+CASES_ECHO = 1_200
 
 
 def _policy_obj(name):
@@ -231,17 +241,30 @@ def _gen_body(rng: random.Random) -> bytes:
         names = names + names  # duplicates
     carrier = rng.random()
     if carrier < 0.45:
+        # half the Nodes bodies carry only names the native Filter echo
+        # vouches for (none empty, none with a space: the reference's
+        # split(" ") quirk sends the rest to the exact path), each on an
+        # object that has one — or that path would hardly be reached
+        echoable = rng.random() < 0.5
+        if echoable:
+            names = [n for n in names if n and " " not in n]
+        named = [
+            lambda n: {"metadata": {"name": n, "labels": {"a": "b"}}},
+            lambda n: {
+                "metadata": {
+                    "name": n,
+                    "annotations": {"k}": '}], "NodeNames": ["x\\"]'},
+                },
+                "status": {"images": [{"names": ["a}", "{b\""]}, 1.5]},
+            },
+        ]
+        nameless = [{}, {"metadata": {}}, {"status": {"phase": "Ready"}}]
         items = [
             {"metadata": {"name": n}}
             if rng.random() < 0.85
-            else rng.choice(
-                [
-                    {},
-                    {"metadata": {}},
-                    {"metadata": {"name": n, "labels": {"a": "b"}}},
-                    {"status": {"phase": "Ready"}},
-                ]
-            )
+            else rng.choice(named)(n)
+            if echoable or rng.random() < 0.4
+            else rng.choice(nameless)
             for n in names
         ]
         nodes = (
@@ -263,13 +286,67 @@ def _gen_body(rng: random.Random) -> bytes:
     if rng.random() < 0.1:
         parts.append(("Unknown" + str(rng.randrange(3)), [None, 1, "x"]))
     rng.shuffle(parts)
+    # how the values are written: json.dumps' own separators, compact as
+    # Go marshals, or indented (whitespace between every token)
+    layout = rng.choice(
+        [{}, {}, {"separators": (",", ":")}, {"indent": 1}]
+    )
     obj = "{" + ", ".join(
         json.dumps(k, ensure_ascii=rng.random() < 0.5)
         + ": "
-        + json.dumps(v, ensure_ascii=rng.random() < 0.5)
+        + json.dumps(v, ensure_ascii=rng.random() < 0.5, **layout)
         for k, v in parts
     ) + "}"
     return obj.encode()
+
+
+def _rand_json(rng: random.Random, depth: int = 0):
+    """A random JSON value; strings lean on what a byte-slicing echo
+    could trip over (braces, brackets, quotes, backslashes, key names)."""
+    roll = rng.random()
+    if depth > 3 or roll < 0.35:
+        return "".join(
+            rng.choice(['}', '{', ']', '"', "\\", ",", ":", " ", "a", "é",
+                        '"NodeNames"', '"items"', "\n", "\U0001f4a1"])
+            for _ in range(rng.randrange(0, 6))
+        )
+    if roll < 0.5:
+        return rng.choice([None, True, False, 0, -7, 2.5, 1e21, -3e-7])
+    if roll < 0.75:
+        return [_rand_json(rng, depth + 1) for _ in range(rng.randrange(0, 4))]
+    return {
+        _rand_json(rng, 4): _rand_json(rng, depth + 1)
+        for _ in range(rng.randrange(0, 4))
+    }
+
+
+def _gen_echo_body(rng: random.Random) -> bytes:
+    names = [
+        n for n in (_rand_name(rng) for _ in range(rng.randrange(1, 14)))
+        if n and " " not in n
+    ] or ["node-001"]
+    if rng.random() < 0.15:
+        names = names + names[: rng.randrange(1, 4)]
+    if rng.random() < 0.2:  # a name the encoder will not vouch for
+        names.insert(rng.randrange(len(names) + 1), rng.choice(["", "a b"]))
+    items = []
+    for name in names:
+        item = {"metadata": {"name": name}}
+        if rng.random() < 0.5:
+            item["metadata"]["labels"] = {"k": _rand_json(rng, 4)}
+        # (an escaped key at an item's top level is the scanner's to
+        # refuse, so these stay plain; nested keys are anything)
+        for key in ("spec", "status", "x}", "a,b:"):
+            if rng.random() < 0.3:
+                item[key] = _rand_json(rng)
+        items.append(dict(rng.sample(list(item.items()), len(item))))
+    pod = {"metadata": {"name": "p", "namespace": "default",
+                        "labels": {"telemetry-policy": "fuzz-pol"}}}
+    layout = rng.choice([{}, {"separators": (",", ":")}, {"indent": 1}])
+    return json.dumps(
+        {"Pod": pod, "Nodes": {"items": items}},
+        ensure_ascii=rng.random() < 0.5, **layout,
+    ).encode()
 
 
 def _mutate(rng: random.Random, body: bytes) -> bytes:
@@ -294,8 +371,18 @@ def _mutate(rng: random.Random, body: bytes) -> bytes:
     return bytes(data)
 
 
+def _native_filters(wire: str) -> float:
+    return trace.COUNTERS.get("pas_filter_native_total", labels={"wire": wire})
+
+
 def _assert_same(native, exact, body: bytes, verb: str):
-    assert native.status == exact.status and native.body == exact.body, (
+    same = native.body == exact.body
+    if verb == "filter" and not same:
+        # the one sanctioned difference: a Nodes-wire Filter answered
+        # natively echoes each passing node as the request's own bytes —
+        # the frame byte-equal, each item JSON-equal (wirehelpers)
+        same = split_filter_echo(native.body) == split_filter_echo(exact.body)
+    assert native.status == exact.status and same, (
         f"{verb} divergence on {body[:200]!r}...: "
         f"native {native.status}/{native.body[:120]!r} vs "
         f"exact {exact.status}/{exact.body[:120]!r}"
@@ -312,6 +399,23 @@ class TestDifferentialWireFuzz:
             verb = "prioritize" if i % 2 == 0 else "filter"
             native, exact = _serve_both(ext, body, verb, monkeypatch)
             _assert_same(native, exact, body, verb)
+
+    def test_nodes_wire_echo_corpus(self, service, monkeypatch):
+        """Filters the policy resolves for, every one carrying ``Nodes``:
+        the general corpus mostly probes the failure paths (one body in
+        thirty names the policy), so the native echo gets a corpus of
+        its own — random ``v1.Node``-shaped objects, written compact,
+        spaced or indented, one body in five with a name the encoder
+        must hand to the exact path."""
+        ext, _ = service
+        rng = random.Random(0xEC40)
+        echoed = _native_filters("nodes")
+        for _ in range(CASES_ECHO):
+            body = _gen_echo_body(rng)
+            native, exact = _serve_both(ext, body, "filter", monkeypatch)
+            _assert_same(native, exact, body, "filter")
+        # not held by the exact path answering both times
+        assert _native_filters("nodes") - echoed >= CASES_ECHO // 2
 
     def test_mutated_corpus(self, service, monkeypatch):
         ext, _ = service

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from platform_aware_scheduling_tpu.extender.server import HTTPRequest
+from platform_aware_scheduling_tpu.extender.types import Args, FilterResult
 from platform_aware_scheduling_tpu.native import get_wirec
 from platform_aware_scheduling_tpu.ops.state import TensorStateMirror
 from platform_aware_scheduling_tpu.tas.cache import AutoUpdatingCache
@@ -14,7 +15,9 @@ from platform_aware_scheduling_tpu.tas.metrics import NodeMetric
 from platform_aware_scheduling_tpu.tas.policy.v1alpha1 import TASPolicy
 from platform_aware_scheduling_tpu.tas.telemetryscheduler import MetricsExtender
 from platform_aware_scheduling_tpu.testing.builders import make_policy, rule
+from platform_aware_scheduling_tpu.utils import trace
 from platform_aware_scheduling_tpu.utils.quantity import Quantity
+from wirehelpers import split_filter_echo
 
 wirec = get_wirec()
 pytestmark = pytest.mark.skipif(
@@ -274,10 +277,212 @@ class TestFilterNativeParity:
             wirec.filter_encode(parsed, table, b"\x01")
 
 
+POD_JSON = (
+    b'{"metadata": {"name": "p", "namespace": "default", '
+    b'"labels": {"telemetry-policy": "pol"}}}'
+)
+
+
+def nodes_wire_body(items, join=b", ", head=b'{"Pod": ', nodes_key=b'"Nodes"'):
+    """A Nodes-wire Args body whose items are written exactly as given:
+    bytes stay as they are, anything else goes through json.dumps."""
+    raw = [
+        i if isinstance(i, bytes) else json.dumps(i).encode() for i in items
+    ]
+    return b"".join(
+        [head, POD_JSON, b", ", nodes_key, b': {"items": [', join.join(raw),
+         b"]}}"]
+    )
+
+
+def node_item(name, **extra):
+    return {"metadata": {"name": name}, **extra}
+
+
+ODD_NAMES = ['we"ird\\name', "uni\u00e9code", "tab\tname", "\u8282\u70b9", "\x7f"]
+ODD_VALUES = {n: (100 if i % 2 == 0 else 1) for i, n in enumerate(ODD_NAMES)}
+TRICKY = {
+    "status": {
+        "images": [{"names": ["a}", "{b", '}], "NodeNames": ["x"]'], "n": -1.5e3}],
+        "note": 'quote \\" and brace } and "NodeNames": [',
+    },
+    "spec": {"taints": [], "deep": {"er": {"est": [None, True, {}]}}},
+}
+
+
+def native_filters(wire="nodes"):
+    return trace.COUNTERS.get("pas_filter_native_total", labels={"wire": wire})
+
+
+class TestFilterNodesWireParity:
+    """filter_encode_nodes (the native answer to a Filter that carried
+    ``Nodes``) against the exact path: the whole answer equal once parsed,
+    the frame byte-equal, every echoed item the request's own bytes."""
+
+    # values n1=100 n2=50 n3=10 n4=70, dontschedule m > 50: n1 and n4 violate
+    NATIVE = {
+        "some violating": nodes_wire_body(
+            [node_item(n) for n in ("n1", "n2", "n3", "n4")]),
+        "none violating": nodes_wire_body([node_item("n3"), node_item("n2")]),
+        "all violating": nodes_wire_body([node_item("n1"), node_item("n4")]),
+        "duplicate names": nodes_wire_body(
+            [node_item(n) for n in ("n1", "n1", "n2", "n2", "n4", "n1")]),
+        "name absent from the table": nodes_wire_body(
+            [node_item("n1"), node_item("ghost"), node_item("n3")]),
+        "compact as Go marshals": nodes_wire_body(
+            [json.dumps(node_item(n, **TRICKY), separators=(",", ":")).encode()
+             for n in ("n1", "n2", "n3")], join=b","),
+        "whitespace between tokens": nodes_wire_body(
+            [b"\n\t" + json.dumps(node_item(n, **TRICKY), indent=2).encode()
+             + b" \r\n" for n in ("n4", "n3", "n2")], join=b" ,\n"),
+        "nested objects and tricky strings": nodes_wire_body(
+            [node_item(n, **TRICKY) for n in ("n2", "n1", "n3")]),
+        "upstream's lowercase keys": nodes_wire_body(
+            [node_item("n1"), node_item("n2")], head=b'{"pod": ',
+            nodes_key=b'"nodes"'),
+        "metadata twice, the last wins": nodes_wire_body(
+            [b'{"metadata": {"name": "n1"}, "metadata": {"name": "n2"}}',
+             node_item("n4")]),
+        "NodeNames beside Nodes": nodes_wire_body(
+            [node_item("n1"), node_item("n3")])[:-1] + b', "NodeNames": ["n4"]}',
+    }
+    # the exact path answers these; the native encoder must not
+    FALL_BACK = {
+        "name with a space": nodes_wire_body(
+            [node_item("n2"), node_item("n 3")]),
+        "empty name": nodes_wire_body([node_item("n2"), node_item("")]),
+        "no name at all": nodes_wire_body([node_item("n2"), {"spec": {}}]),
+        "null metadata": nodes_wire_body([node_item("n2"), {"metadata": None}]),
+        "escaped space in a name": nodes_wire_body(
+            [node_item("n2"), b'{"metadata": {"name": "n\\u00203"}}']),
+        "null item": nodes_wire_body([node_item("n2"), b"null"]),
+        "item that is no object": nodes_wire_body([node_item("n2"), b"7"]),
+        "escaped key": nodes_wire_body(
+            [b'{"met\\u0061data": {"name": "n2"}}']),
+        "items empty": nodes_wire_body([]),
+    }
+
+    @staticmethod
+    def _both(body, monkeypatch, **kwargs):
+        request = request_from(body)
+        before = native_filters()
+        native = build_filter_extender(**kwargs).filter(request)
+        moved = native_filters() - before
+        monkeypatch.setenv("PAS_TPU_NO_NATIVE", "1")
+        python = build_filter_extender(**kwargs).filter(request)
+        monkeypatch.delenv("PAS_TPU_NO_NATIVE")
+        return native, python, moved
+
+    @staticmethod
+    def _assert_echo_parity(native, python, body):
+        assert native.status == python.status == 200
+        assert json.loads(native.body) == json.loads(python.body)
+        frame, items = split_filter_echo(native.body)
+        assert (frame, items) == split_filter_echo(python.body)
+        # what is echoed is the request's own bytes, not a re-encoding
+        sent = json.loads(body)
+        sent = sent.get("Nodes") or sent.get("nodes")
+        assert items == [
+            i for i in sent["items"]
+            if i["metadata"]["name"] in json.loads(native.body)["NodeNames"]
+        ]
+
+    @pytest.mark.parametrize("node_cache_capable", [True, False])
+    @pytest.mark.parametrize("case", sorted(NATIVE))
+    def test_native_answer_equals_the_exact_path(
+        self, case, node_cache_capable, monkeypatch
+    ):
+        body = self.NATIVE[case]
+        native, python, moved = self._both(
+            body, monkeypatch, node_cache_capable=node_cache_capable)
+        assert moved == 1, case
+        self._assert_echo_parity(native, python, body)
+        if case == "all violating":
+            assert native.body.startswith(
+                b'{"Nodes": {"metadata": {}, "items": null}, "NodeNames": [""]')
+            assert native.body == python.body
+
+    @pytest.mark.parametrize("ensure_ascii", [True, False])
+    def test_escaped_and_non_ascii_names(self, ensure_ascii, monkeypatch):
+        names = ODD_NAMES + ["uni\u00e9code", 'we"ird\\name', "plain"]
+        body = nodes_wire_body(
+            [json.dumps(node_item(n), ensure_ascii=ensure_ascii).encode()
+             for n in names])
+        native, python, moved = self._both(
+            body, monkeypatch, values=ODD_VALUES)
+        assert moved == 1
+        self._assert_echo_parity(native, python, body)
+        assert json.loads(native.body)["FailedNodes"]
+        if ensure_ascii:  # json.dumps' own form on the way in: same bytes
+            assert native.body == python.body
+
+    @pytest.mark.parametrize("case", sorted(FALL_BACK))
+    def test_what_the_encoder_will_not_vouch_for_takes_the_exact_path(
+        self, case, monkeypatch
+    ):
+        native, python, moved = self._both(self.FALL_BACK[case], monkeypatch)
+        assert moved == 0, case
+        assert native.status == python.status
+        assert native.body == python.body
+
+    def test_items_are_slices_of_the_request(self):
+        items = [
+            json.dumps(node_item(n, **TRICKY), separators=(",", ":")).encode()
+            for n in ("n1", "n2", "n3", "n4")
+        ]
+        response = build_filter_extender().filter(
+            request_from(nodes_wire_body(items, join=b",")))
+        assert response.body.startswith(
+            b'{"Nodes": {"metadata": {}, "items": ['
+            + items[1] + b", " + items[2] + b']}, "NodeNames": ["n2", "n3", ""]'
+        )
+
+    def test_per_rule_reasons_and_the_default(self):
+        """With a reason table the FailedNodes values are its bytes; a
+        violating row without one gets the reference's "Node violates"."""
+        body = nodes_wire_body([node_item(n) for n in ("n1", "n2", "n4", "n1")])
+        parsed = wirec.parse_prioritize(body)
+        table = wirec.build_table(["n1", "n2", "n3", "n4"])
+        args = Args.from_json(body)
+        passing = [n for n in args.nodes if n.name == "n2"]
+        for reasons, want in (
+            (None, {"n1": "Node violates", "n4": "Node violates"}),
+            ([b'"r\\u00e9ason one"', None, None, None],
+             {"n1": "r\u00e9ason one", "n4": "Node violates"}),
+        ):
+            got, n_failed = wirec.filter_encode_nodes(
+                parsed, table, b"\x01\x00\x00\x01", reasons)
+            assert n_failed == 2
+            assert got == FilterResult(
+                nodes=passing, node_names=["n2", ""], failed_nodes=want
+            ).to_json()
+
+    def test_miss_then_hit_same_bytes(self):
+        ext = build_filter_extender()
+        request = request_from(self.NATIVE["some violating"])
+        before = native_filters()
+        first = ext.filter(request)
+        second = ext.filter(request)
+        assert native_filters() - before == 1  # the second was a cache hit
+        assert first.body == second.body and first.status == 200
+
+    def test_the_scan_keeps_what_prioritize_reads(self):
+        """The spans ride beside the names: same names, same candidate
+        span, and a names-wire body carries none."""
+        body = self.NATIVE["whitespace between tokens"]
+        parsed = wirec.parse_prioritize(body)
+        assert parsed.node_names() == ["n4", "n3", "n2"]
+        assert parsed.nodes_span() == body[body.index(b'{"items"'):-1]
+        with pytest.raises(ValueError):
+            wirec.filter_encode_nodes(
+                wirec.parse_prioritize(nn_body(["n1"])),
+                wirec.build_table(["n1"]), b"\x00")
+
+
 class TestEncoderPoolConcurrency:
-    """The process-wide buffer pool behind select_encode/filter_encode:
-    many threads hammering both encoders (GIL-free sections overlap for
-    real) must produce byte-correct output — a pooled buffer handed to
+    """The process-wide buffer pool behind select_encode/filter_encode/
+    filter_encode_nodes: many threads hammering the encoders (GIL-free
+    sections overlap for real) must produce byte-correct output — a pooled buffer handed to
     two requests at once, or stale mask bytes surviving reuse, would
     corrupt responses."""
 
@@ -305,6 +510,13 @@ class TestEncoderPoolConcurrency:
                 }
             ).encode()
             subsets.append(body)
+        # the same candidates on the Nodes wire, 1 KB an object
+        node_bodies = [
+            nodes_wire_body(
+                [node_item(name, pad="x" * 1000)
+                 for name in json.loads(body)["NodeNames"]])
+            for body in subsets
+        ]
         # per-workload expected bytes computed single-threaded first
         expected = {}
         for bi, body in enumerate(subsets):
@@ -316,6 +528,9 @@ class TestEncoderPoolConcurrency:
                 expected[("fil", bi, mi)] = wirec.filter_encode(
                     parsed, table, mask
                 )
+                expected[("echo", bi, mi)] = wirec.filter_encode_nodes(
+                    wirec.parse_prioritize(node_bodies[bi]), table, mask
+                )
         errors = []
 
         def worker(seed):
@@ -324,7 +539,14 @@ class TestEncoderPoolConcurrency:
                 for _ in range(120):
                     bi = int(r.integers(len(subsets)))
                     parsed = wirec.parse_prioritize(subsets[bi])
-                    if r.random() < 0.5:
+                    if r.random() < 0.25:
+                        mi = int(r.integers(len(masks)))
+                        got = wirec.filter_encode_nodes(
+                            wirec.parse_prioritize(node_bodies[bi]), table,
+                            masks[mi],
+                        )
+                        want = expected[("echo", bi, mi)]
+                    elif r.random() < 0.5:
                         got = wirec.select_encode(
                             parsed, table, ranked, -1, True
                         )

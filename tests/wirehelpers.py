@@ -3,6 +3,7 @@ one place owns the test-side wire framing (request rendering, response
 parse, server starters) so a framing change never has to be fixed in
 several copies."""
 
+import json
 import socket
 import threading
 
@@ -76,3 +77,32 @@ def get_request(port: int, path: str):
         f"GET {path} HTTP/1.1\r\nHost: t\r\nConnection: close\r\n\r\n"
     ).encode()
     return raw_request(port, payload)
+
+
+ECHO_HEAD = '{"Nodes": {"metadata": {}, "items": ['
+
+
+def split_filter_echo(body: bytes):
+    """(frame, items) of a Nodes-mode FilterResult: the answer's text with
+    every echoed ``v1.Node`` replaced by ``@``, and the echoed objects
+    parsed.  The native Nodes-wire Filter echoes a passing node as the
+    slice of the request it arrived in, so against the exact path the
+    frame is byte-equal and each item JSON-equal (the request's
+    separators and escapes are kept, not ``json.dumps``' own).  An answer
+    that echoes nothing (``"Nodes": null``, ``"items": null``) is all
+    frame."""
+    text = body.decode("utf-8")
+    if not text.startswith(ECHO_HEAD + "{"):
+        return text, []
+    decoder = json.JSONDecoder()
+    frame, items, at = [ECHO_HEAD], [], len(ECHO_HEAD)
+    while True:
+        item, at = decoder.raw_decode(text, at)
+        items.append(item)
+        frame.append("@")
+        if not text.startswith(", {", at):
+            break
+        frame.append(", ")
+        at += 2
+    frame.append(text[at:])
+    return "".join(frame), items
