@@ -50,6 +50,9 @@ DEFAULT_MAX_VICTIMS = 8
 #: loop re-consults every Filter; replanning each time would hammer the
 #: census and the actuator gates for a target that just got refused
 DEFAULT_RETRY_S = 5.0
+#: preemption eviction burst of the planner's dedicated SafeActuator; a
+#: victim gang larger than this can never be evicted atomically
+ACTUATOR_BURST = 8
 
 
 class PreemptionPlanner:

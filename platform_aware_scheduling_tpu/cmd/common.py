@@ -39,11 +39,6 @@ def add_robustness_flags(
     parser.add_argument("--retryBaseDelay", default="100ms",
                         help="first retry backoff (Go duration); doubles "
                         "per attempt with deterministic jitter")
-    parser.add_argument("--retryMaxDelay", default="5s",
-                        help="backoff cap (Go duration)")
-    parser.add_argument("--retryDeadline", default="30s",
-                        help="per-call deadline across all retry attempts "
-                        "(Go duration)")
     parser.add_argument("--circuitFailureThreshold", type=int, default=5,
                         help="consecutive transport failures that open an "
                         "endpoint group's circuit")
@@ -75,11 +70,6 @@ def add_decision_flags(parser: argparse.ArgumentParser) -> None:
                         "metrics.  Costs <=5%% serving p99 (pinned by "
                         "the http_load decision A/B); off disables "
                         "recording and 404s the endpoint")
-    parser.add_argument("--decisionLogSize", type=int, default=512,
-                        help="decision-log ring capacity; an open record "
-                        "overwritten before its bind feedback counts in "
-                        "pas_decision_evicted_open_total (size the ring "
-                        "above pending-pods x verbs)")
 
 
 def add_event_flags(parser: argparse.ArgumentParser) -> None:
@@ -97,20 +87,13 @@ def add_event_flags(parser: argparse.ArgumentParser) -> None:
                         "costs <=5 us per warm verb (pinned by "
                         "obs_smoke); off publishes nothing and 404s the "
                         "endpoint")
-    parser.add_argument("--eventsSize", type=int, default=4096,
-                        help="event-journal ring capacity; overflow "
-                        "drops the OLDEST event and counts it in "
-                        "pas_events_dropped_total")
 
 
 def configure_events(args) -> None:
     """Apply the shared event flags to the process-wide EventJournal."""
     from platform_aware_scheduling_tpu.utils import events
 
-    events.JOURNAL.configure(
-        enabled=getattr(args, "events", "on") == "on",
-        capacity=getattr(args, "eventsSize", 4096),
-    )
+    events.JOURNAL.configure(enabled=getattr(args, "events", "on") == "on")
 
 
 def add_gang_flags(parser: argparse.ArgumentParser) -> None:
@@ -126,14 +109,6 @@ def add_gang_flags(parser: argparse.ArgumentParser) -> None:
                         "Prioritize scanner while on (the gang verdict is "
                         "pod-label-dependent state those caches cannot "
                         "key)")
-    parser.add_argument("--gangReservationTTL", default="30s",
-                        help="how long a gang's slice reservation holds "
-                        "without bind progress before it is reclaimed "
-                        "(Go duration); each member Filter refreshes it")
-    parser.add_argument("--gangMeshRefresh", default="30s",
-                        help="max age of the cached node mesh-coordinate "
-                        "map (pas-tpu-coord labels) before the gang "
-                        "tracker relists nodes (Go duration)")
 
 
 def add_admission_flags(
@@ -163,13 +138,6 @@ def add_admission_flags(
                         help="bounded queue depth; overflow sheds the "
                         "worst-ranked entry (or rejects the arrival when "
                         "it ranks worst)")
-    parser.add_argument("--admissionFairnessStreak", type=int, default=8,
-                        help="consecutive same-class admissions before a "
-                        "waiting other class must be let through")
-    parser.add_argument("--admissionStarveConsults", type=int, default=16,
-                        help="queue consults after which each further "
-                        "consult counts one pas_admission_starved_total "
-                        "event (the class availability SLO's bad signal)")
     if preemption:
         parser.add_argument("--preemption", default="off",
                             choices=["off", "on"],
@@ -186,20 +154,6 @@ def add_admission_flags(
                             "may evict (the budget controller's "
                             "aggressiveness knob steps this down under "
                             "availability burn)")
-        parser.add_argument("--preemptionRetry", default="5s",
-                            help="min interval between plans for the "
-                            "same target gang (Go duration)")
-        parser.add_argument("--preemptionRate", type=float, default=0.5,
-                            help="preemption evictions per second "
-                            "(token bucket, separate from the "
-                            "rebalancer's)")
-        parser.add_argument("--preemptionBurst", type=int, default=8,
-                            help="preemption eviction burst; a victim "
-                            "gang larger than this can never be evicted "
-                            "atomically")
-        parser.add_argument("--preemptionCooldown", default="5m",
-                            help="per-pod eviction cooldown for the "
-                            "preemption actuator (Go duration)")
 
 
 def admission_classes(args) -> tuple:
@@ -261,8 +215,6 @@ def build_admission_plane(
         classes=admission_classes(args),
         default_class=args.admissionDefaultClass,
         max_depth=args.admissionDepth,
-        fairness_streak=args.admissionFairnessStreak,
-        starve_consults=args.admissionStarveConsults,
     )
     plane.gangs = gang_tracker
     if (
@@ -270,20 +222,16 @@ def build_admission_plane(
         and gang_tracker is not None
         and kube_client is not None
     ):
+        from platform_aware_scheduling_tpu.admission.preempt import (
+            ACTUATOR_BURST,
+        )
         from platform_aware_scheduling_tpu.rebalance.actuator import (
             MODE_ACTIVE,
             SafeActuator,
         )
-        from platform_aware_scheduling_tpu.utils.duration import (
-            parse_duration,
-        )
 
         actuator = SafeActuator(
-            kube_client,
-            mode=MODE_ACTIVE,
-            rate_per_s=args.preemptionRate,
-            burst=args.preemptionBurst,
-            cooldown_s=parse_duration(args.preemptionCooldown),
+            kube_client, mode=MODE_ACTIVE, burst=ACTUATOR_BURST
         )
         # NOT actuator.gang_tracker: the rebalancer path's full-gang
         # auto-release would fight reservation-while-draining — the
@@ -295,7 +243,6 @@ def build_admission_plane(
             gang_tracker,
             actuator,
             max_victims=args.preemptionMaxVictims,
-            retry_s=parse_duration(args.preemptionRetry),
             leadership=leadership,
         )
     extender.admission = plane
@@ -326,20 +273,6 @@ def add_shard_flags(parser: argparse.ArgumentParser) -> None:
                         "(http://host:port) whose /debug/shard this "
                         "replica pulls remote-partition digests from; "
                         "empty serves local partitions only")
-    parser.add_argument("--shardTopK", type=int, default=16,
-                        help="per-metric candidate summaries carried in "
-                        "each partition digest (k lowest + k highest); "
-                        "the budget controller's per-partition shed "
-                        "knob steps this down under freshness burn")
-    parser.add_argument("--shardStaleBound", default="30s",
-                        help="digest staleness bound (Go duration): a "
-                        "remote digest older than this stops serving "
-                        "and the gather fails open to local-only "
-                        "answers (edge-triggered digest_stale event)")
-    parser.add_argument("--shardMemberTTL", default="15s",
-                        help="membership heartbeat TTL (Go duration): a "
-                        "replica silent for longer drops from the "
-                        "rendezvous and its partitions hand off")
     parser.add_argument("--shardConfigMap", default="pas-shard-partitions",
                         help="ConfigMap name holding the journaled "
                         "partition-ownership state")
@@ -361,8 +294,6 @@ def validate_shard_flags(parser: argparse.ArgumentParser, args) -> None:
         parser.error(
             f"--shardPartitions {args.shardPartitions} must be >= 1"
         )
-    if args.shardTopK < 1:
-        parser.error(f"--shardTopK {args.shardTopK} must be >= 1")
     for peer in shard_peers(args):
         if not (peer.startswith("http://") or peer.startswith("https://")):
             parser.error(
@@ -382,7 +313,6 @@ def build_shard_plane(
     if getattr(args, "shard", "off") != "on":
         return None
     from platform_aware_scheduling_tpu.shard import ShardPlane
-    from platform_aware_scheduling_tpu.utils.duration import parse_duration
 
     plane = ShardPlane(
         identity=replica_identity(args),
@@ -392,9 +322,6 @@ def build_shard_plane(
         configmap=args.shardConfigMap,
         leadership=leadership,
         peers=shard_peers(args),
-        topk=args.shardTopK,
-        stale_after_s=parse_duration(args.shardStaleBound),
-        member_ttl_s=parse_duration(args.shardMemberTTL),
     )
     if cache is not None and mirror is not None:
         plane.attach(cache, mirror)
@@ -430,11 +357,6 @@ def add_forecast_flags(
                         "(the value at the next refresh); capped at "
                         "--forecastWindow refresh steps — no fit "
                         "predicts further ahead than it looked back")
-    parser.add_argument("--forecastBandBound", type=float, default=0.25,
-                        help="max mean relative uncertainty band under "
-                        "which degraded LKG mode keeps serving forecast "
-                        "extrapolations; past it the pre-forecast "
-                        "frozen-LKG/neutral behavior returns")
 
 
 def add_ha_flags(parser: argparse.ArgumentParser, ha: bool = True) -> None:
@@ -626,11 +548,6 @@ def add_record_flags(parser: argparse.ArgumentParser) -> None:
                         "Costs <=5%% serving p99 (pinned by the http_load "
                         "recorder A/B); off records nothing and 404s "
                         "both endpoints")
-    parser.add_argument("--recordSize", type=int, default=4096,
-                        help="flight-recorder ring capacity; overflow "
-                        "drops the OLDEST event (the recorder keeps the "
-                        "latest window) and counts it in "
-                        "pas_record_dropped_total")
 
 
 def build_flight_recorder(args, extender, cache=None):
@@ -645,9 +562,7 @@ def build_flight_recorder(args, extender, cache=None):
         return None
     from platform_aware_scheduling_tpu.utils.record import FlightRecorder
 
-    recorder = FlightRecorder(
-        capacity=getattr(args, "recordSize", 4096)
-    )
+    recorder = FlightRecorder()
     extender.flight = recorder
     if cache is not None:
         cache.on_refresh_pass.append(
@@ -795,7 +710,6 @@ def forecast_options(args, sync_period_s: float) -> Optional[dict]:
         "window": args.forecastWindow,
         "horizon_s": horizon_s,
         "period_s": sync_period_s,
-        "band_bound": args.forecastBandBound,
     }
 
 
@@ -815,13 +729,10 @@ def build_gang_tracker(args, kube_client):
     if getattr(args, "gang", "off") != "on":
         return None
     from platform_aware_scheduling_tpu.gang import GangTracker
-    from platform_aware_scheduling_tpu.utils.duration import parse_duration
 
     return GangTracker(
         nodes_provider=kube_client.list_nodes,
         pods_provider=kube_client.list_pods,
-        ttl_s=parse_duration(args.gangReservationTTL),
-        mesh_max_age_s=parse_duration(args.gangMeshRefresh),
     )
 
 
@@ -829,9 +740,7 @@ def configure_decisions(args) -> None:
     """Apply the shared decision flags to the process-wide DecisionLog."""
     from platform_aware_scheduling_tpu.utils import decisions
 
-    decisions.DECISIONS.configure(
-        enabled=args.decisionLog == "on", capacity=args.decisionLogSize
-    )
+    decisions.DECISIONS.configure(enabled=args.decisionLog == "on")
 
 
 def build_fault_tolerance(args):
@@ -845,8 +754,6 @@ def build_fault_tolerance(args):
     policy = RetryPolicy(
         max_attempts=args.retryMaxAttempts,
         base_delay_s=parse_duration(args.retryBaseDelay),
-        max_delay_s=parse_duration(args.retryMaxDelay),
-        deadline_s=parse_duration(args.retryDeadline),
     )
     breakers = CircuitBreakerRegistry(
         failure_threshold=args.circuitFailureThreshold,

@@ -38,13 +38,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
                         help="HTTP front-end: threaded (reference-parity "
                         "default) or async (event loop + micro-batched "
                         "dispatch, docs/serving.md)")
-    parser.add_argument("--batchWindow", default="1ms",
-                        help="async serving: micro-batch coalescing window")
-    parser.add_argument("--batchMax", type=int, default=64,
-                        help="async serving: max requests fused per batch")
-    parser.add_argument("--queueDepth", type=int, default=256,
-                        help="async serving: admission queue bound; past it "
-                        "requests get 503 + Retry-After")
     # parity with cmd/tas.py via the one shared helper (cmd/common.py);
     # forecast=False: GAS has no telemetry cache to forecast over, so the
     # --forecast* flags are explicitly NOT offered (no dead flags — the
@@ -113,17 +106,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     common.build_solve_observatory(args, extender)
 
     from platform_aware_scheduling_tpu.cmd.tas import build_server
-    from platform_aware_scheduling_tpu.utils.duration import parse_duration
     from platform_aware_scheduling_tpu.utils.gctuning import tune_for_serving
 
     tune_for_serving()
-    server = build_server(
-        extender,
-        serving=args.serving,
-        window_s=parse_duration(args.batchWindow),
-        max_batch=args.batchMax,
-        max_queue_depth=args.queueDepth,
-    )
+    server = build_server(extender, serving=args.serving)
     if budget_controller is not None and hasattr(server, "dispatcher"):
         budget_controller.attach_admission(server.dispatcher)
     done = threading.Event()

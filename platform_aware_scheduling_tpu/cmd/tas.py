@@ -72,14 +72,6 @@ def build_arg_parser() -> argparse.ArgumentParser:
                         help="HTTP front-end: threaded (reference-parity "
                         "default) or async (event loop + micro-batched "
                         "device dispatch, docs/serving.md)")
-    parser.add_argument("--batchWindow", default="1ms",
-                        help="async serving: micro-batch coalescing window "
-                        "(Go duration, e.g. 500us, 1ms)")
-    parser.add_argument("--batchMax", type=int, default=64,
-                        help="async serving: max requests fused per batch")
-    parser.add_argument("--queueDepth", type=int, default=256,
-                        help="async serving: admission queue bound; past it "
-                        "requests get 503 + Retry-After")
     parser.add_argument("--rebalance", default="off",
                         choices=["off", "dry-run", "active"],
                         help="closed-loop rebalancer (docs/rebalance.md): "
@@ -87,23 +79,9 @@ def build_arg_parser() -> argparse.ArgumentParser:
                         "/debug/rebalance without touching the cluster; "
                         "active evicts through pods/eviction behind "
                         "rate-limit, cooldown and min-available guards")
-    parser.add_argument("--rebalanceHysteresis", type=int, default=3,
-                        help="consecutive violating enforcement cycles "
-                        "before a node becomes an eviction candidate")
-    parser.add_argument("--rebalanceMaxMoves", type=int, default=5,
-                        help="churn budget: max evictions planned per cycle")
     parser.add_argument("--rebalanceSolver", default="greedy",
                         choices=["greedy", "sinkhorn"],
                         help="replan solver (mirrors --batchSolver)")
-    parser.add_argument("--rebalanceCooldown", default="5m",
-                        help="per-pod eviction cooldown (Go duration)")
-    parser.add_argument("--rebalanceRate", type=float, default=0.5,
-                        help="token-bucket eviction rate (evictions/s)")
-    parser.add_argument("--rebalanceBurst", type=int, default=3,
-                        help="token-bucket eviction burst")
-    parser.add_argument("--rebalanceMinAvailable", type=int, default=1,
-                        help="per-workload-group running-pod floor the "
-                        "actuator must not evict below")
     common.add_profile_flag(parser)
     common.add_robustness_flags(parser)
     common.add_decision_flags(parser)
@@ -277,13 +255,7 @@ def assemble(
     return cache, mirror, extender, controller, enforcer, stop
 
 
-def build_server(
-    extender,
-    serving: str = "threaded",
-    window_s: float = 0.001,
-    max_batch: int = 64,
-    max_queue_depth: int = 256,
-):
+def build_server(extender, serving: str = "threaded"):
     """The selected HTTP front-end over an extender: the reference-parity
     threaded server (default) or the event-loop micro-batching one
     (serving/, opt-in via --serving=async).  Shared by the TAS and GAS
@@ -296,12 +268,7 @@ def build_server(
     if serving == "async":
         from platform_aware_scheduling_tpu.serving import AsyncServer
 
-        return AsyncServer(
-            extender,
-            window_s=window_s,
-            max_batch=max_batch,
-            max_queue_depth=max_queue_depth,
-        )
+        return AsyncServer(extender)
     provider = getattr(
         extender, "metrics_text", extender.recorder.prometheus_text
     )
@@ -354,15 +321,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         leadership=leadership,
         gang_journal=gang_journal,
         rebalance_mode=args.rebalance,
-        rebalance_options={
-            "hysteresis_cycles": args.rebalanceHysteresis,
-            "max_moves": args.rebalanceMaxMoves,
-            "solver": args.rebalanceSolver,
-            "cooldown_s": parse_duration(args.rebalanceCooldown),
-            "rate_per_s": args.rebalanceRate,
-            "burst": args.rebalanceBurst,
-            "min_available": args.rebalanceMinAvailable,
-        },
+        rebalance_options={"solver": args.rebalanceSolver},
     )
 
     # admission plane (--admission=on; docs/admission.md): the priority
@@ -445,13 +404,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     from platform_aware_scheduling_tpu.utils.gctuning import tune_for_serving
 
     tune_for_serving()
-    server = build_server(
-        extender,
-        serving=args.serving,
-        window_s=parse_duration(args.batchWindow),
-        max_batch=args.batchMax,
-        max_queue_depth=args.queueDepth,
-    )
+    server = build_server(extender, serving=args.serving)
     if budget_controller is not None and hasattr(server, "dispatcher"):
         # the shed knob actuates the async front-end's live-read
         # admission bound; the threaded server has no admission queue,

@@ -17,7 +17,7 @@ one subsystem:
   * **degraded LKG** upgrades to bounded extrapolation: the fit's
     uncertainty band widens with extrapolation distance, and
     tas/degraded.py keeps serving forecasts only while the relative band
-    stays inside ``--forecastBandBound``.
+    stays inside ``DEFAULT_BAND_BOUND``.
 
 Fits run OFF the request path: the cache's end-of-refresh-pass hook
 refits once per pass in the refresh thread (one fused device pass for
@@ -47,6 +47,9 @@ from platform_aware_scheduling_tpu.utils import decisions, klog, trace
 from platform_aware_scheduling_tpu.utils.tracing import CounterSet
 
 DEFAULT_WINDOW = 32
+#: max mean relative uncertainty band under which degraded LKG mode keeps
+#: serving forecast extrapolations; past it the frozen-LKG/neutral
+#: behavior returns
 DEFAULT_BAND_BOUND = 0.25
 
 #: relative-band denominator floor (milli): keeps near-zero predictions

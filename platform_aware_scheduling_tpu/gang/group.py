@@ -68,7 +68,11 @@ STATE_BOUND = "bound"
 STATE_DRAINING = "draining"
 STATE_RELEASED = "released"
 
+#: seconds a gang's slice reservation holds without bind progress before
+#: it is reclaimed; each member Filter refreshes it
 DEFAULT_TTL_S = 30.0
+#: max age, seconds, of the cached node mesh-coordinate map
+#: (pas-tpu-coord labels) before the tracker relists nodes
 DEFAULT_MESH_MAX_AGE_S = 30.0
 
 #: process-wide time-to-full-gang histogram (its own family —

@@ -45,9 +45,12 @@ MODE_DRY_RUN = "dry-run"
 MODE_ACTIVE = "active"
 MODES = (MODE_OFF, MODE_DRY_RUN, MODE_ACTIVE)
 
+#: token-bucket eviction rate (evictions/s) and burst
 DEFAULT_RATE_PER_S = 0.5
 DEFAULT_BURST = 3
+#: per-pod eviction cooldown, seconds
 DEFAULT_COOLDOWN_S = 300.0
+#: per-workload-group running-pod floor the actuator must not evict below
 DEFAULT_MIN_AVAILABLE = 1
 #: back-compat alias — the definition moved to utils/labels.py so
 #: gang/, rebalance/, and the decision records share one constant

@@ -20,6 +20,8 @@ from __future__ import annotations
 
 from typing import Dict, FrozenSet, List
 
+#: consecutive violating enforcement cycles before a node becomes an
+#: eviction candidate
 DEFAULT_HYSTERESIS_CYCLES = 3
 
 

@@ -57,6 +57,7 @@ DEFAULT_VIOLATION_PENALTY = 4.0
 #: stay-put bonus in normalized-utility units: a move must buy at least
 #: this much headroom over the pod's current node
 DEFAULT_MIGRATION_COST = 0.1
+#: churn budget: max evictions planned per cycle
 DEFAULT_MAX_MOVES = 5
 #: incoming moves any one destination accepts per cycle.  Telemetry
 #: utilities rank nodes globally, so every evictee prefers the SAME

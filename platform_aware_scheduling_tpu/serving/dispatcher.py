@@ -45,6 +45,13 @@ from platform_aware_scheduling_tpu.utils.tracing import (
     LatencyRecorder,
 )
 
+#: micro-batch coalescing window, seconds
+DEFAULT_WINDOW_S = 0.001
+#: max requests fused per batch
+DEFAULT_MAX_BATCH = 64
+#: admission queue bound; past it requests get 503 + Retry-After
+DEFAULT_MAX_QUEUE_DEPTH = 256
+
 
 class MicroBatchDispatcher:
     """Admission queue + coalescing window + single-worker batch solve."""
@@ -55,9 +62,9 @@ class MicroBatchDispatcher:
         batch_route: Optional[
             Callable[[List[HTTPRequest]], List[HTTPResponse]]
         ] = None,
-        window_s: float = 0.001,
-        max_batch: int = 64,
-        max_queue_depth: int = 256,
+        window_s: float = DEFAULT_WINDOW_S,
+        max_batch: int = DEFAULT_MAX_BATCH,
+        max_queue_depth: int = DEFAULT_MAX_QUEUE_DEPTH,
         retry_after_s: float = 1.0,
         recorder: Optional[LatencyRecorder] = None,
         counters: Optional[CounterSet] = None,

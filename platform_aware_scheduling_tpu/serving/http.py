@@ -49,6 +49,9 @@ from platform_aware_scheduling_tpu.extender.server import (
 )
 from platform_aware_scheduling_tpu.serving.batch import BatchExecutor
 from platform_aware_scheduling_tpu.serving.dispatcher import (
+    DEFAULT_MAX_BATCH,
+    DEFAULT_MAX_QUEUE_DEPTH,
+    DEFAULT_WINDOW_S,
     MicroBatchDispatcher,
 )
 from platform_aware_scheduling_tpu.utils import klog, trace
@@ -67,9 +70,9 @@ class AsyncServer:
         self,
         scheduler,
         metrics_provider=None,
-        window_s: float = 0.001,
-        max_batch: int = 64,
-        max_queue_depth: int = 256,
+        window_s: float = DEFAULT_WINDOW_S,
+        max_batch: int = DEFAULT_MAX_BATCH,
+        max_queue_depth: int = DEFAULT_MAX_QUEUE_DEPTH,
         retry_after_s: float = 1.0,
     ):
         self.scheduler = scheduler

@@ -46,7 +46,12 @@ from platform_aware_scheduling_tpu.ops.rules import (
 )
 from platform_aware_scheduling_tpu.utils import events, klog
 
+#: per-metric candidate summaries carried in each partition digest (k
+#: lowest + k highest); the budget controller's per-partition shed knob
+#: steps this down under freshness burn
 DEFAULT_TOPK = 16
+#: digest staleness bound, seconds: a remote digest older than this
+#: stops serving and the gather fails open to local-only answers
 DEFAULT_STALE_S = 30.0
 
 #: digest schema version: what a gossip pull must find in ``format``

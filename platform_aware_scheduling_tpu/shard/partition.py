@@ -35,6 +35,8 @@ from platform_aware_scheduling_tpu.utils import events, klog
 OWNERS_FORMAT = "pas-shard-owners/1"
 
 DEFAULT_CONFIGMAP = "pas-shard-partitions"
+#: membership heartbeat TTL, seconds: a replica silent for longer drops
+#: from the rendezvous and its partitions hand off
 DEFAULT_MEMBER_TTL_S = 15.0
 
 

@@ -66,7 +66,6 @@ class ShardPlane:
         configmap: str = DEFAULT_CONFIGMAP,
         leadership=None,
         peers: Sequence = (),
-        topk: int = DEFAULT_TOPK,
         stale_after_s: float = DEFAULT_STALE_S,
         member_ttl_s: float = DEFAULT_MEMBER_TTL_S,
         gossip_timeout_s: float = 1.0,
@@ -97,7 +96,6 @@ class ShardPlane:
         self.gossip = ShardGossip(
             self.store, peers=peers, timeout_s=gossip_timeout_s
         )
-        self._default_topk = max(1, int(topk))
         self._topk_lock = threading.Lock()
         #: per-partition top-k width — the controller's shed surface
         #: (attach_shard ladders these down under pressure)
@@ -195,14 +193,14 @@ class ShardPlane:
 
     def topk_for(self, partition: int) -> int:
         with self._topk_lock:
-            return self._topk.get(int(partition), self._default_topk)
+            return self._topk.get(int(partition), DEFAULT_TOPK)
 
     def set_topk(self, partition: int, k: int) -> None:
         with self._topk_lock:
             self._topk[int(partition)] = max(1, int(k))
 
     def default_topk(self) -> int:
-        return self._default_topk
+        return DEFAULT_TOPK
 
     # -- scatter/gather serving ------------------------------------------------
 
@@ -337,7 +335,7 @@ class ShardPlane:
             "gossip": self.gossip.snapshot(),
             "gather_local_only": self.gather_local_only,
             "topk": {
-                "default": self._default_topk,
+                "default": DEFAULT_TOPK,
                 "overrides": dict(self._topk),
             },
             **self.store.snapshot(),

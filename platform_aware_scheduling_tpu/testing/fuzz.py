@@ -744,7 +744,12 @@ class FuzzScenario(_AdmissionScenario):
         return n.bit_length()  # 0, 1, 2, 2, 3, 3, 3, 3, 4 ...
 
     def _final_coverage(self, twin: TwinCluster) -> None:
+        engine = twin.engine
         for record in events.JOURNAL.snapshot():
+            if record["kind"] == "slo" and engine is not None:
+                slo = engine.slos.get(record["data"].get("slo"))
+                if slo is not None and slo.sli == "latency":
+                    continue  # as in _observe: a wall-clock flip
             self.coverage.add(f"kind:{record['kind']}")
         counter_sets = [("serving", twin.serving_counters)]
         plane = twin.priority_plane()

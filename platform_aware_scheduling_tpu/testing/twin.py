@@ -247,7 +247,7 @@ class TwinCluster(HAHarness):
         self.traffic = {"requests": 0, "errors": 0}
         self.storm_evictions: Optional[int] = None
         #: per-tick verb admission budget (None = unlimited): requests
-        #: past it are SHED the way AsyncServer sheds past --queueDepth —
+        #: past it are SHED the way AsyncServer sheds past its queue bound —
         #: counted into pas_serving_rejected_total (the twin-local
         #: CounterSet below, wired into the engine's sources), never
         #: reaching a verb handler, so verb_availability degrades under

@@ -376,7 +376,7 @@ class DecisionLog:
     def configure(
         self, enabled: Optional[bool] = None, capacity: Optional[int] = None
     ) -> None:
-        """Apply --decisionLog / --decisionLogSize; resets the ring (the
+        """Apply --decisionLog (and a ring capacity); resets the ring (the
         records recorded under the old configuration keyed a different
         retention contract)."""
         with self._lock:

@@ -25,7 +25,7 @@ promised here): a capture NEVER contains node, pod, or namespace names.
 Off by default (``--flightRecorder=off``): while no recorder is wired
 the verbs skip a single attribute check and the wire stays
 byte-identical (pinned by tests/test_record.py).  The ring is bounded
-(``--recordSize``); overflow drops the OLDEST event and counts it in
+(``DEFAULT_CAPACITY``); overflow drops the OLDEST event and counts it in
 ``pas_record_dropped_total`` — a flight recorder keeps the latest
 window, like its aviation namesake.
 
@@ -59,6 +59,8 @@ from platform_aware_scheduling_tpu.utils.tracing import CounterSet
 #: don't infer from, so all stay replayable.
 FORMAT = "pas-flight-record/4"
 
+#: ring capacity; overflow drops the OLDEST event (the recorder keeps the
+#: latest window) and counts it in pas_record_dropped_total
 DEFAULT_CAPACITY = 4096
 
 #: decile grid for telemetry summaries (0%, 10%, ..., 100%)

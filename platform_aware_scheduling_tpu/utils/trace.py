@@ -260,7 +260,7 @@ declare("pas_control_prearmed", "gauge", "1 while the shed knob is tightened by 
 # only while one is wired (--flightRecorder=on) — like pas_slo_*, the
 # off path registers nothing and stays byte-identical on the wire.
 declare("pas_record_events_total", "counter", "Anonymized events accepted into the flight-recorder ring (verb arrivals, telemetry deciles, eviction/leader flips).")
-declare("pas_record_dropped_total", "counter", "Oldest flight-recorder events evicted by ring overflow (raise --recordSize if this moves).")
+declare("pas_record_dropped_total", "counter", "Oldest flight-recorder events evicted by ring overflow.")
 declare("pas_whatif_runs_total", "counter", "What-if twin replay runs served (POST /debug/whatif + the cmd.whatif CLI).")
 declare("pas_whatif_failures_total", "counter", "What-if runs that failed to parse their capture or crashed mid-replay.")
 # priority-aware admission plane (admission/plane.py + admission/preempt.py;
@@ -284,7 +284,7 @@ declare("pas_preemption_reservations_total", "counter", "Freed slices reserved f
 # "Explain plane").  Unlike pas_record_*, these land in the process-wide
 # COUNTERS: the journal is on by default and both front-ends feed it.
 declare("pas_events_published_total", "counter", "Typed events accepted into the causal event journal (label: kind in wire/verdict/admission/preemption/rebalance/control/slo/serving).")
-declare("pas_events_dropped_total", "counter", "Oldest journal events evicted by ring overflow (raise --eventsSize if this moves).")
+declare("pas_events_dropped_total", "counter", "Oldest journal events evicted by ring overflow.")
 declare("pas_explain_requests_total", "counter", "GET /debug/explain queries served (both front-ends).")
 declare("pas_explain_chain_events", "gauge", "Events in the causal chain returned by the most recent /debug/explain query.")
 

@@ -773,6 +773,51 @@ class TestAssemblyWiring:
         assert breakers.failure_threshold == 9
         assert breakers.reset_timeout_s == 60.0
 
+    def test_flag_surface_matches_the_docs(self):
+        """The mains' option surface, held from both sides: a tuning
+        value that nothing ever set is a constant beside its user, not a
+        flag (ROADMAP C2), and every flag the docs name still parses."""
+        import pathlib
+        import re
+
+        from platform_aware_scheduling_tpu.cmd import gas, tas, whatif
+
+        def accepted(parser):
+            return {
+                option
+                for action in parser._actions
+                for option in action.option_strings
+            }
+
+        mains = accepted(tas.build_arg_parser()) | accepted(
+            gas.build_arg_parser()
+        )
+        constants = (
+            "retryMaxDelay retryDeadline decisionLogSize eventsSize "
+            "gangReservationTTL gangMeshRefresh admissionFairnessStreak "
+            "admissionStarveConsults preemptionRetry preemptionRate "
+            "preemptionBurst preemptionCooldown shardTopK shardStaleBound "
+            "shardMemberTTL forecastBandBound recordSize batchWindow "
+            "batchMax queueDepth rebalanceHysteresis rebalanceMaxMoves "
+            "rebalanceCooldown rebalanceRate rebalanceBurst "
+            "rebalanceMinAvailable"
+        ).split()
+        assert len(constants) == 26
+        assert [n for n in constants if "--" + n in mains] == []
+
+        # other programs' flags the docs also name: pascheck's
+        # (docs/analysis.md) and benchmarks/perf_ledger.py's
+        elsewhere = {"--root", "--write-baseline", "--write"}
+        known = mains | accepted(whatif.build_arg_parser()) | elsewhere
+        root = pathlib.Path(__file__).resolve().parents[1]
+        named = {}
+        for page in sorted((root / "docs").glob("*.md")) + [root / "README.md"]:
+            text = page.read_text(encoding="utf-8")
+            for flag in re.findall(r"`(--[A-Za-z][\w-]*)", text):
+                named.setdefault(flag, page.name)
+        assert len(named) > 30, "the scan found no flag tables"
+        assert {f: p for f, p in named.items() if f not in known} == {}
+
 
 # ---------------------------------------------------------------------------
 # satellites: GAS conflict-retry backoff
