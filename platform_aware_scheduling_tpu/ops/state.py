@@ -28,6 +28,7 @@ import numpy as np
 
 from platform_aware_scheduling_tpu.ops import i64, solveobs
 from platform_aware_scheduling_tpu.ops.rules import OP_IDS, RuleSet
+from platform_aware_scheduling_tpu.tas.metrics import MetricColumns
 from platform_aware_scheduling_tpu.tas.policy.v1alpha1 import TASPolicy
 from platform_aware_scheduling_tpu.utils import trace
 
@@ -288,6 +289,8 @@ class TensorStateMirror:
         self._row_versions: Dict[int, int] = {}
         self._intern_version = 0
         self._host_only_metrics: Dict[str, bool] = {}
+        # metric -> (names list of its last columnar round, their columns)
+        self._round_cols: Dict[str, Tuple[List[str], np.ndarray]] = {}
         self._policies: Dict[Tuple[str, str], CompiledPolicy] = {}
         # sources kept so policies can be recompiled when a freed metric row
         # is reused (their rule tensors hold row indices)
@@ -381,6 +384,21 @@ class TensorStateMirror:
         self._row_versions[row] = self._row_versions.get(row, 0) + 1
         return row
 
+    def _round_columns(self, metric_name: str, names: List[str]) -> np.ndarray:
+        """The node columns of a fetched round's names.  A cluster's nodes
+        seldom change between two passes, so the array is kept with the
+        names list it was made for and one list comparison finds it again
+        (a node's column never moves); any other list is interned as the
+        items are."""
+        seen = self._round_cols.get(metric_name)
+        if seen is not None and seen[0] == names:
+            return seen[1]
+        cols = np.fromiter(
+            map(self._intern_node, names), dtype=np.intp, count=len(names)
+        )
+        self._round_cols[metric_name] = (names, cols)
+        return cols
+
     # -- cache hooks ----------------------------------------------------------
 
     def _notify(self) -> None:
@@ -417,8 +435,6 @@ class TensorStateMirror:
             # the periodic refresh re-writes every metric each sync period
             # (autoupdating.go:37-59) and steady-state values must not
             # invalidate snapshots/plans or force device re-uploads
-            host_only = False
-            staged: Dict[int, int] = {}
             scope = self._partition_scope
             owned_parts = None
             if scope is not None:
@@ -428,22 +444,41 @@ class TensorStateMirror:
                 except Exception:
                     owned_parts = frozenset()
             changed_partitions: Dict[int, bool] = {}
-            for node_name, metric in info.items():
-                if owned_parts is not None:
-                    partition = pmap.partition_of(node_name)
-                    if partition not in owned_parts:
-                        continue  # not ours: never interned, never stored
-                col = self._intern_node(node_name)
-                milli, exact = metric.value.milli_value_exact()
-                if not exact:
-                    host_only = True
-                staged[col] = milli
+            # one algorithm, two input forms: a fetched round arrives as
+            # columns and is scattered; a plain dict, and any round under
+            # a partition scope (which keeps nodes one by one), is staged
+            # item by item
+            columnar = isinstance(info, MetricColumns) and owned_parts is None
+            trace.COUNTERS.inc(
+                "pas_refresh_ingest_total",
+                labels={"path": "columnar" if columnar else "items"},
+            )
+            if columnar:
+                cols = self._round_columns(metric_name, info.names)
+                milli = info.milli
+                host_only = not info.exact
+            else:
+                host_only = False
+                staged: Dict[int, int] = {}
+                for node_name, metric in info.items():
+                    if owned_parts is not None:
+                        partition = pmap.partition_of(node_name)
+                        if partition not in owned_parts:
+                            continue  # not ours: never interned, never stored
+                    col = self._intern_node(node_name)
+                    value, exact = metric.value.milli_value_exact()
+                    if not exact:
+                        host_only = True
+                    staged[col] = value
+                cols = np.fromiter(staged, dtype=np.intp, count=len(staged))
+                milli = np.fromiter(
+                    staged.values(), dtype=np.int64, count=len(staged)
+                )
             grew = self._values.shape != shape_before
             new_values = np.zeros(self._values.shape[1], dtype=np.int64)
             new_present = np.zeros(self._values.shape[1], dtype=bool)
-            for col, milli in staged.items():
-                new_values[col] = milli
-                new_present[col] = True
+            new_values[cols] = milli
+            new_present[cols] = True
             changed = (
                 grew
                 or not np.array_equal(self._present[row], new_present)
@@ -493,6 +528,7 @@ class TensorStateMirror:
         with self._lock:
             row = self._metric_index.pop(metric_name, None)
             self._host_only_metrics.pop(metric_name, None)
+            self._round_cols.pop(metric_name, None)
             if row is not None:
                 deleted = True
                 if solveobs.ACTIVE is not None:

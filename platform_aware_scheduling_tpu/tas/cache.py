@@ -21,9 +21,14 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
+from collections.abc import Mapping
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from platform_aware_scheduling_tpu.tas.metrics import Client, NodeMetricsInfo
+from platform_aware_scheduling_tpu.tas.metrics import (
+    Client,
+    MetricColumns,
+    NodeMetricsInfo,
+)
 from platform_aware_scheduling_tpu.tas.policy.v1alpha1 import TASPolicy
 from platform_aware_scheduling_tpu.utils import klog, trace
 from platform_aware_scheduling_tpu.utils.tracing import CounterSet
@@ -152,7 +157,7 @@ class AutoUpdatingCache:
 
     def read_metric(self, metric_name: str) -> NodeMetricsInfo:
         value = self._store.read(METRIC_PATH.format(metric_name))
-        if isinstance(value, dict) and value:
+        if isinstance(value, Mapping) and value:
             return value
         raise CacheMissError(f"no metric {metric_name} found")
 
@@ -187,7 +192,7 @@ class AutoUpdatingCache:
                 # a data-bearing write IS a refresh — the freshness clock
                 # this metric is judged by (telemetry_freshness)
                 stamp = self._clock()
-                # the history sample (one milli conversion per node) is
+                # the history sample (one milli value per node) is
                 # built OUTSIDE the lock — at 10k nodes that work must
                 # not block request-path readers of metric_ages()/
                 # history_snapshot().  The bare int read of the window
@@ -195,10 +200,13 @@ class AutoUpdatingCache:
                 # re-check below decides
                 sample = None
                 if self._history_window:
-                    sample = {
-                        node: metric.value.milli_value_exact()[0]
-                        for node, metric in payload.items()
-                    }
+                    if isinstance(payload, MetricColumns):
+                        sample = dict(zip(payload.names, payload.milli.tolist()))
+                    else:
+                        sample = {
+                            node: metric.value.milli_value_exact()[0]
+                            for node, metric in payload.items()
+                        }
                 with self._mtx:
                     self._last_refresh[metric_name] = stamp
                     if self._history_window and sample is not None:

@@ -40,6 +40,7 @@ import json
 import threading
 import time
 from collections import deque
+from collections.abc import Mapping
 from typing import Dict, Iterable, List, Optional
 
 from platform_aware_scheduling_tpu.utils import klog, trace
@@ -282,7 +283,7 @@ class FlightRecorder:
                     info = cache.read_metric(name)
                 except Exception:
                     continue
-                if not isinstance(info, dict) or not info:
+                if not isinstance(info, Mapping) or not info:
                     continue
                 values = []
                 for metric in info.values():

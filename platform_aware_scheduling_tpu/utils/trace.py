@@ -163,7 +163,10 @@ declare("pas_telemetry_refresh_errors_total", "counter", "Individual metric fetc
 # triggers): four families, not one with a stage label — fetch + publish +
 # warm <= pass, the rest being pass accounting and end-of-pass hooks
 declare("pas_refresh_pass_seconds_total", "counter", "Seconds spent inside telemetry refresh passes (whole update_all_metrics, end-of-pass hooks included).")
-declare("pas_refresh_fetch_seconds_total", "counter", "Seconds of refresh passes spent fetching and parsing metrics from the custom-metrics API (per metric, refresh_filter included).")
+declare("pas_refresh_fetch_seconds_total", "counter", "Seconds of refresh passes spent fetching and parsing metrics from the custom-metrics API (per metric, refresh_filter included): the API's answer, plus pas_refresh_parse_seconds_total.")
+declare("pas_refresh_parse_seconds_total", "counter", "The program's part of the fetch: seconds from the custom-metrics API's answer to the round handed on as columns (tas/metrics.py MetricColumns).")
+declare("pas_refresh_ingest_total", "counter", "Data-bearing metric rounds published into the tensor mirror (label: path = columnar, a fetched round scattered as one vector | items, a plain dict or a partition-scoped mirror staged node by node).")
+declare("pas_refresh_ingest_quantity_fallback_total", "counter", "Fetched value strings that were no plain decimal integer (or overflowed int64 in milli) and took the Quantity parser.")
 declare("pas_refresh_publish_seconds_total", "counter", "Seconds of refresh passes spent in write_metric through the mirror's publish, less the fastpath warm it triggers.")
 declare("pas_refresh_warm_seconds_total", "counter", "Seconds spent in warm_fastpath (ranking precompute, violation sets, response skeletons) — in steady state all on the refresh thread.")
 declare("pas_strategy_evaluations_total", "counter", "Strategy violation evaluations (label: strategy).")

@@ -2,17 +2,23 @@
 policy compilation, host-only fallback marking."""
 
 import numpy as np
+import pytest
 
-from platform_aware_scheduling_tpu.ops import i64
+from platform_aware_scheduling_tpu.ops import i64, solveobs
 from platform_aware_scheduling_tpu.ops.rules import (
     OP_GREATER_THAN,
     OP_LESS_THAN,
 )
 from platform_aware_scheduling_tpu.ops.state import TensorStateMirror
 from platform_aware_scheduling_tpu.tas.cache import AutoUpdatingCache
-from platform_aware_scheduling_tpu.tas.metrics import NodeMetric
+from platform_aware_scheduling_tpu.tas.metrics import (
+    MetricColumns,
+    NodeMetric,
+    wrap_metrics,
+)
 from platform_aware_scheduling_tpu.tas.policy.v1alpha1 import TASPolicy
 from platform_aware_scheduling_tpu.testing.builders import make_policy, rule
+from platform_aware_scheduling_tpu.utils import trace
 from platform_aware_scheduling_tpu.utils.quantity import Quantity
 
 
@@ -215,3 +221,154 @@ def test_unchanged_metric_rewrite_keeps_version():
     assert mirror.device_view() is v1
     cache.write_metric("m", info(a="1"))  # b vanished -> real change
     assert mirror.device_view() is not v1
+
+
+# -- ISSUE 32: a fetched round published as a scatter ---------------------------
+
+
+def value_list(*pairs):
+    return {"items": [{"describedObject": {"name": node}, "value": value}
+                      for node, value in pairs]}
+
+
+def mirror_state(mirror, metric="m"):
+    """Everything a metric write may move, by node name."""
+    row = mirror._metric_index[metric]
+    cols = range(len(mirror._node_names))
+    return {
+        "values": {mirror._node_names[c]: int(mirror._values[row, c]) for c in cols},
+        "present": {mirror._node_names[c]: bool(mirror._present[row, c]) for c in cols},
+        "host_only": mirror._host_only_metrics[metric],
+        "version": mirror._version,
+        "row_version": mirror._row_versions[row],
+        "intern_version": mirror._intern_version,
+        "shape": mirror._values.shape,
+    }
+
+
+def ingest_counts():
+    return {path: trace.COUNTERS.get("pas_refresh_ingest_total", labels={"path": path})
+            for path in ("columnar", "items")}
+
+
+def publish_both(rounds):
+    """The same rounds through both input forms, each into a cache and
+    mirror of its own; the states after every round."""
+    states = {}
+    for form in (MetricColumns, wrap_metrics):
+        cache, mirror = attach_pair()
+        cache.write_metric("m")
+        states[form] = []
+        for pairs in rounds:
+            cache.write_metric("m", form(value_list(*pairs)))
+            states[form].append(mirror_state(mirror))
+    return states[MetricColumns], states[wrap_metrics]
+
+
+@pytest.mark.parametrize("value", [
+    "0", "97", "-5", "100m", "1Ki", "1.5", "1e3", "333333n",
+    "9223372036854775", "9223372036854775807", "-9223372036854775808", 97, 1.5,
+])
+def test_columnar_round_lands_as_the_items_do(value):
+    first = [("a", "1"), ("b", "2")]
+    columnar, items = publish_both([first, [("a", value), ("b", "3")], first])
+    assert columnar == items
+    exact = Quantity(str(value)).milli_value_exact()[1]
+    assert [s["host_only"] for s in columnar] == [False, not exact, False]
+    assert [s["version"] for s in columnar] == [1, 2, 3]
+
+
+@pytest.mark.parametrize("second", [
+    [("a", "1"), ("b", "2"), ("c", "3")],            # the same round again
+    [("a", "1"), ("b", "2"), ("c", "3"), ("d", "4")],  # one added
+    [("a", "1"), ("c", "3")],                        # one gone
+    [("c", "3"), ("a", "1"), ("b", "2")],            # a reorder
+    [("c", "9"), ("e", "5"), ("a", "1")],            # all three at once
+    [("a", "1"), ("b", "2"), ("c", "3"), ("d", "4"), ("e", "5"), ("f", "6")],  # grows the matrix
+    [("a", "7"), ("b", "2"), ("a", "1"), ("c", "3")],  # a duplicate: the last wins
+    [("a", "1"), ("b", "100n"), ("c", "3"), ("b", "2")],  # ... its exactness too
+], ids=["same", "added", "gone", "reorder", "mixed", "grown", "dup", "dup-inexact"])
+def test_columnar_node_set_changes_as_the_items_do(second):
+    first = [("a", "1"), ("b", "2"), ("c", "3")]
+    columnar, items = publish_both([first, second, first])
+    assert columnar == items
+    same = dict(second) == dict(first)
+    assert columnar[1]["version"] == (1 if same else 2)
+    assert columnar[1]["row_version"] - columnar[0]["row_version"] == (not same)
+    gone = set(dict(first)) - set(dict(second))
+    assert all(not columnar[1]["present"][node] for node in gone)
+    assert not columnar[1]["host_only"]
+
+
+def test_columnar_unchanged_round_keeps_the_view_and_reuses_the_columns():
+    cache, mirror = attach_pair()
+    pairs = [("a", "1"), ("b", "2")]
+    cache.write_metric("m", MetricColumns(value_list(*pairs)))
+    view, cols = mirror.device_view(), mirror._round_cols["m"][1]
+    cache.write_metric("m", MetricColumns(value_list(*pairs)))  # new objects
+    assert mirror.device_view() is view
+    assert mirror._round_cols["m"][1] is cols
+    cache.write_metric("m", MetricColumns(value_list(("b", "2"), ("a", "1"))))
+    assert mirror.device_view() is view  # a reorder moves no value
+    assert mirror._round_cols["m"][1].tolist() == cols.tolist()[::-1]
+
+
+def test_a_kept_round_may_be_written_again():
+    """perfbench's ``--fault stale-round`` hands an old round back."""
+    cache, mirror = attach_pair()
+    old = MetricColumns(value_list(("a", "1"), ("b", "2")))
+    cache.write_metric("m", old)
+    was = mirror_state(mirror)
+    cache.write_metric("m", MetricColumns(value_list(("a", "5"), ("c", "2"))))
+    cache.write_metric("m", old)
+    now = mirror_state(mirror)
+    assert now["values"] == {**was["values"], "c": 0}
+    assert now["present"] == {**was["present"], "c": False}
+    assert cache.read_metric("m") is old
+
+
+def test_deleted_metric_forgets_its_columns():
+    cache, mirror = attach_pair()
+    cache.write_metric("m")
+    cache.write_metric("m", MetricColumns(value_list(("a", "1"))))
+    assert "m" in mirror._round_cols
+    cache.delete_metric("m")
+    assert "m" not in mirror._round_cols
+
+
+class _Halves:
+    """A two-partition map by the node's last letter."""
+
+    def partition_of(self, node_name):
+        return ord(node_name[-1]) % 2
+
+
+@pytest.mark.parametrize("scoped", [False, True])
+@pytest.mark.parametrize("form", [MetricColumns, wrap_metrics])
+def test_ingest_path_follows_the_input(form, scoped):
+    """Columns are scattered; a plain dict, and any round while the mirror
+    is partition-scoped, is staged item by item (and kept to the owned
+    partitions)."""
+    cache, mirror = attach_pair()
+    if scoped:
+        mirror.set_partition_scope(_Halves(), lambda: {0})
+    before = ingest_counts()
+    cache.write_metric("m")  # a registration is no round
+    cache.write_metric("m", form(value_list(("nb", "1"), ("nc", "2"), ("nd", "3"))))
+    after = ingest_counts()
+    columnar = form is MetricColumns and not scoped
+    assert after["columnar"] - before["columnar"] == columnar
+    assert after["items"] - before["items"] == (not columnar)
+    state = mirror_state(mirror)
+    kept = {"nb": 1000, "nd": 3000} if scoped else {"nb": 1000, "nc": 2000, "nd": 3000}
+    assert state["values"] == kept
+    assert ("m" in mirror._round_cols) == columnar
+
+
+def test_columnar_churn_counts_the_columns_that_moved(monkeypatch):
+    monkeypatch.setattr(solveobs, "ACTIVE", object())
+    cache, mirror = attach_pair()
+    cache.write_metric("m", MetricColumns(value_list(("a", "1"), ("b", "2"), ("c", "3"))))
+    assert mirror._churn_pending["m"][0] == 3
+    cache.write_metric("m", MetricColumns(value_list(("a", "1"), ("b", "5"))))
+    assert mirror._churn_pending["m"][0] == 3 + 2  # b moved, c left

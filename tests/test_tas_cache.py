@@ -3,12 +3,14 @@ pkg/metrics/client_test.go)."""
 
 import time
 
+import numpy as np
 import pytest
 
 from platform_aware_scheduling_tpu.tas.cache import AutoUpdatingCache, CacheMissError
 from platform_aware_scheduling_tpu.tas.metrics import (
     CustomMetricsClient,
     DummyMetricsClient,
+    MetricColumns,
     MetricsError,
     NodeMetric,
     instance_of_mock_metric_client_map,
@@ -16,7 +18,8 @@ from platform_aware_scheduling_tpu.tas.metrics import (
 )
 from platform_aware_scheduling_tpu.tas.policy.v1alpha1 import TASPolicy
 from platform_aware_scheduling_tpu.testing.fake_kube import FakeKubeClient
-from platform_aware_scheduling_tpu.utils.quantity import Quantity
+from platform_aware_scheduling_tpu.utils import trace
+from platform_aware_scheduling_tpu.utils.quantity import Quantity, QuantityParseError
 
 
 def seeded_cache():
@@ -340,3 +343,129 @@ class TestMetricsClient:
         assert client.get_node_metric("dummyMetric1")["node A"].value.cmp_int64(100) == 0
         with pytest.raises(MetricsError):
             client.get_node_metric("other")
+
+
+def value_list(*pairs):
+    """A MetricValueList of (node, value) pairs."""
+    return {"items": [{"describedObject": {"kind": "Node", "name": node},
+                       "value": value} for node, value in pairs]}
+
+
+#: (value as the API gives it, takes the Quantity parser)
+VALUE_STRINGS = [
+    ("0", False), ("97", False), ("-5", False), ("+5", False), ("007", False),
+    ("100m", True), ("1Ki", True), ("1.5", True), ("1e3", True),
+    ("333333n", True), (" 97 ", True),
+    ("9223372036854775", False),  # the largest integer whose milli fits
+    ("9223372036854776", True), ("9223372036854775807", True),
+    ("-9223372036854775808", True), ("99999999999999999999999", True),
+    (97, False), (1.5, True),
+]
+#: what Quantity refuses, and int() would not: both forms raise
+REFUSED_VALUES = ["", "1_000", "\u0663", "abc", None]
+
+
+class TestMetricColumns:
+    """ISSUE 32: a fetched round as columns reads as ``wrap_metrics`` of
+    the same list does, for every value string and every odd item."""
+
+    @pytest.mark.parametrize("beside", [None, "3", "250m"])
+    @pytest.mark.parametrize("value,parsed", VALUE_STRINGS)
+    def test_milli_is_quantitys_for_every_value(self, value, parsed, beside):
+        pairs = [("a", value)] + ([("b", beside)] if beside is not None else [])
+        columns = MetricColumns(value_list(*pairs))
+        expected = [Quantity(str(v)).milli_value_exact() for _n, v in pairs]
+        assert columns.milli.dtype == np.int64
+        assert columns.milli.tolist() == [milli for milli, _exact in expected]
+        assert columns.exact == all(exact for _milli, exact in expected)
+        assert columns.quantity_fallbacks == parsed + (beside == "250m")
+        assert columns == wrap_metrics(value_list(*pairs))
+
+    @pytest.mark.parametrize("value", REFUSED_VALUES)
+    def test_a_refused_value_raises_as_wrap_metrics_does(self, value):
+        for form in (wrap_metrics, MetricColumns):
+            with pytest.raises(QuantityParseError):
+                form(value_list(("a", "1"), ("b", value)))
+
+    def test_a_refused_window_raises_as_wrap_metrics_does(self):
+        bad = {"items": [{"describedObject": {"name": "a"}, "value": "1",
+                          "windowSeconds": "soon"}]}
+        for form in (wrap_metrics, MetricColumns):
+            with pytest.raises(ValueError):
+                form(bad)
+
+    def test_reads_as_wrap_metrics_does(self):
+        odd = {"items": [
+            {"describedObject": {"name": "n1"}, "value": "5", "timestamp": "t1"},
+            {"describedObject": {"name": "n2"}, "value": "100m",
+             "windowSeconds": 30, "timestamp": "t2"},
+            {"value": "7"},  # no describedObject: the name is ""
+            {"describedObject": None, "value": "8", "timestamp": "t3"},
+            {"describedObject": {"name": "n3"}},  # no value: "0"
+            {"describedObject": {"name": "n1"}, "value": "6", "windowSeconds": 15},
+        ]}
+        expected = wrap_metrics(odd)
+        cache = AutoUpdatingCache()
+        cache.write_metric("m", MetricColumns(odd))
+        got = cache.read_metric("m")
+        assert isinstance(got, MetricColumns)
+        assert list(got) == list(expected) == ["n1", "n2", "", "n3"]
+        assert len(got) == len(expected) and bool(got)
+        assert list(got.items()) == list(expected.items())
+        assert list(got.values()) == list(expected.values())
+        assert got == expected and expected == dict(got)
+        assert got["n1"] == NodeMetric(Quantity("6"), "", 15.0)
+        assert got[""].timestamp == "t3" and got["n2"].window_seconds == 30.0
+        assert "n3" in got and "n4" not in got and got.get("n4") is None
+        assert got["n1"] is got["n1"]  # made once, like a dict's values
+        # the last n1 wins in the first one's place, for the mirror too
+        assert got.names == list(expected)
+        assert got.milli.tolist() == [6000, 100, 8000, 0]
+
+    def test_a_round_is_read_only_and_falsy_when_empty(self):
+        columns = MetricColumns(value_list(("a", "1")))
+        with pytest.raises(ValueError):
+            columns.milli[0] = 2
+        with pytest.raises(TypeError):
+            columns["a"] = NodeMetric(Quantity("2"))
+        with pytest.raises(AttributeError):
+            columns.extra = 1
+        assert not MetricColumns({"items": []})
+        assert not MetricColumns({})
+
+    def test_client_hands_on_columns_and_counts_its_part(self):
+        fake = FakeKubeClient()
+        for node, value in (("n1", "0"), ("n2", "100m"), ("n3", "1.5")):
+            fake.set_node_metric("m", node, value, timestamp="t")
+        names = ("pas_refresh_ingest_quantity_fallback_total",
+                 "pas_refresh_parse_seconds_total")
+        before = [trace.COUNTERS.get(name) for name in names]
+        info = CustomMetricsClient(fake).get_node_metric("m")
+        assert isinstance(info, MetricColumns)
+        assert info == wrap_metrics(fake.get_node_custom_metric("m"))
+        fallbacks, parse_s = (
+            trace.COUNTERS.get(name) - was for name, was in zip(names, before))
+        assert fallbacks == 2 and parse_s > 0
+
+    @pytest.mark.parametrize("form", [MetricColumns, wrap_metrics])
+    def test_history_sample_is_the_milli_column(self, form):
+        cache = AutoUpdatingCache()
+        cache.configure_history(2)
+        cache.write_metric("m", form(value_list(("a", "2"), ("b", "1500m"),
+                                                ("a", "3"))))
+        _gen, rings = cache.history_snapshot()
+        (_stamp, sample), = rings["m"]
+        assert sample == {"a": 3000, "b": 1500}
+        assert all(type(v) is int for v in sample.values())
+
+    def test_flight_recorder_reads_through_the_mapping(self):
+        from platform_aware_scheduling_tpu.utils.record import FlightRecorder
+
+        cache = AutoUpdatingCache()
+        cache.write_metric("m")
+        cache.write_metric("m", MetricColumns(value_list(("a", "2"), ("b", "4"))))
+        seen = []
+        recorder = FlightRecorder()
+        recorder.record_telemetry = lambda name, values: seen.append((name, values))
+        recorder.observe_cache(cache)
+        assert seen == [("m", [2.0, 4.0])]
