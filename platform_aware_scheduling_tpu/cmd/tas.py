@@ -63,6 +63,11 @@ def build_arg_parser() -> argparse.ArgumentParser:
                         choices=["greedy", "sinkhorn"],
                         help="batch planner solver: greedy (sequential-"
                         "equivalent) or sinkhorn (globally coordinated)")
+    parser.add_argument("--batchPlannerDevices", type=int, default=1,
+                        help="devices the batch planner's solve spans: 1 "
+                        "(default) solves on one device; n > 1 solves "
+                        "node-sharded over a mesh of the first n (greedy "
+                        "solver only; docs/architecture.md)")
     parser.add_argument("--nodeCacheCapable", action="store_true",
                         help="serve Prioritize/Filter from Args.NodeNames "
                         "(register the extender nodeCacheCapable: true); "
@@ -105,6 +110,7 @@ def assemble(
     enable_device_path: bool = True,
     enable_batch_planner: bool = False,
     batch_solver: str = "greedy",
+    planner_devices: int = 1,
     node_cache_capable: bool = False,
     rebalance_mode: str = "off",
     rebalance_options: Optional[dict] = None,
@@ -157,7 +163,11 @@ def assemble(
     if enable_batch_planner and mirror is not None:
         from platform_aware_scheduling_tpu.tas.planner import BatchPlanner
 
-        planner = BatchPlanner(cache, mirror, solver=batch_solver)
+        # raises planner.MeshRefused, before any thread is started, when
+        # the solve cannot span ``planner_devices`` devices
+        planner = BatchPlanner(
+            cache, mirror, solver=batch_solver, devices=planner_devices
+        )
     # the forecaster must exist BEFORE the extender: MetricsExtender's
     # constructor runs the first warm pass, and the history rings must
     # already be recording when the initial metric seeds land
@@ -281,6 +291,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     common.validate_control_flags(parser, args)
     common.validate_admission_flags(parser, args)
     common.validate_shard_flags(parser, args)
+    if args.batchPlannerDevices != 1 and not args.batchPlanner:
+        parser.error("--batchPlannerDevices needs --batchPlanner")
     klog.set_verbosity(args.v)
     sync_period_s = parse_duration(args.syncPeriod)
     # decision provenance + causal event journal on/off + ring sizes,
@@ -307,22 +319,28 @@ def main(argv: Optional[List[str]] = None) -> int:
     # the first compile, which assemble's warm pass triggers
     common.prepare_device_runtime()
     gang_tracker = common.build_gang_tracker(args, kube_client)
-    cache, mirror, extender, controller, _, stop = assemble(
-        kube_client,
-        metrics_client,
-        sync_period_s,
-        enable_batch_planner=args.batchPlanner,
-        batch_solver=args.batchSolver,
-        node_cache_capable=args.nodeCacheCapable,
-        breakers=breakers,
-        degraded_mode=args.degradedMode,
-        gang_tracker=gang_tracker,
-        forecast_options=common.forecast_options(args, sync_period_s),
-        leadership=leadership,
-        gang_journal=gang_journal,
-        rebalance_mode=args.rebalance,
-        rebalance_options={"solver": args.rebalanceSolver},
-    )
+    from platform_aware_scheduling_tpu.tas.planner import MeshRefused
+
+    try:
+        cache, mirror, extender, controller, _, stop = assemble(
+            kube_client,
+            metrics_client,
+            sync_period_s,
+            enable_batch_planner=args.batchPlanner,
+            batch_solver=args.batchSolver,
+            planner_devices=args.batchPlannerDevices,
+            node_cache_capable=args.nodeCacheCapable,
+            breakers=breakers,
+            degraded_mode=args.degradedMode,
+            gang_tracker=gang_tracker,
+            forecast_options=common.forecast_options(args, sync_period_s),
+            leadership=leadership,
+            gang_journal=gang_journal,
+            rebalance_mode=args.rebalance,
+            rebalance_options={"solver": args.rebalanceSolver},
+        )
+    except MeshRefused as exc:
+        parser.error(f"--batchPlannerDevices: {exc}")
 
     # admission plane (--admission=on; docs/admission.md): the priority
     # queue both verbs consult, plus — with --preemption=on — the gang

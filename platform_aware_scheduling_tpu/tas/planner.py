@@ -26,6 +26,13 @@ Trigger: one — the end of a refresh pass (``cache.on_refresh_pass``), so
 the plan for the version a pass published exists as soon as that version
 serves.  A plan whose version is not the mirror's is never served.
 
+More than one device (``--batchPlannerDevices=n``, n > 1): the solve runs
+node-sharded over a mesh of the first n devices.  A replan then places the
+mirror's ``[M, N]`` view and the room vector split over the nodes (stage
+``plan.place``), makes the candidate mask and every other ``[P, N]`` array
+on the mesh so that none is ever whole on one device, and reads back
+``node_for_pod`` alone.  The plan is the one-device plan, pod for pod.
+
 OPT-IN (``--batchPlanner`` on cmd/tas.py): with the planner off the verbs
 behave exactly like the reference.  Planner answers degrade gracefully:
 unknown pod / stale plan / no assignment -> the ordinary per-request path.
@@ -43,17 +50,25 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from platform_aware_scheduling_tpu.kube.objects import Pod, object_key
 from platform_aware_scheduling_tpu.models.batch_scheduler import (
     ClusterState,
     PendingPods,
+    mesh_scheduling_step,
     observed_scheduling_step,
     score_and_filter,
 )
 from platform_aware_scheduling_tpu.ops import i64, solveobs
 from platform_aware_scheduling_tpu.ops.rules import RuleSet
 from platform_aware_scheduling_tpu.ops.state import RULE_PAD, TensorStateMirror
+from platform_aware_scheduling_tpu.parallel.mesh import (
+    NODE_AXIS,
+    make_mesh,
+    node_sharded,
+    replicated,
+)
 from platform_aware_scheduling_tpu.tas.cache import AutoUpdatingCache
 from platform_aware_scheduling_tpu.utils import klog, trace
 from platform_aware_scheduling_tpu.utils.quantity import Quantity
@@ -105,6 +120,11 @@ def _candidate_mask(pods: int, nodes: int, pending, known) -> jax.Array:
     return live & (jnp.arange(nodes, dtype=jnp.int32)[None, :] < known)
 
 
+class MeshRefused(ValueError):
+    """The planner cannot span the devices it was told to use; cmd/tas.py
+    turns it into a usage error at start-up."""
+
+
 class _InformerGroup:
     """Stop-handle over the planner's pod + node informers."""
 
@@ -128,8 +148,16 @@ class BatchPlanner:
         mirror: TensorStateMirror,
         node_capacity: int = DEFAULT_NODE_CAPACITY,
         solver: str = "greedy",
+        devices: int = 1,
     ):
-        """``solver``: "greedy" reproduces what the sequential scheduler
+        """``devices``: how many of JAX's devices the solve spans; 1 is the
+        one-device solve (the Pallas assigner on a TPU), more a mesh over
+        the first ``devices`` of them with every ``[P, N]`` array split
+        over the nodes.  :class:`MeshRefused` when JAX has fewer, when the
+        mirror's node capacity does not divide, or with ``solver``
+        "sinkhorn", which has no mesh form on this path.
+
+        ``solver``: "greedy" reproduces what the sequential scheduler
         would do; "sinkhorn" globally coordinates the batch
         (ops/sinkhorn.py) — strictly an enhancement over the reference.
 
@@ -159,6 +187,48 @@ class BatchPlanner:
         self._bound_used: Dict[str, Tuple[int, int, int]] = {}
         # shapes every padded size below them has been compiled for
         self._warmed: set = set()
+        # more than one device: the mesh, the mask born split over it, and
+        # where a replan's operands go — what has a node axis split over the
+        # mesh, the per-pod columns and the rules on every device
+        self.mesh = None
+        self._mask = _candidate_mask
+        if devices != 1:
+            self.mesh = mesh = self._mesh_over(devices)
+            by_node, everywhere = node_sharded(mesh), replicated(mesh)
+            self._mask = jax.jit(
+                _candidate_mask.__wrapped__, static_argnums=(0, 1),
+                out_shardings=by_node,
+            )
+            self._placement = (
+                ClusterState(
+                    metric_values=by_node, metric_present=by_node,
+                    dontschedule=everywhere,
+                    capacity=NamedSharding(mesh, PartitionSpec(NODE_AXIS)),
+                ),
+                PendingPods(
+                    metric_row=everywhere, op_id=everywhere,
+                    candidates=by_node, policy=everywhere,
+                ),
+            )
+            trace.COUNTERS.set_gauge("pas_planner_mesh_devices", devices)
+
+    def _mesh_over(self, devices: int):
+        have = jax.devices()
+        n_cap = self.mirror.device_view().node_capacity
+        if devices < 1:
+            refusal = f"{devices} devices is not a number of devices"
+        elif self.solver == "sinkhorn":
+            refusal = "the sinkhorn solver runs on one device"
+        elif len(have) < devices:
+            refusal = f"JAX has {len(have)} device(s)"
+        elif n_cap % devices:
+            # the capacity only ever doubles: what divides it now always will
+            refusal = f"the mirror's node capacity ({n_cap}) does not divide"
+        else:
+            return make_mesh(n_node_shards=devices, devices=have[:devices])
+        raise MeshRefused(
+            f"the batch planner cannot span {devices} devices: {refusal}"
+        )
 
     # -- pending-set maintenance ----------------------------------------------
 
@@ -264,8 +334,14 @@ class BatchPlanner:
             return 0
         state, batch, keys, view, timer = snapshot
         p = len(keys)
+        if self.mesh is not None:
+            with trace.stage("plan.place", "pas_planner_place_seconds_total"):
+                state, batch = self._place(state, batch)
         with trace.stage("plan.solve", "pas_planner_solve_seconds_total"):
-            if self.solver == "sinkhorn":
+            if self.mesh is not None:
+                assigned = np.asarray(self._mesh_step(state, batch, timer))
+                trace.COUNTERS.inc("pas_planner_mesh_solves_total")
+            elif self.solver == "sinkhorn":
                 from platform_aware_scheduling_tpu.ops.sinkhorn import (
                     sinkhorn_assign_kernel,
                 )
@@ -304,6 +380,21 @@ class BatchPlanner:
         if self.solver != "sinkhorn":
             self._warm_smaller(state, batch, known)
         return len(plan)
+
+    def _place(self, state: ClusterState, batch: PendingPods):
+        """The replan's operands on the mesh (``self._placement``): the
+        view's ``[M, N]`` values and presence go device to device, the room
+        vector and the per-pod columns up; ``candidates`` was born split
+        (``self._mask``)."""
+        return jax.device_put((state, batch), self._placement)
+
+    def _mesh_step(self, state: ClusterState, batch: PendingPods, timer=None):
+        """``node_for_pod`` of the mesh solve, ready on the devices."""
+        assigned = mesh_scheduling_step(self.mesh, state, batch)
+        assigned.block_until_ready()
+        if timer is not None:
+            timer.mark("execute")
+        return assigned
 
     def _snapshot(self):
         """(state, batch, pod keys in order, view, timer) of one replan, or
@@ -357,7 +448,7 @@ class BatchPlanner:
         batch = PendingPods(
             metric_row=padded(rows[policy]),
             op_id=padded(ops[policy]),
-            candidates=_candidate_mask(size, n_cap, p, known),
+            candidates=self._mask(size, n_cap, p, known),
             policy=padded(policy),
         )
         need = (1000, max(cpus), max(mems))
@@ -425,11 +516,18 @@ class BatchPlanner:
         smaller = size // 2
         while smaller >= PAD_FLOOR and (smaller, *shape[1:]) not in self._warmed:
             empty = jnp.zeros(smaller, dtype=jnp.int32)
-            observed_scheduling_step(state, PendingPods(
+            nothing = PendingPods(
                 metric_row=empty, op_id=empty,
-                candidates=_candidate_mask(smaller, n_cap, 0, known),
+                candidates=self._mask(smaller, n_cap, 0, known),
                 policy=empty,
-            )).assignment.node_for_pod.block_until_ready()
+            )
+            if self.mesh is not None:
+                # placed as a replan places it: the same program is found
+                self._mesh_step(*self._place(state, nothing))
+            else:
+                observed_scheduling_step(
+                    state, nothing
+                ).assignment.node_for_pod.block_until_ready()
             self._warmed.add((smaller, *shape[1:]))
             smaller //= 2
         self._warmed.add(shape)

@@ -125,6 +125,12 @@ declare("pas_planner_replan_seconds_total", "counter", "Seconds spent in those r
 declare("pas_planner_snapshot_seconds_total", "counter", "Replan seconds reading the pending set, the policies and the nodes' room into the solve's operands.")
 declare("pas_planner_solve_seconds_total", "counter", "Replan seconds from the solve's dispatch to the readback of its assignment.")
 declare("pas_planner_publish_seconds_total", "counter", "Replan seconds building and publishing the plan's pod -> node table.")
+# the solve over more than one device (--batchPlannerDevices > 1); never
+# emitted by a one-device planner.  place lies between snapshot and solve:
+# snapshot + place + solve + publish <= replan
+declare("pas_planner_place_seconds_total", "counter", "Replan seconds placing the solve's operands on the mesh: the view's metric matrix and the room vector split over the nodes, the rest on every device.")
+declare("pas_planner_mesh_devices", "gauge", "Devices the batch planner's solve spans (set only when more than one).")
+declare("pas_planner_mesh_solves_total", "counter", "Replans solved node-sharded over the mesh.")
 declare("pas_planner_pending_pods", "gauge", "Pending pods the last replan solved.")
 declare("pas_planner_promoted_total", "counter", "Prioritize answers that carried a current plan's node to rank 1.")
 declare("pas_planner_reordered_total", "counter", "Of those, answers in which the plan's node was moved past a candidate the ordinal ranking put first: the answers the plan changed.")
