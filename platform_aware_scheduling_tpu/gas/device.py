@@ -239,14 +239,15 @@ class GASUsageMirror:
 
     def views(self):
         """(node_index, known, has_gpus, res_index): the host copies a
-        Filter reads after it has let go of the lock, memoized per
+        Filter reads after it has let go of the lock (the two row vectors
+        as lists: it reads them a candidate name at a time), memoized per
         structure version."""
         if self._views is None or self._views[0] != self._structure:
             self._views = (
                 self._structure,
                 dict(self._node_index),
-                self._known.copy(),
-                self._has_gpus.copy(),
+                self._known.tolist(),
+                self._has_gpus.tolist(),
                 dict(self._res_index),
             )
         return self._views[1:]
@@ -440,19 +441,22 @@ class DeviceBinpacker:
                         raise
                 self._remember_fits(state, signature, fits_all)
         with span.stage("rows"):
-            out = [False] * len(node_names)
-            codes = [decisions.CODE_GAS_CAPACITY] * len(node_names)
-            for pos, name in enumerate(node_names):
-                row = node_index.get(name)
+            # plain lists, one pass: indexing a NumPy array a name costs
+            # three times a list's, and whole-vector NumPy lets go of the
+            # GIL at every operation — beside a Bind each is a hand-off
+            fits_row = fits_all.tolist()
+            codes = []
+            for row in map(node_index.get, node_names):
                 if row is None or not known[row]:
-                    codes[pos] = decisions.CODE_GAS_UNKNOWN_NODE
-                    continue  # pre-failed
-                if not has_gpus[row]:
-                    codes[pos] = decisions.CODE_GAS_NO_GPUS
-                    continue
-                out[pos] = bool(fits_all[row])
-                if out[pos]:
-                    codes[pos] = decisions.CODE_ELIGIBLE
+                    code = decisions.CODE_GAS_UNKNOWN_NODE  # pre-failed
+                elif not has_gpus[row]:
+                    code = decisions.CODE_GAS_NO_GPUS
+                elif fits_row[row]:
+                    code = decisions.CODE_ELIGIBLE
+                else:
+                    code = decisions.CODE_GAS_CAPACITY
+                codes.append(code)
+            out = [code == decisions.CODE_ELIGIBLE for code in codes]
         return out, codes
 
     # -- per-request staging path (control) ------------------------------------

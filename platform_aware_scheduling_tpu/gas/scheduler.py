@@ -25,6 +25,9 @@ sequential per-node loop — the host loop remains as exact fallback/control.
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import itertools
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -117,7 +120,12 @@ class GASExtender:
         # workqueue work-latency histogram merges into this extender's
         # pas_request_duration_seconds family (verb="workqueue_work")
         self.cache.work_queue.recorder = self.recorder
+        # what Bind and the host loop's Filter hold (scheduler.go's
+        # rwmutex); a Filter the device answers takes the usage mirror's
+        # lock instead and is only counted here, exactly under threads
         self._rwmutex = threading.RLock()
+        self._in_flight_lock = threading.Lock()
+        self._device_filters_in_flight = 0
         # opt-in utils.slo.SLOEngine (--slo=on): judged over this
         # extender's recorder; front-ends serve GET /debug/slo (404
         # while None) and /metrics gains the pas_slo_* gauges
@@ -211,9 +219,9 @@ class GASExtender:
                     None, len(args.node_names or ())
                 )
             admission_codes: Dict[str, int] = {}
-            # kernel contains lock_wait, mirror_wait, state_upload,
-            # req_upload, solve, rows and verdict: a container, so never
-            # annotated
+            # kernel contains mirror_wait, state_upload, req_upload,
+            # solve, rows and verdict (the host loop: lock_wait and the
+            # loop): a container, so never annotated
             with span.stage("kernel", leaf=False):
                 result = self._filter_nodes(
                     args, span=span, codes_out=admission_codes
@@ -303,13 +311,12 @@ class GASExtender:
             klog.error(error)
             return FilterResult(error=error)
         summary = request_summary(args.pod)
-        # the wait for the verbs' mutex (the same stage on Bind), apart
-        # from what is done under it: acquired by hand so that the stage
-        # ends where the lock is held
-        with span.stage("lock_wait"):
-            self._rwmutex.acquire()
-        try:
-            if self._device is not None:
+        if self._device is not None:
+            # a Filter the device answers takes no verbs' mutex: the
+            # mirror's own lock orders its solve against every booking
+            # and release (gas/device.py _fit_mirror), and neither the
+            # verdict nor the decision record reads the cluster cache
+            with self._device_filter():
                 try:
                     res = self._device.batch_fit(
                         args.pod, args.node_names, with_reasons=True,
@@ -323,28 +330,39 @@ class GASExtender:
                         fits, codes = res
                         span.set("path", "device")
                         trace.COUNTERS.inc("pas_gas_filter_device_total")
-                        node_names = [
-                            n for n, ok in zip(args.node_names, fits) if ok
-                        ]
-                        failed = {
-                            n: decisions.gas_reason(code, summary)
-                            for n, ok, code in zip(
-                                args.node_names, fits, codes
-                            )
-                            if not ok
+                        node_names = list(
+                            itertools.compress(args.node_names, fits)
+                        )
+                        missed = [not ok for ok in fits]
+                        failed_names = list(
+                            itertools.compress(args.node_names, missed)
+                        )
+                        failed_codes = list(itertools.compress(codes, missed))
+                        # a reason is its code and the pod's request: one
+                        # string a class, not one a node
+                        reasons = {
+                            code: decisions.gas_reason(code, summary)
+                            for code in set(failed_codes)
                         }
+                        failed = dict(zip(
+                            failed_names,
+                            map(reasons.__getitem__, failed_codes),
+                        ))
                         if codes_out is not None:
-                            for n, ok, code in zip(
-                                args.node_names, fits, codes
-                            ):
-                                if not ok:
-                                    codes_out[n] = code
+                            codes_out.update(zip(failed_names, failed_codes))
                         self._record_filter_decision(
                             span, args.pod, args.node_names, failed, codes
                         )
                     return FilterResult(
                         node_names=node_names, failed_nodes=failed, error=""
                     )
+        # the host loop reads the cache once a card a node, so it holds
+        # the verbs' mutex as scheduler.go does.  The wait (the same
+        # stage on Bind) apart from what is done under it: acquired by
+        # hand so that the stage ends where the lock is held
+        with span.stage("lock_wait"):
+            self._rwmutex.acquire()
+        try:
             span.set("path", "host")
             trace.COUNTERS.inc("pas_gas_filter_host_total")
             node_names: List[str] = []
@@ -379,6 +397,19 @@ class GASExtender:
             return FilterResult(node_names=node_names, failed_nodes=failed, error="")
         finally:
             self._rwmutex.release()
+
+    @contextlib.contextmanager
+    def _device_filter(self):
+        """Counts a Filter in while the device path has it: what a Bind
+        reads to say whether it booked beside one
+        (``pas_gas_bind_overlapped_total``)."""
+        with self._in_flight_lock:
+            self._device_filters_in_flight += 1
+        try:
+            yield
+        finally:
+            with self._in_flight_lock:
+                self._device_filters_in_flight -= 1
 
     def _admission_review(
         self,
@@ -423,10 +454,8 @@ class GASExtender:
         log = decisions.DECISIONS
         if not log.enabled:
             return
-        reason_counts: Dict[int, int] = {}
-        for code in codes:
-            if code != decisions.CODE_ELIGIBLE:
-                reason_counts[code] = reason_counts.get(code, 0) + 1
+        reason_counts = collections.Counter(codes)
+        reason_counts.pop(decisions.CODE_ELIGIBLE, None)
         log.record_filter(
             verb="gas_filter",
             request_id=getattr(span, "trace_id", ""),
@@ -520,6 +549,8 @@ class GASExtender:
         with span.stage("lock_wait"):
             self._rwmutex.acquire()
         try:
+            if self._device_filters_in_flight:
+                trace.COUNTERS.inc("pas_gas_bind_overlapped_total")
             resources_adjusted = False
             annotation = ""
             try:

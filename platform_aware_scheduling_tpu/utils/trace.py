@@ -116,6 +116,7 @@ declare("pas_gas_filter_host_total", "counter", "GAS Filter requests served by t
 declare("pas_gas_state_incremental_total", "counter", "GAS device solves whose usage state was brought current by an update block of changed rows (a zero-row block included).")
 declare("pas_gas_state_full_restage_total", "counter", "GAS device solves that re-uploaded the whole usage tensor (more changed rows than the block holds, or a structure change).")
 declare("pas_gas_state_rows_applied_total", "counter", "Usage rows sent to the device inside update blocks.")
+declare("pas_gas_bind_overlapped_total", "counter", "GAS Binds that took the verbs' mutex while a device Filter was in flight (a Filter the device answers takes the usage mirror's lock, not the mutex).")
 # batch planner (tas/planner.py; --batchPlanner): one replan after every
 # refresh pass.  snapshot + solve + publish <= replan; promoted, stale and
 # unplanned partition the Prioritize answers given with the planner on;

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from platform_aware_scheduling_tpu.extender.server import HTTPRequest
+from platform_aware_scheduling_tpu.extender.types import Args
 from platform_aware_scheduling_tpu.gas import device as gas_device
 from platform_aware_scheduling_tpu.gas.cache import Cache, get_key
 from platform_aware_scheduling_tpu.gas.resource_map import ResourceMap
@@ -41,6 +42,10 @@ def post(obj) -> HTTPRequest:
         headers={"Content-Type": "application/json"},
         body=json.dumps(obj).encode(),
     )
+
+
+def request_args(request: HTTPRequest) -> Args:
+    return Args.from_json(request.body)
 
 
 def gpu_node(name, cards=2, i915=2, millicores=2000, memory=4000):
@@ -524,6 +529,46 @@ class TestUsageMirrorSync:
             cache.stop()
 
 
+    @pytest.mark.parametrize("ghost_at", ["first", "last", "both"])
+    def test_every_reason_class_in_one_candidate_list(self, ghost_at):
+        """The row lookups, one pass over lists: a fitting node, a full
+        one, one without GPUs, a deleted one and a name the mirror never
+        saw (wherever it stands) in one request get the host loop's
+        verdicts, reasons and codes."""
+        kube = FakeKubeClient()
+        kube.add_node(gpu_node("fits"))
+        kube.add_node(gpu_node("full", cards=1, i915=1, millicores=100))
+        kube.add_node(make_node("bare"))
+        kube.add_node(gpu_node("gone"))
+        cache = Cache(kube, start=False)
+        ext = GASExtender(kube, cache=cache, use_device=True, use_mirror=True)
+        host = GASExtender(kube, cache=cache, use_device=False)
+        cache.start()
+        try:
+            mirror = ext._device.mirror
+            assert wait_until(lambda: int(mirror._known.sum()) == 4)
+            kube.delete_node("gone")
+            assert wait_until(lambda: int(mirror._known.sum()) == 3)
+            names = ["full", "bare", "fits", "gone"]
+            if ghost_at in ("first", "both"):
+                names.insert(0, "ghost-a")
+            if ghost_at in ("last", "both"):
+                names.append("ghost-z")
+            pod = gpu_pod("probe", millicores="500")
+            request = post({"Pod": pod.raw, "NodeNames": names})
+            got = json.loads(ext.filter(request).body)
+            assert got["NodeNames"] == ["fits"]
+            assert list(got["FailedNodes"]) == [n for n in names if n != "fits"]
+            assert got == json.loads(host.filter(request).body)
+            device_codes, host_codes = {}, {}
+            ext._filter_nodes(request_args(request), codes_out=device_codes)
+            host._filter_nodes(request_args(request), codes_out=host_codes)
+            assert device_codes == host_codes
+            assert len(set(device_codes.values())) == 3
+        finally:
+            cache.stop()
+
+
 class _Readback:
     """Stands in for one array of a solve's result: counts the copies to
     the host (``np.asarray`` calls ``__array__``)."""
@@ -823,3 +868,314 @@ class TestResidentState:
         # a stale solve never replaced a newer version's state
         assert len(installed) > 3 and installed == sorted(installed)
         self._agree(cluster, gpu_pod("after", millicores="60"), "after")
+
+
+class _HeldFits:
+    """A solve's ``fits`` whose readback waits for the test."""
+
+    def __init__(self, fits, reached, release):
+        self.fits, self.reached, self.release = fits, reached, release
+
+    def __array__(self, dtype=None, copy=None):
+        self.reached.set()
+        assert self.release.wait(30)
+        return np.asarray(self.fits)
+
+
+class TestVerbLocking:
+    """PR 34: which lock a Filter takes follows which path answers it —
+    the usage mirror's for a device Filter, the verbs' mutex for the
+    host loop — so a Bind books while a device Filter waits for the
+    chip (gas/scheduler.py ``_filter_nodes``)."""
+
+    NAMES = ["n0", "n1", "n2"]
+    CARD_MILLICORES = 1000
+
+    @pytest.fixture
+    def verbs(self):
+        kube = FakeKubeClient()
+        for name in self.NAMES:
+            # i915 to spare: millicores decide every fit
+            kube.add_node(gpu_node(
+                name, cards=2, i915=64, millicores=2 * self.CARD_MILLICORES))
+        for index in range(400):
+            kube.add_pod(gpu_pod(f"p{index}", millicores=str(
+                (350, 600, 250, 700)[index % 4])))
+        cache = Cache(kube, start=False)
+        ext = GASExtender(kube, cache=cache, use_device=True, use_mirror=True)
+        host = GASExtender(kube, cache=cache, use_device=False)
+        cache.start()
+        mirror = ext._device.mirror
+        assert wait_until(lambda: int(mirror._known.sum()) == len(self.NAMES))
+        yield kube, cache, ext, host
+        cache.stop()
+
+    @staticmethod
+    def _overlapped():
+        return trace.COUNTERS.get("pas_gas_bind_overlapped_total")
+
+    def _filter(self, ext, pod):
+        request = post({"Pod": pod.raw, "NodeNames": self.NAMES})
+        request.span = trace.Span("POST /scheduler/filter")
+        response = ext.filter(request)
+        assert response.status == 200, response.body
+        return json.loads(response.body), request.span
+
+    @staticmethod
+    def _bind(ext, name, node):
+        request = post({"PodName": name, "PodNamespace": "default",
+                        "PodUID": f"uid-{name}", "Node": node})
+        request.span = trace.Span("POST /scheduler/bind")
+        return ext.bind(request), request.span
+
+    @staticmethod
+    def _passed(answer):
+        return set(answer["NodeNames"] or ())
+
+    def test_a_bind_books_while_a_device_filter_waits_for_the_chip(
+        self, verbs, monkeypatch
+    ):
+        _kube, cache, ext, host = verbs
+        probe = gpu_pod("probe", millicores="600")
+        # serial Filter then Bind (card0 of n0 to 350): no Bind beside a
+        # Filter, the counter stays
+        before = self._overlapped()
+        answer, span = self._filter(ext, probe)
+        assert self._passed(answer) == set(self.NAMES)
+        assert span.attrs["path"] == "device"
+        assert "lock_wait" not in span.stage_seconds()
+        response, _span = self._bind(ext, "p0", "n0")
+        assert response.status == 200, response.body
+        assert self._overlapped() == before
+
+        reached, release = threading.Event(), threading.Event()
+        inner = gas_device.binpack_kernel
+
+        def held_kernel(*args, **kwargs):
+            result = inner(*args, **kwargs)
+            return result._replace(
+                fits=_HeldFits(result.fits, reached, release))
+
+        monkeypatch.setattr(gas_device, "binpack_kernel", held_kernel)
+        # 700 fits card1 of n0 only: after p3 (700) books there it does not
+        wide = gpu_pod("wide", millicores="700")
+        held = {}
+        filtering = threading.Thread(
+            target=lambda: held.update(answer=self._filter(ext, wide)))
+        filtering.start()
+        try:
+            assert reached.wait(30)
+            monkeypatch.setattr(gas_device, "binpack_kernel", inner)
+            # the Filter has staged and dispatched and waits for the
+            # chip: the Bind is answered meanwhile
+            response, bind_span = self._bind(ext, "p3", "n0")
+            assert response.status == 200, response.body
+            assert filtering.is_alive() and not release.is_set()
+            assert self._overlapped() == before + 1
+            assert "lock_wait" in bind_span.stage_seconds()
+            used = cache.get_node_resource_status("n0")
+            assert used["card1"]["gpu.intel.com/millicores"] == 700
+        finally:
+            release.set()
+            filtering.join(timeout=30)
+        assert not filtering.is_alive()
+        # first fit on the state it staged: the one before the booking
+        answer, span = held["answer"]
+        assert self._passed(answer) == set(self.NAMES)
+        assert span.attrs["path"] == "device"
+        # the next Filter sees the booking, as the host loop does
+        answer, _span = self._filter(ext, wide)
+        assert self._passed(answer) == {"n1", "n2"}
+        assert answer == self._filter(host, wide)[0]
+        assert self._overlapped() == before + 1
+
+    @pytest.mark.parametrize("why", ["no-device", "no-card-demand",
+                                     "device-raises"])
+    def test_a_host_path_filter_still_holds_the_verbs_mutex(
+        self, verbs, monkeypatch, why
+    ):
+        _kube, cache, device_ext, host = verbs
+        ext = host if why == "no-device" else device_ext
+        pod = gpu_pod("probe", millicores="600")
+        if why == "no-card-demand":
+            pod = make_pod("probe", container_requests=[
+                {"gpu.intel.com/millicores": "600"}])
+        elif why == "device-raises":
+            def broken(*args, **kwargs):
+                raise RuntimeError("device lost")
+
+            monkeypatch.setattr(ext._device, "batch_fit", broken)
+        reached, release = threading.Event(), threading.Event()
+        pod_read = threading.Event()
+        logic, fetch_pod = ext._run_scheduling_logic, cache.fetch_pod
+
+        def held_logic(pod, node_name):
+            if threading.current_thread() is filtering:
+                reached.set()
+                assert release.wait(30)
+            return logic(pod, node_name)
+
+        def seen_fetch_pod(namespace, name):
+            try:
+                return fetch_pod(namespace, name)
+            finally:
+                pod_read.set()
+
+        monkeypatch.setattr(ext, "_run_scheduling_logic", held_logic)
+        monkeypatch.setattr(cache, "fetch_pod", seen_fetch_pod)
+        before = self._overlapped()
+        done = {}
+        filtering = threading.Thread(
+            target=lambda: done.update(filter=self._filter(ext, pod)))
+        binding = threading.Thread(
+            target=lambda: done.update(bind=self._bind(ext, "p0", "n0")))
+        filtering.start()
+        try:
+            assert reached.wait(30)
+            binding.start()
+            assert pod_read.wait(30)
+            # the Bind has its pod and wants the mutex the host loop holds
+            binding.join(timeout=0.2)
+            assert binding.is_alive()
+            assert cache.get_node_resource_status("n0") == {}
+        finally:
+            release.set()
+            filtering.join(timeout=30)
+            binding.join(timeout=30)
+        assert not filtering.is_alive() and not binding.is_alive()
+        answer, span = done["filter"]
+        assert span.attrs["path"] == "host"
+        assert "lock_wait" in span.stage_seconds()
+        assert self._passed(answer) == set(self.NAMES)
+        response, bind_span = done["bind"]
+        assert response.status == 200, response.body
+        assert bind_span.stage_seconds()["lock_wait"] >= 0.1
+        # only a Filter the device has counts as one a Bind overlapped
+        assert self._overlapped() == before
+
+    def test_two_filters_and_a_bind_race_through_the_verbs(self, verbs):
+        kube, cache, ext, host = verbs
+        mirror = ext._device.mirror
+        cap = self.CARD_MILLICORES
+        model = {name: {"card0": 0, "card1": 0} for name in self.NAMES}
+        stop, errors, counts = threading.Event(), [], {"bound": 0, "refused": 0}
+
+        def filtering(millicores):
+            try:
+                pod = gpu_pod("probe", millicores=str(millicores))
+                passing = set(self.NAMES)
+                while not stop.is_set():
+                    answer, span = self._filter(ext, pod)
+                    assert span.attrs["path"] == "device"
+                    assert "lock_wait" not in span.stage_seconds()
+                    # bookings only: each solve runs on a version no older
+                    # than the last, so a node that filled never comes back
+                    now = self._passed(answer)
+                    assert now <= passing, (now, passing)
+                    passing = now
+            except BaseException as exc:  # noqa: BLE001 — reported below
+                errors.append(exc)
+
+        def binding():
+            try:
+                rng = np.random.default_rng(34)
+                for index in range(400):
+                    if stop.is_set():
+                        break
+                    name = f"p{index}"
+                    node = self.NAMES[int(rng.integers(0, len(self.NAMES)))]
+                    need = int(container_requests(
+                        kube.get_pod("default", name)
+                    )[0]["gpu.intel.com/millicores"])
+                    want = next((card for card in ("card0", "card1")
+                                 if model[node][card] + need <= cap), None)
+                    response, _span = self._bind(ext, name, node)
+                    if response.status == 200:
+                        cards = kube.get_pod(
+                            "default", name).get_annotations()[CARD_ANNOTATION]
+                        assert cards == want, (name, node, cards, want)
+                        model[node][cards] += need
+                        counts["bound"] += 1
+                    else:
+                        # every refused Bind is a pod that did not fit
+                        assert want is None, (name, node, model[node])
+                        counts["refused"] += 1
+            except BaseException as exc:  # noqa: BLE001
+                errors.append(exc)
+
+        threads = [threading.Thread(target=filtering, args=(300,)),
+                   threading.Thread(target=filtering, args=(650,)),
+                   threading.Thread(target=binding)]
+        before = self._overlapped()
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for thread in threads:
+                thread.start()
+            time.sleep(1.5)
+        finally:
+            stop.set()
+            for thread in threads:
+                thread.join(timeout=30)
+            sys.setswitchinterval(interval)
+        assert not any(thread.is_alive() for thread in threads)
+        assert not errors, errors
+        assert counts["bound"] >= 6 and self._overlapped() > before, counts
+        assert ext._device_filters_in_flight == 0
+        # no card over capacity, and the cache holds what was acknowledged
+        for node, cards in model.items():
+            used = cache.get_node_resource_status(node)
+            for card, millicores in cards.items():
+                assert millicores <= cap
+                assert used.get(card, {}).get(
+                    "gpu.intel.com/millicores", 0) == millicores
+        # device and host loop agree afterwards
+        for millicores in ("250", "600", "1000"):
+            pod = gpu_pod("after", millicores=millicores)
+            assert self._filter(ext, pod)[0] == self._filter(host, pod)[0]
+        with mirror._lock:
+            _version, _structure, state = mirror._device
+            assert np.array_equal(i64.to_int64_np(state.used), mirror._used)
+
+    def test_a_rolled_back_booking_is_seen_then_gone(self, verbs, monkeypatch):
+        kube, cache, ext, host = verbs
+        mirror = ext._device.mirror
+        wide = gpu_pod("wide", millicores="700")
+        # card0 of n0 to 600: 700 then fits card1 of n0 only
+        assert self._bind(ext, "p1", "n0")[0].status == 200
+        assert self._passed(self._filter(ext, wide)[0]) == set(self.NAMES)
+        with mirror._lock:
+            row = mirror._used[mirror._node_index["n0"]].copy()
+        reached, release = threading.Event(), threading.Event()
+
+        def failing_bind(*args, **kwargs):
+            reached.set()
+            assert release.wait(30)
+            raise RuntimeError("the API refused the binding")
+
+        monkeypatch.setattr(kube, "bind_pod", failing_bind)
+        done = {}
+        binding = threading.Thread(
+            target=lambda: done.update(bind=self._bind(ext, "p3", "n0")))
+        binding.start()
+        try:
+            assert reached.wait(30)
+            # the booking is made and its Bind not yet answered: a Filter
+            # beside it refuses the node it could have passed, never the
+            # reverse
+            answer, span = self._filter(ext, wide)
+            assert span.attrs["path"] == "device"
+            assert self._passed(answer) == {"n1", "n2"}
+        finally:
+            release.set()
+            binding.join(timeout=30)
+        assert not binding.is_alive()
+        response, _span = done["bind"]
+        assert response.status == 404
+        assert "refused the binding" in json.loads(response.body)["Error"]
+        with mirror._lock:
+            assert np.array_equal(
+                mirror._used[mirror._node_index["n0"]], row)
+        answer, _span = self._filter(ext, wide)
+        assert self._passed(answer) == set(self.NAMES)
+        assert answer == self._filter(host, wide)[0]

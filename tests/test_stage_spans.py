@@ -135,12 +135,41 @@ class TestGasStages:
         assert ext.filter(request).status == 200
         covered, kernel, stages = _tiles(request.span, "kernel",
                                          GAS_FILTER_PARTS)
-        for required in ("lock_wait", "mirror_wait", "req_upload", "solve",
-                         "rows", "verdict"):
+        for required in ("mirror_wait", "req_upload", "solve", "rows",
+                         "verdict"):
             assert required in stages, (required, sorted(stages))
+        # the device answers under the mirror's lock, not the verbs' mutex
+        assert "lock_wait" not in stages, sorted(stages)
         assert stages["solve"] >= SLOW_S
         assert abs(kernel - covered) <= 0.10 * kernel, (covered, kernel, stages)
         assert request.span.attrs["path"] == "device"
+
+    @pytest.mark.parametrize("why", ["no-device", "no-card-demand",
+                                     "device-raises"])
+    def test_a_host_path_filter_carries_lock_wait(self, gas, monkeypatch, why):
+        """The host loop answers under the verbs' mutex, so its span
+        carries the stage ``gas_lock_wait_ms`` reads — and none of the
+        device's."""
+        kube, cache, ext = gas
+        names = [f"n{i}" for i in range(4)]
+        pod = _gpu_pod("probe")
+        if why == "no-device":
+            ext = GASExtender(kube, cache=cache, use_device=False)
+        elif why == "no-card-demand":
+            pod = make_pod("probe", container_requests=[
+                {"gpu.intel.com/millicores": "500"}])
+        else:
+            def broken(*args, **kwargs):
+                raise RuntimeError("device lost")
+
+            monkeypatch.setattr(ext._device, "batch_fit", broken)
+        request = _request("/scheduler/filter",
+                           {"Pod": pod.raw, "NodeNames": names})
+        assert ext.filter(request).status == 200
+        stages = request.span.stage_seconds()
+        assert request.span.attrs["path"] == "host"
+        assert "lock_wait" in stages, sorted(stages)
+        assert not {"solve", "verdict"} & set(stages), sorted(stages)
 
     def test_a_moved_version_shows_as_state_upload(self, gas):
         kube, cache, ext = gas
@@ -603,7 +632,7 @@ def test_new_families_are_declared():
         "pas_refresh_pass_seconds_total", "pas_refresh_fetch_seconds_total",
         "pas_refresh_publish_seconds_total", "pas_refresh_warm_seconds_total",
         "pas_gc_pause_seconds_total", "pas_gc_collections_total",
-        "pas_filter_native_total",
+        "pas_filter_native_total", "pas_gas_bind_overlapped_total",
     }
     for name in expected:
         assert trace.METRICS[name][0] == "counter", name
