@@ -62,12 +62,17 @@ def build_arg_parser() -> argparse.ArgumentParser:
     parser.add_argument("--batchSolver", default="greedy",
                         choices=["greedy", "sinkhorn"],
                         help="batch planner solver: greedy (sequential-"
-                        "equivalent) or sinkhorn (globally coordinated)")
+                        "equivalent; pods of unlike requests each book "
+                        "their own cpu, memory and pod slot) or sinkhorn "
+                        "(globally coordinated; takes a count only, so "
+                        "unlike pods are each counted as the largest "
+                        "request pending: never an overcommit, not exact)")
     parser.add_argument("--batchPlannerDevices", type=int, default=1,
                         help="devices the batch planner's solve spans: 1 "
                         "(default) solves on one device; n > 1 solves "
                         "node-sharded over a mesh of the first n (greedy "
-                        "solver only; docs/architecture.md)")
+                        "solver only; pods of unlike requests are each "
+                        "counted as the largest there; docs/architecture.md)")
     parser.add_argument("--nodeCacheCapable", action="store_true",
                         help="serve Prioritize/Filter from Args.NodeNames "
                         "(register the extender nodeCacheCapable: true); "

@@ -13,6 +13,12 @@ solved at once over dense tensors:
 Greedy-in-pod-order reproduces what the sequential system would decide, so
 answers to individual /scheduler verbs can be served from this solution.
 
+Room has two forms (ops/assign.py), and which one a solve runs follows from
+its operands — no flag.  ``PendingPods.demand`` absent: ``capacity`` counts
+pods, one unit a pod (a pending set of alike pods).  ``demand`` given
+(``[P, L, R]``): ``capacity`` is the nodes' room ``[L, R, N]`` over R
+resources in L limbs of 31 bits, and every pod books its own vector.
+
 Multi-chip (:func:`mesh_scheduling_step`, the planner's
 ``--batchPlannerDevices`` path): the operands arrive node-sharded over a
 mesh, rules and score keys run under GSPMD (elementwise over nodes: no
@@ -54,7 +60,9 @@ class ClusterState(NamedTuple):
     # violation rules, ``[D, R]``: one (padded) list per distinct policy of
     # the pending set, picked per pod by ``PendingPods.policy``
     dontschedule: RuleSet
-    capacity: jax.Array  # int32 [N] — pods each node may still accept
+    # int32 [N] — pods each node may still accept; with
+    # ``PendingPods.demand`` int32 [L, R, N] — the room left of R resources
+    capacity: jax.Array
 
 
 class PendingPods(NamedTuple):
@@ -64,6 +72,9 @@ class PendingPods(NamedTuple):
     op_id: jax.Array  # int32 [P]
     candidates: jax.Array  # bool [P, N]
     policy: jax.Array  # int32 [P] — the pod's row of ``dontschedule``
+    # int32 [P, L, R] — each pod's own requests, in the units and limbs of
+    # ``ClusterState.capacity``; None: alike pods, one unit of room a pod
+    demand: Optional[jax.Array] = None
 
 
 class ScheduleOutput(NamedTuple):
@@ -143,10 +154,20 @@ def _scheduling_step(
     # Both assigners are exact greedy-in-order and return identical
     # results: the Pallas kernel keeps capacity resident in VMEM for one
     # launch, the scan pays P dispatch-bound steps.
-    if assigner == ASSIGNER_PALLAS:
-        assignment = greedy_assign_pallas(score, eligible, state.capacity)
+    assign = (
+        greedy_assign_pallas if assigner == ASSIGNER_PALLAS
+        else greedy_assign_kernel
+    )
+    if pods.demand is None:
+        assignment = assign(score, eligible, state.capacity)
     else:
-        assignment = greedy_assign_kernel(score, eligible, state.capacity)
+        # the kernels take the limbs as rows: [L * R, N] and [P, L * R]
+        p, limbs, resources = pods.demand.shape
+        assignment = assign(
+            score, eligible,
+            state.capacity.reshape(limbs * resources, -1),
+            demand=pods.demand.reshape(p, limbs * resources), limbs=limbs,
+        )
     return ScheduleOutput(
         assignment=assignment, violating=violating, score=score, eligible=eligible
     )
@@ -232,7 +253,7 @@ def observed_scheduling_step(
     if own:
         timer.done(
             pods=int(pods.metric_row.shape[0]),
-            nodes=int(state.capacity.shape[0]),
+            nodes=int(state.capacity.shape[-1]),
         )
     return out
 
@@ -242,9 +263,11 @@ def example_inputs(
     num_nodes: int = 64,
     num_pods: int = 16,
     seed: int = 0,
+    resources: int = 0,
 ):
     """Small synthetic (state, pods) pair for compile checks and benches:
-    one policy (D = 1) over every pod."""
+    one policy (D = 1) over every pod.  ``resources`` > 0 gives the demand
+    form: room ``[1, resources, N]`` and a request vector a pod."""
     import numpy as np
 
     rng = np.random.default_rng(seed)
@@ -280,4 +303,11 @@ def example_inputs(
         candidates=jnp.asarray(rng.random((num_pods, num_nodes)) > 0.1),
         policy=jnp.zeros(num_pods, dtype=jnp.int32),
     )
+    if resources:
+        state = state._replace(capacity=jnp.asarray(
+            rng.integers(0, 12, size=(1, resources, num_nodes)).astype(np.int32)
+        ))
+        pods = pods._replace(demand=jnp.asarray(
+            rng.integers(0, 5, size=(num_pods, 1, resources)).astype(np.int32)
+        ))
     return state, pods

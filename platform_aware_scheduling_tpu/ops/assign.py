@@ -11,6 +11,20 @@ Greedy-in-order matches what the sequential kube-scheduler+extender system
 would produce: pod i gets its best feasible node given pods 0..i-1's
 placements — so the batch solve is semantics-preserving, just ~P times
 fewer round trips.
+
+Room comes in two forms, and both are exact.  The count form: ``capacity``
+int32 [N], how many pods a node still takes, one unit booked a pod — what a
+pending set of ALIKE pods needs, and what ``auction_assign_kernel``,
+ops/sinkhorn.py, rebalance/ and gang/ speak (a count is a demand of 1 on
+one resource).  The demand form (``demand`` given): ``capacity`` is the
+nodes' room as integer rows ``[limbs * R, N]`` over R resources and
+``demand`` ``[P, limbs * R]`` each pod's own request vector; pod i is
+feasible on node j iff ``room[r, j] >= demand[i, r]`` for every r
+(kube-scheduler's NodeResourcesFit), and the chosen node's column loses
+``demand[i, :]``.  Quantities are non-negative int32; one that does not fit
+31 bits rides as two limbs of 31 (``limbs=2``: rows ``0..R-1`` the low
+limbs, rows ``R..2R-1`` the high ones, ops/i64.split31_np), compared and
+subtracted with a borrow, so no demand is ever rounded up and no room down.
 """
 
 from __future__ import annotations
@@ -51,29 +65,70 @@ def lex_argmin(key: i64.I64, valid: jax.Array) -> tuple:
     return jnp.where(found, idx, UNASSIGNED), found
 
 
-@partial(jax.jit, donate_argnums=())
+LIMB_BITS = 31  # a two-limb quantity is hi * 2**31 + lo, both int32 >= 0
+LIMB_MASK = (1 << LIMB_BITS) - 1
+
+
+def room_covers(room, demand, limbs: int):
+    """bool [N]: every resource's room covers the demand.  ``room`` is
+    ``[limbs * R, N]``, ``demand`` ``[limbs * R, 1]``.  (The Pallas kernel
+    does the same compares row by row on scalars it reads from SMEM.)"""
+    r = room.shape[0] // limbs
+    if limbs == 1:
+        return jnp.all(room >= demand, axis=0)
+    lo, hi, d_lo, d_hi = room[:r], room[r:], demand[:r], demand[r:]
+    return jnp.all((hi > d_hi) | ((hi == d_hi) & (lo >= d_lo)), axis=0)
+
+
+def room_less(room, demand, take, limbs: int):
+    """``room`` with ``demand`` taken off the lanes of ``take`` (bool [N]):
+    a plain subtraction, with a borrow from the high limb when there are
+    two.  Only ever applied where :func:`room_covers` held."""
+    r = room.shape[0] // limbs
+    taken = jnp.where(take, demand, 0)  # [limbs * R, N]
+    if limbs == 1:
+        return room - taken
+    lo = room[:r] - taken[:r]
+    borrow = (lo < 0).astype(room.dtype)
+    return jnp.concatenate(
+        [lo & jnp.int32(LIMB_MASK), room[r:] - taken[r:] - borrow], axis=0
+    )
+
+
+@partial(jax.jit, static_argnames=("limbs",))
 def greedy_assign_kernel(
     score: i64.I64,  # [P, N] — larger is better
     eligible: jax.Array,  # bool [P, N] — pod may land on node (post-filter)
-    capacity: jax.Array,  # int32 [N] — pods each node can still take
+    capacity: jax.Array,  # int32 [N] pods a node still takes | [limbs*R, N] room
+    demand: jax.Array = None,  # int32 [P, limbs*R] — each pod's own requests
+    limbs: int = 1,
 ) -> AssignResult:
-    """Assign every pending pod its best feasible node, in order."""
+    """Assign every pending pod its best feasible node, in order; with
+    ``demand`` each pod books its own vector (module docstring) and
+    ``capacity_left`` is the room left, ``[limbs * R, N]``."""
+    n = eligible.shape[-1]
+    lanes = jnp.arange(n, dtype=jnp.int32)
 
     def step(cap, pod):
-        s_hi, s_lo, elig = pod
-        ok = elig & (cap > 0)
+        s_hi, s_lo, elig, asked = pod
+        if asked is None:
+            ok = elig & (cap > 0)
+        else:
+            ok = elig & room_covers(cap, asked[:, None], limbs)
         # maximize score == minimize flipped score
         flipped = i64.flip(i64.I64(hi=s_hi, lo=s_lo))
         best, found = lex_argmin(flipped, ok)
-        take = jnp.where(
-            found,
-            jax.nn.one_hot(best, cap.shape[0], dtype=cap.dtype),
-            jnp.zeros_like(cap),
-        )
-        return cap - take, best
+        if asked is None:
+            take = jnp.where(
+                found,
+                jax.nn.one_hot(best, n, dtype=cap.dtype),
+                jnp.zeros_like(cap),
+            )
+            return cap - take, best
+        return room_less(cap, asked[:, None], lanes == best, limbs), best
 
     capacity_left, node_for_pod = jax.lax.scan(
-        step, capacity, (score.hi, score.lo, eligible)
+        step, capacity, (score.hi, score.lo, eligible, demand)
     )
     return AssignResult(node_for_pod=node_for_pod, capacity_left=capacity_left)
 

@@ -53,6 +53,18 @@ def split_int64_np(values: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     ).astype(np.uint32)
 
 
+def split31_np(values: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """(lo, hi) limbs of 31 bits, both int32 >= 0, of non-negative values
+    under 2**62: ``v == hi * 2**31 + lo``.  The form room and demand ride
+    in where a quantity overflows int32 (ops/assign.py): signed 32-bit
+    compares order both limbs, and a subtraction borrows with one mask —
+    no unsigned type, which a Pallas TPU kernel would have to bias."""
+    arr = np.asarray(values, dtype=np.int64)
+    return (arr & np.int64(0x7FFFFFFF)).astype(np.int32), (
+        arr >> np.int64(31)
+    ).astype(np.int32)
+
+
 def to_int64_np(value: I64) -> np.ndarray:
     """Device -> host: reassemble numpy int64 (for wire encoding/tests)."""
     hi = np.asarray(value.hi).astype(np.int64)

@@ -17,6 +17,32 @@ pod's OWN policy's ``dontschedule`` rules, and has room left by
 kube-scheduler's ``NodeResourcesFit`` (pods, cpu, memory) after the bound
 and the already-planned pods.
 
+Room, and in which representation it is exact.  A replan reads the nodes'
+room as exact integers over :data:`FIT_RESOURCES` (pods, cpu, memory:
+allocatable − what the bound pods hold, in milli-units) and each pending
+pod's own demand.  Which form the solve runs follows from the pending set:
+
+* pods that all ask for the same amounts: the room is ONE int32 count a
+  node, the pods of that request it still takes, and a pod books one unit
+  (:meth:`BatchPlanner._room`) — exact for such a set;
+* pods of unlike requests: the room is ``[L, R, n_cap]`` int32 rows and
+  the demand ``[size, L, R]`` columns, padded like the other columns
+  (:meth:`BatchPlanner._room_rows`); pod i is feasible on node j iff every
+  resource's room covers ITS request, and books its own vector.  Each
+  resource's row is divided by the gcd of its rooms and demands (512Gi
+  nodes and pods of whole Gi are counts under a thousand), a room no
+  pending set can exhaust is cut to the set's total, and where a quantity
+  still passes 31 bits the row rides as two limbs of 31
+  (ops/i64.split31_np, ``L`` = 2).  No demand is rounded up and no room
+  down: the comparisons are kube-scheduler's NodeResourcesFit's own, on
+  int64 quantities.
+
+The mesh form (``--batchPlannerDevices`` > 1) and ``--batchSolver sinkhorn``
+take a count only: a replan of unlike pods there counts every pod as the
+LARGEST request of the set (never an overcommit, not exact; such a plan
+names a node the pod's own rule ranks lower once the true room would still
+take it) and counts itself in ``pas_planner_conservative_room_total``.
+
 Shapes: the pending set is padded to a few fixed sizes (powers of two
 from :data:`PAD_FLOOR`), so a draining backlog runs a handful of compiled
 programs and never retraces; the first time a size is seen every smaller
@@ -61,6 +87,7 @@ from platform_aware_scheduling_tpu.models.batch_scheduler import (
     score_and_filter,
 )
 from platform_aware_scheduling_tpu.ops import i64, solveobs
+from platform_aware_scheduling_tpu.ops.assign import LIMB_MASK
 from platform_aware_scheduling_tpu.ops.rules import RuleSet
 from platform_aware_scheduling_tpu.ops.state import RULE_PAD, TensorStateMirror
 from platform_aware_scheduling_tpu.parallel.mesh import (
@@ -81,6 +108,7 @@ POLICY_PAD = 8  # distinct policies of a pending set, padded to multiples
 #: milli-units; one pod asks for 1000 milli of ``pods``
 FIT_RESOURCES = ("pods", "cpu", "memory")
 _UNBOUNDED = 1 << 60  # a resource the node does not report: no limit
+_ROOM_LIMIT = 1 << 62  # what two limbs of 31 bits hold
 
 
 def padded_size(pending: int) -> int:
@@ -101,14 +129,15 @@ def _milli(text: str) -> int:
 
 
 def _pod_requests(pod: Pod) -> Tuple[int, int]:
-    """(cpu, memory) the pod's containers request, in milli-units."""
+    """(cpu, memory) the pod's containers request, in milli-units (an
+    amount no node could report is cut to :data:`_UNBOUNDED`)."""
     cpu = mem = 0
     for requests in pod.container_resource_requests():
         if "cpu" in requests:
             cpu += _milli(str(requests["cpu"]))
         if "memory" in requests:
             mem += _milli(str(requests["memory"]))
-    return cpu, mem
+    return min(cpu, _UNBOUNDED), min(mem, _UNBOUNDED)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
@@ -298,26 +327,78 @@ class BatchPlanner:
                 )
 
     @staticmethod
-    def _room(alloc: Dict, used: Dict, need: Tuple[int, int, int],
-              names: List[str], fallback: int, n_cap: int) -> np.ndarray:
-        """int32 [n_cap]: how many of the pods being planned each interned
-        node can still take, as kube-scheduler's NodeResourcesFit counts
-        it — the least, over pods, cpu and memory, of (allocatable − what
-        the bound pods request) over the LARGEST request in the pending
-        set, floored: exact when the pending pods are alike, never an
-        overcommit when they are not.  A node whose allocatable was never
-        seen has ``fallback`` − bound pods."""
+    def _free(alloc: Dict, used: Dict, names: List[str],
+              fallback: int) -> np.ndarray:
+        """int64 [known, 3]: what each interned node has free of pods, cpu
+        and memory in milli-units — allocatable − what the bound pods
+        request, never under 0 (an overcommitted node has room for
+        nothing that asks).  A node whose allocatable was never seen has
+        ``fallback`` − bound pods and no other limit."""
         unseen = (fallback * 1000, _UNBOUNDED, _UNBOUNDED)
         have = np.array([alloc.get(n, unseen) for n in names], dtype=np.int64)
         held = np.array([used.get(n, (0, 0, 0)) for n in names], dtype=np.int64)
+        return np.maximum(have.reshape(-1, 3) - held.reshape(-1, 3), 0)
+
+    @staticmethod
+    def _room(free: np.ndarray, need: Tuple[int, int, int],
+              n_cap: int) -> np.ndarray:
+        """int32 [n_cap]: how many pods asking for ``need`` each interned
+        node can still take, as kube-scheduler's NodeResourcesFit counts
+        it — the least, over pods, cpu and memory, of ``free`` over the
+        request, floored.  EXACT when the pending pods all ask for
+        ``need``, which is when a one-device greedy replan uses it; with
+        ``need`` the largest request of a set of unlike pods it is the
+        conservative room of the mesh and sinkhorn forms: never an
+        overcommit, not exact."""
         room = np.zeros(n_cap, dtype=np.int64)
-        if names:
+        if len(free):
             asked = np.array(need, dtype=np.int64)
             counted = asked > 0
-            room[: len(names)] = (
-                (have - held)[:, counted] // asked[counted]
-            ).min(axis=1)
+            room[: len(free)] = (free[:, counted] // asked[counted]).min(axis=1)
         return np.clip(room, 0, np.iinfo(np.int32).max).astype(np.int32)
+
+    @staticmethod
+    def _room_rows(free: np.ndarray, asked: np.ndarray, n_cap: int,
+                   size: int) -> Tuple[np.ndarray, np.ndarray]:
+        """(room int32 [L, R, n_cap], demand int32 [size, L, R]) of pods
+        that ask for unlike amounts: ``free`` [known, R] and ``asked``
+        [p, R] in milli-units, exactly.  Per resource the row and the
+        column are divided by the gcd of every bounded room and every
+        demand in them, so each stays a whole number; a room at or past
+        the pending set's total demand — a resource the node does not
+        report, at :data:`_UNBOUNDED` — is cut to that total, which no
+        plan can exhaust.  L is 1 where every quantity then fits 31 bits
+        (the common case: nodes and pods in whole Mi and millicores), else
+        2: limbs of 31 bits, low first (ops/i64.split31_np).  Padding
+        lanes have no room and padding rows ask for nothing; both are
+        masked as in the count form."""
+        known, p = len(free), len(asked)
+        resources = asked.shape[1]
+        have = np.empty((resources, known), dtype=np.int64)
+        want = np.empty((p, resources), dtype=np.int64)
+        for r in range(resources):
+            rooms, demands = free[:, r], asked[:, r]
+            bounded = rooms[rooms < _UNBOUNDED // 2]
+            unit = int(np.gcd(np.gcd.reduce(demands), np.gcd.reduce(bounded))) or 1
+            demands = demands // unit
+            most = (
+                int(demands.sum())
+                if float(demands.max(initial=0)) * p < _ROOM_LIMIT
+                else _ROOM_LIMIT - 1
+            )
+            have[r] = np.minimum(rooms // unit, most)
+            want[:, r] = demands
+        wide = max(have.max(initial=0), want.max(initial=0)) > LIMB_MASK
+        limbs = 2 if wide else 1
+        room = np.zeros((limbs, resources, n_cap), dtype=np.int32)
+        demand = np.zeros((size, limbs, resources), dtype=np.int32)
+        if wide:
+            room[0, :, :known], room[1, :, :known] = i64.split31_np(have)
+            demand[:p, 0], demand[:p, 1] = i64.split31_np(want)
+        else:
+            room[0, :, :known] = have
+            demand[:p, 0] = want
+        return room, demand
 
     # -- solve ----------------------------------------------------------------
 
@@ -367,6 +448,8 @@ class BatchPlanner:
             self._published = (plan, view.version)
         counters = trace.COUNTERS
         counters.inc("pas_planner_replans_total")
+        if batch.demand is not None:
+            counters.inc("pas_planner_demand_solves_total")
         counters.inc(
             "pas_planner_replan_seconds_total", time.perf_counter() - began
         )
@@ -428,6 +511,7 @@ class BatchPlanner:
         policy = np.fromiter(
             (d_of.get(key, -1) for key in policy_of), np.int32, len(keys)
         )
+        kept = None
         if len(usable) < len(distinct):
             kept = policy >= 0
             keys = tuple(itertools.compress(keys, kept.tolist()))
@@ -445,15 +529,33 @@ class BatchPlanner:
 
         rows = np.array([c.scheduleonmetric_row for c in compiled], np.int32)
         ops = np.array([c.scheduleonmetric_op for c in compiled], np.int32)
+        with trace.stage("plan.room", "pas_planner_room_seconds_total"):
+            free = self._free(alloc, used, view.node_names, self.node_capacity)
+            classes = len(set(zip(cpus, mems)))
+            trace.COUNTERS.set_gauge("pas_planner_demand_classes", classes)
+            demand = None
+            if classes == 1 or self.mesh is not None or self.solver == "sinkhorn":
+                # alike pods: the count is exact.  Unlike pods on the mesh
+                # or under sinkhorn: every pod counted as the largest
+                if classes > 1:
+                    trace.COUNTERS.inc("pas_planner_conservative_room_total")
+                room = self._room(free, (1000, max(cpus), max(mems)), n_cap)
+            else:
+                # what each planned pod asks of FIT_RESOURCES, milli-units
+                asked = np.empty((len(cpus), 3), dtype=np.int64)
+                asked[:, 0] = 1000
+                asked[:, 1] = cpus
+                asked[:, 2] = mems
+                if kept is not None:
+                    asked = asked[kept]
+                room, demand = self._room_rows(free, asked, n_cap, size)
+                demand = jnp.asarray(demand)
         batch = PendingPods(
             metric_row=padded(rows[policy]),
             op_id=padded(ops[policy]),
             candidates=self._mask(size, n_cap, p, known),
             policy=padded(policy),
-        )
-        need = (1000, max(cpus), max(mems))
-        room = self._room(
-            alloc, used, need, view.node_names, self.node_capacity, n_cap
+            demand=demand,
         )
         if timer is not None:
             timer.mark("snapshot")
@@ -510,7 +612,9 @@ class BatchPlanner:
         compile must never land between two of its replans."""
         size, n_cap = batch.candidates.shape
         rules = state.dontschedule.active.shape
-        shape = (size, n_cap, state.metric_present.shape[0], *rules)
+        # the form of the room is a part of the program: () for a count
+        form = () if batch.demand is None else batch.demand.shape[1:]
+        shape = (size, n_cap, state.metric_present.shape[0], *rules, form)
         if shape in self._warmed:
             return
         smaller = size // 2
@@ -520,6 +624,9 @@ class BatchPlanner:
                 metric_row=empty, op_id=empty,
                 candidates=self._mask(smaller, n_cap, 0, known),
                 policy=empty,
+                demand=None if batch.demand is None else jnp.zeros(
+                    (smaller, *form), dtype=jnp.int32
+                ),
             )
             if self.mesh is not None:
                 # placed as a replan places it: the same program is found

@@ -277,14 +277,20 @@ def test_room_is_what_the_schedulers_fit_leaves():
     assert {planner.planned_node(pending(f"p{i:02d}", "fit-pol"))
             for i in range(40)} == {"n2"}
     assert planner.planned_node(pending("p40", "fit-pol")) is None
-    # memory binds the same way: 32Gi holds sixty-five 500Mi pods, and the
-    # largest request of the pending set is what every slot is sized by
+    # memory binds the same way, and each pod is counted at its own
+    # requests (tests/test_planner_demand.py): a pod of another size in the
+    # pending set no longer sizes every slot by the largest
     planner.pod_observed(make_pod("b0", node_name="n1", phase="Succeeded"))
     planner.pod_added(pending("big", "fit-pol", cpu="100m", memory="20Gi"))
     planner.replan()
-    # n1: 0.1 cpu free but 32Gi - 39 x 500Mi = 12.96Gi < 20Gi: no slot
+    # n1, the better node, has 0.1 cpu free again: p00 takes it (500Mi
+    # fits 12.96Gi) where a slot sized by "big" would have found no room;
+    # forty more fill n2; "big", created last, finds no cpu anywhere
     assert planner.planned_node(pending("big", "fit-pol")) is None
-    assert planner.planned_node(pending("p00", "fit-pol")) == "n2"
+    assert planner.planned_node(pending("p00", "fit-pol")) == "n1"
+    assert {planner.planned_node(pending(f"p{i:02d}", "fit-pol"))
+            for i in range(1, 41)} == {"n2"}
+    assert planner.planned_node(pending("p41", "fit-pol")) is None
 
 
 def prioritize_request(pod, nodes):
