@@ -14,6 +14,17 @@ request was made of: ``lock_wait``, ``mirror_wait``, ``state_upload``,
 ``solve``, ``gc_ms``.  One JSON object per run, appended to ``--out``
 (default ``chiprun_out/stage_split.jsonl``) and printed.
 
+Who held the interpreter (PR 37), which the benchmark cannot print yet: per
+verb the mean ``arrive`` (recv returned -> GIL held), thread CPU over wall
+time, ``read_gil_ms`` and each sampled stage's CPU beside its wall
+milliseconds (``recent``); over EVERY verb span of the window, by a span
+observer of its own (``interpreter``): the share that carried a stamp, the
+arrival wait's mean and tail, how far arrive + read + handle + write_arm +
+write tile the sampled spans, and the same for the verbs of the STALLED
+cycles (over 1.5 medians on the generator's clock — one clock with the
+spans': ``CLOCK_MONOTONIC``) beside the plain ones; and the window's five
+``pas_cpu_*`` deltas as shares of ``pas_cpu_wall_seconds_total``'s (``cpu``).
+
 With ``--stage-cost`` the same window also says what a stage costs WHERE IT
 IS SERVED (a hot loop, ``benchmarks/observer_cost.py``, says less): every
 third span is sampled, and of the others every second one (by the parity of
@@ -43,7 +54,7 @@ PERFBENCH = os.path.join(ROOT, "perfbench")
 
 #: the stages that contain others (recorded with ``leaf=False``)
 CONTAINERS = ("handle", "kernel", "cache_probe")
-TOP = ("read", "handle", "write_arm", "write")
+TOP = ("arrive", "read", "handle", "write_arm", "write")
 
 
 def covered(entry: dict) -> dict:
@@ -87,18 +98,141 @@ def summarize(spans: list) -> dict:
             for name, ms in per_span.items():
                 sums.setdefault(name, []).append(ms)
         shares = {}
+        cpu = {}  # sampled spans: {stage: [cpu ms of each entry]}
         for entry in entries:
             if any(s["name"] == "handle" for s in entry["stages"]):
                 for name, share in covered(entry).items():
                     shares.setdefault(name, []).append(share)
+            for stage in entry["stages"]:
+                if "cpu_ms" in stage:
+                    cpu.setdefault(stage["name"], []).append(stage["cpu_ms"])
+        wall_ms = sum(e["duration_ms"] for e in entries)
+        # the spans that read their CPU clock (trace.cpu_sample_due), and
+        # the wall time their CPU is a share of: the span less its arrive
+        read = [e for e in entries if "cpu_ms" in e]
+        cpu_ms = sum(e["cpu_ms"] for e in read)
+        cpu_wall_ms = sum(
+            e["duration_ms"] - sum(s["duration_ms"] for s in e["stages"]
+                                   if s["name"] == "arrive") for e in read)
+        gil = [e["attrs"]["read_gil_ms"] for e in entries
+               if "read_gil_ms" in e["attrs"]]
         out[verb] = {
             "n": len(entries),
-            "duration_ms": sum(e["duration_ms"] for e in entries) / len(entries),
+            "duration_ms": wall_ms / len(entries),
+            # thread CPU beside wall time (a parent's spans carry neither)
+            "cpu_ms": [cpu_ms / len(read), len(read)] if read else None,
+            "oncpu_pct": 100.0 * cpu_ms / cpu_wall_ms if cpu_wall_ms else None,
+            "read_gil_ms": [sum(gil) / len(gil), len(gil)] if gil else None,
             "stages": {name: [sum(v) / len(v), len(v)]
                        for name, v in sorted(sums.items())},
+            "stage_cpu": {name: [sum(v) / len(v), len(v)]
+                          for name, v in sorted(cpu.items())},
             "tiles": {name: sum(v) / len(v) for name, v in sorted(shares.items())},
         }
     return out
+
+
+def watch_interpreter(trace) -> list:
+    """A span observer that keeps, of every served verb from now on,
+    (first byte there, wall s, arrive s or None, (cpu s, the wall s they
+    are a share of) or None, sampled, share of the span its top stages
+    tile or None)."""
+    kept = []
+
+    def observe(span) -> None:
+        if not span.name.startswith("POST /scheduler/"):
+            return
+        arrive, tiled = None, 0.0
+        for name, _start, seconds in span.stages:
+            if name == "arrive":
+                arrive = seconds
+            if name in TOP:
+                tiled += seconds
+        cpu = getattr(span, "cpu_s", None)
+        kept.append((
+            span._t0, span.duration_s, arrive,
+            None if cpu is None else (cpu, span.cpu_wall_s()),
+            span.sampled,
+            tiled / span.duration_s if span.sampled and span.duration_s else None,
+        ))
+
+    trace.SPAN_OBSERVERS.append(observe)
+    return kept
+
+
+def interpreter_split(kept: list, window: dict, cycle_span) -> dict:
+    """The window's verbs, all of them, and those of the stalled cycles
+    beside the plain ones (a verb belongs to the cycle whose first byte
+    sent and last byte received enclose its own first byte)."""
+    began, ended = window["began"], window["ended"]
+    verbs = sorted(v for v in kept if began <= v[0] <= ended)
+    if not verbs:
+        return {}
+
+    def digest(rows: list) -> dict:
+        waits = sorted(r[2] for r in rows if r[2] is not None)
+        wall = sum(r[1] for r in rows)
+        cpu = [r[3] for r in rows if r[3] is not None]
+        cpu_wall = sum(w for _c, w in cpu)
+        out = {"verbs": len(rows),
+               "duration_ms": wall / len(rows) * 1e3 if rows else None,
+               # over the verbs that read their CPU clock
+               "cpu_verbs": len(cpu),
+               "oncpu_pct": (100.0 * sum(c for c, _w in cpu) / cpu_wall
+                             if cpu_wall else None),
+               "stamped_pct": 100.0 * len(waits) / len(rows) if rows else None}
+        if waits:
+            out["arrive_ms"] = {
+                "mean": sum(waits) / len(waits) * 1e3,
+                "p50": waits[len(waits) // 2] * 1e3,
+                "p95": waits[min(int(len(waits) * 0.95), len(waits) - 1)] * 1e3,
+                "max": waits[-1] * 1e3,
+                "seconds": sum(waits)}
+        return out
+
+    records = window["records"]
+    spans = sorted(cycle_span(r) for r in records)
+    limit = 1.5 * spans[len(spans) // 2]
+    slow = sorted((r["t"][0], max(x for x in r["t"] if x == x))
+                  for r in records if cycle_span(r) > limit)
+    stalled, plain, at = [], [], 0
+    for verb in verbs:
+        while at < len(slow) and slow[at][1] < verb[0]:
+            at += 1
+        inside = at < len(slow) and slow[at][0] <= verb[0] <= slow[at][1]
+        (stalled if inside else plain).append(verb)
+    tiles = [v[5] for v in verbs if v[5] is not None]
+    return {
+        "all": digest(verbs),
+        "stalled_cycles_pct": 100.0 * len(slow) / len(records),
+        "stalled": digest(stalled), "plain": digest(plain),
+        # sampled spans: mean share arrive+read+handle+write_arm+write tile
+        "tiles_span": [sum(tiles) / len(tiles), len(tiles)] if tiles else None,
+    }
+
+
+def cpu_shares(before: dict, after: dict) -> dict:
+    """The window's ``pas_cpu_*`` deltas as shares (%) of the wall clock's,
+    their seconds, and how far the four roles are from the process's CPU."""
+    def moved(name):
+        return after.get(name, 0.0) - before.get(name, 0.0)
+
+    wall = moved("pas_cpu_wall_seconds_total")
+    if wall <= 0:
+        return {}
+    roles = ("verbs", "refresh", "informers", "other")
+    seconds = {r: moved(f"pas_cpu_{r}_seconds_total") for r in roles}
+    verb_s = moved("pas_verb_seconds_total")
+    pass_s = moved("pas_refresh_pass_seconds_total")
+    return {"wall_s": wall, "seconds": seconds,
+            "pct_of_wall": {r: 100.0 * s / wall for r, s in seconds.items()},
+            "process_cpu_pct_of_wall": 100.0 * sum(seconds.values()) / wall,
+            # every verb of the window, whole threads' clocks: the handler
+            # threads' CPU over the verbs' wall seconds (arrive included)
+            "verbs_oncpu_pct": 100.0 * seconds["verbs"] / verb_s if verb_s else None,
+            "refresh_oncpu_pct": (
+                100.0 * moved("pas_refresh_pass_cpu_seconds_total") / pass_s
+                if pass_s else None)}
 
 
 def watch_stage_cost(trace) -> dict:
@@ -208,16 +342,17 @@ def main(argv=None) -> int:
         klog.set_verbosity(1)
         child.receive()
         system = run.set_up(args, config, spec["traffic"], child)
-        stage_cost = None
-        if args.stage_cost:
-            from platform_aware_scheduling_tpu.utils import trace
+        from platform_aware_scheduling_tpu.utils import trace
 
-            stage_cost = watch_stage_cost(trace)
+        stage_cost = watch_stage_cost(trace) if args.stage_cost else None
+        verbs_seen = watch_interpreter(trace)
         wall0 = time.time()
+        cpu0 = time.process_time()
         before = run.scrape_counters(system.port)
         paths_before = scrape_labelled(run, system.port)
         window = child.ask({"cmd": "window", "seconds": args.seconds})
         after = run.scrape_counters(system.port)
+        process_cpu = time.process_time() - cpu0
         paths = scrape_labelled(run, system.port)
         traces = json.loads(run.http_get(system.port, "/debug/traces")[1])
         records = window["records"]
@@ -241,7 +376,13 @@ def main(argv=None) -> int:
                 name: after.get(name, 0.0) - before.get(name, 0.0)
                 for name in after
                 if name.startswith(
-                    ("pas_refresh_", "pas_gc_", "pas_gas_", "pas_filter_"))},
+                    ("pas_refresh_", "pas_gc_", "pas_gas_", "pas_filter_",
+                     "pas_verb_", "pas_stage_", "pas_cpu_"))},
+            "interpreter": interpreter_split(
+                verbs_seen, window, world.cycle_span),
+            # the ledger's window; process_cpu_s is time.process_time()'s
+            # own delta around the same two scrapes
+            "cpu": {**cpu_shares(before, after), "process_cpu_s": process_cpu},
             "paths": {sample: value - paths_before.get(sample, 0.0)
                       for sample, value in paths.items()},
         }

@@ -19,8 +19,10 @@ Family-name resolution: string literals, module-level string constants
 Wrapper methods whose family name arrives as a *function parameter*
 (workqueue's ``self._inc(name)``) are skipped silently — their callers
 are resolved instead.  The dead-metric scan additionally accepts any
-equal string literal elsewhere in the package (outside the inventory
-module) as evidence of use, so indirection doesn't false-positive.
+equal string literal elsewhere in the package (in the inventory module,
+anywhere but a ``declare()`` call's own arguments) as evidence of use,
+so indirection — a table of families moved in by one ``inc_many`` —
+doesn't false-positive.
 
 ``LatencyRecorder.observe`` is not an emission in this model: its
 family is fixed (``utils.tracing.HISTOGRAM_METRIC``) and its argument
@@ -124,10 +126,23 @@ def check(
     literal_refs: Set[str] = set()
     for mod in modules.values():
         spans: Optional[Dict[int, str]] = None
-        if mod.modname != inv_modname:
-            for node in ast.walk(mod.tree):
-                if isinstance(node, ast.Constant) and isinstance(node.value, str):
-                    literal_refs.add(node.value)
+        declared_here: Set[int] = set()
+        if mod.modname == inv_modname:
+            declared_here = {
+                id(arg)
+                for node in ast.walk(mod.tree)
+                if isinstance(node, ast.Call)
+                and isinstance(node.func, ast.Name)
+                and node.func.id == "declare"
+                for arg in node.args
+            }
+        for node in ast.walk(mod.tree):
+            if (
+                isinstance(node, ast.Constant)
+                and isinstance(node.value, str)
+                and id(node) not in declared_here
+            ):
+                literal_refs.add(node.value)
         for node in ast.walk(mod.tree):
             if not isinstance(node, ast.Call):
                 continue

@@ -130,7 +130,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             failed.append(exc)
             done.set()
 
-    threading.Thread(target=serve, daemon=True).start()
+    threading.Thread(target=serve, name="pas-serve", daemon=True).start()
 
     for sig in (signal.SIGINT, signal.SIGTERM):
         signal.signal(sig, lambda *_: done.set())

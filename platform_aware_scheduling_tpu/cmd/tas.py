@@ -265,7 +265,9 @@ def assemble(
         # above), so the plan for a version exists as soon as it serves
         cache.on_refresh_pass.append(planner.replan)
         threading.Thread(
-            target=lambda: (stop.wait(), planner_informer.stop()), daemon=True
+            target=lambda: (stop.wait(), planner_informer.stop()),
+            name="pas-stop-planner",
+            daemon=True,
         ).start()
     return cache, mirror, extender, controller, enforcer, stop
 
@@ -463,7 +465,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             failed.append(exc)
             done.set()
 
-    threading.Thread(target=serve, daemon=True).start()
+    threading.Thread(target=serve, name="pas-serve", daemon=True).start()
 
     # catchInterrupt (reference cmd/main.go:113-117)
     for sig in (signal.SIGINT, signal.SIGTERM):

@@ -22,6 +22,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, Optional, TYPE_CHECKING
 
+from platform_aware_scheduling_tpu.native import get_wirec
 from platform_aware_scheduling_tpu.utils import (
     decisions,
     devicewatch,
@@ -262,6 +263,17 @@ def render_simple(
     )
 
 
+def stamped_recv():
+    """``_wirec.recv_stamped`` where its stamps are on the spans' clock,
+    else None: a read that says when the bytes were there and when this
+    thread held the interpreter again (native/wirec.c).  The helper stamps
+    ``CLOCK_MONOTONIC``; spans run on ``time.perf_counter()``, so it is
+    used only where that is the same clock."""
+    if "CLOCK_MONOTONIC" not in time.get_clock_info("perf_counter").implementation:
+        return None
+    return getattr(get_wirec(), "recv_stamped", None)
+
+
 class _FastHTTPHandler(socketserver.BaseRequestHandler):
     """Minimal HTTP/1.1 connection handler for the extender hot path.
 
@@ -270,25 +282,54 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
     status line + headers + body with one ``sendall``.  Supports
     keep-alive, pipelined requests, and ``Expect: 100-continue``.  Read
     and write timeouts mirror the reference server's 5 s / 10 s
-    (scheduler.go:136-137)."""
+    (scheduler.go:136-137).
+
+    On a plain socket with ``_wirec`` loaded every read goes through
+    ``recv_stamped`` (set by the enclosing Server), and the span begins
+    where the request's first byte WAS THERE, not where this thread next
+    ran: the stage ``arrive`` is the wait for the interpreter between the
+    two.  A TLS connection, or a process without ``_wirec``, reads with
+    ``sock.recv`` and records no ``arrive``; the answers are the same
+    bytes."""
 
     route = staticmethod(lambda request: HTTPResponse(status=500))
+    recv_stamped = None
     rbufsize = 1 << 16
 
-    def handle(self) -> None:  # noqa: C901 — one tight loop, deliberately
+    def handle(self) -> None:
+        # the thread ledger (utils/trace.py): socketserver names no thread,
+        # and a closed connection's CPU seconds stay on its role's account
+        trace.name_connection_thread()
+        try:
+            self._serve()
+        finally:
+            trace.fold_thread_cpu()
+
+    def _serve(self) -> None:  # noqa: C901 — one tight loop, deliberately
         sock = self.request
         try:
             sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         except OSError:
             pass
+        # an SSLSocket is a socket.socket too: its bytes are not the fd's
+        stamped = type(self).recv_stamped if type(sock) is socket.socket else None
+        fd = sock.fileno()
         buf = bytearray()
         while True:
             # -- read the request head --------------------------------------
             # span timing starts at the request's FIRST byte (leftover
             # pipelined bytes count as already-arrived): stamping at loop
             # entry would charge keep-alive idle time between requests to
-            # the next request's read stage (utils/trace.py)
+            # the next request's read stage (utils/trace.py).  t_ready:
+            # the first byte was there (stamped reads only); t_accept:
+            # this thread held the interpreter with it; cpu0: its CPU
+            # clock then, on the spans picked to read it (a system call:
+            # trace.cpu_sample_due).  gil: what the reads after the first
+            # waited for the interpreter with their bytes in hand.
+            t_ready = cpu0 = gil = None
             t_accept = time.perf_counter() if buf else None
+            if buf and trace.cpu_sample_due(t_accept):
+                cpu0 = time.thread_time()
             sock.settimeout(READ_HEADER_TIMEOUT_S)
             head_end = buf.find(b"\r\n\r\n")
             while head_end < 0:
@@ -296,13 +337,25 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
                     self._send_simple(sock, 431, close=True)
                     return
                 try:
-                    chunk = sock.recv(self.rbufsize)
+                    if stamped is not None:
+                        chunk, ready, held = stamped(
+                            fd, self.rbufsize, READ_HEADER_TIMEOUT_S
+                        )
+                    else:
+                        chunk = sock.recv(self.rbufsize)
                 except (TimeoutError, OSError):
                     return
                 if not chunk:
                     return
                 if t_accept is None:
-                    t_accept = time.perf_counter()
+                    if stamped is not None:
+                        t_ready, t_accept = ready, held
+                    else:
+                        t_accept = time.perf_counter()
+                    if trace.cpu_sample_due(t_accept):
+                        cpu0 = time.thread_time()
+                elif stamped is not None:
+                    gil = (gil or 0.0) + (held - ready)
                 buf += chunk
                 head_end = buf.find(b"\r\n\r\n")
             if head_end > MAX_HEAD_LENGTH:
@@ -330,7 +383,13 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
                 with trace.stage("read"):
                     while len(buf) < length:
                         try:
-                            chunk = sock.recv(self.rbufsize)
+                            if stamped is not None:
+                                chunk, ready, held = stamped(
+                                    fd, self.rbufsize, READ_HEADER_TIMEOUT_S
+                                )
+                                gil = (gil or 0.0) + (held - ready)
+                            else:
+                                chunk = sock.recv(self.rbufsize)
                         except (TimeoutError, OSError):
                             return
                         if not chunk:
@@ -340,15 +399,33 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
             del buf[:length]
             # -- dispatch + respond ------------------------------------------
             request_id = lowered.get("x-request-id") or trace.new_request_id()
-            span = trace.Span(f"{method} {path}", request_id, t0=t_accept)
-            span.add_stage("read", time.perf_counter() - t_accept)
+            span = trace.Span(
+                f"{method} {path}",
+                request_id,
+                t0=t_accept if t_ready is None else t_ready,
+                cpu0=cpu0,
+            )
+            arrived = 0.0
+            if t_ready is not None:
+                # by hand, at offset 0: the wait ended before this thread
+                # could record anything
+                arrived = t_accept - t_ready
+                span.add_stage("arrive", arrived, offset=0.0)
+            span.add_stage(
+                "read",
+                time.perf_counter() - t_accept,
+                offset=arrived,
+                cpu=None if cpu0 is None else time.thread_time() - cpu0,
+            )
+            if gil is not None:
+                span.set("read_gil_ms", round(gil * 1e3, 4))
             request = HTTPRequest(
                 method=method, path=path, headers=headers, body=body,
                 span=span,
             )
-            # read + handle + write_arm + write tile the span (handle and
-            # write_arm on sampled spans); handle contains the verb's own
-            # stages, so it is never annotated
+            # arrive + read + handle + write_arm + write tile the span
+            # (handle and write_arm on sampled spans); handle contains the
+            # verb's own stages, so it is never annotated
             with span.stage("handle", leaf=False, sampled=True):
                 try:
                     response = type(self).route(request)
@@ -368,13 +445,19 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
             with span.stage("write_arm", sampled=True):
                 sock.settimeout(WRITE_TIMEOUT_S)
             t_write = time.perf_counter()
+            cpu_write = None if cpu0 is None else time.thread_time()
             try:
                 sock.sendall(render_response(response, close))
             except OSError:
                 span.set("error", "write failed")
                 return
             finally:
-                span.add_stage("write", time.perf_counter() - t_write)
+                span.add_stage(
+                    "write",
+                    time.perf_counter() - t_write,
+                    cpu=None if cpu_write is None
+                    else time.thread_time() - cpu_write,
+                )
                 trace.TRACES.add(span.finish(response.status))
             if close:
                 return
@@ -835,6 +918,9 @@ class Server:
 
         class Handler(_FastHTTPHandler):
             route = staticmethod(server.route)
+            # resolved once, here: loading _wirec may build it.  Read off
+            # the class, never an instance, so it needs no staticmethod
+            recv_stamped = stamped_recv()
 
         httpd = socketserver.ThreadingTCPServer(
             (host, int(port)), Handler, bind_and_activate=False
@@ -856,7 +942,9 @@ class Server:
         if block:
             httpd.serve_forever()
         else:
-            thread = threading.Thread(target=httpd.serve_forever, daemon=True)
+            thread = threading.Thread(
+                target=httpd.serve_forever, name="pas-serve", daemon=True
+            )
             thread.start()
 
     @property

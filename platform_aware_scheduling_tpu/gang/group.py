@@ -334,7 +334,9 @@ class GangTracker:
         if wait:
             scan()
         else:
-            threading.Thread(target=scan, daemon=True).start()
+            threading.Thread(
+                target=scan, name="pas-gang-sweep", daemon=True
+            ).start()
 
     # -- reservation bookkeeping (all under the lock) --------------------------
 

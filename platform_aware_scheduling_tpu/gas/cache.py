@@ -144,7 +144,9 @@ class Cache:
         self._pod_informer.start()
         self._node_informer.wait_for_cache_sync()
         self._pod_informer.wait_for_cache_sync()
-        self._worker = threading.Thread(target=self._worker_run, daemon=True)
+        self._worker = threading.Thread(
+            target=self._worker_run, name="pas-gas-worker", daemon=True
+        )
         self._worker.start()
 
     def stop(self) -> None:

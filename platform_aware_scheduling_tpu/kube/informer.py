@@ -133,13 +133,19 @@ class Informer:
     # -- lifecycle -----------------------------------------------------------
 
     def start(self) -> None:
-        self._thread = threading.Thread(target=self._run, daemon=True)
+        # the thread ledger's role is in the prefix (utils/trace.py)
+        label = self.name or "unnamed"
+        self._thread = threading.Thread(
+            target=self._run, name=f"pas-informer-{label}", daemon=True
+        )
         self._thread.start()
         if self._resync_period > 0:
             # dedicated timer thread: an idle watch stream must not starve
             # resync (client-go resyncs from its own timer too)
             self._resync_thread = threading.Thread(
-                target=self._resync_loop, daemon=True
+                target=self._resync_loop,
+                name=f"pas-informer-{label}-resync",
+                daemon=True,
             )
             self._resync_thread.start()
 

@@ -402,7 +402,7 @@ class LeaseElector:
                     self.renew_period_s * _deterministic_jitter(seed, n)
                 )
 
-        thread = threading.Thread(target=loop, daemon=True)
+        thread = threading.Thread(target=loop, name="pas-lease", daemon=True)
         thread.start()
         return thread
 

@@ -94,6 +94,7 @@ class WorkQueue:
         self._inc("pas_workqueue_retries_total")
         delay = min(self._base_delay * (2**failures), self._max_delay)
         timer = threading.Timer(delay, self.add, args=(item,))
+        timer.name = "pas-workqueue-retry"
         timer.daemon = True
         timer.start()
 

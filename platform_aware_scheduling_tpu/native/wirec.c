@@ -2581,6 +2581,99 @@ static PyObject *wirec_select_encode_universe(PyObject *mod, PyObject *args) {
 }
 
 /* ------------------------------------------------------------------ */
+/* recv_stamped: a socket read that says when the bytes were there      */
+
+#include <errno.h>
+#include <poll.h>
+#include <sys/socket.h>
+#include <time.h>
+
+static double monotonic_now(void) {
+    struct timespec ts;
+    clock_gettime(CLOCK_MONOTONIC, &ts);
+    return (double)ts.tv_sec + (double)ts.tv_nsec * 1e-9;
+}
+
+/* recv_stamped(fd, max_bytes, timeout_s) -> (bytes, t_ready, t_held)
+ *
+ * poll + recv with the GIL released, as sock.recv does on a socket with a
+ * timeout (whose descriptor is non-blocking): poll, then recv, again on
+ * EAGAIN / EINTR.  t_ready is CLOCK_MONOTONIC taken after recv has returned
+ * and BEFORE the GIL is asked back; t_held the same clock right after it is
+ * held again.  Both in seconds on time.perf_counter()'s clock where that is
+ * CLOCK_MONOTONIC (the caller checks; extender/server.py).  t_held - t_ready
+ * is what the calling thread waited for the interpreter with its bytes in
+ * hand — the one wait no Python-level stamp can see.  timeout_s < 0 waits
+ * for ever.  A time-out raises TimeoutError, an error OSError, as sock.recv
+ * does; a closed peer gives b"". */
+static PyObject *wirec_recv_stamped(PyObject *self, PyObject *args) {
+    int fd;
+    Py_ssize_t max_bytes;
+    double timeout_s;
+    if (!PyArg_ParseTuple(args, "ind", &fd, &max_bytes, &timeout_s))
+        return NULL;
+    if (max_bytes < 0) {
+        PyErr_SetString(PyExc_ValueError, "negative buffersize in recv_stamped");
+        return NULL;
+    }
+    if (fd < 0) {  /* a closed socket's fileno(): poll would wait it out */
+        errno = EBADF;
+        return PyErr_SetFromErrno(PyExc_OSError);
+    }
+    PyObject *out = PyBytes_FromStringAndSize(NULL, max_bytes);
+    if (!out) return NULL;
+    char *data = PyBytes_AS_STRING(out);
+    ssize_t got = -1;
+    int err = 0, timed_out = 0;
+    double t_ready, t_held;
+
+    Py_BEGIN_ALLOW_THREADS
+    double deadline = timeout_s >= 0 ? monotonic_now() + timeout_s : 0.0;
+    for (;;) {
+        int wait_ms = -1;
+        if (timeout_s >= 0) {
+            double left = deadline - monotonic_now();
+            if (left < 0) left = 0;
+            /* round up: a poll that returns a millisecond early would spin */
+            wait_ms = left > 2e6 ? 2000000000 : (int)(left * 1e3 + 0.999);
+        }
+        struct pollfd p = {fd, POLLIN, 0};
+        int ready = poll(&p, 1, wait_ms);
+        if (ready < 0) {
+            if (errno == EINTR) continue;
+            err = errno;
+            break;
+        }
+        if (ready == 0) {
+            if (timeout_s >= 0 && monotonic_now() < deadline) continue;
+            timed_out = 1;
+            break;
+        }
+        got = recv(fd, data, (size_t)max_bytes, 0);
+        if (got >= 0) break;
+        if (errno == EAGAIN || errno == EWOULDBLOCK || errno == EINTR) continue;
+        err = errno;
+        break;
+    }
+    t_ready = monotonic_now();
+    Py_END_ALLOW_THREADS
+    t_held = monotonic_now();
+
+    if (timed_out) {
+        Py_DECREF(out);
+        PyErr_SetString(PyExc_TimeoutError, "timed out");
+        return NULL;
+    }
+    if (got < 0) {
+        Py_DECREF(out);
+        errno = err;
+        return PyErr_SetFromErrno(PyExc_OSError);
+    }
+    if (got != max_bytes && _PyBytes_Resize(&out, got) < 0) return NULL;
+    return Py_BuildValue("(Ndd)", out, t_ready, t_held);
+}
+
+/* ------------------------------------------------------------------ */
 
 static PyMethodDef wirec_methods[] = {
     {"parse_prioritize", wirec_parse_prioritize, METH_O,
@@ -2604,6 +2697,10 @@ static PyMethodDef wirec_methods[] = {
     {"select_encode_universe", wirec_select_encode_universe, METH_VARARGS,
      "select_encode over an interned Universe: candidate mask from the "
      "cached row map instead of per-name hash lookups."},
+    {"recv_stamped", wirec_recv_stamped, METH_VARARGS,
+     "recv_stamped(fd, max_bytes, timeout_s) -> (bytes, t_ready, t_held): "
+     "poll + recv with the GIL released; CLOCK_MONOTONIC seconds when the "
+     "bytes were there and when the interpreter was held again."},
     {NULL},
 };
 

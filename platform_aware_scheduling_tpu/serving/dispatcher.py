@@ -82,7 +82,7 @@ class MicroBatchDispatcher:
         self._task: Optional[asyncio.Task] = None
         # ONE worker: batches execute serially by design (module doc)
         self._executor = ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="serving-batch"
+            max_workers=1, thread_name_prefix="pas-serving-batch"
         )
 
     # -- lifecycle (event-loop thread only) -----------------------------------

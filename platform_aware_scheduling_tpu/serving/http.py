@@ -203,6 +203,7 @@ class AsyncServer:
         self._thread = threading.Thread(
             target=self._serve_loop,
             args=(host, port, ssl_context, unsafe, False),
+            name="pas-serve-async",
             daemon=True,
         )
         self._thread.start()

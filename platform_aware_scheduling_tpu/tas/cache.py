@@ -270,14 +270,19 @@ class AutoUpdatingCache:
     def update_all_metrics(self, client: Client) -> None:
         """One refresh pass.  A pass is no request, so it lands on no
         span: its seconds go to the four ``pas_refresh_*_seconds_total``
-        counters (fetch + publish + warm <= pass)."""
-        began = time.perf_counter()
-        try:
+        counters (fetch + publish + warm <= pass), and this thread's CPU
+        seconds inside it to ``pas_refresh_pass_cpu_seconds_total`` — two
+        ``thread_time()`` reads a pass: what is left of the pass's wall
+        seconds it was blocked, asleep by design, or waiting for the
+        interpreter.  A container of the rf.* stages: never annotated."""
+        with trace.stage(
+            "rf.pass",
+            "pas_refresh_pass_seconds_total",
+            self.counters,
+            cpu_counter="pas_refresh_pass_cpu_seconds_total",
+            leaf=False,
+        ):
             self._refresh_pass(client)
-        finally:
-            self.counters.inc(
-                "pas_refresh_pass_seconds_total", time.perf_counter() - began
-            )
 
     def _refresh_pass(self, client: Client) -> None:
         with self._mtx:
@@ -501,6 +506,7 @@ class AutoUpdatingCache:
         thread = threading.Thread(
             target=self.periodic_update,
             args=(period_seconds, client, initial_data, stop),
+            name="pas-refresh",
             daemon=True,
         )
         thread.start()

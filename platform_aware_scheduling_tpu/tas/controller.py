@@ -107,6 +107,7 @@ class TelemetryPolicyController:
         if stop is not None:
             threading.Thread(
                 target=lambda: (stop.wait(), informer.stop()),
+                name="pas-stop-taspolicy",
                 daemon=True,
             ).start()
         return informer

@@ -233,6 +233,7 @@ class MetricEnforcer:
         thread = threading.Thread(
             target=self.enforce_registered_strategies,
             args=(cache, period_seconds, stop),
+            name="pas-enforce",
             daemon=True,
         )
         thread.start()

@@ -44,7 +44,9 @@ def serve_prestop(trigger: threading.Event, port: int = PRESTOP_PORT) -> HTTPSer
             pass
 
     server = HTTPServer(("127.0.0.1", port), Handler)
-    threading.Thread(target=server.serve_forever, daemon=True).start()
+    threading.Thread(
+        target=server.serve_forever, name="pas-prestop", daemon=True
+    ).start()
     return server
 
 
@@ -67,7 +69,9 @@ def main(argv=None) -> int:
 
     result = [0]
     thread = threading.Thread(
-        target=lambda: result.__setitem__(0, svc.main(rest)), daemon=True
+        target=lambda: result.__setitem__(0, svc.main(rest)),
+        name="pas-main",
+        daemon=True,
     )
     thread.start()
     stop.wait()

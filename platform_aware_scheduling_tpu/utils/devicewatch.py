@@ -93,7 +93,9 @@ class DeviceWatcher:
                     klog.v(4).info_s(f"device sample failed: {exc}")
                 stop.wait(self.period_s)
 
-        self._thread = threading.Thread(target=loop, daemon=True)
+        self._thread = threading.Thread(
+            target=loop, name="pas-devicewatch", daemon=True
+        )
         self._thread.start()
         return stop
 

@@ -670,7 +670,7 @@ class SLOEngine:
                 except Exception as exc:
                     klog.error("slo tick failed: %s", exc)
 
-        threading.Thread(target=loop, daemon=True).start()
+        threading.Thread(target=loop, name="pas-slo", daemon=True).start()
         return stop
 
 

@@ -158,6 +158,32 @@ declare("pas_state_churn_passes_total", "counter", "Refresh passes whose churn t
 declare("pas_state_churn_rows_changed_total", "counter", "Total node columns changed across all flushed refresh passes.")
 # trace buffer health
 declare("pas_traces_recorded_total", "counter", "Completed spans recorded into the trace ring buffer.")
+# who held the interpreter (docs/observability.md "Who holds the
+# interpreter").  Label-free families, folded for every POST /scheduler/*
+# span in TraceBuffer.add under the ring's own lock and moved here when
+# /metrics is rendered (_flush_verbs): a reader that sums a family over
+# its label sets (perfbench's) can read each alone.
+declare("pas_verb_total", "counter", "Served verb spans (POST /scheduler/*) finished.")
+declare("pas_verb_seconds_total", "counter", "Their wall seconds, first byte there to answer written (the arrival wait included where it was stamped).")
+declare("pas_verb_cpu_seconds_total", "counter", "Thread CPU seconds of the verb spans whose CPU clock was read (one span at most every trace.CPU_SAMPLE_GAP_S: a read of the thread's CPU clock is a system call), first byte held to answer written.")
+declare("pas_verb_cpu_wall_seconds_total", "counter", "Wall seconds of those same spans over the same interval: pas_verb_cpu_seconds_total over this is the share of a verb its thread spent running, the rest blocked or waiting for the interpreter.")
+declare("pas_verb_arrive_total", "counter", "Verb spans that carried an arrival stamp (plain socket, native recv_stamped).")
+declare("pas_verb_arrive_wait_seconds_total", "counter", "Seconds verbs waited for the interpreter with their first bytes already received (stage arrive: recv returned -> GIL held).")
+declare("pas_verb_read_seconds_total", "counter", "Seconds of verbs' read stage (first byte held -> last byte of the body).")
+declare("pas_verb_read_gil_seconds_total", "counter", "Of those, seconds the reading thread waited for the interpreter after a recv had returned (span attribute read_gil_ms).")
+declare("pas_stage_handle_total", "counter", "Verb spans that recorded the sampled stage handle (one span in SAMPLE_EVERY).")
+declare("pas_stage_handle_seconds_total", "counter", "Seconds of those handle stages: route(request), whole.")
+declare("pas_stage_scan_total", "counter", "Sampled scan stages recorded on verb spans (Filter's native scan in the probe).")
+declare("pas_stage_scan_seconds_total", "counter", "Seconds of those scan stages.")
+declare("pas_refresh_pass_cpu_seconds_total", "counter", "The refresh thread's CPU seconds inside telemetry refresh passes (beside pas_refresh_pass_seconds_total: the rest of a pass it was blocked, asleep by design or waiting for the interpreter).")
+# the thread ledger, read when /metrics is rendered (thread_cpu): CPU
+# seconds by thread role — under one GIL, to first order who held the
+# interpreter.  verbs + refresh + informers + other = the process's CPU.
+declare("pas_cpu_verbs_seconds_total", "counter", "CPU seconds of the connection handlers (threads pas-conn-*), closed connections included.")
+declare("pas_cpu_refresh_seconds_total", "counter", "CPU seconds of the refresh thread (pas-refresh: passes, publishes, warms and the batch planner's replan).")
+declare("pas_cpu_informers_seconds_total", "counter", "CPU seconds of the informers and the GAS work-queue worker (pas-informer-*, pas-gas-worker).")
+declare("pas_cpu_other_seconds_total", "counter", "The process's CPU seconds (time.process_time) less the three roles above: the accept loop, the other pas-* loops, JAX's and any unnamed thread.")
+declare("pas_cpu_wall_seconds_total", "counter", "Monotonic seconds since utils/trace.py was imported (the process's start, nearly): the denominator of the pas_cpu_* shares.")
 # collector pauses (watch_gc: one gc.callbacks entry; a span that a
 # collection ended inside carries the attribute gc_ms)
 declare("pas_gc_pause_seconds_total", "counter", "Seconds the interpreter spent inside garbage collections (every thread is stopped for them).")
@@ -390,6 +416,129 @@ def watch_gc() -> None:
 
 
 # ---------------------------------------------------------------------------
+# the thread ledger: CPU seconds by thread role
+# ---------------------------------------------------------------------------
+
+# Python under one GIL runs one thread at a time, so CPU seconds by thread
+# role over a window is, to first order, who held the interpreter.  Nothing
+# here runs on a request: every thread the program starts carries a name
+# with a role prefix (thread_name), and the exposition walks the live
+# threads' CPU clocks when /metrics is rendered (_flush_cpu).
+
+#: thread-name prefix -> role; a ``pas-`` thread of no listed prefix (the
+#: accept loop, enforce, lease, slo, devicewatch) and every unnamed thread
+#: (JAX's workers, a harness's) is ``other`` = the process less these
+THREAD_ROLES = (
+    ("pas-conn-", "verbs"),
+    ("pas-refresh", "refresh"),
+    ("pas-informer-", "informers"),
+    ("pas-gas-worker", "informers"),
+)
+_ROLES = ("verbs", "refresh", "informers")
+_CONN_SEQ = itertools.count()
+_cpu_lock = threading.Lock()
+_cpu_exited = {role: 0.0 for role in _ROLES}  # folded by threads that ended
+_cpu_flushed = {}  # family -> what COUNTERS holds of it
+_CPU_FAMILIES = {
+    "verbs": "pas_cpu_verbs_seconds_total",
+    "refresh": "pas_cpu_refresh_seconds_total",
+    "informers": "pas_cpu_informers_seconds_total",
+    "other": "pas_cpu_other_seconds_total",
+    "wall": "pas_cpu_wall_seconds_total",
+}
+_IMPORTED_AT = time.monotonic()  # pascheck: allow[clock] -- the ledger's denominator is observability-only elapsed time, never control flow or replayed state
+
+
+def _thread_clock(native_id: int) -> int:
+    """Linux's clock id of one thread's CPU time, from its kernel thread
+    id: what ``pthread_getcpuclockid`` computes, without its pointer —
+    that call reads the thread's descriptor through a ``pthread_t`` that
+    dangles once a detached thread has exited (every Python thread is
+    detached), while the kernel refuses a dead id with EINVAL."""
+    return (~native_id << 3) | 6  # CPUCLOCK_SCHED | CPUCLOCK_PERTHREAD_MASK
+
+
+def _can_read_thread_clocks() -> bool:
+    # held to pthread_getcpuclockid on the one thread it is safe for, this
+    # one; any other platform reads no foreign clock and books all to other
+    try:
+        return time.pthread_getcpuclockid(
+            threading.get_ident()
+        ) == _thread_clock(threading.get_native_id())
+    except (AttributeError, OSError, OverflowError):
+        return False
+
+
+_THREAD_CLOCKS = _can_read_thread_clocks()
+
+
+def thread_role(name: str) -> Optional[str]:
+    for prefix, role in THREAD_ROLES:
+        if name.startswith(prefix):
+            return role
+    return None
+
+
+def name_connection_thread() -> None:
+    """Name the calling thread as a connection handler (``pas-conn-<n>``):
+    socketserver starts them unnamed."""
+    threading.current_thread().name = f"pas-conn-{next(_CONN_SEQ)}"
+
+
+def fold_thread_cpu() -> None:
+    """Book the calling thread's CPU seconds to its role for good and
+    take the thread off the ledger's walk: called as a named thread ends
+    (a connection handler's ``finally``), so that a closed connection's
+    seconds are not lost.  A thread of no role books nothing."""
+    thread = threading.current_thread()
+    role = thread_role(thread.name)
+    if role is None or getattr(thread, "_pas_cpu_folded", False):
+        return
+    with _cpu_lock:
+        thread._pas_cpu_folded = True
+        _cpu_exited[role] += time.thread_time()
+
+
+def thread_cpu() -> Dict[str, float]:
+    """{role: CPU seconds} as of now — verbs, refresh, informers, other —
+    and ``wall``.  About a microsecond a live thread; never on a request."""
+    with _cpu_lock:
+        totals = dict(_cpu_exited)
+        if _THREAD_CLOCKS:
+            for thread in threading.enumerate():
+                role = thread_role(thread.name)
+                if role is None or getattr(thread, "_pas_cpu_folded", False):
+                    continue
+                native_id = thread.native_id
+                if native_id is None:
+                    continue
+                try:
+                    totals[role] += time.clock_gettime(_thread_clock(native_id))
+                except OSError:
+                    pass  # it ended between the walk and the read
+    # read last: a thread's clock is then never ahead of the process's
+    totals["other"] = max(time.process_time() - sum(totals.values()), 0.0)
+    totals["wall"] = time.monotonic() - _IMPORTED_AT  # pascheck: allow[clock] -- as _IMPORTED_AT: observability-only
+    return totals
+
+
+def _flush_cpu() -> None:
+    """Move the ledger's reading into the five ``pas_cpu_*`` families (from
+    the exposition, as _flush_gc): counters, so a reading never goes back."""
+    totals = thread_cpu()
+    updates = []
+    with _cpu_lock:
+        for role, seconds in totals.items():
+            family = _CPU_FAMILIES[role]
+            grew = seconds - _cpu_flushed.get(family, 0.0)
+            if grew > 0 or family not in _cpu_flushed:
+                # a role nobody ran in yet still shows, at 0
+                _cpu_flushed[family] = max(seconds, 0.0)
+                updates.append((family, max(grew, 0.0)))
+    COUNTERS.inc_many(updates)
+
+
+# ---------------------------------------------------------------------------
 # request ids and spans
 # ---------------------------------------------------------------------------
 
@@ -433,7 +582,12 @@ class _Stage:
     is being taken the annotation costs one call that says so.
     ``leaf=False`` is for a stage that contains others (handle,
     cache_probe, GAS's kernel): recorded on the span, never annotated — a gap is named by the two annotations that
-    cover most of it, and a container would crowd its own children out."""
+    cover most of it, and a container would crowd its own children out.
+
+    A stage on a span whose CPU clock is being read (``Span.stage_cpu``),
+    and a stage off a request that names a ``cpu_counter``, is a
+    :class:`_CpuStage`: the same interval read on a third clock, the
+    thread's CPU seconds."""
 
     __slots__ = ("_name", "_span", "_counter", "_counters", "_leaf", "_mark", "_t0")
 
@@ -469,16 +623,100 @@ class _Stage:
         return False
 
 
+class _CpuStage(_Stage):
+    """A stage that also reads ``time.thread_time()`` at both ends: wall
+    seconds say how long the interval lasted, CPU seconds how much of it
+    this thread ran.  For a stage that blocks by nature (solve, write,
+    lock_wait) the difference is the block plus the wait to get the
+    interpreter back; for one that only computes (verdict, rows, decode)
+    it is every slice another thread took.  On a span the CPU seconds
+    land in ``span.stage_cpu`` under the stage's index; off a request
+    they go to ``cpu_counter``.  ``thread_time()`` is a system call (0.3
+    us on one host, 6 us on the benchmark's: PERF.md section 6, PR 37), so
+    these are opened only on the spans :func:`cpu_sample_due` picks.  The
+    bodies repeat :class:`_Stage`'s rather than call them: such a span
+    opens a dozen, and three calls up the class are not free either."""
+
+    __slots__ = ("_cpu_counter", "_c0")
+
+    def __init__(self, name, span=None, counter=None, counters=None,
+                 leaf=True, cpu_counter=None):
+        self._name = name
+        self._span = span
+        self._counter = counter
+        self._counters = counters
+        self._leaf = leaf
+        self._mark = None
+        self._cpu_counter = cpu_counter
+
+    def __enter__(self):
+        if self._leaf:
+            cls = _ANNOTATION
+            if cls is None:
+                cls = _annotation()
+            if cls and cls.is_enabled():  # a profile is being taken
+                self._mark = cls("pas:" + self._name)
+        self._t0 = time.perf_counter()
+        self._c0 = time.thread_time()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        cpu = time.thread_time() - self._c0
+        now = time.perf_counter()
+        if self._mark is not None:
+            self._mark.__exit__(None, None, None)
+        t0 = self._t0
+        span = self._span
+        if span is not None:
+            span.stage_cpu[len(span.stages)] = cpu
+            span.stages.append((self._name, t0 - span._t0, now - t0))
+        if self._counter is not None:
+            (self._counters or COUNTERS).inc(self._counter, now - t0)
+        if self._cpu_counter is not None:
+            (self._counters or COUNTERS).inc(self._cpu_counter, cpu)
+        return False
+
+
 def stage(
     name: str,
     counter: Optional[str] = None,
     counters: Optional[CounterSet] = None,
+    cpu_counter: Optional[str] = None,
+    leaf: bool = True,
 ) -> _Stage:
     """A stage off any request (the refresh thread, an informer): no
     span to land on — the ``pas:<name>`` annotation, plus ``counter``
     (a ``*_seconds_total`` family, in ``counters`` or the process-wide
-    set) when the interval is to be summed."""
-    return _Stage(name, None, counter, counters)
+    set) when the interval is to be summed, and ``cpu_counter`` when the
+    thread's CPU seconds inside it are (the refresh pass as a whole).
+    ``leaf=False`` for an interval that contains other stages: summed,
+    never annotated."""
+    if cpu_counter is not None:
+        return _CpuStage(name, None, counter, counters, leaf, cpu_counter)
+    return _Stage(name, None, counter, counters, leaf)
+
+
+#: at most one span every this many seconds reads its thread's CPU clock
+#: (at both ends, and around every stage it records).  By time and not by
+#: count, so that the cost is a share of the wall clock whatever the verbs'
+#: rate: some 26 reads a span, 6 us each where a clock read is a slow
+#: system call, is 0.15% of a second at ten spans a second — and a cell of
+#: two verbs a second still reads every sampled span.
+CPU_SAMPLE_GAP_S = 0.1
+_cpu_sample_after = 0.0
+
+
+def cpu_sample_due(now: float) -> bool:
+    """Whether a span whose first byte is held at ``now`` (perf_counter)
+    is to read CPU clocks: the front-end asks before it reads the first,
+    and hands the reading to the span (``Span(cpu0=...)``).  Unlocked: two
+    threads that ask in the same microsecond both read, which costs two
+    spans' worth once."""
+    global _cpu_sample_after
+    if now < _cpu_sample_after:
+        return False
+    _cpu_sample_after = now + CPU_SAMPLE_GAP_S
+    return True
 
 
 #: one span in this many records the ``sampled`` stages (Span.stage).
@@ -507,6 +745,9 @@ class Span:
         "attrs",
         "links",
         "sampled",
+        "_cpu0",
+        "cpu_s",
+        "stage_cpu",
     )
 
     def __init__(
@@ -514,6 +755,7 @@ class Span:
         name: str,
         trace_id: Optional[str] = None,
         t0: Optional[float] = None,
+        cpu0: Optional[float] = None,
     ):
         self.trace_id = trace_id or new_request_id()
         self.name = name
@@ -527,6 +769,19 @@ class Span:
         self.attrs: Dict[str, object] = {}
         self.links: List[str] = []
         self.sampled = next(_SPAN_SEQ) % SAMPLE_EVERY == 0
+        # the owning thread's CPU clock where the span's first byte was
+        # held, on the spans picked to read it (cpu_sample_due: the
+        # front-end passes its reading); finish() reads it again and every
+        # stage the span records reads it at both ends.  Which stages it
+        # records is the sequence number's business alone: the pick is by
+        # time, so it favours the slow stretches of a window, and a stage
+        # mean must not.  The other spans read no CPU clock
+        self._cpu0 = cpu0
+        self.cpu_s: Optional[float] = None
+        # {index into stages: thread CPU seconds}, CPU-read spans only
+        self.stage_cpu: Optional[Dict[int, float]] = (
+            None if cpu0 is None else {}
+        )
 
     def stage(self, name: str, leaf: bool = True, sampled: bool = False):
         """``with span.stage(name):`` — the one way to time an interval of
@@ -535,17 +790,34 @@ class Span:
         every recorded stage is a measurable share of the verb: such a
         stage is recorded on one span in :data:`SAMPLE_EVERY` and is a
         no-op on the others, so a stage mean over the ring is a mean over
-        the spans that carry it."""
+        the spans that carry it.  On a span that reads its CPU clock
+        (``Span(cpu0=...)``) every stage it records also records its
+        thread CPU seconds (:class:`_CpuStage`); on the others a stage
+        costs what it did."""
         if sampled and not self.sampled:
             return _NULL_STAGE
+        if self.stage_cpu is not None:
+            return _CpuStage(name, self, leaf=leaf)
         return _Stage(name, self, leaf=leaf)
 
-    def add_stage(self, name: str, seconds: float) -> None:
+    def add_stage(
+        self,
+        name: str,
+        seconds: float,
+        offset: Optional[float] = None,
+        cpu: Optional[float] = None,
+    ) -> None:
         """Record a stage that just ended (start inferred from now) —
-        for an interval timed by hand: read, which began before the span
-        existed, and write, as it always was.  It carries no profiler
-        annotation (one cannot be back-dated)."""
-        offset = max(0.0, time.perf_counter() - self._t0 - seconds)
+        for an interval timed by hand: arrive (at ``offset`` 0: it ended
+        before the thread held the interpreter), read, which began
+        before the span existed, and write, as it always was.  It
+        carries no profiler annotation (one cannot be back-dated);
+        ``cpu`` is its thread CPU seconds where the caller read them (a
+        span that reads its CPU clock)."""
+        if offset is None:
+            offset = max(0.0, time.perf_counter() - self._t0 - seconds)
+        if cpu is not None and self.stage_cpu is not None:
+            self.stage_cpu[len(self.stages)] = cpu
         self.stages.append((name, offset, seconds))
 
     def set(self, key: str, value) -> None:
@@ -556,6 +828,8 @@ class Span:
 
     def finish(self, status: Optional[int] = None) -> "Span":
         self.duration_s = time.perf_counter() - self._t0
+        if self._cpu0 is not None:
+            self.cpu_s = time.thread_time() - self._cpu0
         if status is not None:
             self.status = status
         if _gc_last_end >= self._t0:
@@ -566,6 +840,16 @@ class Span:
             )
         return self
 
+    def cpu_wall_s(self) -> float:
+        """The wall seconds ``cpu_s`` is a share of: first byte HELD to
+        finish(), i.e. the span less its arrival wait."""
+        waited = (
+            self.stages[0][2]
+            if self.stages and self.stages[0][0] == "arrive"
+            else 0.0
+        )
+        return (self.duration_s or 0.0) - waited
+
     def stage_seconds(self) -> Dict[str, float]:
         """Total recorded seconds per stage name."""
         out: Dict[str, float] = {}
@@ -573,24 +857,37 @@ class Span:
             out[name] = out.get(name, 0.0) + dur
         return out
 
+    def _stage_dicts(self) -> List[Dict]:
+        cpu = self.stage_cpu or {}
+        out = []
+        for index, (name, start, dur) in enumerate(self.stages):
+            entry = {
+                "name": name,
+                "start_ms": round(start * 1e3, 4),
+                "duration_ms": round(dur * 1e3, 4),
+            }
+            if index in cpu:  # a sampled span's stage
+                entry["cpu_ms"] = round(cpu[index] * 1e3, 4)
+            out.append(entry)
+        return out
+
     def to_dict(self) -> Dict:
-        return {
+        out = {
             "id": self.trace_id,
             "name": self.name,
             "status": self.status,
             "start": round(self.start_wall, 6),
             "duration_ms": round((self.duration_s or 0.0) * 1e3, 4),
-            "stages": [
-                {
-                    "name": name,
-                    "start_ms": round(start * 1e3, 4),
-                    "duration_ms": round(dur * 1e3, 4),
-                }
-                for name, start, dur in self.stages
-            ],
+            "stages": self._stage_dicts(),
             "attrs": dict(self.attrs),
             "links": list(self.links),
         }
+        if self.cpu_s is not None:
+            # thread CPU, first byte held -> finish(), on the spans that
+            # read it: under duration_ms less arrive by what the thread
+            # spent blocked or waiting for the GIL
+            out["cpu_ms"] = round(self.cpu_s * 1e3, 4)
+        return out
 
 
 class _NullSpan:
@@ -600,6 +897,8 @@ class _NullSpan:
     trace_id = ""
     name = ""
     duration_s = None
+    cpu_s = None
+    sampled = False
     status = None
     stages: List[Tuple[str, float, float]] = []
     attrs: Dict[str, object] = {}
@@ -610,7 +909,7 @@ class _NullSpan:
     ) -> "_NullStageTimer":
         return _NULL_STAGE
 
-    def add_stage(self, name: str, seconds: float) -> None:
+    def add_stage(self, name: str, seconds: float, offset=None, cpu=None) -> None:
         pass
 
     def set(self, key: str, value) -> None:
@@ -661,6 +960,87 @@ def of(request) -> Span:
 SPAN_OBSERVERS: List[Callable] = []
 
 
+#: the label-free verb families, in the order of a buffer's tally
+VERB_FAMILIES = (
+    "pas_verb_total",
+    "pas_verb_seconds_total",
+    "pas_verb_cpu_seconds_total",
+    "pas_verb_cpu_wall_seconds_total",
+    "pas_verb_arrive_total",
+    "pas_verb_arrive_wait_seconds_total",
+    "pas_verb_read_seconds_total",
+    "pas_verb_read_gil_seconds_total",
+    "pas_stage_handle_total",
+    "pas_stage_handle_seconds_total",
+    "pas_stage_scan_total",
+    "pas_stage_scan_seconds_total",
+)
+_VERB_SPANS = "POST /scheduler/"  # the name of a served verb's span begins so
+_at = VERB_FAMILIES.index
+_READ_GIL = _at("pas_verb_read_gil_seconds_total")
+_CPU = _at("pas_verb_cpu_seconds_total")
+_CPU_WALL = _at("pas_verb_cpu_wall_seconds_total")
+#: stage name -> (tally index of its count or -1, of its seconds): the
+#: stages of a served verb whose seconds are summed window-wide as they
+#: land in the ring, so that a metric reads the whole window and not the
+#: ring's end
+_FOLDED_STAGES = {
+    "arrive": (
+        _at("pas_verb_arrive_total"), _at("pas_verb_arrive_wait_seconds_total")
+    ),
+    "read": (-1, _at("pas_verb_read_seconds_total")),
+    "handle": (
+        _at("pas_stage_handle_total"), _at("pas_stage_handle_seconds_total")
+    ),
+    "scan": (_at("pas_stage_scan_total"), _at("pas_stage_scan_seconds_total")),
+}
+
+
+def summarize(spans: List[Span]) -> Dict:
+    """What the ring says of who held the interpreter, over ``spans``:
+    the mean arrival wait (over the spans that carry a stamp), thread CPU
+    over wall time and each stage's mean wall and CPU milliseconds (over
+    the spans that read their CPU clock), the mean ``read_gil_ms`` (over
+    the spans that carry one) — /debug/traces' ``summary``, per verb with ``?verb=``."""
+    verbs = [s for s in spans if s.name.startswith(_VERB_SPANS)]
+    if not verbs:
+        return {"spans": 0}
+    wall = sum(s.duration_s or 0.0 for s in verbs)
+    read = [s for s in verbs if s.cpu_s is not None]  # their CPU clock
+    cpu_s = sum(s.cpu_s for s in read)
+    cpu_wall = sum(s.cpu_wall_s() for s in read)
+    arrive = [
+        dur for s in verbs for name, _start, dur in s.stages if name == "arrive"
+    ]
+    gil = [s.attrs["read_gil_ms"] for s in verbs if "read_gil_ms" in s.attrs]
+    stages: Dict[str, List[float]] = {}  # name -> [n, wall s, cpu s]
+    for span in verbs:
+        for index, cpu in (span.stage_cpu or {}).items():
+            if index < len(span.stages):
+                name, _start, dur = span.stages[index]
+                row = stages.setdefault(name, [0, 0.0, 0.0])
+                row[0] += 1
+                row[1] += dur
+                row[2] += cpu
+    return {
+        "spans": len(verbs),
+        "duration_ms": round(wall / len(verbs) * 1e3, 4),
+        # over the spans that read their CPU clock (cpu_sample_due)
+        "cpu_spans": len(read),
+        "cpu_ms": round(cpu_s / len(read) * 1e3, 4) if read else None,
+        "oncpu_pct": round(100.0 * cpu_s / cpu_wall, 2) if cpu_wall > 0 else None,
+        "arrive_spans": len(arrive),
+        "arrive_ms": round(sum(arrive) / len(arrive) * 1e3, 4) if arrive else None,
+        "read_gil_spans": len(gil),
+        "read_gil_ms": round(sum(gil) / len(gil), 4) if gil else None,
+        # CPU-read spans only: {stage: [spans, mean ms, mean cpu ms]}
+        "stage_cpu": {
+            name: [n, round(dur / n * 1e3, 4), round(cpu / n * 1e3, 4)]
+            for name, (n, dur, cpu) in sorted(stages.items())
+        },
+    }
+
+
 class TraceBuffer:
     """Bounded ring of recent completed spans + bounded top-K slowest.
 
@@ -674,6 +1054,12 @@ class TraceBuffer:
         self._lock = threading.Lock()
         self._recent: deque = deque(maxlen=self.capacity)
         self._slow: List[Span] = []  # sorted by duration, slowest first
+        # VERB_FAMILIES as plain tallies, written under the ring's own
+        # lock as a verb span lands: a request takes no lock for them that
+        # it did not take already.  The exposition moves TRACES' into
+        # COUNTERS (_flush_verbs), as it does the collector's.
+        self._verbs = [0.0] * len(VERB_FAMILIES)
+        self._verbs_taken = list(self._verbs)
 
     def add(self, span: Span) -> None:
         if span.duration_s is None:
@@ -691,12 +1077,37 @@ class TraceBuffer:
                     i += 1
                 slow.insert(i, span)
                 del slow[self.slow_capacity :]
+            if span.name.startswith(_VERB_SPANS):
+                tally = self._verbs
+                tally[0] += 1
+                tally[1] += span.duration_s
+                folded = _FOLDED_STAGES
+                for name, _start, seconds in span.stages:
+                    if name in folded:
+                        count, total = folded[name]
+                        if count >= 0:
+                            tally[count] += 1
+                        tally[total] += seconds
+                if span.cpu_s is not None:
+                    tally[_CPU] += span.cpu_s
+                    tally[_CPU_WALL] += span.cpu_wall_s()
+                gil_ms = span.attrs.get("read_gil_ms")
+                if gil_ms is not None:
+                    tally[_READ_GIL] += gil_ms * 1e-3
         COUNTERS.inc("pas_traces_recorded_total")
         for observer in SPAN_OBSERVERS:
             try:
                 observer(span)
             except Exception:
                 pass
+
+    def take_verb_tallies(self) -> List[float]:
+        """What the verb spans that landed here have added to each of
+        VERB_FAMILIES, in that order, since this was last asked."""
+        with self._lock:
+            grew = [now - was for now, was in zip(self._verbs, self._verbs_taken)]
+            self._verbs_taken = list(self._verbs)
+        return grew
 
     def find(self, trace_id: str) -> Optional[Span]:
         with self._lock:
@@ -741,6 +1152,10 @@ class TraceBuffer:
             "slow_capacity": self.slow_capacity,
             "recent": [s.to_dict() for s in recent],
             "slowest": [s.to_dict() for s in slow],
+            # who held the interpreter, over ``recent``'s served verbs,
+            # and the thread ledger as it stands (seconds by role)
+            "summary": summarize(recent),
+            "cpu_seconds": thread_cpu(),
         }
         if verb is not None:
             out["verb"] = verb
@@ -761,6 +1176,13 @@ class TraceBuffer:
 
 #: the process-wide buffer both front-ends record into
 TRACES = TraceBuffer()
+
+
+
+def _flush_verbs() -> None:
+    """Move TRACES' verb tallies into COUNTERS, from the exposition: one
+    acquisition of the counters' lock a scrape, none a request."""
+    COUNTERS.inc_many(zip(VERB_FAMILIES, TRACES.take_verb_tallies()))
 
 
 # ---------------------------------------------------------------------------
@@ -909,6 +1331,8 @@ def exposition(
         parts.append(cs.prometheus_text(help_texts=helps))
     if include_global:
         _flush_gc()
+        _flush_verbs()
+        _flush_cpu()
         parts.append(COUNTERS.prometheus_text(help_texts=helps))
         for provider in list(EXTRA_PROVIDERS):
             parts.append(provider())

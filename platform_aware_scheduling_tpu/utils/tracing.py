@@ -191,6 +191,18 @@ class CounterSet:
             series = self._counters.setdefault(name, {})
             series[key] = series.get(key, 0) + by
 
+    def inc_many(self, updates: Iterable[Tuple[str, float]]) -> None:
+        """``inc`` of several unlabelled counters under ONE acquisition of
+        the lock: what the exposition moves in from tallies kept elsewhere
+        (utils/trace.py: the verb families, the thread ledger)."""
+        with self._lock:
+            counters = self._counters
+            for name, by in updates:
+                series = counters.get(name)
+                if series is None:
+                    series = counters[name] = {}
+                series[()] = series.get((), 0) + by
+
     def set_gauge(
         self,
         name: str,
