@@ -109,8 +109,7 @@ def main(argv=None) -> int:
                 for name in FILTER_STAGES:
                     with one.stage(name, sampled=name != "intern"):
                         pass
-        with one.stage("write_arm", sampled=True):
-            pass
+        one.set("read_calls", 1)
         cpu_write = None if cpu0 is None else time.thread_time()
         one.add_stage("write", 0.0, cpu=None if cpu_write is None
                       else time.thread_time() - cpu_write)
@@ -160,11 +159,12 @@ def main(argv=None) -> int:
     def counter_inc():
         trace.COUNTERS.inc("pas_filter_cache_miss_total")
 
-    from platform_aware_scheduling_tpu.extender.server import stamped_recv
+    from platform_aware_scheduling_tpu.extender.server import stamped_reads
 
     left, right = socket.socketpair()
     left.settimeout(5.0)
-    fd, stamped = left.fileno(), stamped_recv()
+    fd = left.fileno()
+    stamped = getattr(stamped_reads(), "recv_stamped", None)
 
     def recv_stamped():
         right.send(b"x")

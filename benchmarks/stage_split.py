@@ -16,14 +16,22 @@ request was made of: ``lock_wait``, ``mirror_wait``, ``state_upload``,
 
 Who held the interpreter (PR 37), which the benchmark cannot print yet: per
 verb the mean ``arrive`` (recv returned -> GIL held), thread CPU over wall
-time, ``read_gil_ms`` and each sampled stage's CPU beside its wall
+time, ``read_gil_ms``, ``read_calls`` and ``read_recvs`` (PR 38: the reads
+made from Python, each one release of the GIL, and the kernel's recvs inside
+a body's one native read) and each sampled stage's CPU beside its wall
 milliseconds (``recent``); over EVERY verb span of the window, by a span
 observer of its own (``interpreter``): the share that carried a stamp, the
-arrival wait's mean and tail, how far arrive + read + handle + write_arm +
-write tile the sampled spans, and the same for the verbs of the STALLED
+arrival wait's mean and tail, the mean ``read`` with its ``read_gil_ms`` and
+``read_calls``, how far arrive + read + handle + write_arm + write tile the
+sampled spans (``write_arm`` where the socket's time-out is still armed a
+request: TLS, no ``_wirec``), and the same for the verbs of the STALLED
 cycles (over 1.5 medians on the generator's clock — one clock with the
 spans': ``CLOCK_MONOTONIC``) beside the plain ones; and the window's five
 ``pas_cpu_*`` deltas as shares of ``pas_cpu_wall_seconds_total``'s (``cpu``).
+``faults_a_cycle`` is the window's minor page faults by process (the program's
+own, the generator child's) over its cycles: on the Nodes wire the generator's
+allocator maps each of a cycle's 6 MB buffers in anew or reuses it, ~60 to
+~7,000 a cycle in steps of ~1,500, and the cell's spread is that (PR 38).
 
 With ``--stage-cost`` the same window also says what a stage costs WHERE IT
 IS SERVED (a hot loop, ``benchmarks/observer_cost.py``, says less): every
@@ -114,15 +122,18 @@ def summarize(spans: list) -> dict:
         cpu_wall_ms = sum(
             e["duration_ms"] - sum(s["duration_ms"] for s in e["stages"]
                                    if s["name"] == "arrive") for e in read)
-        gil = [e["attrs"]["read_gil_ms"] for e in entries
-               if "read_gil_ms" in e["attrs"]]
+        # span attributes: [mean over the spans that carry it, spans]
+        attrs = {}
+        for name in ("read_gil_ms", "read_calls", "read_recvs"):
+            values = [e["attrs"][name] for e in entries if name in e["attrs"]]
+            attrs[name] = [sum(values) / len(values), len(values)] if values else None
         out[verb] = {
             "n": len(entries),
             "duration_ms": wall_ms / len(entries),
             # thread CPU beside wall time (a parent's spans carry neither)
             "cpu_ms": [cpu_ms / len(read), len(read)] if read else None,
             "oncpu_pct": 100.0 * cpu_ms / cpu_wall_ms if cpu_wall_ms else None,
-            "read_gil_ms": [sum(gil) / len(gil), len(gil)] if gil else None,
+            **attrs,
             "stages": {name: [sum(v) / len(v), len(v)]
                        for name, v in sorted(sums.items())},
             "stage_cpu": {name: [sum(v) / len(v), len(v)]
@@ -136,16 +147,18 @@ def watch_interpreter(trace) -> list:
     """A span observer that keeps, of every served verb from now on,
     (first byte there, wall s, arrive s or None, (cpu s, the wall s they
     are a share of) or None, sampled, share of the span its top stages
-    tile or None)."""
+    tile or None, (read s, read_gil_ms or None, read_calls or None))."""
     kept = []
 
     def observe(span) -> None:
         if not span.name.startswith("POST /scheduler/"):
             return
-        arrive, tiled = None, 0.0
+        arrive, tiled, read = None, 0.0, 0.0
         for name, _start, seconds in span.stages:
             if name == "arrive":
                 arrive = seconds
+            elif name == "read":
+                read = seconds
             if name in TOP:
                 tiled += seconds
         cpu = getattr(span, "cpu_s", None)
@@ -154,6 +167,7 @@ def watch_interpreter(trace) -> list:
             None if cpu is None else (cpu, span.cpu_wall_s()),
             span.sampled,
             tiled / span.duration_s if span.sampled and span.duration_s else None,
+            (read, span.attrs.get("read_gil_ms"), span.attrs.get("read_calls")),
         ))
 
     trace.SPAN_OBSERVERS.append(observe)
@@ -181,6 +195,16 @@ def interpreter_split(kept: list, window: dict, cycle_span) -> dict:
                "oncpu_pct": (100.0 * sum(c for c, _w in cpu) / cpu_wall
                              if cpu_wall else None),
                "stamped_pct": 100.0 * len(waits) / len(rows) if rows else None}
+        if rows:
+            # the way in: mean read, what of it waited for the interpreter
+            # after a read had returned, and the reads made from Python (a
+            # parent's spans carry no count)
+            gil = [r[6][1] for r in rows if r[6][1] is not None]
+            calls = [r[6][2] for r in rows if r[6][2] is not None]
+            out["read_ms"] = sum(r[6][0] for r in rows) / len(rows) * 1e3
+            out["read_gil_ms"] = sum(gil) / len(rows) if gil else None
+            out["read_gil_seconds"] = sum(gil) * 1e-3 if gil else None
+            out["read_calls"] = sum(calls) / len(calls) if calls else None
         if waits:
             out["arrive_ms"] = {
                 "mean": sum(waits) / len(waits) * 1e3,
@@ -311,6 +335,14 @@ def lags_by_metric(system, config, traffic, seed, window) -> dict:
     return out
 
 
+def minor_faults(pid: int) -> int:
+    """Pages a process has had mapped in so far without I/O (``minflt`` of
+    ``/proc/<pid>/stat``): a 6 MB buffer that ``malloc`` takes fresh from the
+    kernel is ~1,500 of them, one it reuses none."""
+    with open(f"/proc/{pid}/stat") as handle:
+        return int(handle.read().rsplit(")", 1)[1].split()[7])
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--workload", required=True)
@@ -350,7 +382,11 @@ def main(argv=None) -> int:
         cpu0 = time.process_time()
         before = run.scrape_counters(system.port)
         paths_before = scrape_labelled(run, system.port)
+        pids = {"program": os.getpid(), "generator": child.process.pid}
+        faults0 = {who: minor_faults(pid) for who, pid in pids.items()}
         window = child.ask({"cmd": "window", "seconds": args.seconds})
+        faults = {who: minor_faults(pid) - faults0[who]
+                  for who, pid in pids.items()}
         after = run.scrape_counters(system.port)
         process_cpu = time.process_time() - cpu0
         paths = scrape_labelled(run, system.port)
@@ -364,6 +400,10 @@ def main(argv=None) -> int:
             "filters": len(records),
             "pods_per_s": len(records) / (window["ended"] - window["began"]),
             "cycle_p50_ms": spans[len(spans) // 2] * 1e3,
+            # minor page faults a cycle, by process: what the allocator took
+            # fresh from the kernel (PR 38: the Nodes wire's two regimes)
+            "faults_a_cycle": {who: n / len(records)
+                               for who, n in faults.items()},
             "window_began_wall": wall0,
             # [seconds into the window, cycle ms, Filter ms] of the longest
             "longest_cycles": [

@@ -263,15 +263,20 @@ def render_simple(
     )
 
 
-def stamped_recv():
-    """``_wirec.recv_stamped`` where its stamps are on the spans' clock,
-    else None: a read that says when the bytes were there and when this
-    thread held the interpreter again (native/wirec.c).  The helper stamps
-    ``CLOCK_MONOTONIC``; spans run on ``time.perf_counter()``, so it is
+def stamped_reads():
+    """``_wirec`` where it has the two stamped reads and their stamps are
+    on the spans' clock, else None: ``recv_stamped`` for a request's head,
+    ``recv_body`` for a body that did not come with it (native/wirec.c).
+    Each says when its bytes were there and when this thread held the
+    interpreter again, and carries its own time-out.  The helpers stamp
+    ``CLOCK_MONOTONIC``; spans run on ``time.perf_counter()``, so they are
     used only where that is the same clock."""
     if "CLOCK_MONOTONIC" not in time.get_clock_info("perf_counter").implementation:
         return None
-    return getattr(get_wirec(), "recv_stamped", None)
+    wirec = get_wirec()
+    if hasattr(wirec, "recv_stamped") and hasattr(wirec, "recv_body"):
+        return wirec
+    return None
 
 
 class _FastHTTPHandler(socketserver.BaseRequestHandler):
@@ -282,18 +287,26 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
     status line + headers + body with one ``sendall``.  Supports
     keep-alive, pipelined requests, and ``Expect: 100-continue``.  Read
     and write timeouts mirror the reference server's 5 s / 10 s
-    (scheduler.go:136-137).
+    (scheduler.go:136-137): 5 s without a byte on the way in, 10 s for
+    the answer.
 
-    On a plain socket with ``_wirec`` loaded every read goes through
-    ``recv_stamped`` (set by the enclosing Server), and the span begins
-    where the request's first byte WAS THERE, not where this thread next
-    ran: the stage ``arrive`` is the wait for the interpreter between the
-    two.  A TLS connection, or a process without ``_wirec``, reads with
-    ``sock.recv`` and records no ``arrive``; the answers are the same
-    bytes."""
+    On a plain socket with ``_wirec`` loaded (``native``, set by the
+    enclosing Server) a request gives the interpreter away once for its
+    head and once for a body that did not come with it: the head through
+    ``recv_stamped``, the body through ONE ``recv_body``, which fills a
+    ``bytes`` of ``Content-Length`` in place however many ``recv`` the
+    kernel needs.  Both carry the read time-out themselves, so the
+    socket's own is armed once a connection, for ``sendall``.  The span
+    begins where the request's first byte WAS THERE, not where this
+    thread next ran: the stage ``arrive`` is the wait for the interpreter
+    between the two.  A TLS connection, or a process without ``_wirec``,
+    reads with ``sock.recv`` / ``sock.recv_into`` (one release a call),
+    arms the socket's time-out before the head and before the answer
+    (stage ``write_arm``), and records no ``arrive``; the answers are the
+    same bytes."""
 
     route = staticmethod(lambda request: HTTPResponse(status=500))
-    recv_stamped = None
+    native = None
     rbufsize = 1 << 16
 
     def handle(self) -> None:
@@ -312,7 +325,13 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
         except OSError:
             pass
         # an SSLSocket is a socket.socket too: its bytes are not the fd's
-        stamped = type(self).recv_stamped if type(sock) is socket.socket else None
+        native = type(self).native if type(sock) is socket.socket else None
+        stamped = read_body = None
+        if native is not None:
+            stamped, read_body = native.recv_stamped, native.recv_body
+            # the native reads carry their own time-out and never consult
+            # the socket's: armed once, for every sendall of the connection
+            sock.settimeout(WRITE_TIMEOUT_S)
         fd = sock.fileno()
         buf = bytearray()
         while True:
@@ -325,12 +344,16 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
             # this thread held the interpreter with it; cpu0: its CPU
             # clock then, on the spans picked to read it (a system call:
             # trace.cpu_sample_due).  gil: what the reads after the first
-            # waited for the interpreter with their bytes in hand.
-            t_ready = cpu0 = gil = None
+            # waited for the interpreter with their bytes in hand.  calls:
+            # the reads made from Python, each one release of the GIL;
+            # recvs: the kernel's, inside recv_body's one.
+            t_ready = cpu0 = gil = recvs = None
+            calls = 0
             t_accept = time.perf_counter() if buf else None
             if buf and trace.cpu_sample_due(t_accept):
                 cpu0 = time.thread_time()
-            sock.settimeout(READ_HEADER_TIMEOUT_S)
+            if native is None:
+                sock.settimeout(READ_HEADER_TIMEOUT_S)
             head_end = buf.find(b"\r\n\r\n")
             while head_end < 0:
                 if len(buf) > MAX_HEAD_LENGTH:
@@ -347,6 +370,7 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
                     return
                 if not chunk:
                     return
+                calls += 1
                 if t_accept is None:
                     if stamped is not None:
                         t_ready, t_accept = ready, held
@@ -377,26 +401,38 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
                     return
             # -- read the body ----------------------------------------------
             if len(buf) < length:
-                # a body that outlasts the first recv (the Nodes wire's
-                # 6 MB): annotated, so that a profiled window shows the
-                # receive; the span's read stage below covers it by hand
+                # a body that did not come whole with its head (the Nodes
+                # wire's 6 MB, a planner cell's 0.5 MB): annotated, so that
+                # a profiled window shows the receive; the span's read
+                # stage below covers it by hand
                 with trace.stage("read"):
-                    while len(buf) < length:
-                        try:
-                            if stamped is not None:
-                                chunk, ready, held = stamped(
-                                    fd, self.rbufsize, READ_HEADER_TIMEOUT_S
-                                )
-                                gil = (gil or 0.0) + (held - ready)
-                            else:
-                                chunk = sock.recv(self.rbufsize)
-                        except (TimeoutError, OSError):
-                            return
-                        if not chunk:
-                            return
-                        buf += chunk
-            body = bytes(buf[:length])
-            del buf[:length]
+                    try:
+                        if read_body is not None:
+                            body, ready, held, recvs = read_body(
+                                fd, buf, length, READ_HEADER_TIMEOUT_S
+                            )
+                            calls += 1
+                            gil = (gil or 0.0) + (held - ready)
+                        else:
+                            whole = bytearray(length)
+                            filled = len(buf)
+                            whole[:filled] = buf
+                            with memoryview(whole) as into:
+                                while filled < length:
+                                    got = sock.recv_into(into[filled:])
+                                    if not got:
+                                        return
+                                    calls += 1
+                                    filled += got
+                            body = bytes(whole)
+                    except (TimeoutError, OSError, MemoryError):
+                        # no byte for 5 s, a peer that went, or no room
+                        # for a length that was only declared so far
+                        return
+                buf.clear()
+            else:
+                body = bytes(buf[:length])
+                del buf[:length]
             # -- dispatch + respond ------------------------------------------
             request_id = lowered.get("x-request-id") or trace.new_request_id()
             span = trace.Span(
@@ -419,13 +455,17 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
             )
             if gil is not None:
                 span.set("read_gil_ms", round(gil * 1e3, 4))
+            span.set("read_calls", calls)
+            if recvs is not None:
+                span.set("read_recvs", recvs)
             request = HTTPRequest(
                 method=method, path=path, headers=headers, body=body,
                 span=span,
             )
-            # arrive + read + handle + write_arm + write tile the span
-            # (handle and write_arm on sampled spans); handle contains the
-            # verb's own stages, so it is never annotated
+            # arrive + read + handle + write tile the span (handle on
+            # sampled spans), with write_arm before write where the socket
+            # has to be armed; handle contains the verb's own stages, so it
+            # is never annotated
             with span.stage("handle", leaf=False, sampled=True):
                 try:
                     response = type(self).route(request)
@@ -438,12 +478,14 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
                 version == "HTTP/1.0"
                 or lowered.get("connection", "").lower() == "close"
             )
-            # arming the timeout is an ioctl: the GIL is released for it and
-            # has to be won back from whatever the verb woke (informers,
-            # the refresh thread) — a stage of its own, so that the stages
-            # still tile the span and write keeps its meaning
-            with span.stage("write_arm", sampled=True):
-                sock.settimeout(WRITE_TIMEOUT_S)
+            if native is None:
+                # the reads ran under the socket's own 5 s.  Arming the
+                # write's is an ioctl: the GIL is released for it and has to
+                # be won back from whatever the verb woke (informers, the
+                # refresh thread) — a stage of its own, so that the stages
+                # still tile the span and write keeps its meaning
+                with span.stage("write_arm", sampled=True):
+                    sock.settimeout(WRITE_TIMEOUT_S)
             t_write = time.perf_counter()
             cpu_write = None if cpu0 is None else time.thread_time()
             try:
@@ -918,9 +960,8 @@ class Server:
 
         class Handler(_FastHTTPHandler):
             route = staticmethod(server.route)
-            # resolved once, here: loading _wirec may build it.  Read off
-            # the class, never an instance, so it needs no staticmethod
-            recv_stamped = stamped_recv()
+            # resolved once, here: loading _wirec may build it
+            native = stamped_reads()
 
         httpd = socketserver.ThreadingTCPServer(
             (host, int(port)), Handler, bind_and_activate=False

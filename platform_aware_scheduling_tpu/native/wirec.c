@@ -2581,7 +2581,7 @@ static PyObject *wirec_select_encode_universe(PyObject *mod, PyObject *args) {
 }
 
 /* ------------------------------------------------------------------ */
-/* recv_stamped: a socket read that says when the bytes were there      */
+/* recv_stamped, recv_body: socket reads that say when their bytes were there */
 
 #include <errno.h>
 #include <poll.h>
@@ -2592,6 +2592,30 @@ static double monotonic_now(void) {
     struct timespec ts;
     clock_gettime(CLOCK_MONOTONIC, &ts);
     return (double)ts.tv_sec + (double)ts.tv_nsec * 1e-9;
+}
+
+/* poll(fd, POLLIN) until `deadline` (monotonic_now()'s seconds; for ever
+ * where timeout_s < 0): 1 readable, 0 timed out, -1 with errno set.  Touches
+ * no Python object: the stamped reads call it with the GIL released. */
+static int wait_readable(int fd, double timeout_s, double deadline) {
+    for (;;) {
+        int wait_ms = -1;
+        if (timeout_s >= 0) {
+            double left = deadline - monotonic_now();
+            if (left < 0) left = 0;
+            /* round up: a poll that returns a millisecond early would spin */
+            wait_ms = left > 2e6 ? 2000000000 : (int)(left * 1e3 + 0.999);
+        }
+        struct pollfd p = {fd, POLLIN, 0};
+        int ready = poll(&p, 1, wait_ms);
+        if (ready > 0) return 1;
+        if (ready < 0) {
+            if (errno == EINTR) continue;
+            return -1;
+        }
+        if (timeout_s >= 0 && monotonic_now() < deadline) continue;
+        return 0;
+    }
 }
 
 /* recv_stamped(fd, max_bytes, timeout_s) -> (bytes, t_ready, t_held)
@@ -2630,22 +2654,12 @@ static PyObject *wirec_recv_stamped(PyObject *self, PyObject *args) {
     Py_BEGIN_ALLOW_THREADS
     double deadline = timeout_s >= 0 ? monotonic_now() + timeout_s : 0.0;
     for (;;) {
-        int wait_ms = -1;
-        if (timeout_s >= 0) {
-            double left = deadline - monotonic_now();
-            if (left < 0) left = 0;
-            /* round up: a poll that returns a millisecond early would spin */
-            wait_ms = left > 2e6 ? 2000000000 : (int)(left * 1e3 + 0.999);
-        }
-        struct pollfd p = {fd, POLLIN, 0};
-        int ready = poll(&p, 1, wait_ms);
+        int ready = wait_readable(fd, timeout_s, deadline);
         if (ready < 0) {
-            if (errno == EINTR) continue;
             err = errno;
             break;
         }
         if (ready == 0) {
-            if (timeout_s >= 0 && monotonic_now() < deadline) continue;
             timed_out = 1;
             break;
         }
@@ -2671,6 +2685,100 @@ static PyObject *wirec_recv_stamped(PyObject *self, PyObject *args) {
     }
     if (got != max_bytes && _PyBytes_Resize(&out, got) < 0) return NULL;
     return Py_BuildValue("(Ndd)", out, t_ready, t_held);
+}
+
+/* recv_body(fd, prefix, length, timeout_s) -> (bytes, t_ready, t_held, n_recv)
+ *
+ * A request's body in one release of the GIL: the bytes object of exactly
+ * `length` is allocated here, `prefix` (what the head's read left over) is
+ * copied to its front, and poll + recv fill the rest of it in place — as
+ * many recvs as the kernel needs, none of them a trip through Python.  recv
+ * is never asked for more than is still missing, so a pipelined next request
+ * stays in the socket.  timeout_s is so long WITHOUT A BYTE, as each
+ * recv_stamped of the same body had it, not so long for the body; < 0 waits
+ * for ever.  t_ready / t_held as recv_stamped's, after the last byte; n_recv
+ * counts the recvs that returned bytes.  A time-out raises TimeoutError, an
+ * error OSError, a peer that closes before the last byte
+ * ConnectionResetError (an OSError: the caller ends the connection on any
+ * of them). */
+static PyObject *wirec_recv_body(PyObject *self, PyObject *args) {
+    int fd;
+    Py_buffer prefix;
+    Py_ssize_t length;
+    double timeout_s;
+    if (!PyArg_ParseTuple(args, "iy*nd", &fd, &prefix, &length, &timeout_s))
+        return NULL;
+    if (length < 0 || prefix.len > length) {
+        PyBuffer_Release(&prefix);
+        PyErr_SetString(PyExc_ValueError,
+                        length < 0 ? "negative length in recv_body"
+                                   : "prefix longer than length in recv_body");
+        return NULL;
+    }
+    if (fd < 0) {
+        PyBuffer_Release(&prefix);
+        errno = EBADF;
+        return PyErr_SetFromErrno(PyExc_OSError);
+    }
+    PyObject *out = PyBytes_FromStringAndSize(NULL, length);
+    if (!out) {
+        PyBuffer_Release(&prefix);
+        return NULL;
+    }
+    char *data = PyBytes_AS_STRING(out);
+    Py_ssize_t filled = prefix.len;
+    memcpy(data, prefix.buf, (size_t)filled);
+    PyBuffer_Release(&prefix);  /* no Python object is touched below */
+    int err = 0, timed_out = 0, closed = 0;
+    long n_recv = 0;
+    double t_ready, t_held;
+
+    Py_BEGIN_ALLOW_THREADS
+    double deadline = timeout_s >= 0 ? monotonic_now() + timeout_s : 0.0;
+    while (filled < length) {
+        int ready = wait_readable(fd, timeout_s, deadline);
+        if (ready < 0) {
+            err = errno;
+            break;
+        }
+        if (ready == 0) {
+            timed_out = 1;
+            break;
+        }
+        ssize_t got = recv(fd, data + filled, (size_t)(length - filled), 0);
+        if (got > 0) {
+            filled += got;
+            n_recv++;
+            if (timeout_s >= 0) deadline = monotonic_now() + timeout_s;
+            continue;
+        }
+        if (got == 0) {
+            closed = 1;
+            break;
+        }
+        if (errno == EAGAIN || errno == EWOULDBLOCK || errno == EINTR) continue;
+        err = errno;
+        break;
+    }
+    t_ready = monotonic_now();
+    Py_END_ALLOW_THREADS
+    t_held = monotonic_now();
+
+    if (filled < length) {
+        Py_DECREF(out);
+        if (timed_out) {
+            PyErr_SetString(PyExc_TimeoutError, "timed out");
+        } else if (closed) {
+            PyErr_Format(PyExc_ConnectionResetError,
+                         "peer closed %zd bytes short of the body",
+                         length - filled);
+        } else {
+            errno = err;
+            PyErr_SetFromErrno(PyExc_OSError);
+        }
+        return NULL;
+    }
+    return Py_BuildValue("(Nddl)", out, t_ready, t_held, n_recv);
 }
 
 /* ------------------------------------------------------------------ */
@@ -2701,6 +2809,10 @@ static PyMethodDef wirec_methods[] = {
      "recv_stamped(fd, max_bytes, timeout_s) -> (bytes, t_ready, t_held): "
      "poll + recv with the GIL released; CLOCK_MONOTONIC seconds when the "
      "bytes were there and when the interpreter was held again."},
+    {"recv_body", wirec_recv_body, METH_VARARGS,
+     "recv_body(fd, prefix, length, timeout_s) -> (bytes, t_ready, t_held, "
+     "n_recv): prefix + the bytes still missing of a body of length, read "
+     "in place under one release of the GIL; timeout_s without a byte."},
     {NULL},
 };
 

@@ -171,6 +171,7 @@ declare("pas_verb_arrive_total", "counter", "Verb spans that carried an arrival 
 declare("pas_verb_arrive_wait_seconds_total", "counter", "Seconds verbs waited for the interpreter with their first bytes already received (stage arrive: recv returned -> GIL held).")
 declare("pas_verb_read_seconds_total", "counter", "Seconds of verbs' read stage (first byte held -> last byte of the body).")
 declare("pas_verb_read_gil_seconds_total", "counter", "Of those, seconds the reading thread waited for the interpreter after a recv had returned (span attribute read_gil_ms).")
+declare("pas_verb_read_calls_total", "counter", "Reads the verbs' threads made from Python on their requests' way in, each one release of the interpreter (span attribute read_calls): the head's recvs, then one native read a body that did not come with its head — one recv_into a piece where the body is read through the socket object (TLS, no _wirec).")
 declare("pas_stage_handle_total", "counter", "Verb spans that recorded the sampled stage handle (one span in SAMPLE_EVERY).")
 declare("pas_stage_handle_seconds_total", "counter", "Seconds of those handle stages: route(request), whole.")
 declare("pas_stage_scan_total", "counter", "Sampled scan stages recorded on verb spans (Filter's native scan in the probe).")
@@ -970,6 +971,7 @@ VERB_FAMILIES = (
     "pas_verb_arrive_wait_seconds_total",
     "pas_verb_read_seconds_total",
     "pas_verb_read_gil_seconds_total",
+    "pas_verb_read_calls_total",
     "pas_stage_handle_total",
     "pas_stage_handle_seconds_total",
     "pas_stage_scan_total",
@@ -978,6 +980,7 @@ VERB_FAMILIES = (
 _VERB_SPANS = "POST /scheduler/"  # the name of a served verb's span begins so
 _at = VERB_FAMILIES.index
 _READ_GIL = _at("pas_verb_read_gil_seconds_total")
+_READ_CALLS = _at("pas_verb_read_calls_total")
 _CPU = _at("pas_verb_cpu_seconds_total")
 _CPU_WALL = _at("pas_verb_cpu_wall_seconds_total")
 #: stage name -> (tally index of its count or -1, of its seconds): the
@@ -1094,6 +1097,7 @@ class TraceBuffer:
                 gil_ms = span.attrs.get("read_gil_ms")
                 if gil_ms is not None:
                     tally[_READ_GIL] += gil_ms * 1e-3
+                tally[_READ_CALLS] += span.attrs.get("read_calls", 0)
         COUNTERS.inc("pas_traces_recorded_total")
         for observer in SPAN_OBSERVERS:
             try:

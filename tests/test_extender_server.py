@@ -2,12 +2,17 @@
 (modeled on the reference's httptest-driven handler tests,
 telemetry-aware-scheduling/pkg/telemetryscheduler/scheduler_test.go)."""
 
+import hashlib
 import http.client
 import json
+import socket
+import socketserver
 import threading
+import time
 
 import pytest
 
+from platform_aware_scheduling_tpu.extender import server as server_module
 from platform_aware_scheduling_tpu.extender.server import (
     HTTPRequest,
     HTTPResponse,
@@ -25,6 +30,8 @@ from platform_aware_scheduling_tpu.extender.types import (
     encode_host_priority_list,
 )
 from platform_aware_scheduling_tpu.kube.objects import Node, Pod
+from platform_aware_scheduling_tpu.utils import trace
+from wirehelpers import post_bytes, wait_for_span
 
 
 class EchoScheduler:
@@ -244,6 +251,238 @@ class TestLiveServer:
             t.join()
         assert not errors
         assert len(scheduler.calls) == 8
+
+
+class DigestScheduler:
+    """Answers with what it was given: the body's length and SHA-256."""
+
+    def __init__(self):
+        self.calls = 0
+
+    def filter(self, request):
+        self.calls += 1
+        assert type(request.body) is bytes
+        return HTTPResponse.json(json.dumps(_digest(request.body)).encode())
+
+    prioritize = bind = filter
+
+
+def _digest(body):
+    return {"n": len(body), "sha": hashlib.sha256(body).hexdigest()}
+
+
+def _body(n, salt):
+    """``n`` bytes that differ wherever a misplaced piece would show."""
+    block = hashlib.sha256(salt).digest()
+    return (block * (n // len(block) + 1))[:n]
+
+
+def _responses(sock, count):
+    """``count`` answers off one connection: [(head, parsed JSON body)]."""
+    out, buf = [], bytearray()
+    while len(out) < count:
+        end = buf.find(b"\r\n\r\n")
+        length = None
+        if end >= 0:
+            for line in bytes(buf[:end]).split(b"\r\n")[1:]:
+                name, _, value = line.partition(b":")
+                if name.lower() == b"content-length":
+                    length = int(value)
+        if length is not None and len(buf) >= end + 4 + length:
+            out.append((bytes(buf[:end]),
+                        json.loads(bytes(buf[end + 4:end + 4 + length]))))
+            del buf[:end + 4 + length]
+            continue
+        chunk = sock.recv(1 << 16)
+        assert chunk, ("closed early", out, bytes(buf[:200]))
+        buf += chunk
+    assert not buf
+    return out
+
+
+def _closed_without_an_answer(sock):
+    sock.settimeout(10)
+    return sock.recv(1 << 16) == b""
+
+
+#: the front-end's two read paths (extender/server.py _serve): _wirec's
+#: stamped reads on a plain socket, the socket's own where there is none
+READ_PATHS = [
+    pytest.param("native", marks=pytest.mark.skipif(
+        server_module.stamped_reads() is None,
+        reason="_wirec (recv_stamped, recv_body) unavailable")),
+    "no-native",
+]
+
+
+@pytest.mark.parametrize("path", READ_PATHS)
+class TestBodyReads:
+    """A body that does not come whole with its head, on both read paths:
+    the same answers byte for byte, the framing and the time-outs kept."""
+
+    @pytest.fixture()
+    def served(self, path, monkeypatch):
+        if path == "no-native":
+            monkeypatch.setenv("PAS_TPU_NO_NATIVE", "1")
+        monkeypatch.setattr(trace, "SAMPLE_EVERY", 1)
+        scheduler = DigestScheduler()
+        server = Server(scheduler)
+        server.start_server(port="0", unsafe=True, host="127.0.0.1", block=False)
+        assert server.wait_ready()
+        assert (server._httpd.RequestHandlerClass.native is not None) == (
+            path == "native")
+        sock = socket.create_connection(("127.0.0.1", server.port), timeout=15)
+        try:
+            yield sock, scheduler
+        finally:
+            sock.close()
+            server.shutdown()
+
+    def test_a_body_in_4k_pieces_is_answered_as_when_sent_whole(self, served):
+        sock, _ = served
+        body = _body(1_000_000, b"pieces")
+        whole = post_bytes("/scheduler/filter", body, extra="X-Request-ID: same\r\n")
+        sock.sendall(whole)
+        sent_whole = _responses(sock, 1)
+        for at in range(0, len(whole), 4096):
+            sock.sendall(whole[at:at + 4096])
+        in_pieces = _responses(sock, 1)
+        assert in_pieces == sent_whole
+        assert in_pieces[0][1] == _digest(body)
+
+    def test_two_pipelined_requests_the_first_with_a_multi_read_body(self, served):
+        sock, scheduler = served
+        first, second = _body(300_000, b"first"), _body(70_000, b"second")
+        one = post_bytes("/scheduler/filter", first)
+        two = post_bytes("/scheduler/prioritize", second)
+        sock.sendall(one[:100_000])
+        time.sleep(0.05)  # the server is inside the first body's read
+        sock.sendall(one[100_000:] + two)  # its tail and all of the next
+        answers = _responses(sock, 2)
+        assert [a[1] for a in answers] == [_digest(first), _digest(second)]
+        assert all(a[0].startswith(b"HTTP/1.1 200 OK") for a in answers)
+        # and a third, whole in the buffer the second one's read filled
+        sock.sendall(two + two)
+        assert [a[1] for a in _responses(sock, 2)] == [_digest(second)] * 2
+        assert scheduler.calls == 4
+
+    def test_a_body_whose_first_bytes_ride_with_the_head(self, served):
+        sock, _ = served
+        body = _body(200_000, b"rides")
+        request = post_bytes("/scheduler/filter", body)
+        head_end = request.index(b"\r\n\r\n") + 4
+        sock.sendall(request[:head_end + 10])
+        time.sleep(0.05)
+        sock.sendall(request[head_end + 10:])
+        assert _responses(sock, 1)[0][1] == _digest(body)
+
+    def test_the_span_of_a_multi_read_verb(self, served, path):
+        """One native read a body, however many pieces it came in (the
+        kernel's recvs are counted apart); one call a piece through the
+        socket.  The top stages tile the span with the body's read in it
+        (which stages a path records: tests/test_trace_cpu.py)."""
+        sock, _ = served
+        request = post_bytes(
+            "/scheduler/filter", _body(400_000, b"span"),
+            extra=f"X-Request-ID: multi-{path}\r\n")
+        for at in range(0, len(request), 40_000):
+            sock.sendall(request[at:at + 40_000])
+            time.sleep(0.005)
+        _responses(sock, 1)
+        span = wait_for_span(f"multi-{path}")
+        if path == "native":
+            assert 2 <= span.attrs["read_calls"] <= 3
+            assert span.attrs["read_recvs"] > 1
+            assert span.attrs["read_gil_ms"] >= 0
+        else:
+            assert span.attrs["read_calls"] > 3
+            assert "read_recvs" not in span.attrs
+        stages = span.stage_seconds()
+        tiled = sum(stages.get(name, 0.0) for name in (
+            "arrive", "read", "handle", "write_arm", "write"))
+        assert abs(span.duration_s - tiled) <= 0.05 * span.duration_s, (
+            tiled, span.duration_s, stages)
+        # a body that came whole with its head is no read of its own
+        sock.sendall(post_bytes(
+            "/scheduler/filter", b"{}", extra=f"X-Request-ID: small-{path}\r\n"))
+        _responses(sock, 1)
+        small = wait_for_span(f"small-{path}")
+        assert small.attrs["read_calls"] == 1
+        assert "read_recvs" not in small.attrs
+
+    def test_a_trickle_is_read_and_a_stall_ends_the_connection(
+        self, served, monkeypatch
+    ):
+        """READ_HEADER_TIMEOUT_S is so long without a byte, not so long for
+        the body; past it the connection ends without an answer."""
+        sock, scheduler = served
+        monkeypatch.setattr(server_module, "READ_HEADER_TIMEOUT_S", 0.5)
+        body = _body(160, b"trickle")
+        request = post_bytes("/scheduler/filter", body)
+        head_end = request.index(b"\r\n\r\n") + 4
+        began = time.monotonic()
+        sock.sendall(request[:head_end])
+        for at in range(head_end, len(request), 10):  # 16 pieces, 0.8 s
+            time.sleep(0.05)
+            sock.sendall(request[at:at + 10])
+        assert _responses(sock, 1)[0][1] == _digest(body)
+        assert time.monotonic() - began > 0.5
+        sock.sendall(request[:head_end + 80])  # half a body, then silence
+        assert _closed_without_an_answer(sock)
+        assert scheduler.calls == 1
+
+    def test_a_peer_that_closes_mid_body_gets_no_answer(self, served):
+        sock, scheduler = served
+        request = post_bytes("/scheduler/filter", _body(100_000, b"closes"))
+        sock.sendall(request[:50_000])
+        sock.shutdown(socket.SHUT_WR)
+        assert _closed_without_an_answer(sock)
+        assert scheduler.calls == 0
+
+    def test_a_length_there_is_no_room_for_ends_the_connection(
+        self, served, path, monkeypatch
+    ):
+        """The body's room is taken when only its length has been declared:
+        where there is none the connection ends like one that stalled."""
+        first, scheduler = served
+        declared = 64 << 20
+
+        def no_room(*args):  # recv_body's, or bytearray's, arguments
+            if declared in args:
+                raise MemoryError
+            return bytearray(*args)
+
+        if path == "native":
+            monkeypatch.setattr(server_module.stamped_reads(), "recv_body", no_room)
+        else:
+            monkeypatch.setattr(server_module, "bytearray", no_room, raising=False)
+        # socketserver's own handler would end the connection too, with a
+        # traceback on stderr: the front-end's is meant to, silently
+        unhandled = []
+        monkeypatch.setattr(
+            socketserver.BaseServer, "handle_error",
+            lambda self, request, address: unhandled.append(address))
+        request = post_bytes("/scheduler/filter", b"x" * 100).replace(
+            b"Content-Length: 100", b"Content-Length: %d" % declared)
+        # a connection of its own: a handler picks its reads when it begins
+        sock = socket.create_connection(first.getpeername(), timeout=15)
+        try:
+            sock.sendall(request)
+            assert _closed_without_an_answer(sock)
+        finally:
+            sock.close()
+        assert scheduler.calls == 0 and not unhandled
+
+    def test_an_oversized_length_is_refused_before_any_read(self, served):
+        sock, scheduler = served
+        sock.sendall(
+            b"POST /scheduler/filter HTTP/1.1\r\n"
+            b"Content-Type: application/json\r\n"
+            + f"Content-Length: {server_module.MAX_CONTENT_LENGTH + 1}".encode()
+            + b"\r\n\r\nxx")
+        data = sock.recv(1 << 16)
+        assert data.startswith(b"HTTP/1.1 500 ") and b"Connection: close" in data
+        assert scheduler.calls == 0
 
 
 class TestDuration:

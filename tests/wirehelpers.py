@@ -6,9 +6,11 @@ several copies."""
 import json
 import socket
 import threading
+import time
 
 from platform_aware_scheduling_tpu.extender.server import Server
 from platform_aware_scheduling_tpu.serving import AsyncServer
+from platform_aware_scheduling_tpu.utils import trace
 
 
 def start_threaded(ext) -> Server:
@@ -28,6 +30,18 @@ def start_async(ext, **kwargs) -> AsyncServer:
     server.start_server(port="0", unsafe=True, host="127.0.0.1", block=False)
     assert server.wait_ready(10)
     return server
+
+
+def wait_for_span(trace_id: str, timeout: float = 5.0):
+    """The finished span of ``trace_id`` (a handler books it after its
+    answer's bytes are out, so a client that has its answer may be early)."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        span = trace.TRACES.find(trace_id)
+        if span is not None:
+            return span
+        time.sleep(0.002)
+    raise AssertionError(f"span {trace_id} never recorded")
 
 
 def post_bytes(path: str, body: bytes, extra: str = "") -> bytes:
