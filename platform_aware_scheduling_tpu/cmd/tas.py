@@ -64,15 +64,17 @@ def build_arg_parser() -> argparse.ArgumentParser:
                         help="batch planner solver: greedy (sequential-"
                         "equivalent; pods of unlike requests each book "
                         "their own cpu, memory and pod slot) or sinkhorn "
-                        "(globally coordinated; takes a count only, so "
-                        "unlike pods are each counted as the largest "
-                        "request pending: never an overcommit, not exact)")
+                        "(globally coordinated; the one form that takes a "
+                        "count only, so unlike pods are each counted as the "
+                        "largest request pending: never an overcommit, not "
+                        "exact)")
     parser.add_argument("--batchPlannerDevices", type=int, default=1,
                         help="devices the batch planner's solve spans: 1 "
                         "(default) solves on one device; n > 1 solves "
                         "node-sharded over a mesh of the first n (greedy "
-                        "solver only; pods of unlike requests are each "
-                        "counted as the largest there; docs/architecture.md)")
+                        "solver only; the same plan, pods of unlike "
+                        "requests each booking their own; "
+                        "docs/architecture.md)")
     parser.add_argument("--nodeCacheCapable", action="store_true",
                         help="serve Prioritize/Filter from Args.NodeNames "
                         "(register the extender nodeCacheCapable: true); "

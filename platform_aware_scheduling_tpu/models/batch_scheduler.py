@@ -23,7 +23,8 @@ Multi-chip (:func:`mesh_scheduling_step`, the planner's
 ``--batchPlannerDevices`` path): the operands arrive node-sharded over a
 mesh, rules and score keys run under GSPMD (elementwise over nodes: no
 collective), and the assignment is parallel/sharded.py's hand-written
-collective form; only ``node_for_pod`` comes back.
+collective form, for either form of the room; only ``node_for_pod`` comes
+back.
 """
 
 from __future__ import annotations
@@ -146,6 +147,18 @@ def choose_assigner(*operands) -> str:
     return ASSIGNER_SCAN
 
 
+def _room_operands(state: ClusterState, pods: PendingPods) -> tuple:
+    """(room, the demand's keyword arguments) as every assigner takes them:
+    the count alone, or the limbs as rows — ``[L * R, N]`` and
+    ``[P, L * R]``."""
+    if pods.demand is None:
+        return state.capacity, {}
+    p, limbs, resources = pods.demand.shape
+    return state.capacity.reshape(limbs * resources, -1), dict(
+        demand=pods.demand.reshape(p, limbs * resources), limbs=limbs
+    )
+
+
 @partial(jax.jit, static_argnames=("assigner",))
 def _scheduling_step(
     state: ClusterState, pods: PendingPods, assigner: str
@@ -158,16 +171,8 @@ def _scheduling_step(
         greedy_assign_pallas if assigner == ASSIGNER_PALLAS
         else greedy_assign_kernel
     )
-    if pods.demand is None:
-        assignment = assign(score, eligible, state.capacity)
-    else:
-        # the kernels take the limbs as rows: [L * R, N] and [P, L * R]
-        p, limbs, resources = pods.demand.shape
-        assignment = assign(
-            score, eligible,
-            state.capacity.reshape(limbs * resources, -1),
-            demand=pods.demand.reshape(p, limbs * resources), limbs=limbs,
-        )
+    room, form = _room_operands(state, pods)
+    assignment = assign(score, eligible, room, **form)
     return ScheduleOutput(
         assignment=assignment, violating=violating, score=score, eligible=eligible
     )
@@ -200,9 +205,8 @@ def _mesh_scheduling_step(state: ClusterState, pods: PendingPods, mesh) -> jax.A
         lo=jax.lax.with_sharding_constraint(score.lo, by_node),
     )
     eligible = jax.lax.with_sharding_constraint(eligible, by_node)
-    node_for_pod, _left = sharded_greedy_assign(
-        mesh, score, eligible, state.capacity
-    )
+    room, form = _room_operands(state, pods)
+    node_for_pod, _left = sharded_greedy_assign(mesh, score, eligible, room, **form)
     return node_for_pod
 
 
@@ -216,8 +220,10 @@ def mesh_scheduling_step(mesh, state: ClusterState, pods: PendingPods) -> jax.Ar
     (tas/planner.py places them): the same plan as :func:`scheduling_step`
     — greedy in pod order, first index on a tie — as ``node_for_pod``
     alone, int32 [P], replicated.  ``score`` and ``eligible`` never leave
-    the mesh.  Assigned by ``sharded_greedy_assign``, one all_gather a
-    block of 32 pods: 0.43 s at 32,768 x 65,536 on four v5e chips, where
+    the mesh.  The room's form follows ``pods.demand`` as in
+    :func:`scheduling_step`: a count, or each pod's own vector.  Assigned
+    by ``sharded_greedy_assign``, one all_gather a block of 32 pods:
+    0.43 s at 32,768 x 65,536 on four v5e chips, where
     ``greedy_assign_kernel`` under GSPMD (four all-reduces a pod) took
     0.76 s for the same plan (PERF.md §6, PR 33)."""
     return _mesh_scheduling_step(state, pods, mesh=mesh)

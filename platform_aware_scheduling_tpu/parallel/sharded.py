@@ -10,10 +10,11 @@ Four building blocks, each the multi-chip form of an ops/ kernel:
     rank-by-counting its local lanes against the global key set —
     rank_i = |{j : key_j < key_i or (key_j = key_i and j < i)}|,
     identical to the single-chip sort's ranks;
-  * :func:`sharded_greedy_assign` — the sequential-in-pods greedy solve:
-    each step reduces a per-shard lexicographic argmin, all_gathers the
-    per-chip candidates (4 scalars per chip), and every chip deterministically
-    agrees on the winner; only the owning shard books the capacity;
+  * :func:`sharded_greedy_assign` — the sequential-in-pods greedy solve,
+    a block of pods at a time: each shard extracts its best candidates a pod
+    among the nodes whose room covers that pod's demand, ONE all_gather
+    carries them with their room, every chip deterministically replays the
+    block's decisions alike, and only the owning shard books the room;
   * :func:`sharded_sinkhorn_assign` — the mesh form of the Sinkhorn churn
     engine (ops/sinkhorn.py, BASELINE config #5): the [P, N] logit matrix
     stays node-sharded end to end; row normalizers are global
@@ -36,7 +37,11 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from platform_aware_scheduling_tpu.ops import i64
-from platform_aware_scheduling_tpu.ops.assign import UNASSIGNED
+from platform_aware_scheduling_tpu.ops.assign import (
+    UNASSIGNED,
+    room_covers,
+    room_less,
+)
 from platform_aware_scheduling_tpu.ops.rules import (
     OP_GREATER_THAN,
     OP_LESS_THAN,
@@ -185,28 +190,58 @@ def greedy_assign_collective_count(num_pods: int, block_size: int = 32) -> int:
 
 
 def sharded_greedy_assign(
-    mesh: Mesh, score: i64.I64, eligible, capacity, block_size: int = 32
+    mesh: Mesh,
+    score: i64.I64,
+    eligible,
+    room,
+    demand=None,
+    limbs: int = 1,
+    block_size: int = 32,
 ):
     """Greedy batch assignment with the node axis sharded, chunked into
     pod blocks: ONE all_gather per ``block_size`` pods instead of the
     per-pod gather the round-2/3 verdicts flagged (1k sequential
     collectives at target scale -> ~32).
 
-    Per block of B pods, each shard extracts its top-B local candidates
-    per pod (score order, block-start capacity attached), gathers the
-    [B, B, 5] payload once, and every chip deterministically REPLAYS the
-    block's greedy decisions from the merged candidate lists — bookings
-    within the block are counted against each candidate's block-start
-    capacity, so the replay reproduces the sequential solve exactly.
+    Room takes the forms of ops/assign.greedy_assign_kernel, and they are
+    one form here.  ``demand`` given: ``room`` is the nodes' room
+    ``[limbs * R, N]`` (split over the nodes) and ``demand`` each pod's own
+    request vector ``[P, limbs * R]`` (on every chip); pod i is feasible on
+    node j iff ``room_covers`` and books its vector with ``room_less``.
+    ``demand`` None: ``room`` is a count ``[N]``, solved as a demand of one
+    on one resource, and the room left comes back as ``[N]``.
 
-    Top-B per shard suffices for exactness: making a shard's j-th best
-    candidate for some pod infeasible takes >= j bookings, and a block
-    books at most B-1 times before any pod's turn, so the block winner is
-    always within the shard's top-B (equality with the single-chip kernel
-    is pinned by tests/test_parallel.py at 1k pods x 8k nodes).
+    Per block of B pods, each shard extracts its top-B local candidates
+    per pod: nodes where every resource covers THAT pod's demand at block
+    start, in score order, each with its block-start room attached.  The
+    ``[B, B, 4 + limbs * R]`` payload (hi, lo, index, found, room) is
+    gathered once, and every chip deterministically REPLAYS the block's
+    greedy decisions from the merged candidate lists: a pod's booking is
+    taken off every candidate entry of the node it chose, so each later
+    pod sees the room its turn would see, and the plan is the sequential
+    solve's.  After the block each shard's lanes take the room left of the
+    nodes its pods chose.
+
+    Top-B per shard suffices for exactness, with vectors as with a count:
+    a booking on node j lowers only j's room, so it can make only j
+    infeasible for a later pod, whatever that pod asks.  A pod's
+    sequential best node on shard s is feasible at its turn, hence at block
+    start, and sits in s's list unless every node above it was made
+    infeasible, one booking each: that takes B bookings, and a block books
+    at most B-1 times before any pod's turn (equality with the single-chip
+    kernels is pinned by tests/test_parallel.py and
+    tests/test_planner_demand.py).  Padding pods ask for nothing and are
+    eligible nowhere.
     """
     n_shards = dict(zip(mesh.axis_names, mesh.devices.shape))[NODE_AXIS]
     num_pods = score.hi.shape[0]
+    counted = demand is None
+    if counted:
+        # a count is a demand of one on one resource
+        room = room.reshape(1, -1)
+        demand = jnp.ones((num_pods, 1), dtype=room.dtype)
+        limbs = 1
+    held = room.shape[0]  # limbs * R
     padded = -(-num_pods // block_size) * block_size
     pad = padded - num_pods
     if pad:
@@ -216,6 +251,7 @@ def sharded_greedy_assign(
             lo=jnp.pad(score.lo, ((0, pad), (0, 0))),
         )
         eligible = jnp.pad(eligible, ((0, pad), (0, 0)))
+        demand = jnp.pad(demand, ((0, pad), (0, 0)))
 
     @partial(
         shard_map,
@@ -223,32 +259,39 @@ def sharded_greedy_assign(
         in_specs=(
             i64.I64(hi=P(None, NODE_AXIS), lo=P(None, NODE_AXIS)),
             P(None, NODE_AXIS),
-            P(NODE_AXIS),
+            P(None, NODE_AXIS),
+            P(),
         ),
-        out_specs=(P(), P(NODE_AXIS)),
+        out_specs=(P(), P(None, NODE_AXIS)),
         # `assigned` is replicated by construction (every chip replays the
         # same decision from the same gathered candidates); the static
         # varying-axes check can't see that
         check_vma=False,
     )
-    def _impl(s, elig, cap):
-        n_loc = cap.shape[-1]
+    def _impl(s, elig, room_loc, asked):
+        n_loc = room_loc.shape[-1]
         b_top = min(block_size, n_loc)
+        width = b_top * n_shards  # candidate entries a pod
         shard = jax.lax.axis_index(NODE_AXIS)
         offset = (shard * n_loc).astype(jnp.int32)
         big_hi = jnp.int32(2**31 - 1)
         big_lo = jnp.uint32(2**32 - 1)
         big_idx = jnp.int32(2**30)
         iota_loc = jnp.arange(n_loc, dtype=jnp.int32)
+        row = jnp.arange(block_size, dtype=jnp.int32)
         num_blocks = padded // block_size
         s_hi = s.hi.reshape(num_blocks, block_size, n_loc)
         s_lo = s.lo.reshape(num_blocks, block_size, n_loc)
         elig_b = elig.reshape(num_blocks, block_size, n_loc)
+        asked_b = asked.reshape(num_blocks, block_size, held)
 
-        def block_step(cap, blk):
-            b_hi, b_lo, b_elig = blk
+        def block_step(room_loc, blk):
+            b_hi, b_lo, b_elig, b_asked = blk
             flipped = i64.flip(i64.I64(hi=b_hi, lo=b_lo))  # lex-min = best
-            avail = b_elig & (cap > 0)[None, :]  # [B, n_loc]
+            # [B, n_loc]: every resource covers that pod's own demand
+            avail = b_elig & room_covers(
+                room_loc[:, None, :], b_asked.T[:, :, None], limbs
+            )
 
             def extract(taken, _):
                 ok = avail & ~taken
@@ -264,21 +307,23 @@ def sharded_greedy_assign(
                 )  # [B] local index (n_loc when none)
                 found = jnp.any(ok, axis=-1)  # [B]
                 safe = jnp.minimum(pick, jnp.int32(n_loc - 1))
-                row = jnp.arange(block_size, dtype=jnp.int32)
-                cand = jnp.stack(
+                cand = jnp.concatenate(
                     [
-                        jnp.where(found, flipped.hi[row, safe], big_hi),
-                        jnp.where(
-                            found,
-                            flipped.lo[row, safe],
-                            big_lo,
-                        ).astype(jnp.int32),
-                        jnp.where(found, safe + offset, big_idx),
-                        jnp.where(found, cap[safe], jnp.int32(0)),
-                        found.astype(jnp.int32),
+                        jnp.stack(
+                            [
+                                jnp.where(found, flipped.hi[row, safe], big_hi),
+                                jnp.where(
+                                    found, flipped.lo[row, safe], big_lo
+                                ).astype(jnp.int32),
+                                jnp.where(found, safe + offset, big_idx),
+                                found.astype(jnp.int32),
+                            ],
+                            axis=-1,
+                        ),
+                        jnp.where(found[:, None], room_loc[:, safe].T, 0),
                     ],
                     axis=-1,
-                )  # [B, 5]
+                )  # [B, 4 + held]
                 taken = taken | (
                     found[:, None] & (iota_loc[None, :] == safe[:, None])
                 )
@@ -289,26 +334,30 @@ def sharded_greedy_assign(
                 jnp.zeros_like(avail),
                 None,
                 length=b_top,
-            )  # [b_top, B, 5]
-            payload = jnp.transpose(cands, (1, 0, 2))  # [B, b_top, 5]
-            gathered = jax.lax.all_gather(payload, NODE_AXIS)  # [D, B, b_top, 5]
+            )  # [b_top, B, 4 + held]
+            payload = jnp.transpose(cands, (1, 0, 2))  # [B, b_top, 4 + held]
+            # [D, B, b_top, 4 + held]
+            gathered = jax.lax.all_gather(payload, NODE_AXIS)
             merged = jnp.transpose(gathered, (1, 0, 2, 3)).reshape(
-                block_size, n_shards * b_top, 5
+                block_size, width, 4 + held
             )
             c_hi = merged[..., 0]
             c_lo = merged[..., 1].astype(jnp.uint32)
             c_idx = merged[..., 2]
-            c_cap = merged[..., 3]
-            c_valid = merged[..., 4] > 0
+            c_valid = merged[..., 3] > 0
+            # [held, B * width]: the room of every entry's node as the
+            # replay goes; entries of one node always hold one value
+            c_room = jnp.transpose(merged[..., 4:], (2, 0, 1)).reshape(
+                held, block_size * width
+            )
+            flat_idx = c_idx.reshape(-1)
 
-            def replay(chosen, pod):
-                step_i, f_hi, f_lo, idx, cap0, valid = pod
-                booked = jnp.sum(
-                    (chosen[:, None] == idx[None, :]) & (chosen >= 0)[:, None],
-                    axis=0,
-                    dtype=jnp.int32,
+            def replay(c_room, pod):
+                step_i, f_hi, f_lo, idx, valid, need = pod
+                mine = jax.lax.dynamic_slice_in_dim(
+                    c_room, step_i * width, width, axis=1
                 )
-                feas = valid & (cap0 - booked > 0)
+                feas = valid & room_covers(mine, need[:, None], limbs)
                 hi = jnp.where(feas, f_hi, big_hi)
                 m_hi = jnp.min(hi)
                 on_hi = feas & (f_hi == m_hi)
@@ -317,34 +366,40 @@ def sharded_greedy_assign(
                 on_lo = on_hi & (f_lo == m_lo)
                 winner = jnp.min(jnp.where(on_lo, idx, big_idx))
                 choice = jnp.where(jnp.any(feas), winner, UNASSIGNED)
-                chosen = chosen.at[step_i].set(choice)
-                return chosen, choice
+                c_room = room_less(
+                    c_room, need[:, None], flat_idx == choice, limbs
+                )
+                return c_room, choice
 
-            init = jnp.full(block_size, UNASSIGNED, dtype=jnp.int32)
-            _, choices = jax.lax.scan(
+            c_room, choices = jax.lax.scan(
                 replay,
-                init,
-                (
-                    jnp.arange(block_size, dtype=jnp.int32),
-                    c_hi,
-                    c_lo,
-                    c_idx,
-                    c_cap,
-                    c_valid,
-                ),
+                c_room,
+                (row, c_hi, c_lo, c_idx, c_valid, b_asked),
             )
+            # each pod's chosen node's room after the whole block, from the
+            # first entry of its own list that names it
+            c_room = c_room.reshape(held, block_size, width)
+            at = jnp.argmax(c_idx == choices[:, None], axis=-1)  # [B]
+            left = c_room[:, row, at]  # [held, B]
             mine = (choices >= offset) & (choices < offset + n_loc)
-            local = jnp.where(mine, choices - offset, jnp.int32(n_loc))
-            delta = jnp.sum(
-                jax.nn.one_hot(local, n_loc, dtype=cap.dtype), axis=0
-            )  # out-of-range rows are all-zero
-            return cap - delta, choices
+            hit = mine[:, None] & (
+                iota_loc[None, :] == (choices - offset)[:, None]
+            )  # [B, n_loc]
+            chosen_room = jnp.max(
+                jnp.where(hit[None], left[:, :, None], jnp.int32(-1)), axis=1
+            )  # [held, n_loc]
+            room_loc = jnp.where(jnp.any(hit, axis=0), chosen_room, room_loc)
+            return room_loc, choices
 
-        cap_left, chosen = jax.lax.scan(block_step, cap, (s_hi, s_lo, elig_b))
-        return chosen.reshape(padded), cap_left
+        room_left, chosen = jax.lax.scan(
+            block_step, room_loc, (s_hi, s_lo, elig_b, asked_b)
+        )
+        return chosen.reshape(padded), room_left
 
-    assigned, cap_left = _impl(score, eligible, capacity)
-    return assigned[:num_pods], cap_left
+    assigned, room_left = _impl(score, eligible, room, demand)
+    if counted:
+        room_left = room_left[0]
+    return assigned[:num_pods], room_left
 
 
 def sharded_auction_assign(

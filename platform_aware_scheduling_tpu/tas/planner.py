@@ -37,11 +37,14 @@ pod's own demand.  Which form the solve runs follows from the pending set:
   down: the comparisons are kube-scheduler's NodeResourcesFit's own, on
   int64 quantities.
 
-The mesh form (``--batchPlannerDevices`` > 1) and ``--batchSolver sinkhorn``
-take a count only: a replan of unlike pods there counts every pod as the
-LARGEST request of the set (never an overcommit, not exact; such a plan
-names a node the pod's own rule ranks lower once the true room would still
-take it) and counts itself in ``pas_planner_conservative_room_total``.
+The mesh form (``--batchPlannerDevices`` > 1) runs both forms, as one
+device does: unlike pods book their own vectors there too, and such a
+replan counts itself in ``pas_planner_mesh_demand_solves_total``.  Only
+``--batchSolver sinkhorn`` takes a count alone: a replan of unlike pods
+there counts every pod as the LARGEST request of the set (never an
+overcommit, not exact; such a plan names a node the pod's own rule ranks
+lower once the true room would still take it) and counts itself in
+``pas_planner_conservative_room_total``.
 
 Shapes: the pending set is padded to a few fixed sizes (powers of two
 from :data:`PAD_FLOOR`), so a draining backlog runs a handful of compiled
@@ -54,7 +57,8 @@ serves.  A plan whose version is not the mirror's is never served.
 
 More than one device (``--batchPlannerDevices=n``, n > 1): the solve runs
 node-sharded over a mesh of the first n devices.  A replan then places the
-mirror's ``[M, N]`` view and the room vector split over the nodes (stage
+mirror's ``[M, N]`` view and the room (a count or ``[L, R, N]`` rows) split
+over the nodes, the demand columns on every device (stage
 ``plan.place``), makes the candidate mask and every other ``[P, N]`` array
 on the mesh so that none is ever whole on one device, and reads back
 ``node_for_pod`` alone.  The plan is the one-device plan, pod for pod.
@@ -193,7 +197,7 @@ class BatchPlanner:
         ``node_capacity`` is only the fallback for nodes whose allocatable
         hasn't been observed (``node_capacity`` − bound pods); observed
         nodes have the room kube-scheduler's NodeResourcesFit leaves
-        (:meth:`_room`), fed by :meth:`node_changed` /
+        (:meth:`_room`, :meth:`_room_rows`), fed by :meth:`node_changed` /
         :meth:`pod_observed` (wired to informers by :meth:`watch`)."""
         self.cache = cache
         self.mirror = mirror
@@ -228,17 +232,25 @@ class BatchPlanner:
                 _candidate_mask.__wrapped__, static_argnums=(0, 1),
                 out_shardings=by_node,
             )
-            self._placement = (
-                ClusterState(
-                    metric_values=by_node, metric_present=by_node,
-                    dontschedule=everywhere,
-                    capacity=NamedSharding(mesh, PartitionSpec(NODE_AXIS)),
-                ),
-                PendingPods(
-                    metric_row=everywhere, op_id=everywhere,
-                    candidates=by_node, policy=everywhere,
-                ),
-            )
+            # keyed by the room's form: a count a node, or [L, R, N] rows
+            # with each pod's [L, R] demand on every device
+            room_spec = {False: PartitionSpec(NODE_AXIS),
+                         True: PartitionSpec(None, None, NODE_AXIS)}
+            self._placement = {
+                vectors: (
+                    ClusterState(
+                        metric_values=by_node, metric_present=by_node,
+                        dontschedule=everywhere,
+                        capacity=NamedSharding(mesh, room_spec[vectors]),
+                    ),
+                    PendingPods(
+                        metric_row=everywhere, op_id=everywhere,
+                        candidates=by_node, policy=everywhere,
+                        demand=everywhere if vectors else None,
+                    ),
+                )
+                for vectors in (False, True)
+            }
             trace.COUNTERS.set_gauge("pas_planner_mesh_devices", devices)
 
     def _mesh_over(self, devices: int):
@@ -346,10 +358,10 @@ class BatchPlanner:
         node can still take, as kube-scheduler's NodeResourcesFit counts
         it — the least, over pods, cpu and memory, of ``free`` over the
         request, floored.  EXACT when the pending pods all ask for
-        ``need``, which is when a one-device greedy replan uses it; with
+        ``need``, which is when a greedy replan uses it; with
         ``need`` the largest request of a set of unlike pods it is the
-        conservative room of the mesh and sinkhorn forms: never an
-        overcommit, not exact."""
+        conservative room of the sinkhorn form: never an overcommit, not
+        exact."""
         room = np.zeros(n_cap, dtype=np.int64)
         if len(free):
             asked = np.array(need, dtype=np.int64)
@@ -448,11 +460,13 @@ class BatchPlanner:
             self._published = (plan, view.version)
         counters = trace.COUNTERS
         counters.inc("pas_planner_replans_total")
+        took = time.perf_counter() - began
         if batch.demand is not None:
             counters.inc("pas_planner_demand_solves_total")
-        counters.inc(
-            "pas_planner_replan_seconds_total", time.perf_counter() - began
-        )
+            if self.mesh is not None:
+                counters.inc("pas_planner_mesh_demand_solves_total")
+                counters.inc("pas_planner_mesh_demand_seconds_total", took)
+        counters.inc("pas_planner_replan_seconds_total", took)
         counters.set_gauge("pas_planner_pending_pods", p)
         if timer is not None:
             timer.mark("encode")
@@ -467,9 +481,10 @@ class BatchPlanner:
     def _place(self, state: ClusterState, batch: PendingPods):
         """The replan's operands on the mesh (``self._placement``): the
         view's ``[M, N]`` values and presence go device to device, the room
-        vector and the per-pod columns up; ``candidates`` was born split
+        and the per-pod columns up; ``candidates`` was born split
         (``self._mask``)."""
-        return jax.device_put((state, batch), self._placement)
+        placement = self._placement[batch.demand is not None]
+        return jax.device_put((state, batch), placement)
 
     def _mesh_step(self, state: ClusterState, batch: PendingPods, timer=None):
         """``node_for_pod`` of the mesh solve, ready on the devices."""
@@ -534,9 +549,9 @@ class BatchPlanner:
             classes = len(set(zip(cpus, mems)))
             trace.COUNTERS.set_gauge("pas_planner_demand_classes", classes)
             demand = None
-            if classes == 1 or self.mesh is not None or self.solver == "sinkhorn":
-                # alike pods: the count is exact.  Unlike pods on the mesh
-                # or under sinkhorn: every pod counted as the largest
+            if classes == 1 or self.solver == "sinkhorn":
+                # alike pods: the count is exact.  Unlike pods under
+                # sinkhorn: every pod counted as the largest
                 if classes > 1:
                     trace.COUNTERS.inc("pas_planner_conservative_room_total")
                 room = self._room(free, (1000, max(cpus), max(mems)), n_cap)

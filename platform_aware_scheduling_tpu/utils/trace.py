@@ -133,9 +133,11 @@ declare("pas_planner_publish_seconds_total", "counter", "Replan seconds building
 declare("pas_planner_place_seconds_total", "counter", "Replan seconds placing the solve's operands on the mesh: the view's metric matrix and the room vector split over the nodes, the rest on every device.")
 declare("pas_planner_mesh_devices", "gauge", "Devices the batch planner's solve spans (set only when more than one).")
 declare("pas_planner_mesh_solves_total", "counter", "Replans solved node-sharded over the mesh.")
+declare("pas_planner_mesh_demand_solves_total", "counter", "Of the mesh's replans, those in which every pod booked its own pods, cpu and memory: the pending pods asked for unlike amounts.")
+declare("pas_planner_mesh_demand_seconds_total", "counter", "Replan seconds of those mesh replans, snapshot to published plan.")
 declare("pas_planner_demand_solves_total", "counter", "Replans solved with per-pod demands: the pending pods asked for unlike amounts, and each booked its own pods, cpu and memory (a replan of alike pods books one unit a pod and does not count).")
 declare("pas_planner_demand_classes", "gauge", "Distinct (cpu, memory) request vectors in the last replan's pending set.")
-declare("pas_planner_conservative_room_total", "counter", "Replans of unlike pods solved with every pod counted as the largest request in the set (never an overcommit, not exact): the mesh and the sinkhorn forms, which take no per-pod demand.")
+declare("pas_planner_conservative_room_total", "counter", "Replans of unlike pods solved with every pod counted as the largest request in the set (never an overcommit, not exact): the sinkhorn form alone, which takes no per-pod demand.")
 declare("pas_planner_pending_pods", "gauge", "Pending pods the last replan solved.")
 declare("pas_planner_promoted_total", "counter", "Prioritize answers that carried a current plan's node to rank 1.")
 declare("pas_planner_reordered_total", "counter", "Of those, answers in which the plan's node was moved past a candidate the ordinal ranking put first: the answers the plan changed.")

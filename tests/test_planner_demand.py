@@ -21,6 +21,8 @@ from platform_aware_scheduling_tpu.ops.assign import (
     greedy_assign_kernel,
 )
 from platform_aware_scheduling_tpu.ops.pallas_assign import greedy_assign_pallas
+from platform_aware_scheduling_tpu.parallel.mesh import make_mesh
+from platform_aware_scheduling_tpu.parallel.sharded import sharded_greedy_assign
 from platform_aware_scheduling_tpu.ops.state import TensorStateMirror
 from platform_aware_scheduling_tpu.tas import planner as planner_module
 from platform_aware_scheduling_tpu.tas.cache import AutoUpdatingCache
@@ -31,7 +33,10 @@ from test_planner_batch import build, metric_info, moved, pending, write_policy
 
 GI = 1 << 30
 NEW_COUNTERS = ("pas_planner_demand_solves_total", "pas_planner_room_seconds_total",
-                "pas_planner_conservative_room_total", "pas_planner_replans_total")
+                "pas_planner_conservative_room_total", "pas_planner_replans_total",
+                "pas_planner_mesh_demand_solves_total",
+                "pas_planner_mesh_demand_seconds_total")
+DEVICES = 4  # of the conftest's eight CPU devices: tas-40k's mesh
 
 
 def now():
@@ -122,6 +127,100 @@ def test_a_count_is_a_demand_of_one_on_one_resource():
                           np.asarray(vector.node_for_pod))
     assert np.array_equal(np.asarray(count.capacity_left),
                           np.asarray(vector.capacity_left)[0])
+
+
+def four_devices():
+    if len(jax.devices()) < DEVICES:
+        pytest.skip("needs four of the virtual CPU devices")
+    return make_mesh(n_node_shards=DEVICES, devices=jax.devices()[:DEVICES])
+
+
+def over_the_mesh(score, eligible, room, demand, limbs, block_size, lanes=64):
+    """(node per pod, room left [limbs * R, nodes]) of the mesh solve, the
+    node axis padded to ``lanes`` with nodes that have no room and take no
+    pod, as the mirror pads it."""
+    pods, nodes = eligible.shape
+    wide = np.zeros((pods, lanes), dtype=np.int64)
+    wide[:, :nodes] = score
+    allowed = np.zeros((pods, lanes), dtype=bool)
+    allowed[:, :nodes] = eligible
+    rows = np.zeros((room.shape[0], lanes), dtype=np.int64)
+    rows[:, :nodes] = room
+    got, left = sharded_greedy_assign(
+        four_devices(), i64.from_int64(wide), jnp.asarray(allowed),
+        jnp.asarray(as_limbs(rows, limbs, 0)),
+        demand=jnp.asarray(as_limbs(demand, limbs, 1)), limbs=limbs,
+        block_size=block_size)
+    left = np.asarray(left).astype(np.int64)
+    if limbs == 2:
+        r = room.shape[0]
+        assert left.min() >= 0 and left[:r].max() <= LIMB_MASK
+        left = left[:r] + (left[r:] << LIMB_BITS)
+    assert not left[:, nodes:].any()
+    return np.asarray(got), left[:, :nodes]
+
+
+@pytest.mark.parametrize("block_size", [4, 32])
+@pytest.mark.parametrize("limbs", [1, 2])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_the_mesh_books_each_pods_own_vector_as_the_plain_loop(
+        seed, limbs, block_size):
+    """41 pods (no multiple of either block) over 50 nodes in 64 lanes, 16
+    a device: under a block of 32, and no multiple of it."""
+    score, eligible, room, demand = operands(seed, limbs)
+    want, want_left = plain_assign(score, eligible, room, demand)
+    got, left = over_the_mesh(score, eligible, room, demand, limbs, block_size)
+    assert np.array_equal(got, want)
+    assert np.array_equal(left, want_left)
+    assert (want < 0).any() and (want >= 0).any()  # room does run out
+
+
+@pytest.mark.parametrize("limbs", [1, 2])
+def test_a_pods_best_node_is_the_last_of_its_shards_top_b(limbs):
+    """The top-B argument, at its edge.  One block of B = 4 pods; every pod
+    ranks shard 0's nodes 0, 1, 2, ... first.  Pods 0-2 each take all the
+    cpu of one of nodes 0-2 and leave its memory and pod slots; pod 3 asks
+    for more cpu than any node of the other shards has, so its best node at
+    its turn is node 3: shard 0's B-th candidate at block start, after B - 1
+    bookings in the block made the first three infeasible for it and for it
+    alone."""
+    block, nodes = 4, 64  # 16 lanes a device
+    scale = 1 if limbs == 1 else (1 << 33) + 7
+    score = np.tile(np.arange(nodes, 0, -1, dtype=np.int64), (block, 1))
+    eligible = np.ones((block, nodes), dtype=bool)
+    room = np.zeros((3, nodes), dtype=np.int64)  # pods, cpu, memory
+    room[0], room[2] = 10, 64
+    room[1, :16], room[1, 16:] = 8, 2  # shard 0 has the cpu
+    room *= scale
+    demand = np.array([[1, 8, 1], [1, 8, 1], [1, 8, 1], [1, 4, 32]],
+                      dtype=np.int64) * scale
+    want, want_left = plain_assign(score, eligible, room, demand)
+    assert want.tolist() == [0, 1, 2, 3]
+    got, left = over_the_mesh(score, eligible, room, demand, limbs, block)
+    assert got.tolist() == want.tolist()
+    assert np.array_equal(left, want_left)
+    # the same pods in blocks of one: the plain sequential solve
+    alone, _ = over_the_mesh(score, eligible, room, demand, limbs, 1)
+    assert alone.tolist() == want.tolist()
+
+
+def test_a_count_on_the_mesh_is_a_demand_of_one():
+    """Alike pods keep tas-40k's plan: the count form and a demand of one on
+    one resource give the same plan and room on the mesh, and the
+    one-device scan's."""
+    score, eligible, room, _demand = operands(4, 1, pods=70, nodes=64,
+                                              resources=1)
+    keys, allowed = i64.from_int64(score), jnp.asarray(eligible)
+    count = jnp.asarray(room[0].astype(np.int32))
+    want = greedy_assign_kernel(keys, allowed, count)
+    got, left = sharded_greedy_assign(four_devices(), keys, allowed, count)
+    ones = np.ones((70, 1), dtype=np.int64)
+    vector, vector_left = over_the_mesh(score, eligible, room, ones, 1, 32)
+    assert np.array_equal(np.asarray(got), np.asarray(want.node_for_pod))
+    assert np.array_equal(np.asarray(left), np.asarray(want.capacity_left))
+    assert left.shape == (64,)
+    assert np.array_equal(vector, np.asarray(got))
+    assert np.array_equal(vector_left[0], np.asarray(left))
 
 
 @pytest.mark.parametrize("assigner", ["scan", "pallas-interpret"])
@@ -340,41 +439,97 @@ def test_a_drain_of_unlike_pods_compiles_no_more_than_the_padded_sizes(monkeypat
     assert step.cache_size() == after_first  # the drain compiled nothing
 
 
-# -- the forms that take a count only -------------------------------------------------
+def test_a_drain_of_unlike_pods_on_the_mesh_compiles_no_more_than_the_padded_sizes(
+        monkeypatch):
+    """``_warm_smaller`` compiles the mesh's demand shapes below the first
+    size, placed as a replan places them: the drain compiles nothing."""
+    four_devices()
+    monkeypatch.setattr(planner_module, "PAD_FLOOR", 8)
+    cache = AutoUpdatingCache()
+    mirror = TensorStateMirror()
+    mirror.attach(cache)
+    planner = BatchPlanner(cache, mirror, node_capacity=1000, devices=DEVICES)
+    write_policy(cache, "drain-mix", rule("m", "GreaterThan", 0),
+                 [rule("m", "GreaterThan", 900)])
+    cache.write_metric("m", metric_info(**{f"n{i}": 100 - i for i in range(6)}))
+    pods = [pending(f"p{i:03d}", "drain-mix", cpu=f"{1 + i % 3}")
+            for i in range(100)]
+    for pod in pods:
+        planner.pod_added(pod)
+    step = batch_scheduler._mesh_scheduling_step
+    before = step.cache_size()
+    assert planner.replan() == 100
+    assert 1 <= step.cache_size() - before <= 5  # 128, 64, 32, 16, 8
+    after_first = step.cache_size()
+    for left in (65, 33, 17, 9, 8, 3):
+        for pod in pods[: 100 - left]:
+            planner.pod_bound(pod)
+        assert planner.replan() == left
+    assert step.cache_size() == after_first
 
 
-def conservative_plan(nodes, bound, pods, values, forbidden_over, fallback):
-    """The plain loop with every pod asking for the largest request."""
-    most = {r: max((milli(q.get(r, 0)) for _, q in pods), default=0)
-            for r in ("cpu", "memory")}
-    largest = {r: f"{v}m" for r, v in most.items() if v}
-    return plain_plan(nodes, bound, [(name, largest) for name, _ in pods],
-                      values, forbidden_over, fallback)
+# -- the mesh books each pod's own vector; sinkhorn takes a count only ------------------
 
 
-@pytest.mark.parametrize("form", ["mesh", "sinkhorn"])
+@pytest.mark.parametrize("seed", [41, 11, 12, 13, 14])
+def test_the_mesh_plans_unlike_pods_as_the_plain_reference(seed, monkeypatch):
+    """``BatchPlanner(devices=4)`` over the worlds of
+    ``test_the_plan_for_unlike_pods_is_the_plain_references`` (41: whole
+    units, one limb; the others two): each pod books its own vector, the
+    plan is ``plain_plan``'s, and no room is conservative."""
+    four_devices()
+    monkeypatch.setattr(planner_module, "PAD_FLOOR", 64)
+    classes = CLASSES
+    if seed == 41:
+        classes = tuple(c for c in CLASSES if c.get("memory") != str(GI + 1))
+    nodes, values, bound, pods = mixed_world(seed, classes=classes)
+    cache = AutoUpdatingCache()
+    mirror = TensorStateMirror()
+    mirror.attach(cache)
+    planner = BatchPlanner(cache, mirror, node_capacity=4, devices=DEVICES)
+    feed(planner, cache, nodes, values, bound, pods, forbidden_over=200)
+    before = now()
+    want = plain_plan(nodes, bound, pods, values, 200, fallback=4)
+    assert planner.replan() == sum(1 for node in want.values() if node is not None)
+    got = {name: planner.planned_node(pending(name, "mix-pol")) for name, _ in pods}
+    assert got == want
+    assert len(set(want.values())) > 5 and None in want.values()
+    assert moved("pas_planner_mesh_demand_solves_total", before) == 1
+    assert moved("pas_planner_mesh_demand_seconds_total", before) > 0
+    assert moved("pas_planner_demand_solves_total", before) == 1
+    assert moved("pas_planner_conservative_room_total", before) == 0
+    # the room rows split over the nodes, the demand on every device
+    state, batch, *_ = planner._snapshot()
+    state, batch = planner._place(state, batch)
+    assert batch.demand.shape[1:] == ((1, 3) if seed == 41 else (2, 3))
+    assert state.capacity.sharding.spec == (None, None, "nodes")
+    assert batch.demand.sharding.is_fully_replicated
+    # the one-device planner's plan is the same, and neither counts the mesh
+    one = BatchPlanner(cache, mirror, node_capacity=4)
+    feed(one, cache, nodes, values, bound, pods, forbidden_over=200)
+    before = now()
+    one.replan()
+    assert one._published[0] == planner._published[0]
+    assert moved("pas_planner_mesh_demand_solves_total", before) == 0
+
+
+@pytest.mark.parametrize("form", ["sinkhorn"])
 def test_a_count_only_form_counts_unlike_pods_as_the_largest_and_says_so(
         form, monkeypatch):
+    """Sinkhorn, the one form left that takes a count only."""
     monkeypatch.setattr(planner_module, "PAD_FLOOR", 64)
     whole = tuple(c for c in CLASSES if c.get("memory") != str(GI + 1))
     nodes, values, bound, pods = mixed_world(41, classes=whole, n_pods=90)
     cache = AutoUpdatingCache()
     mirror = TensorStateMirror()
     mirror.attach(cache)
-    if form == "mesh":
-        assert len(jax.devices()) >= 4
-        planner = BatchPlanner(cache, mirror, node_capacity=4, devices=4)
-    else:
-        planner = BatchPlanner(cache, mirror, node_capacity=4, solver="sinkhorn")
+    planner = BatchPlanner(cache, mirror, node_capacity=4, solver="sinkhorn")
     feed(planner, cache, nodes, values, bound, pods, forbidden_over=200)
     before = now()
     planner.replan()
     assert moved("pas_planner_conservative_room_total", before) == 1
     assert moved("pas_planner_demand_solves_total", before) == 0
     got = {name: planner.planned_node(pending(name, "mix-pol")) for name, _ in pods}
-    if form == "mesh":
-        # the one-device greedy plan over the same conservative room
-        assert got == conservative_plan(nodes, bound, pods, values, 200, 4)
     # never an overcommit: the true requests fit where the plan put them
     held = {}
     for (name, requests), node in zip(pods, got.values()):
@@ -402,17 +557,21 @@ def test_the_new_families_are_declared_with_the_stage():
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A described v5e chip (nothing attached): the TPU's own compiler says
-    here what it would say there — a misaligned block, an SMEM operand it
-    will not take, too much VMEM."""
+def v5e_2x2():
+    """Four described v5e chips (nothing attached): the TPU's own compiler
+    says here what it would say there — a misaligned block, an SMEM operand
+    it will not take, too much VMEM, a program too large for a chip."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as exc:  # noqa: BLE001 — no compiler here: nothing to check
         pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @pytest.mark.parametrize("limbs", [1, 2])
@@ -433,3 +592,49 @@ def test_the_demand_kernel_compiles_for_a_v5e_at_the_cells_widths(one_chip, limb
         spec((rows, held), jnp.int32)).compile()
     # the [limbs * R, n] room and the block's score rows fit the 16 MB of VMEM
     assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+def test_the_mesh_demand_solve_compiles_for_four_v5e_chips_at_the_cells_size(v5e_2x2):
+    """``alibaba-colo-40k``'s solve: 32,768 rows over 65,536 lanes split on
+    four chips, room ``[1, 3, N]`` split with them, demand on every chip.
+    It fits a chip (5.37 GB: 0.54 of arguments, 4.83 of temporaries) where
+    the whole ``[P, N]`` state is 32 GB, and the block loop's one gather a
+    block is there."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from platform_aware_scheduling_tpu.ops.rules import RuleSet
+    from platform_aware_scheduling_tpu.parallel.mesh import make_mesh
+
+    rows, lanes, metrics, policies, rules = 32768, 65536, 4, 8, 8
+    mesh = make_mesh(n_node_shards=DEVICES, devices=v5e_2x2.devices[:DEVICES])
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, PartitionSpec(*axes)))
+
+    def split(shape, dtype):
+        return spec(shape, dtype, *(None,) * (len(shape) - 1), "nodes")
+
+    def copied(shape, dtype):
+        return spec(shape, dtype)
+
+    state = batch_scheduler.ClusterState(
+        metric_values=i64.I64(hi=split((metrics, lanes), jnp.int32),
+                              lo=split((metrics, lanes), jnp.uint32)),
+        metric_present=split((metrics, lanes), jnp.bool_),
+        dontschedule=RuleSet(
+            metric_row=copied((policies, rules), jnp.int32),
+            op_id=copied((policies, rules), jnp.int32),
+            target=i64.I64(hi=copied((policies, rules), jnp.int32),
+                           lo=copied((policies, rules), jnp.uint32)),
+            active=copied((policies, rules), jnp.bool_)),
+        capacity=split((1, 3, lanes), jnp.int32))
+    pods = batch_scheduler.PendingPods(
+        metric_row=copied((rows,), jnp.int32), op_id=copied((rows,), jnp.int32),
+        candidates=split((rows, lanes), jnp.bool_),
+        policy=copied((rows,), jnp.int32), demand=copied((rows, 1, 3), jnp.int32))
+    compiled = batch_scheduler._mesh_scheduling_step.lower(
+        state, pods, mesh=mesh).compile()
+    used = compiled.memory_analysis()
+    assert used.argument_size_in_bytes + used.temp_size_in_bytes < 8 << 30
+    assert "all-gather" in compiled.as_text()
