@@ -159,12 +159,12 @@ def main(argv=None) -> int:
     def counter_inc():
         trace.COUNTERS.inc("pas_filter_cache_miss_total")
 
-    from platform_aware_scheduling_tpu.extender.server import stamped_reads
+    from platform_aware_scheduling_tpu.extender.server import native_io
 
     left, right = socket.socketpair()
     left.settimeout(5.0)
     fd = left.fileno()
-    stamped = getattr(stamped_reads(), "recv_stamped", None)
+    stamped = getattr(native_io(), "recv_stamped", None)
 
     def recv_stamped():
         right.send(b"x")

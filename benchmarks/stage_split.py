@@ -18,11 +18,13 @@ Who held the interpreter (PR 37), which the benchmark cannot print yet: per
 verb the mean ``arrive`` (recv returned -> GIL held), thread CPU over wall
 time, ``read_gil_ms``, ``read_calls`` and ``read_recvs`` (PR 38: the reads
 made from Python, each one release of the GIL, and the kernel's recvs inside
-a body's one native read) and each sampled stage's CPU beside its wall
-milliseconds (``recent``); over EVERY verb span of the window, by a span
-observer of its own (``interpreter``): the share that carried a stamp, the
-arrival wait's mean and tail, the mean ``read`` with its ``read_gil_ms`` and
-``read_calls``, how far arrive + read + handle + write_arm + write tile the
+a body's one native read), ``write_sends`` and ``write_releases`` (PR 40: the
+kernel's sends of an answer, and whether it gave the GIL away) and each
+sampled stage's CPU beside its wall milliseconds (``recent``); over EVERY
+verb span of the window, by a span observer of its own (``interpreter``):
+the share that carried a stamp, the arrival wait's mean and tail, the mean
+``read`` with its ``read_gil_ms`` and ``read_calls``, the mean ``write``, its
+share of the verb and its releases a verb, how far arrive + read + handle + write_arm + write tile the
 sampled spans (``write_arm`` where the socket's time-out is still armed a
 request: TLS, no ``_wirec``), and the same for the verbs of the STALLED
 cycles (over 1.5 medians on the generator's clock — one clock with the
@@ -124,7 +126,8 @@ def summarize(spans: list) -> dict:
                                    if s["name"] == "arrive") for e in read)
         # span attributes: [mean over the spans that carry it, spans]
         attrs = {}
-        for name in ("read_gil_ms", "read_calls", "read_recvs"):
+        for name in ("read_gil_ms", "read_calls", "read_recvs",
+                     "write_sends", "write_releases"):
             values = [e["attrs"][name] for e in entries if name in e["attrs"]]
             attrs[name] = [sum(values) / len(values), len(values)] if values else None
         out[verb] = {
@@ -147,18 +150,21 @@ def watch_interpreter(trace) -> list:
     """A span observer that keeps, of every served verb from now on,
     (first byte there, wall s, arrive s or None, (cpu s, the wall s they
     are a share of) or None, sampled, share of the span its top stages
-    tile or None, (read s, read_gil_ms or None, read_calls or None))."""
+    tile or None, (read s, read_gil_ms or None, read_calls or None),
+    (write s, write_releases or None))."""
     kept = []
 
     def observe(span) -> None:
         if not span.name.startswith("POST /scheduler/"):
             return
-        arrive, tiled, read = None, 0.0, 0.0
+        arrive, tiled, read, write = None, 0.0, 0.0, 0.0
         for name, _start, seconds in span.stages:
             if name == "arrive":
                 arrive = seconds
             elif name == "read":
                 read = seconds
+            elif name == "write":
+                write = seconds
             if name in TOP:
                 tiled += seconds
         cpu = getattr(span, "cpu_s", None)
@@ -168,6 +174,7 @@ def watch_interpreter(trace) -> list:
             span.sampled,
             tiled / span.duration_s if span.sampled and span.duration_s else None,
             (read, span.attrs.get("read_gil_ms"), span.attrs.get("read_calls")),
+            (write, span.attrs.get("write_releases")),
         ))
 
     trace.SPAN_OBSERVERS.append(observe)
@@ -205,6 +212,14 @@ def interpreter_split(kept: list, window: dict, cycle_span) -> dict:
             out["read_gil_ms"] = sum(gil) / len(rows) if gil else None
             out["read_gil_seconds"] = sum(gil) * 1e-3 if gil else None
             out["read_calls"] = sum(calls) / len(calls) if calls else None
+            # the way out: mean write, its share of the verbs' wall time,
+            # and the releases of the GIL a verb (a parent's carry none)
+            releases = [r[7][1] for r in rows if r[7][1] is not None]
+            out["write_ms"] = sum(r[7][0] for r in rows) / len(rows) * 1e3
+            out["write_pct"] = (100.0 * sum(r[7][0] for r in rows) / wall
+                                if wall else None)
+            out["write_releases"] = (sum(releases) / len(releases)
+                                     if releases else None)
         if waits:
             out["arrive_ms"] = {
                 "mean": sum(waits) / len(waits) * 1e3,

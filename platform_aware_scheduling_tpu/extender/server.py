@@ -231,8 +231,9 @@ def parse_request_head(head: bytes):
     return method, path, version, headers, lowered, length
 
 
-def render_response(response: HTTPResponse, close: bool) -> bytes:
-    """Status line + headers + body as one buffer (one sendall/write)."""
+def render_head(response: HTTPResponse, close: bool) -> bytes:
+    """Status line + headers + the blank line: all of an answer but its
+    body (which ``send_answer`` sends from where it lies)."""
     reason = _STATUS_REASON.get(response.status, "Unknown")
     out = [f"HTTP/1.1 {response.status} {reason}\r\n".encode("ascii")]
     for k, v in response.headers.items():
@@ -241,8 +242,12 @@ def render_response(response: HTTPResponse, close: bool) -> bytes:
     if close:
         out.append(b"Connection: close\r\n")
     out.append(b"\r\n")
-    out.append(response.body)
     return b"".join(out)
+
+
+def render_response(response: HTTPResponse, close: bool) -> bytes:
+    """Status line + headers + body as one buffer (one sendall/write)."""
+    return render_head(response, close) + response.body
 
 
 def render_simple(
@@ -263,18 +268,20 @@ def render_simple(
     )
 
 
-def stamped_reads():
-    """``_wirec`` where it has the two stamped reads and their stamps are
-    on the spans' clock, else None: ``recv_stamped`` for a request's head,
-    ``recv_body`` for a body that did not come with it (native/wirec.c).
-    Each says when its bytes were there and when this thread held the
-    interpreter again, and carries its own time-out.  The helpers stamp
-    ``CLOCK_MONOTONIC``; spans run on ``time.perf_counter()``, so they are
-    used only where that is the same clock."""
+def native_io():
+    """``_wirec`` where it has the socket helpers and their stamps are on
+    the spans' clock, else None: ``recv_stamped`` for a request's head,
+    ``recv_body`` for a body that did not come with it, ``send_answer``
+    for every write (native/wirec.c).  The reads say when their bytes were
+    there and when this thread held the interpreter again; each helper
+    carries its own time-out.  The helpers stamp ``CLOCK_MONOTONIC``; spans
+    run on ``time.perf_counter()``, so they are used only where that is the
+    same clock."""
     if "CLOCK_MONOTONIC" not in time.get_clock_info("perf_counter").implementation:
         return None
     wirec = get_wirec()
-    if hasattr(wirec, "recv_stamped") and hasattr(wirec, "recv_body"):
+    if all(hasattr(wirec, name)
+           for name in ("recv_stamped", "recv_body", "send_answer")):
         return wirec
     return None
 
@@ -284,7 +291,7 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
 
     Reads each request with a single rolling buffer (no per-line reads),
     dispatches through ``route`` (set by the enclosing Server), and writes
-    status line + headers + body with one ``sendall``.  Supports
+    status line + headers + body as one answer.  Supports
     keep-alive, pipelined requests, and ``Expect: 100-continue``.  Read
     and write timeouts mirror the reference server's 5 s / 10 s
     (scheduler.go:136-137): 5 s without a byte on the way in, 10 s for
@@ -295,15 +302,18 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
     head and once for a body that did not come with it: the head through
     ``recv_stamped``, the body through ONE ``recv_body``, which fills a
     ``bytes`` of ``Content-Length`` in place however many ``recv`` the
-    kernel needs.  Both carry the read time-out themselves, so the
-    socket's own is armed once a connection, for ``sendall``.  The span
-    begins where the request's first byte WAS THERE, not where this
-    thread next ran: the stage ``arrive`` is the wait for the interpreter
-    between the two.  A TLS connection, or a process without ``_wirec``,
-    reads with ``sock.recv`` / ``sock.recv_into`` (one release a call),
-    arms the socket's time-out before the head and before the answer
-    (stage ``write_arm``), and records no ``arrive``; the answers are the
-    same bytes."""
+    kernel needs.  An answer leaves through ONE ``send_answer``: head and
+    body in one ``sendmsg`` with the interpreter HELD, given away once
+    only where the kernel does not take it whole (span attributes
+    ``write_sends``, ``write_releases``).  Each helper carries its own
+    time-out, so the socket's is never armed.  The span begins where the
+    request's first byte WAS THERE, not where this thread next ran: the
+    stage ``arrive`` is the wait for the interpreter between the two.  A
+    TLS connection, or a process without ``_wirec``, reads with
+    ``sock.recv`` / ``sock.recv_into`` (one release a call), arms the
+    socket's time-out before the head and before the answer (stage
+    ``write_arm``), writes with ``sendall`` and records no ``arrive``; the
+    answers are the same bytes."""
 
     route = staticmethod(lambda request: HTTPResponse(status=500))
     native = None
@@ -326,12 +336,11 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
             pass
         # an SSLSocket is a socket.socket too: its bytes are not the fd's
         native = type(self).native if type(sock) is socket.socket else None
+        # the native helpers carry their own time-outs and never consult
+        # the socket's, which is left unarmed
         stamped = read_body = None
         if native is not None:
             stamped, read_body = native.recv_stamped, native.recv_body
-            # the native reads carry their own time-out and never consult
-            # the socket's: armed once, for every sendall of the connection
-            sock.settimeout(WRITE_TIMEOUT_S)
         fd = sock.fileno()
         buf = bytearray()
         while True:
@@ -357,7 +366,7 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
             head_end = buf.find(b"\r\n\r\n")
             while head_end < 0:
                 if len(buf) > MAX_HEAD_LENGTH:
-                    self._send_simple(sock, 431, close=True)
+                    self._send_simple(sock, native, 431, close=True)
                     return
                 try:
                     if stamped is not None:
@@ -383,7 +392,7 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
                 buf += chunk
                 head_end = buf.find(b"\r\n\r\n")
             if head_end > MAX_HEAD_LENGTH:
-                self._send_simple(sock, 431, close=True)
+                self._send_simple(sock, native, 431, close=True)
                 return
             head = bytes(buf[:head_end])
             del buf[: head_end + 4]
@@ -392,11 +401,11 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
                     parse_request_head(head)
                 )
             except HeadParseError as exc:
-                self._send_simple(sock, exc.status, close=True)
+                self._send_simple(sock, native, exc.status, close=True)
                 return
             if lowered.get("expect", "").lower() == "100-continue":
                 try:
-                    sock.sendall(b"HTTP/1.1 100 Continue\r\n\r\n")
+                    self._send(sock, native, b"HTTP/1.1 100 Continue\r\n\r\n")
                 except OSError:
                     return
             # -- read the body ----------------------------------------------
@@ -489,7 +498,16 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
             t_write = time.perf_counter()
             cpu_write = None if cpu0 is None else time.thread_time()
             try:
-                sock.sendall(render_response(response, close))
+                if native is not None:
+                    # head and body as they lie: no join copy of the body
+                    sends, released = native.send_answer(
+                        fd, render_head(response, close), response.body,
+                        WRITE_TIMEOUT_S,
+                    )
+                    span.set("write_sends", sends)
+                    span.set("write_releases", released)
+                else:
+                    sock.sendall(render_response(response, close))
             except OSError:
                 span.set("error", "write failed")
                 return
@@ -505,10 +523,20 @@ class _FastHTTPHandler(socketserver.BaseRequestHandler):
                 return
 
     @staticmethod
-    def _send_simple(sock, status: int, close: bool = False) -> None:
+    def _send(sock, native, data: bytes) -> None:
+        """``data`` whole: through ``send_answer`` where the connection
+        writes natively, else ``sendall``."""
+        if native is None:
+            sock.sendall(data)
+        else:
+            native.send_answer(sock.fileno(), data, b"", WRITE_TIMEOUT_S)
+
+    @classmethod
+    def _send_simple(cls, sock, native, status: int, close: bool = False) -> None:
         try:
-            sock.sendall(
-                render_simple(status, close, request_id=trace.new_request_id())
+            cls._send(
+                sock, native,
+                render_simple(status, close, request_id=trace.new_request_id()),
             )
         except OSError:
             pass
@@ -950,7 +978,7 @@ class Server:
         (callers use :meth:`wait_ready` / :meth:`shutdown`).
 
         The connection loop is a slim hand-rolled HTTP/1.1 handler
-        (keep-alive, single-buffer header parse, one sendall per response,
+        (keep-alive, single-buffer header parse, one send per response,
         TCP_NODELAY) rather than http.server's per-line machinery — at 10k
         nodes this layer runs on every request and its cost lands straight
         in p99 (the Go reference gets the equivalent from net/http's
@@ -961,7 +989,7 @@ class Server:
         class Handler(_FastHTTPHandler):
             route = staticmethod(server.route)
             # resolved once, here: loading _wirec may build it
-            native = stamped_reads()
+            native = native_io()
 
         httpd = socketserver.ThreadingTCPServer(
             (host, int(port)), Handler, bind_and_activate=False

@@ -2581,11 +2581,14 @@ static PyObject *wirec_select_encode_universe(PyObject *mod, PyObject *args) {
 }
 
 /* ------------------------------------------------------------------ */
-/* recv_stamped, recv_body: socket reads that say when their bytes were there */
+/* recv_stamped, recv_body: socket reads that say when their bytes were
+ * there; send_answer: an answer out with the GIL held where the kernel
+ * takes it whole */
 
 #include <errno.h>
 #include <poll.h>
 #include <sys/socket.h>
+#include <sys/uio.h>
 #include <time.h>
 
 static double monotonic_now(void) {
@@ -2594,10 +2597,11 @@ static double monotonic_now(void) {
     return (double)ts.tv_sec + (double)ts.tv_nsec * 1e-9;
 }
 
-/* poll(fd, POLLIN) until `deadline` (monotonic_now()'s seconds; for ever
- * where timeout_s < 0): 1 readable, 0 timed out, -1 with errno set.  Touches
- * no Python object: the stamped reads call it with the GIL released. */
-static int wait_readable(int fd, double timeout_s, double deadline) {
+/* poll(fd, events) until `deadline` (monotonic_now()'s seconds; for ever
+ * where timeout_s < 0): 1 ready, 0 timed out, -1 with errno set.  Touches
+ * no Python object: the reads and send_answer call it with the GIL
+ * released. */
+static int wait_ready(int fd, short events, double timeout_s, double deadline) {
     for (;;) {
         int wait_ms = -1;
         if (timeout_s >= 0) {
@@ -2606,7 +2610,7 @@ static int wait_readable(int fd, double timeout_s, double deadline) {
             /* round up: a poll that returns a millisecond early would spin */
             wait_ms = left > 2e6 ? 2000000000 : (int)(left * 1e3 + 0.999);
         }
-        struct pollfd p = {fd, POLLIN, 0};
+        struct pollfd p = {fd, events, 0};
         int ready = poll(&p, 1, wait_ms);
         if (ready > 0) return 1;
         if (ready < 0) {
@@ -2621,8 +2625,8 @@ static int wait_readable(int fd, double timeout_s, double deadline) {
 /* recv_stamped(fd, max_bytes, timeout_s) -> (bytes, t_ready, t_held)
  *
  * poll + recv with the GIL released, as sock.recv does on a socket with a
- * timeout (whose descriptor is non-blocking): poll, then recv, again on
- * EAGAIN / EINTR.  t_ready is CLOCK_MONOTONIC taken after recv has returned
+ * timeout: poll, then a recv that never blocks (MSG_DONTWAIT, whatever the
+ * descriptor's own mode), again on EAGAIN / EINTR.  t_ready is CLOCK_MONOTONIC taken after recv has returned
  * and BEFORE the GIL is asked back; t_held the same clock right after it is
  * held again.  Both in seconds on time.perf_counter()'s clock where that is
  * CLOCK_MONOTONIC (the caller checks; extender/server.py).  t_held - t_ready
@@ -2654,7 +2658,7 @@ static PyObject *wirec_recv_stamped(PyObject *self, PyObject *args) {
     Py_BEGIN_ALLOW_THREADS
     double deadline = timeout_s >= 0 ? monotonic_now() + timeout_s : 0.0;
     for (;;) {
-        int ready = wait_readable(fd, timeout_s, deadline);
+        int ready = wait_ready(fd, POLLIN, timeout_s, deadline);
         if (ready < 0) {
             err = errno;
             break;
@@ -2663,7 +2667,7 @@ static PyObject *wirec_recv_stamped(PyObject *self, PyObject *args) {
             timed_out = 1;
             break;
         }
-        got = recv(fd, data, (size_t)max_bytes, 0);
+        got = recv(fd, data, (size_t)max_bytes, MSG_DONTWAIT);
         if (got >= 0) break;
         if (errno == EAGAIN || errno == EWOULDBLOCK || errno == EINTR) continue;
         err = errno;
@@ -2736,7 +2740,7 @@ static PyObject *wirec_recv_body(PyObject *self, PyObject *args) {
     Py_BEGIN_ALLOW_THREADS
     double deadline = timeout_s >= 0 ? monotonic_now() + timeout_s : 0.0;
     while (filled < length) {
-        int ready = wait_readable(fd, timeout_s, deadline);
+        int ready = wait_ready(fd, POLLIN, timeout_s, deadline);
         if (ready < 0) {
             err = errno;
             break;
@@ -2745,7 +2749,8 @@ static PyObject *wirec_recv_body(PyObject *self, PyObject *args) {
             timed_out = 1;
             break;
         }
-        ssize_t got = recv(fd, data + filled, (size_t)(length - filled), 0);
+        ssize_t got = recv(fd, data + filled, (size_t)(length - filled),
+                           MSG_DONTWAIT);
         if (got > 0) {
             filled += got;
             n_recv++;
@@ -2781,6 +2786,124 @@ static PyObject *wirec_recv_body(PyObject *self, PyObject *args) {
     return Py_BuildValue("(Nddl)", out, t_ready, t_held, n_recv);
 }
 
+/* The part of head + body not yet sent, from `sent` on, as iovecs: the
+ * number of them (0 where nothing is left). */
+static int answer_iov(struct iovec *iov, const Py_buffer *head,
+                      const Py_buffer *body, size_t sent) {
+    int n = 0;
+    size_t head_len = (size_t)head->len, body_len = (size_t)body->len;
+    if (sent < head_len) {
+        iov[n].iov_base = (char *)head->buf + sent;
+        iov[n++].iov_len = head_len - sent;
+        sent = 0;
+    } else {
+        sent -= head_len;
+    }
+    if (sent < body_len) {
+        iov[n].iov_base = (char *)body->buf + sent;
+        iov[n++].iov_len = body_len - sent;
+    }
+    return n;
+}
+
+/* One non-blocking sendmsg of what is left: bytes the kernel took, or -1
+ * with errno set.  MSG_DONTWAIT whatever the descriptor's own mode, and
+ * MSG_NOSIGNAL: a peer that went is EPIPE, never SIGPIPE. */
+static ssize_t send_rest(int fd, const Py_buffer *head, const Py_buffer *body,
+                         size_t sent) {
+    struct iovec iov[2];
+    struct msghdr msg;
+    memset(&msg, 0, sizeof msg);
+    msg.msg_iov = iov;
+    msg.msg_iovlen = (size_t)answer_iov(iov, head, body, sent);
+    return sendmsg(fd, &msg, MSG_DONTWAIT | MSG_NOSIGNAL);
+}
+
+/* send_answer(fd, head, body, timeout_s) -> (sends, released)
+ *
+ * An answer's status line and headers (`head`) and its body, any two buffer
+ * objects, sent as one: first ONE sendmsg of both with the GIL HELD — a
+ * loopback or LAN socket's send buffer takes a scheduler-extender answer
+ * whole, for the cost of the copy into the kernel — and only for what it
+ * did not take (a partial send, EAGAIN) the GIL released ONCE, poll(POLLOUT)
+ * + sendmsg looped in C until the last byte is out or timeout_s (for the
+ * whole answer, as sock.sendall's; < 0 waits for ever) has run out, and
+ * the GIL taken back once.  sock.sendall under a time-out releases it for a
+ * poll and for each send, and each release waits up to a switch interval
+ * to win it back from whatever Python thread runs.  sends counts the
+ * kernel's sends that took bytes, released is 0 where the answer went whole
+ * with the GIL held, else 1.  A time-out raises TimeoutError, a peer that
+ * went (EPIPE, ECONNRESET) or any other error OSError, as sock.sendall
+ * does; nothing raises SIGPIPE. */
+static PyObject *wirec_send_answer(PyObject *self, PyObject *args) {
+    int fd;
+    Py_buffer head, body;
+    double timeout_s;
+    if (!PyArg_ParseTuple(args, "iy*y*d", &fd, &head, &body, &timeout_s))
+        return NULL;
+    if (fd < 0) {
+        PyBuffer_Release(&head);
+        PyBuffer_Release(&body);
+        errno = EBADF;
+        return PyErr_SetFromErrno(PyExc_OSError);
+    }
+    size_t total = (size_t)head.len + (size_t)body.len, sent = 0;
+    long sends = 0;
+    int released = 0, err = 0, timed_out = 0;
+
+    while (sent < total) {  /* with the GIL held: no wait, no release */
+        ssize_t put = send_rest(fd, &head, &body, sent);
+        if (put >= 0) {
+            sent += (size_t)put;
+            sends++;
+            break;
+        }
+        if (errno == EINTR) continue;
+        if (errno != EAGAIN && errno != EWOULDBLOCK) err = errno;
+        break;
+    }
+    if (sent < total && !err) {
+        released = 1;
+        /* the buffers stay exported (and a bytearray unresizable) until
+         * they are released below, with the GIL held again */
+        Py_BEGIN_ALLOW_THREADS
+        double deadline = timeout_s >= 0 ? monotonic_now() + timeout_s : 0.0;
+        while (sent < total) {
+            int ready = wait_ready(fd, POLLOUT, timeout_s, deadline);
+            if (ready < 0) {
+                err = errno;
+                break;
+            }
+            if (ready == 0) {
+                timed_out = 1;
+                break;
+            }
+            ssize_t put = send_rest(fd, &head, &body, sent);
+            if (put >= 0) {
+                sent += (size_t)put;
+                sends++;
+                continue;
+            }
+            if (errno == EAGAIN || errno == EWOULDBLOCK || errno == EINTR) continue;
+            err = errno;
+            break;
+        }
+        Py_END_ALLOW_THREADS
+    }
+    PyBuffer_Release(&head);
+    PyBuffer_Release(&body);
+
+    if (timed_out) {
+        PyErr_SetString(PyExc_TimeoutError, "timed out");
+        return NULL;
+    }
+    if (err) {
+        errno = err;
+        return PyErr_SetFromErrno(PyExc_OSError);
+    }
+    return Py_BuildValue("(li)", sends, released);
+}
+
 /* ------------------------------------------------------------------ */
 
 static PyMethodDef wirec_methods[] = {
@@ -2813,6 +2936,10 @@ static PyMethodDef wirec_methods[] = {
      "recv_body(fd, prefix, length, timeout_s) -> (bytes, t_ready, t_held, "
      "n_recv): prefix + the bytes still missing of a body of length, read "
      "in place under one release of the GIL; timeout_s without a byte."},
+    {"send_answer", wirec_send_answer, METH_VARARGS,
+     "send_answer(fd, head, body, timeout_s) -> (sends, released): head + "
+     "body in one sendmsg with the GIL held; only what the kernel did not "
+     "take waits (poll + send) under one release; timeout_s for the whole."},
     {NULL},
 };
 

@@ -174,6 +174,7 @@ declare("pas_verb_arrive_wait_seconds_total", "counter", "Seconds verbs waited f
 declare("pas_verb_read_seconds_total", "counter", "Seconds of verbs' read stage (first byte held -> last byte of the body).")
 declare("pas_verb_read_gil_seconds_total", "counter", "Of those, seconds the reading thread waited for the interpreter after a recv had returned (span attribute read_gil_ms).")
 declare("pas_verb_read_calls_total", "counter", "Reads the verbs' threads made from Python on their requests' way in, each one release of the interpreter (span attribute read_calls): the head's recvs, then one native read a body that did not come with its head — one recv_into a piece where the body is read through the socket object (TLS, no _wirec).")
+declare("pas_verb_write_releases_total", "counter", "Answers whose native send gave the interpreter away (span attribute write_releases: 0 where the kernel took the whole answer with the GIL held, else 1); sendall's answers (TLS, no _wirec) are not counted.")
 declare("pas_stage_handle_total", "counter", "Verb spans that recorded the sampled stage handle (one span in SAMPLE_EVERY).")
 declare("pas_stage_handle_seconds_total", "counter", "Seconds of those handle stages: route(request), whole.")
 declare("pas_stage_scan_total", "counter", "Sampled scan stages recorded on verb spans (Filter's native scan in the probe).")
@@ -974,6 +975,7 @@ VERB_FAMILIES = (
     "pas_verb_read_seconds_total",
     "pas_verb_read_gil_seconds_total",
     "pas_verb_read_calls_total",
+    "pas_verb_write_releases_total",
     "pas_stage_handle_total",
     "pas_stage_handle_seconds_total",
     "pas_stage_scan_total",
@@ -983,6 +985,7 @@ _VERB_SPANS = "POST /scheduler/"  # the name of a served verb's span begins so
 _at = VERB_FAMILIES.index
 _READ_GIL = _at("pas_verb_read_gil_seconds_total")
 _READ_CALLS = _at("pas_verb_read_calls_total")
+_WRITE_RELEASES = _at("pas_verb_write_releases_total")
 _CPU = _at("pas_verb_cpu_seconds_total")
 _CPU_WALL = _at("pas_verb_cpu_wall_seconds_total")
 #: stage name -> (tally index of its count or -1, of its seconds): the
@@ -1100,6 +1103,7 @@ class TraceBuffer:
                 if gil_ms is not None:
                     tally[_READ_GIL] += gil_ms * 1e-3
                 tally[_READ_CALLS] += span.attrs.get("read_calls", 0)
+                tally[_WRITE_RELEASES] += span.attrs.get("write_releases", 0)
         COUNTERS.inc("pas_traces_recorded_total")
         for observer in SPAN_OBSERVERS:
             try:

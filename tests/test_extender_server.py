@@ -5,8 +5,10 @@ telemetry-aware-scheduling/pkg/telemetryscheduler/scheduler_test.go)."""
 import hashlib
 import http.client
 import json
+import signal
 import socket
 import socketserver
+import struct
 import threading
 import time
 
@@ -309,8 +311,8 @@ def _closed_without_an_answer(sock):
 #: stamped reads on a plain socket, the socket's own where there is none
 READ_PATHS = [
     pytest.param("native", marks=pytest.mark.skipif(
-        server_module.stamped_reads() is None,
-        reason="_wirec (recv_stamped, recv_body) unavailable")),
+        server_module.native_io() is None,
+        reason="_wirec (recv_stamped, recv_body, send_answer) unavailable")),
     "no-native",
 ]
 
@@ -453,7 +455,7 @@ class TestBodyReads:
             return bytearray(*args)
 
         if path == "native":
-            monkeypatch.setattr(server_module.stamped_reads(), "recv_body", no_room)
+            monkeypatch.setattr(server_module.native_io(), "recv_body", no_room)
         else:
             monkeypatch.setattr(server_module, "bytearray", no_room, raising=False)
         # socketserver's own handler would end the connection too, with a
@@ -483,6 +485,305 @@ class TestBodyReads:
         data = sock.recv(1 << 16)
         assert data.startswith(b"HTTP/1.1 500 ") and b"Connection: close" in data
         assert scheduler.calls == 0
+
+
+class SizedScheduler:
+    """Answers a request whose body is a size ``n`` with ``n`` bytes, once
+    ``gate`` is open; ``asked`` is set as a request reaches it."""
+
+    def __init__(self):
+        self.gate, self.asked = threading.Event(), threading.Event()
+        self.gate.set()
+
+    def filter(self, request):
+        self.asked.set()
+        assert self.gate.wait(10)
+        return HTTPResponse.json(_body(int(request.body), b"answer"))
+
+    prioritize = bind = filter
+
+
+def _ask(size, request_id):
+    return post_bytes("/scheduler/filter", str(size).encode(),
+                      extra=f"X-Request-ID: {request_id}\r\n")
+
+
+def _expected(size, request_id):
+    """The answer's bytes as the reference's net/http frames them."""
+    return (b"HTTP/1.1 200 OK\r\nContent-Type: application/json\r\n"
+            + f"X-Request-ID: {request_id}\r\nContent-Length: {size}\r\n\r\n"
+            .encode()) + _body(size, b"answer")
+
+
+def _raw_answer(sock):
+    """One answer off a keep-alive connection, head and body, as sent; or
+    what came before the peer closed."""
+    buf = bytearray()
+    while b"\r\n\r\n" not in buf:
+        chunk = sock.recv(1 << 16)
+        if not chunk:
+            return bytes(buf)
+        buf += chunk
+    head_end = buf.index(b"\r\n\r\n") + 4
+    length = int(bytes(buf[:head_end]).split(b"Content-Length: ")[1].split(b"\r\n")[0])
+    while len(buf) < head_end + length:
+        chunk = sock.recv(1 << 16)
+        if not chunk:
+            break
+        buf += chunk
+    return bytes(buf)
+
+
+def _small_window_connection(port):
+    """A client whose receive buffer is a few KB (set before the connect,
+    so that the window it offers is that small)."""
+    sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+    sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 4096)
+    sock.settimeout(15)
+    sock.connect(("127.0.0.1", port))
+    return sock
+
+
+#: from nothing to past what a loopback socket's send buffer holds (4 MB)
+ANSWER_SIZES = [0, 2_000, 1_400_000, 6_000_000]
+
+
+@pytest.mark.parametrize("path", READ_PATHS)
+class TestAnswers:
+    """An answer's way out on both write paths (extender/server.py _serve):
+    ``send_answer`` on a plain socket with _wirec, ``sendall`` where there is
+    none — the same bytes, and the write's time-out and errors kept."""
+
+    @pytest.fixture()
+    def answering(self, path, monkeypatch):
+        if path == "no-native":
+            monkeypatch.setenv("PAS_TPU_NO_NATIVE", "1")
+        monkeypatch.setattr(trace, "SAMPLE_EVERY", 1)
+        scheduler = SizedScheduler()
+        server = Server(scheduler)
+        server.start_server(port="0", unsafe=True, host="127.0.0.1", block=False)
+        assert server.wait_ready()
+        try:
+            yield server, scheduler
+        finally:
+            scheduler.gate.set()
+            server.shutdown()
+
+    @pytest.mark.parametrize("size", ANSWER_SIZES)
+    def test_the_same_bytes_on_either_write_path(self, answering, path, size):
+        server, _ = answering
+        with socket.create_connection(("127.0.0.1", server.port), timeout=15) as sock:
+            for turn in range(2):  # keep-alive: the second follows the first
+                request_id = f"answer-{path}-{size}-{turn}"
+                sock.sendall(_ask(size, request_id))
+                assert _raw_answer(sock) == _expected(size, request_id)
+        span = wait_for_span(f"answer-{path}-{size}-1")
+        names = [name for name, _start, _dur in span.stages]
+        if path == "native":
+            assert span.attrs["write_sends"] >= 1
+            assert span.attrs["write_releases"] in (0, 1)
+            assert "write_arm" not in names
+        else:
+            assert "write_sends" not in span.attrs
+            assert "write_releases" not in span.attrs
+            assert "write_arm" in names
+
+    def test_a_slow_reader_drives_the_partial_send(self, answering, path):
+        """6 MB against a few KB of window and a send buffer of at most
+        4 MB: the kernel cannot take the answer whole, and what it did not
+        take goes out under one release of the interpreter."""
+        server, _ = answering
+        request_id = f"slow-{path}"
+        with _small_window_connection(server.port) as sock:
+            sock.sendall(_ask(6_000_000, request_id))
+            time.sleep(0.2)  # both buffers fill before a byte is read
+            assert _raw_answer(sock) == _expected(6_000_000, request_id)
+        span = wait_for_span(request_id)
+        if path == "native":
+            assert span.attrs["write_releases"] == 1
+            assert span.attrs["write_sends"] > 1
+
+    def test_a_peer_that_stops_reading_ends_the_connection(
+        self, answering, path, monkeypatch
+    ):
+        server, _ = answering
+        monkeypatch.setattr(server_module, "WRITE_TIMEOUT_S", 0.3)
+        request_id = f"stops-{path}"
+        with _small_window_connection(server.port) as sock:
+            sock.sendall(_ask(6_000_000, request_id))
+            span = wait_for_span(request_id, timeout=10)
+            # what was sent before the time-out, then the end: no hang
+            assert len(_raw_answer(sock)) < len(_expected(6_000_000, request_id))
+        assert span.attrs["error"] == "write failed"
+        assert 0.25 <= span.stage_seconds()["write"] < 5.0
+
+    def test_a_peer_that_resets_gets_write_failed_and_no_signal(
+        self, answering, path
+    ):
+        server, scheduler = answering
+        scheduler.gate.clear()
+        request_id = f"reset-{path}"
+        signals = []
+        previous = signal.signal(signal.SIGPIPE, lambda *args: signals.append(args))
+        try:
+            sock = socket.create_connection(("127.0.0.1", server.port), timeout=15)
+            sock.sendall(_ask(6_000_000, request_id))
+            assert scheduler.asked.wait(10)
+            # linger 0: close sends a reset, and the answer meets it
+            sock.setsockopt(socket.SOL_SOCKET, socket.SO_LINGER,
+                            struct.pack("ii", 1, 0))
+            sock.close()
+            scheduler.gate.set()
+            span = wait_for_span(request_id)
+            time.sleep(0.05)  # a pending handler runs at a bytecode boundary
+        finally:
+            signal.signal(signal.SIGPIPE, previous)
+        assert span.attrs["error"] == "write failed"
+        if path == "native":  # sendall leaves SIGPIPE to its disposition
+            assert not signals
+
+
+@pytest.mark.skipif(server_module.native_io() is None,
+                    reason="_wirec (send_answer) unavailable")
+def test_beside_a_spinning_thread_a_small_answer_keeps_the_interpreter(monkeypatch):
+    """A thread that runs Python and lets go of nothing: sendall under a
+    time-out would hand it the GIL twice an answer; a 2 KB answer goes out
+    whole in the one send made with the GIL held."""
+    server = Server(SizedScheduler())
+    server.start_server(port="0", unsafe=True, host="127.0.0.1", block=False)
+    assert server.wait_ready()
+    stop = threading.Event()
+
+    def spin():
+        while not stop.is_set():
+            pass
+
+    spinner = threading.Thread(target=spin)
+    spinner.start()
+    try:
+        with socket.create_connection(("127.0.0.1", server.port), timeout=15) as sock:
+            for index in range(10):
+                sock.sendall(_ask(2_000, f"spin-{index}"))
+                assert _raw_answer(sock) == _expected(2_000, f"spin-{index}")
+    finally:
+        stop.set()
+        spinner.join(10)
+        server.shutdown()
+    for index in range(10):
+        span = wait_for_span(f"spin-{index}")
+        assert (span.attrs["write_sends"], span.attrs["write_releases"]) == (1, 0)
+
+
+def test_write_releases_fold_as_spans_finish():
+    ring = trace.TraceBuffer()
+    for releases in (1, 0, None, 1):
+        span = trace.Span("POST /scheduler/filter")
+        if releases is not None:
+            span.set("write_releases", releases)
+        ring.add(span.finish(200))
+    other = trace.Span("GET /metrics")  # not a verb: not counted
+    other.set("write_releases", 1)
+    ring.add(other.finish(200))
+    tally = dict(zip(trace.VERB_FAMILIES, ring.take_verb_tallies()))
+    assert tally["pas_verb_total"] == 4
+    assert tally["pas_verb_write_releases_total"] == 2
+    assert "pas_verb_write_releases_total" in trace.METRICS
+
+
+# _wirec.send_answer alone, over a socketpair
+
+
+def _any_buffer_and_nothing_at_all(wirec, left, right):
+    sent = []
+    for head, body in ((b"head:", b"body"), (bytearray(b"head:"), memoryview(b"body"))):
+        sent.append(wirec.send_answer(left.fileno(), head, body, 1.0))
+        assert right.recv(64) == b"head:body"
+    assert sent == [(1, 0), (1, 0)]
+    assert wirec.send_answer(left.fileno(), b"", b"", 1.0) == (0, 0)
+    assert wirec.send_answer(left.fileno(), b"", b"tail", 1.0) == (1, 0)
+    assert right.recv(64) == b"tail"
+
+
+def _what_the_kernel_will_not_take_waits_under_one_release(wirec, left, right):
+    left.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 16384)
+    head, body = b"h" * 100, _body(2_000_000, b"rest")
+    got = bytearray()
+
+    def read_slowly():
+        time.sleep(0.1)
+        while len(got) < len(head) + len(body):
+            chunk = right.recv(1 << 16)
+            if not chunk:
+                return
+            got.extend(chunk)
+
+    reader = threading.Thread(target=read_slowly)
+    reader.start()
+    sends, released = wirec.send_answer(left.fileno(), head, body, 10.0)
+    reader.join(10)
+    assert (released, bytes(got)) == (1, head + body)
+    assert sends > 1
+
+
+def _a_reader_that_never_comes_times_out(wirec, left, right):
+    began = time.monotonic()
+    with pytest.raises(TimeoutError):
+        wirec.send_answer(left.fileno(), b"", b"x" * 8_000_000, 0.2)
+    assert 0.2 <= time.monotonic() - began < 5.0
+
+
+def _a_blocking_descriptor_is_never_blocked_on(wirec, left, right):
+    left.settimeout(None)  # what an unarmed accepted socket is
+    with pytest.raises(TimeoutError):
+        wirec.send_answer(left.fileno(), b"", b"x" * 8_000_000, 0.2)
+
+
+def _a_peer_that_went_is_an_oserror_and_no_signal(wirec, left, right):
+    right.close()
+    signals = []
+    previous = signal.signal(signal.SIGPIPE, lambda *args: signals.append(args))
+    try:
+        with pytest.raises(BrokenPipeError):  # an OSError: what _serve catches
+            wirec.send_answer(left.fileno(), b"head", b"body", 1.0)
+        time.sleep(0.01)
+    finally:
+        signal.signal(signal.SIGPIPE, previous)
+    assert not signals
+
+
+def _arguments_are_checked_before_any_send(wirec, left, right):
+    with pytest.raises(OSError):
+        wirec.send_answer(-1, b"", b"x", 0.1)
+    with pytest.raises(TypeError):
+        wirec.send_answer(left.fileno(), "text", b"", 0.1)
+    fd = left.fileno()
+    left.close()
+    with pytest.raises(OSError):
+        wirec.send_answer(fd, b"", b"x", 0.1)
+
+
+SEND_ANSWER_CASES = {
+    case.__name__.lstrip("_"): case for case in (
+        _any_buffer_and_nothing_at_all,
+        _what_the_kernel_will_not_take_waits_under_one_release,
+        _a_reader_that_never_comes_times_out,
+        _a_blocking_descriptor_is_never_blocked_on,
+        _a_peer_that_went_is_an_oserror_and_no_signal,
+        _arguments_are_checked_before_any_send,
+    )
+}
+
+
+@pytest.mark.skipif(server_module.native_io() is None,
+                    reason="_wirec (send_answer) unavailable")
+@pytest.mark.parametrize("case", sorted(SEND_ANSWER_CASES))
+def test_send_answer(case):
+    left, right = socket.socketpair()
+    try:
+        SEND_ANSWER_CASES[case](server_module.native_io(), left, right)
+    finally:
+        left.close()
+        right.close()
 
 
 class TestDuration:

@@ -34,8 +34,8 @@ from wirehelpers import (
 
 wirec = get_wirec()
 needs_wirec = pytest.mark.skipif(
-    wirec is None or server_module.stamped_reads() is None,
-    reason="_wirec (recv_stamped, recv_body) unavailable",
+    wirec is None or server_module.native_io() is None,
+    reason="_wirec (recv_stamped, recv_body, send_answer) unavailable",
 )
 
 #: what tiles a span, by read path: the native reads stamp ``arrive`` and
@@ -45,13 +45,13 @@ TOP_STAGES = {
     "fallback": ("read", "handle", "write_arm", "write"),
 }
 
-#: every family of ISSUE 37's table, and ISSUE 38's one
+#: every family of ISSUE 37's table, and ISSUE 38's and 40's one each
 VERB_FAMILIES = (
     "pas_verb_total", "pas_verb_seconds_total", "pas_verb_cpu_seconds_total",
     "pas_verb_cpu_wall_seconds_total",
     "pas_verb_arrive_total", "pas_verb_arrive_wait_seconds_total",
     "pas_verb_read_seconds_total", "pas_verb_read_gil_seconds_total",
-    "pas_verb_read_calls_total",
+    "pas_verb_read_calls_total", "pas_verb_write_releases_total",
     "pas_stage_handle_total", "pas_stage_handle_seconds_total",
     "pas_stage_scan_total", "pas_stage_scan_seconds_total",
 )
@@ -417,7 +417,7 @@ def test_without_wirec_the_answers_are_the_same_bytes_and_carry_no_arrive(
         stamped, stamped_spans = _answers(stamped_server, "stamped", bodies)
     finally:
         stamped_server.shutdown()
-    monkeypatch.setattr(server_module, "stamped_reads", lambda: None)
+    monkeypatch.setattr(server_module, "native_io", lambda: None)
     plain_server = _serve()
     try:
         plain, plain_spans = _answers(plain_server, "plain", bodies)
@@ -439,8 +439,8 @@ def test_a_tls_connection_reads_through_the_ssl_socket(
     tmp_path, every_span_reads_cpu
 ):
     """An SSLSocket is a socket.socket whose bytes are not its
-    descriptor's: the front-end keeps sock.recv there and records no
-    stamp."""
+    descriptor's: the front-end keeps sock.recv and sendall there and
+    records no stamp."""
     from test_hardening import gen_certs
 
     ca, certs = gen_certs(tmp_path)
@@ -471,6 +471,7 @@ def test_a_tls_connection_reads_through_the_ssl_socket(
     assert over_tls.replace(b"tls-0", b"T") == plain[0].replace(b"tcp-0", b"T")
     span = _span("tls-0")
     assert "arrive" not in [s[0] for s in span.stages]
+    assert "write_sends" not in span.attrs  # sendall, as before
     assert span.cpu_s is not None and span.cpu_s >= 0
 
 
@@ -677,6 +678,8 @@ def test_metrics_show_every_family_and_they_count_the_verbs(
     assert moved("pas_verb_read_seconds_total") < moved("pas_verb_seconds_total")
     # a names-wire Filter comes whole with its head: one read a verb
     assert moved("pas_verb_read_calls_total") == len(bodies)
+    # and its answer of a few KB goes whole with the interpreter held
+    assert moved("pas_verb_write_releases_total") == 0
 
 
 def test_the_roles_sum_to_the_process_and_never_go_back():
@@ -849,11 +852,15 @@ def test_stage_split_puts_the_stalled_cycles_verbs_apart():
             # (read s, read_gil_ms, read_calls): the stalled verbs waited
             # 3 of their 4 ms of read for the interpreter, in two reads
             read = (0.004, 3.0, 2) if index == 5 else (0.0001, None, 1)
+            # (write s, write_releases): the stalled verbs' answers gave
+            # the interpreter away, the plain ones' went whole
+            write = (0.0001, 1) if index == 5 else (0.00005, 0)
             kept.append((verb_at, 0.0002 + wait, wait, cpu, index == 0,
-                         0.96 if index == 0 else None, read))
+                         0.96 if index == 0 else None, read, write))
         at += length + 0.0005
     # before the window
-    kept.append((50.0, 1.0, 1.0, None, False, None, (0.0, None, None)))
+    kept.append((50.0, 1.0, 1.0, None, False, None, (0.0, None, None),
+                 (0.0, None)))
     window = {"began": 100.0, "ended": at, "records": records}
     split = stage_split.interpreter_split(
         kept, window, lambda r: r["t"][3] - r["t"][0])
@@ -874,6 +881,11 @@ def test_stage_split_puts_the_stalled_cycles_verbs_apart():
     assert (stalled["read_calls"], plain["read_calls"]) == (2.0, 1.0)
     assert plain["read_gil_ms"] is None  # no read after the first
     assert split["all"]["read_calls"] == pytest.approx(24 / 22)
+    assert (stalled["write_ms"], plain["write_ms"]) == (
+        pytest.approx(0.1), pytest.approx(0.05))
+    assert stalled["write_pct"] == pytest.approx(100 * 0.0001 / 0.0042)
+    assert (stalled["write_releases"], plain["write_releases"]) == (1.0, 0.0)
+    assert split["all"]["write_releases"] == pytest.approx(2 / 22)
 
 
 def test_stage_split_counts_a_process_minor_faults():
