@@ -212,29 +212,14 @@ class PreemptionPlanner:
             for name in mesh.coord_of
             if name not in held or name in freed
         ]
-        free_mask = mesh.free_mask(free_names)
-        h, w = spec.topology
-        best = None
-        for idx, (hh, ww) in enumerate(
-            [(h, w)] if h == w else [(h, w), (w, h)]
-        ):
-            feas = topology.topology_feasibility(
-                free_mask, hh, ww, use_device=self.tracker.use_device
-            )
-            anchor = topology.best_anchor(feas)
-            if anchor is None:
-                continue
-            i, j, score = anchor
-            key = (score, idx, i, j)
-            if best is None or key < best[0]:
-                best = (key, i, j, hh, ww)
-        if best is None:
+        found = topology.best_slice(
+            mesh, free_names, spec.topology,
+            use_device=self.tracker.use_device,
+        )
+        if found is None:
             return None
-        _, i, j, hh, ww = best
-        names = mesh.names_for(topology.slice_cells(i, j, hh, ww))
-        if names is None:
-            return None
-        return names, (i, j, hh, ww)
+        names, anchor, _domain = found
+        return names, anchor
 
     # -- execution -------------------------------------------------------------
 

@@ -141,7 +141,8 @@ def assemble(
     ``gang_tracker``: the --gang=on GangTracker
     (common.build_gang_tracker); attached to the extender so Filter/
     Prioritize/Bind consult gang reservations and the front-ends serve
-    GET /debug/gangs (docs/gang.md).
+    GET /debug/gangs, and fed the cluster's pods (its members' bindings
+    and departures) from ``kube_client`` (docs/gang.md).
 
     ``forecast_options``: the --forecast=on options dict
     (common.forecast_options); a Forecaster (forecast/engine.py) is
@@ -260,6 +261,16 @@ def assemble(
     cache.start_periodic_update(sync_period_s, metrics_client, stop=stop)
     controller.run(stop)
     enforcer.start_enforcing(cache, sync_period_s, stop=stop)
+    if gang_tracker is not None and kube_client is not None:
+        # the gang tracker learns its members' bindings and departures
+        # from the cluster's pods: kube-scheduler sends no Bind to an
+        # extender without a bindVerb (docs/gang.md)
+        gang_feed = gang_tracker.watch(kube_client)
+        threading.Thread(
+            target=lambda: (stop.wait(), gang_feed.stop()),
+            name="pas-stop-gang-feed",
+            daemon=True,
+        ).start()
     if planner is not None:
         planner_informer = planner.watch(kube_client)
         # the plan's one trigger: the end of a refresh pass, after its

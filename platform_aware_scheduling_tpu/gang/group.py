@@ -14,17 +14,22 @@ The :class:`GangTracker` makes co-scheduling atomic:
     optionally ``pas-gang-topology: "HxW"``) labels is a **gang
     member** (utils/labels.py);
   * the FIRST member's Filter runs the topology-feasibility kernel
-    (ops/topology.py) over the mesh's free cells and — all-or-nothing —
-    either **reserves a whole feasible slice** (best anchor = fewest
-    stranded free neighbors) or fails every candidate with a concrete
-    ``gang ...: no feasible HxW slice`` reason;
+    (ops/topology.py) over the free cells of every ICI domain's mesh —
+    one program per orientation; a slice never spans two domains — and,
+    all-or-nothing, either **reserves a whole feasible slice** (best
+    anchor = fewest stranded free neighbors) or fails every candidate
+    with a concrete ``gang ...: no feasible HxW slice`` reason;
   * while the reservation holds, members pass Filter ONLY on reserved
     nodes, other gangs' pods fail reserved nodes with
     ``gang: node reserved by gang ...``, and each member Filter
     refreshes the reservation TTL;
-  * Bind observations promote members to bound; when every member has
-    bound the gang is **admitted** (``pas_gang_admitted_total``, time
-    to full gang recorded);
+  * bindings promote members to bound — learned from the cluster's pod
+    feed (:meth:`GangTracker.watch`: kube-scheduler sends Bind only to
+    an extender configured with a ``bindVerb``), and from the Bind verb
+    where one is sent; when every member has bound the gang is
+    **admitted** (``pas_gang_admitted_total``, time to full gang
+    recorded), and the deletion of its last bound member releases the
+    slice;
   * a reservation whose TTL lapses before the gang fully binds is
     **reclaimed** (``pas_gang_reservation_expirations_total``) and the
     gang re-forms — so an abandoned half-gang can never pin mesh nodes
@@ -150,6 +155,7 @@ class _Gang:
         "bound",
         "reserved_nodes",
         "anchor",
+        "domain",
         "created_at",
         "last_seen",
         "expires_at",
@@ -163,6 +169,7 @@ class _Gang:
         self.bound: Dict[str, str] = {}  # pod key -> node
         self.reserved_nodes: List[str] = []  # row-major slice order
         self.anchor: Optional[Tuple[int, int, int, int]] = None  # i, j, h, w
+        self.domain = ""  # the ICI domain the anchor lies in
         self.created_at = now
         self.last_seen = now
         self.expires_at: Optional[float] = None
@@ -180,6 +187,8 @@ class _Gang:
         if self.anchor is not None:
             i, j, h, w = self.anchor
             out["anchor"] = {"row": i, "col": j, "rows": h, "cols": w}
+            if self.domain:
+                out["anchor"]["domain"] = self.domain
         if self.state == STATE_RESERVED and self.expires_at is not None:
             out["ttl_remaining_s"] = round(max(0.0, self.expires_at - now), 3)
         return out
@@ -245,6 +254,8 @@ class GangTracker:
         # otherwise land an OLDER snapshot after a newer one while the
         # generation math marks the state clean
         self._journal_write_lock = threading.Lock()
+        # the pod feed's informer once watch() has started it
+        self._feed = None
 
     # -- mesh ------------------------------------------------------------------
 
@@ -266,22 +277,27 @@ class GangTracker:
         with self._lock:
             self._mesh = new
             self._mesh_at = now
+        trace.COUNTERS.set_gauge("pas_gang_domains", float(len(new.domains)))
         return new
 
     def _sweep_dead_gangs(self, now: float, wait: bool = False) -> None:
-        """Release bound gangs whose members have ALL stopped running
-        (job finished / pods deleted) — at most one pod list per
-        ``mesh_max_age_s``.  Without this, a completed job's slice would
-        stay reserved forever (the actuator's whole-gang release covers
-        evictions, not completions).
+        """The pod feed's departures for a tracker that has no feed: a
+        pod LIST at most once per ``mesh_max_age_s``, and every bound
+        member that no longer runs (job finished / pods deleted) leaves
+        through :meth:`observe_gone`, the feed's own rule.  ``cmd/tas``
+        always starts the feed, so there this never runs; it serves a
+        tracker built with ``pods_provider`` alone (the HA twin,
+        testing/ha.py).  Unlike the feed, which every replica runs on
+        its own ledger, it is leader-only: a cluster-wide LIST from
+        every replica would multiply API load.
 
         The cluster pod LIST never runs on a verb's thread: a Filter
         that trips the interval hands the scan to a one-shot daemon
         thread (``wait=False``); :meth:`prune` runs it inline
         (``wait=True``) so tests and maintenance calls are
         deterministic."""
-        if self.pods_provider is None:
-            return
+        if self.pods_provider is None or self._feed is not None:
+            return  # no live view, or the pod feed releases dead gangs
         if self.leadership is not None and not self.leadership.is_leader():
             # singleton loop: only the leader scans the cluster and
             # releases dead gangs (module attr doc); _swept_at is left
@@ -317,14 +333,10 @@ class GangTracker:
                     if pod.phase not in ("Succeeded", "Failed")
                     and pod.deletion_timestamp is None
                 }
-                for gang_id, members in bound_gangs.items():
-                    if members and not (members & live):
-                        klog.v(1).info_s(
-                            f"gang {gang_id}: every bound member gone; "
-                            f"releasing its slice",
-                            component="gang",
-                        )
-                        self.release(gang_id)
+                for members in bound_gangs.values():
+                    for key in sorted(members - live):
+                        namespace, _, name = key.partition("/")
+                        self.observe_gone(namespace, name)
             except Exception as exc:
                 klog.error("gang dead-sweep pod list failed: %s", exc)
             finally:
@@ -371,6 +383,7 @@ class GangTracker:
                 gang.state = STATE_FORMING
                 gang.reserved_nodes = []
                 gang.anchor = None
+                gang.domain = ""
                 gang.expires_at = None
                 # binds on the abandoned slice do not carry over: the
                 # re-formed gang may reserve a DIFFERENT slice, and
@@ -452,33 +465,18 @@ class GangTracker:
                 return "infeasible"
             gang.reserved_nodes = chosen
             gang.anchor = None
+            gang.domain = ""
         else:
             if mesh is None or len(mesh) == 0:
                 return "no_mesh"
-            free_mask = mesh.free_mask(free)
-            h, w = spec.topology
-            best = None  # (score, orientation index, i, j, h, w)
-            for idx, (hh, ww) in enumerate(
-                [(h, w)] if h == w else [(h, w), (w, h)]
-            ):
-                feas = topology.topology_feasibility(
-                    free_mask, hh, ww, use_device=self.use_device
-                )
-                anchor = topology.best_anchor(feas)
-                if anchor is None:
-                    continue
-                i, j, score = anchor
-                key = (score, idx, i, j)
-                if best is None or key < best[0]:
-                    best = (key, i, j, hh, ww)
-            if best is None:
+            # one device program per orientation over every ICI domain:
+            # a slice never spans two
+            found = topology.best_slice(
+                mesh, free, spec.topology, use_device=self.use_device
+            )
+            if found is None:
                 return "infeasible"
-            _, i, j, hh, ww = best
-            names = mesh.names_for(topology.slice_cells(i, j, hh, ww))
-            if names is None:  # a hole raced into the window
-                return "infeasible"
-            gang.reserved_nodes = names
-            gang.anchor = (i, j, hh, ww)
+            gang.reserved_nodes, gang.anchor, gang.domain = found
         gang.state = STATE_RESERVED
         gang.expires_at = now + self.ttl_s
         self._reservation_version += 1
@@ -488,7 +486,7 @@ class GangTracker:
     # -- verb overlays ---------------------------------------------------------
 
     def filter_overlay(
-        self, pod: Pod, candidates: List[str]
+        self, pod: Pod, candidates: List[str], span=trace.NULL_SPAN
     ) -> Tuple[Dict[str, str], Dict[str, int]]:
         """The gang verdict for one Filter request: ``(failed, codes)``
         merged over the telemetry violation map by the caller
@@ -498,7 +496,10 @@ class GangTracker:
         concrete ``gang: node reserved by gang <id>`` reason
         (CODE_GANG_RESERVED).  Gang member: only the gang's reserved
         slice passes; with no reservable slice EVERY candidate fails
-        (CODE_GANG_INFEASIBLE) — the all-or-nothing invariant."""
+        (CODE_GANG_INFEASIBLE) — the all-or-nothing invariant.  The
+        Filter that reserves records the stage ``gang_reserve`` on
+        ``span``: the held map, the free mask, the solve, the anchor and
+        its names."""
         now = self._clock()
         spec = GangSpec.from_pod(pod)
         self._sweep_dead_gangs(now)
@@ -531,9 +532,10 @@ class GangTracker:
                     spec.gang_id
                 )
                 if gang.state == STATE_FORMING:
-                    rejected_reason = self._try_reserve_locked(
-                        gang, candidates, mesh, now
-                    )
+                    with span.stage("gang_reserve"):
+                        rejected_reason = self._try_reserve_locked(
+                            gang, candidates, mesh, now
+                        )
                     if rejected_reason is None:
                         reservations_created = 1
                 if gang.state in (STATE_RESERVED, STATE_BOUND):
@@ -583,7 +585,7 @@ class GangTracker:
         return failed, codes
 
     def prioritize_overlay(
-        self, pod: Pod, candidates: List[str]
+        self, pod: Pod, candidates: List[str], span=trace.NULL_SPAN
     ) -> Optional[List[HostPriority]]:
         """Gang-member Prioritize: the reserved slice's nodes in
         row-major slice order (the topology kernel already chose the
@@ -597,7 +599,7 @@ class GangTracker:
         # Filter normally runs first and holds the reservation; this
         # degenerates to a lookup.  A Prioritize-first arrival drives the
         # same reservation path so the verbs cannot disagree.
-        self.filter_overlay(pod, candidates)
+        self.filter_overlay(pod, candidates, span)
         with self._lock:
             gang = self._gangs.get(spec.gang_id)
             reserved = (
@@ -617,9 +619,13 @@ class GangTracker:
 
     def observe_bind(self, namespace: str, name: str, node: str) -> None:
         """A member landed: promote it within its gang; the gang is
-        admitted when every member has bound onto the reserved slice."""
+        admitted when every member has bound onto the reserved slice.
+        Fed by the pod feed (:meth:`watch`: a pod update that carries
+        ``spec.nodeName``) and by the Bind verb where a kube-scheduler
+        sends one; the same binding seen twice counts once."""
         key = f"{namespace}/{name}"
         admitted: Optional[_Gang] = None
+        learned = False
         now = self._clock()
         with self._lock:
             gang_id = self._member_gang.get(key)
@@ -638,6 +644,9 @@ class GangTracker:
                     component="gang",
                 )
                 return
+            if gang.bound.get(key) == node:
+                return  # seen already: the Bind verb and the feed both say it
+            learned = True
             gang.bound[key] = node
             self._journal_gen += 1  # binds are durable: recovery replays them
             if (
@@ -648,6 +657,8 @@ class GangTracker:
                 gang.expires_at = None
                 admitted = gang
             gauges = self._publish_gauges_locked()
+        if learned:
+            trace.COUNTERS.inc("pas_gang_member_binds_total")
         if admitted is not None:
             trace.COUNTERS.inc("pas_gang_admitted_total")
             FULL_GANG_LATENCY.observe(
@@ -661,6 +672,85 @@ class GangTracker:
             )
         self._set_gauges(gauges)
         self._journal_flush()
+
+    def observe_gone(self, namespace: str, name: str) -> None:
+        """A pod was deleted, or no longer runs (Succeeded, Failed,
+        terminating): a bound member of a bound or draining gang leaves
+        it, and the departure of the last one releases the slice at once.
+        Fed by the pod feed (:meth:`watch`) or, without one, by the
+        dead-gang sweep's pod LIST."""
+        key = f"{namespace}/{name}"
+        with self._lock:
+            gang_id = self._member_gang.get(key)
+            gang = self._gangs.get(gang_id) if gang_id is not None else None
+            if (
+                gang is None
+                or gang.state not in (STATE_BOUND, STATE_DRAINING)
+                or gang.bound.pop(key, None) is None
+            ):
+                return
+            self._journal_gen += 1
+            gauges = self._publish_gauges_locked() if gang.bound else None
+        if gauges is not None:  # others remain: the gang keeps its slice
+            self._set_gauges(gauges)
+            self._journal_flush()
+            return
+        klog.v(1).info_s(
+            f"gang {gang_id}: every bound member gone; releasing its slice",
+            component="gang",
+        )
+        self.release(gang_id)
+
+    def watch(self, kube_client):
+        """The cluster's pod feed, as kube-scheduler learns its own
+        bindings: a pod update carrying ``spec.nodeName`` is a member's
+        binding (:meth:`observe_bind`), and a deletion — or a pod that
+        no longer runs — is a departure (:meth:`observe_gone`).  A
+        kube-scheduler sends Bind only to an extender configured with a
+        ``bindVerb``; without one this feed is how the tracker hears of
+        bindings at all.  While it runs, the periodic pod LIST of the
+        dead-gang sweep is skipped.  Every replica runs its own feed, as
+        every replica keeps its own ledger (a follower's slices are
+        released as promptly as the leader's).  Returns the informer
+        (``.stop()``)."""
+        from platform_aware_scheduling_tpu.kube.informer import (
+            DeletedFinalStateUnknown,
+            Informer,
+            ListWatch,
+        )
+        from platform_aware_scheduling_tpu.kube.objects import object_key
+
+        def on_event(pod: Pod) -> None:
+            if (
+                pod.phase in ("Succeeded", "Failed")
+                or pod.deletion_timestamp is not None
+            ):
+                self.observe_gone(pod.namespace, pod.name)
+            elif pod.spec_node_name:
+                self.observe_bind(pod.namespace, pod.name, pod.spec_node_name)
+
+        def on_delete(obj) -> None:
+            if isinstance(obj, DeletedFinalStateUnknown):
+                obj = obj.obj
+            if isinstance(obj, Pod):
+                self.observe_gone(obj.namespace, obj.name)
+
+        informer = Informer(
+            ListWatch(
+                lambda: (kube_client.list_pods(), ""),
+                lambda rv: (
+                    (etype, Pod(raw)) for etype, raw in kube_client.watch_pods()
+                ),
+                object_key,
+            ),
+            on_add=on_event,
+            on_update=lambda _old, new: on_event(new),
+            on_delete=on_delete,
+            name="gang-pods",
+        )
+        informer.start()
+        self._feed = informer
+        return informer
 
     def release(self, gang_id: str) -> bool:
         """Drop a gang and free its slice (job finished or evicted whole
@@ -805,6 +895,7 @@ class GangTracker:
                     "anchor": (
                         list(gang.anchor) if gang.anchor is not None else None
                     ),
+                    "domain": gang.domain,
                     "bound": dict(gang.bound),
                     "members": sorted(gang.members),
                 }
@@ -942,6 +1033,7 @@ class GangTracker:
                 gang.reserved_nodes = reserved
                 anchor = entry.get("anchor")
                 gang.anchor = tuple(anchor) if anchor else None
+                gang.domain = str(entry.get("domain") or "")
                 gang.bound = bound
                 gang.members = members | set(bound)
                 if entry.get("state") == STATE_BOUND and len(bound) >= size:

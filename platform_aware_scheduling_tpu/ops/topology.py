@@ -32,12 +32,22 @@ unlike binpack's i64 capacities there is nothing to overflow, and the
 host mirror (:func:`topology_feasibility_host`, numpy, used for
 device<->host parity exactly like the dontschedule/GAS dual paths) is
 byte-comparable by construction.
+
+A cluster of many ICI domains (TPU pods: no ICI link joins two, so a
+slice lies inside one) is a ``[D, M, N]`` free mask, one grid a domain
+(``MeshView``).  The gang reservation asks only for the best anchor, so
+:func:`best_domain_anchor` evaluates every anchor of every domain in ONE
+program per orientation (``_domains_best_anchor``: the same arithmetic
+over the leading domain axis, an argmin over all of it) and reads back
+four integers: (score, domain, row, col).  ``D`` is padded to a power of
+two with empty domains, so a domain that appears or disappears does not
+compile a new program.
 """
 
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, List, NamedTuple, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -45,10 +55,19 @@ import jax
 import jax.numpy as jnp
 
 from platform_aware_scheduling_tpu.utils import labels as shared_labels
+from platform_aware_scheduling_tpu.utils import trace
 
 #: big-order sentinel for "no feasible window here" (the masking idiom of
 #: ops/binpack.py's first-fit: invalid lanes sort past every real score)
 INFEASIBLE = 2**30
+
+
+def _count_fallback() -> None:
+    """A device-path failure served by the host mirror: counted, so a
+    broken device path never runs unseen."""
+    trace.COUNTERS.inc(
+        "pas_device_path_errors_total", labels={"site": "gang_topology"}
+    )
 
 
 class TopologyFeasibility(NamedTuple):
@@ -60,15 +79,40 @@ class TopologyFeasibility(NamedTuple):
     node_score: np.ndarray  # int32 [M, N]: best (lowest) covering-window score
 
 
-def _window_sums(integral: jnp.ndarray, h: int, w: int) -> jnp.ndarray:
+def _window_sums(integral, h: int, w: int):
     """All ``h x w`` window sums from a padded integral image
-    (``integral[a, b] = sum grid[:a, :b]``)."""
+    (``integral[..., a, b] = sum grid[..., :a, :b]``), over any leading
+    axes."""
     return (
-        integral[h:, w:]
-        - integral[:-h, w:]
-        - integral[h:, :-w]
-        + integral[:-h, :-w]
+        integral[..., h:, w:]
+        - integral[..., :-h, w:]
+        - integral[..., h:, :-w]
+        + integral[..., :-h, :-w]
     )
+
+
+def _anchor_grid(xp, fi, h: int, w: int):
+    """(ok, score) of every ``h x w`` anchor of an int32 ``[..., M, N]``
+    free grid (0/1), each ``[..., M-h+1, N-w+1]``: ``ok`` where the whole
+    window is free, ``score`` the free cells in the one-cell halo ring
+    around it that placing it would leave behind (fewest = best anchor),
+    ``INFEASIBLE`` where not ok.  ``xp`` is ``jnp`` (the kernels) or
+    ``np`` (the host mirrors): one arithmetic, so the two are
+    byte-comparable by construction."""
+    lead = [(0, 0)] * (fi.ndim - 2)
+
+    def integral(grid):
+        summed = xp.cumsum(
+            xp.cumsum(grid, axis=-2, dtype=xp.int32), axis=-1, dtype=xp.int32
+        )
+        return xp.pad(summed, lead + [(1, 0), (1, 0)])
+
+    window = _window_sums(integral(fi), h, w)
+    ok = window == h * w
+    halo = _window_sums(
+        integral(xp.pad(fi, lead + [(1, 1), (1, 1)])), h + 2, w + 2
+    )  # the same anchor grid
+    return ok, xp.where(ok, halo - window, xp.int32(INFEASIBLE))
 
 
 @partial(jax.jit, static_argnames=("h", "w"))
@@ -76,23 +120,7 @@ def _topology_kernel(free: jnp.ndarray, h: int, w: int):
     """(anchor_ok, anchor_score, node_score) over a bool [M, N] free mask
     for an ``h x w`` window — one fused pass for every anchor."""
     m, n = free.shape
-    fi = free.astype(jnp.int32)
-    integral = jnp.zeros((m + 1, n + 1), jnp.int32)
-    integral = integral.at[1:, 1:].set(
-        jnp.cumsum(jnp.cumsum(fi, axis=0), axis=1)
-    )
-    window = _window_sums(integral, h, w)  # [m-h+1, n-w+1]
-    ok_valid = window == h * w
-    # stranded-fragment score: free cells in the one-cell halo ring around
-    # the window that placing it would leave behind (fewest = best anchor)
-    halo_grid = jnp.zeros((m + 2, n + 2), jnp.int32).at[1:-1, 1:-1].set(fi)
-    halo_integral = jnp.zeros((m + 3, n + 3), jnp.int32)
-    halo_integral = halo_integral.at[1:, 1:].set(
-        jnp.cumsum(jnp.cumsum(halo_grid, axis=0), axis=1)
-    )
-    halo = _window_sums(halo_integral, h + 2, w + 2)  # same anchor grid
-    ring = halo - window
-    score_valid = jnp.where(ok_valid, ring, jnp.int32(INFEASIBLE))
+    ok_valid, score_valid = _anchor_grid(jnp, free.astype(jnp.int32), h, w)
     anchor_ok = jnp.zeros((m, n), bool)
     anchor_score = jnp.full((m, n), INFEASIBLE, jnp.int32)
     anchor_ok = anchor_ok.at[: m - h + 1, : n - w + 1].set(ok_valid)
@@ -148,34 +176,11 @@ def topology_feasibility_host(
     m, n = free.shape
     if h > m or w > n:
         return _all_infeasible(m, n)
-    fi = free.astype(np.int32)
-    integral = np.zeros((m + 1, n + 1), np.int32)
-    integral[1:, 1:] = np.cumsum(np.cumsum(fi, axis=0), axis=1)
-    window = (
-        integral[h:, w:]
-        - integral[:-h, w:]
-        - integral[h:, :-w]
-        + integral[:-h, :-w]
-    )
-    ok_valid = window == h * w
-    halo_grid = np.zeros((m + 2, n + 2), np.int32)
-    halo_grid[1:-1, 1:-1] = fi
-    halo_integral = np.zeros((m + 3, n + 3), np.int32)
-    halo_integral[1:, 1:] = np.cumsum(np.cumsum(halo_grid, axis=0), axis=1)
-    h2, w2 = h + 2, w + 2
-    halo = (
-        halo_integral[h2:, w2:]
-        - halo_integral[:-h2, w2:]
-        - halo_integral[h2:, :-w2]
-        + halo_integral[:-h2, :-w2]
-    )
-    ring = halo - window
+    ok_valid, score_valid = _anchor_grid(np, free.astype(np.int32), h, w)
     anchor_ok = np.zeros((m, n), bool)
     anchor_score = np.full((m, n), INFEASIBLE, np.int32)
     anchor_ok[: m - h + 1, : n - w + 1] = ok_valid
-    anchor_score[: m - h + 1, : n - w + 1] = np.where(
-        ok_valid, ring, np.int32(INFEASIBLE)
-    )
+    anchor_score[: m - h + 1, : n - w + 1] = score_valid
     # windowed min via the h*w shift union (h, w are small static ints)
     node_score = np.full((m, n), INFEASIBLE, np.int32)
     for a in range(h):
@@ -192,18 +197,92 @@ def topology_feasibility_host(
     )
 
 
-def topology_feasibility(
-    free: np.ndarray, h: int, w: int, use_device: bool = True
-) -> TopologyFeasibility:
-    """The dual-path entry: device kernel by default, exact host mirror
-    as the control/fallback (device trouble must never fail a verb —
-    the same invariant the TAS fastpath keeps)."""
+# ---------------------------------------------------------------------------
+# the best anchor over many ICI domains, one program per orientation
+# ---------------------------------------------------------------------------
+
+
+def padded_domains(count: int) -> int:
+    """Domains padded to a power of two (at least one)."""
+    return 1 << max(count - 1, 0).bit_length()
+
+
+@partial(jax.jit, static_argnames=("h", "w"))
+def _domains_best_anchor(free: jnp.ndarray, h: int, w: int) -> jnp.ndarray:
+    """int32 [4]: (score, domain, row, col) of the best ``h x w`` anchor
+    over every domain of a bool [D, M, N] free mask — fewest stranded
+    free ring cells inside its own domain, ties to the lowest (domain,
+    row, col) (argmin's first occurrence); score ``INFEASIBLE`` where no
+    window fits anywhere."""
+    _, score = _anchor_grid(jnp, free.astype(jnp.int32), h, w)
+    _, rows, cols = score.shape
+    flat = score.reshape(-1)
+    best = jnp.argmin(flat).astype(jnp.int32)
+    return jnp.stack(
+        [flat[best], best // (rows * cols), best // cols % rows, best % cols]
+    )
+
+
+def _anchor_of(best) -> Optional[Tuple[int, int, int, int]]:
+    score, d, i, j = (int(x) for x in best)
+    return None if score >= INFEASIBLE else (score, d, i, j)
+
+
+def domains_anchor_device(free, h: int, w: int):
+    """Device path: ``(score, domain, row, col)`` of the best anchor over
+    a bool [D, M, N] mask (a NumPy array, or one already on the device),
+    None when no window fits; one dispatch and a 16-byte readback."""
+    _, m, n = free.shape
+    if h > m or w > n:
+        return None
+    return _anchor_of(
+        np.asarray(
+            _domains_best_anchor(jnp.asarray(free, dtype=bool), int(h), int(w))
+        )
+    )
+
+
+def domains_anchor_host(free: np.ndarray, h: int, w: int):
+    """Exact host mirror of :func:`domains_anchor_device` (NumPy, the same
+    arithmetic and the same tie order)."""
+    free = np.asarray(free, dtype=bool)
+    _, m, n = free.shape
+    if h > m or w > n:
+        return None
+    _, score = _anchor_grid(np, free.astype(np.int32), h, w)
+    rows, cols = score.shape[1:]
+    best = int(np.argmin(score))
+    return _anchor_of(
+        (score.reshape(-1)[best], best // (rows * cols), best // cols % rows,
+         best % cols)
+    )
+
+
+def best_domain_anchor(
+    free: np.ndarray, shapes: Sequence[Tuple[int, int]], use_device: bool = True
+) -> Optional[Tuple[int, int, int, int, int]]:
+    """``(h, w, domain, row, col)`` of the best anchor of a bool [D, M, N]
+    free mask over the orientations ``shapes`` (in preference order): the
+    lowest (score, orientation, domain, row, col).  The mask goes up once
+    and each orientation is one program over every domain; device
+    trouble falls back to the host mirror, counted."""
+    found = None
     if use_device:
         try:
-            return topology_feasibility_device(free, h, w)
+            on_device = jnp.asarray(free, dtype=bool)
+            found = [domains_anchor_device(on_device, h, w) for h, w in shapes]
         except Exception:
-            pass
-    return topology_feasibility_host(free, h, w)
+            _count_fallback()
+    if found is None:
+        found = [domains_anchor_host(free, h, w) for h, w in shapes]
+    best = None
+    for index, ((h, w), anchor) in enumerate(zip(shapes, found)):
+        if anchor is None:
+            continue
+        score, d, i, j = anchor
+        if best is None or (score, index) < best[0]:
+            best = ((score, index), (h, w, d, i, j))
+    return None if best is None else best[1]
 
 
 # ---------------------------------------------------------------------------
@@ -292,8 +371,9 @@ def torus_feasibility_host(
 def torus_feasibility(
     free: np.ndarray, h: int, w: int, use_device: bool = True
 ) -> TopologyFeasibility:
-    """Dual-path entry for wraparound windows, same fallback stance as
-    :func:`topology_feasibility`."""
+    """Dual-path entry for wraparound windows: the device kernel, the
+    exact host mirror as the fallback (device trouble must never fail a
+    verb)."""
     if use_device:
         try:
             return torus_feasibility_device(free, h, w)
@@ -333,27 +413,45 @@ def slice_cells(i: int, j: int, h: int, w: int) -> List[Tuple[int, int]]:
 class MeshView:
     """Node-name <-> mesh-coordinate mapping built from ``pas-tpu-coord``
     node labels (testing/fake_kube synthesizes them for hermetic
-    meshes).  Nodes without a parseable coordinate sit outside the mesh
-    and can never join a topology-constrained gang slice."""
+    meshes), one grid per ICI domain (``pas-tpu-domain``; the nodes
+    without one form one domain together).  Domains are indexed in the
+    sorted order of their labels and share one ``rows x cols`` shape, the
+    largest any of them needs.  Nodes without a parseable coordinate —
+    or with one past ``labels.mesh_dim_limit`` of the padded domain count,
+    so that one mislabeled node cannot size every domain's grid — sit
+    outside the mesh and can never join a topology-constrained gang
+    slice."""
 
     def __init__(self, nodes):
+        parsed = []
+        for node in nodes:
+            node_labels = node.get_labels()
+            coord = shared_labels.parse_coord(node_labels)
+            if coord is not None:
+                domain = node_labels.get(shared_labels.TPU_DOMAIN_LABEL, "")
+                parsed.append((node.name, domain, coord))
+        self.domains: List[str] = sorted({domain for _, domain, _ in parsed})
+        self.padded_domains = padded_domains(len(self.domains))
+        limit = shared_labels.mesh_dim_limit(self.padded_domains)
+        index = {domain: d for d, domain in enumerate(self.domains)}
         coord_of: Dict[str, Tuple[int, int]] = {}
-        name_at: Dict[Tuple[int, int], str] = {}
+        domain_of: Dict[str, int] = {}
+        name_at: Dict[Tuple[int, int, int], str] = {}
         max_row = -1
         max_col = -1
-        for node in nodes:
-            coord = shared_labels.parse_coord(node.get_labels())
-            if coord is None:
-                continue
+        for name, domain, coord in parsed:
+            cell = (index[domain], *coord)
             # first writer wins on a duplicate coordinate (deterministic
             # given the provider's stable node order)
-            if coord in name_at:
+            if coord[0] >= limit or coord[1] >= limit or cell in name_at:
                 continue
-            coord_of[node.name] = coord
-            name_at[coord] = node.name
+            coord_of[name] = coord
+            domain_of[name] = cell[0]
+            name_at[cell] = name
             max_row = max(max_row, coord[0])
             max_col = max(max_col, coord[1])
         self.coord_of = coord_of
+        self.domain_of = domain_of
         self.name_at = name_at
         self.rows = max_row + 1
         self.cols = max_col + 1
@@ -361,23 +459,56 @@ class MeshView:
     def __len__(self) -> int:
         return len(self.coord_of)
 
-    def free_mask(self, free_names) -> np.ndarray:
-        """bool [rows, cols]: cell is free iff its node is in
-        ``free_names`` (holes — coordinates with no node — stay False)."""
-        mask = np.zeros((self.rows, self.cols), dtype=bool)
+    def free_masks(self, free_names) -> np.ndarray:
+        """bool [padded domains, rows, cols]: a cell is free iff its node
+        is in ``free_names`` (holes — coordinates with no node — and the
+        padding domains stay False)."""
+        mask = np.zeros((self.padded_domains, self.rows, self.cols), dtype=bool)
         for name in free_names:
             coord = self.coord_of.get(name)
             if coord is not None:
-                mask[coord] = True
+                mask[(self.domain_of[name], *coord)] = True
         return mask
 
-    def names_for(self, cells) -> Optional[List[str]]:
-        """The node names at ``cells`` (row-major); None when any cell is
-        a hole."""
+    def free_mask(self, free_names) -> np.ndarray:
+        """bool [rows, cols] of a mesh of one domain (see
+        :meth:`free_masks`)."""
+        if len(self.domains) > 1:
+            raise ValueError(
+                f"the mesh spans {len(self.domains)} ICI domains: one grid "
+                "cannot hold them (free_masks)"
+            )
+        return self.free_masks(free_names)[0]
+
+    def names_for(self, cells, domain: int = 0) -> Optional[List[str]]:
+        """The node names at ``cells`` (row-major) of one domain; None
+        when any cell is a hole."""
         names = []
-        for cell in cells:
-            name = self.name_at.get(cell)
+        for row, col in cells:
+            name = self.name_at.get((domain, row, col))
             if name is None:
                 return None
             names.append(name)
         return names
+
+
+def best_slice(
+    mesh: MeshView,
+    free_names,
+    shape: Tuple[int, int],
+    use_device: bool = True,
+) -> Optional[Tuple[List[str], Tuple[int, int, int, int], str]]:
+    """The gang reservation's solve: ``(names in row-major slice order,
+    (row, col, h, w), domain label)`` of the best ``h x w`` slice, either
+    orientation, over the free nodes of every ICI domain — one device
+    program per orientation (:func:`best_domain_anchor`); None when no
+    slice fits."""
+    h, w = shape
+    shapes = [(h, w)] if h == w else [(h, w), (w, h)]
+    found = best_domain_anchor(mesh.free_masks(free_names), shapes, use_device)
+    if found is None:
+        return None
+    hh, ww, d, i, j = found
+    # every cell of the window is free, so every one has its node
+    names = mesh.names_for(slice_cells(i, j, hh, ww), domain=d)
+    return names, (i, j, hh, ww), mesh.domains[d]

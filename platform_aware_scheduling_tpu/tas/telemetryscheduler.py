@@ -708,7 +708,8 @@ class MetricsExtender:
             gang_codes: Dict[str, int] = {}
             with span.stage("kernel"):
                 result = self._filter_nodes(
-                    args, degraded=degraded_action, gang_codes=gang_codes
+                    args, degraded=degraded_action, gang_codes=gang_codes,
+                    span=span,
                 )
             if result is None:
                 klog.v(2).info_s("No filtered nodes returned", component="extender")
@@ -1187,11 +1188,15 @@ class MetricsExtender:
 
     def bind(self, request: HTTPRequest) -> HTTPResponse:
         # TAS does not implement Bind (telemetryscheduler.go:179-181) —
-        # the 404 wire behavior is untouched, but the body (the real
-        # kube-scheduler POSTs BindingArgs regardless) is outcome
-        # feedback: which node the pod actually landed on closes the
-        # pod's open decision records AND promotes its gang reservation
-        # toward fully-bound (gang/group.py observe_bind)
+        # the 404 wire behavior is untouched.  A kube-scheduler POSTs
+        # BindingArgs only to an extender configured with a bindVerb
+        # (IsBinder), which upstream TAS's deployment does not set, so in
+        # most clusters this verb never arrives and the gang tracker
+        # learns bindings from its pod feed (gang/group.py watch).  Where
+        # a Bind does arrive its body is outcome feedback: which node the
+        # pod actually landed on closes the pod's open decision records
+        # AND promotes its gang reservation toward fully-bound
+        # (gang/group.py observe_bind)
         if (
             decisions.DECISIONS.enabled
             or self.gangs is not None
@@ -1522,9 +1527,11 @@ class MetricsExtender:
                     # otherwise it could reserve a slice containing a
                     # violating node that Filter will then never pass
                     # (the livelock the Filter path explicitly excludes)
-                    gang_result = self.gangs.prioritize_overlay(
-                        args.pod, self._telemetry_clean(args.pod, names)
-                    )
+                    with span.stage("gang_overlay", leaf=False):
+                        gang_result = self.gangs.prioritize_overlay(
+                            args.pod, self._telemetry_clean(args.pod, names),
+                            span=span,
+                        )
                 except Exception as exc:  # overlay fails open to the ranking
                     klog.error(
                         "gang prioritize overlay failed open: %s", exc
@@ -1705,6 +1712,7 @@ class MetricsExtender:
         args: Args,
         degraded: Optional[str] = None,
         gang_codes: Optional[Dict[str, int]] = None,
+        span=trace.NULL_SPAN,
     ) -> Optional[FilterResult]:
         """filterNodes (telemetryscheduler.go:184-225).  ``degraded``
         overrides ONLY the telemetry-dependent violation set: fail_open
@@ -1715,7 +1723,8 @@ class MetricsExtender:
         verdict: gang members pass only their reserved slice, other pods
         fail gang-held nodes (docs/gang.md); ``gang_codes`` (when given)
         is filled with {node: decision reason code} for the overlay's
-        failures so the caller's decision record counts them exactly."""
+        failures so the caller's decision record counts them exactly;
+        the overlay is the stage ``gang_overlay`` of ``span``."""
         try:
             policy = self._policy_from_pod(args.pod)
         except Exception as exc:
@@ -1756,9 +1765,10 @@ class MetricsExtender:
                     for name in self._candidate_names(args)
                     if name not in violating
                 ]
-                gang_failed, codes = self.gangs.filter_overlay(
-                    args.pod, clean
-                )
+                with span.stage("gang_overlay", leaf=False):
+                    gang_failed, codes = self.gangs.filter_overlay(
+                        args.pod, clean, span=span
+                    )
             except Exception as exc:
                 # the overlay fails OPEN: gang trouble must never take
                 # down plain telemetry filtering

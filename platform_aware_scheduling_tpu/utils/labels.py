@@ -15,6 +15,10 @@ back-compat alias).  This module must stay importable without jax.
     ``k`` mesh nodes (no adjacency constraint);
   * ``TPU_COORD_LABEL`` — a node's mesh coordinate ``"row,col"``
     (synthesized by testing/fake_kube for hermetic meshes);
+  * ``TPU_DOMAIN_LABEL`` — the ICI domain (a TPU pod) the coordinate
+    lies in: no ICI link joins two domains, so a slice never spans one
+    and coordinates repeat from domain to domain.  Nodes without it
+    form one domain together;
   * ``PRIORITY_LABEL`` — the pod's admission priority class name
     (admission/plane.py; unlabeled or unknown-class pods take the
     plane's default class).
@@ -22,12 +26,14 @@ back-compat alias).  This module must stay importable without jax.
 
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional
 
 GROUP_LABEL = "pas-workload-group"
 GANG_SIZE_LABEL = "pas-gang-size"
 GANG_TOPOLOGY_LABEL = "pas-gang-topology"
 TPU_COORD_LABEL = "pas-tpu-coord"
+TPU_DOMAIN_LABEL = "pas-tpu-domain"
 PRIORITY_LABEL = "pas-priority"
 
 
@@ -90,6 +96,15 @@ def priority_class_for(pod_labels: Dict[str, str], classes) -> Optional[str]:
 #: turn every gang Filter into a terabyte allocation.  1024x1024 = 1M
 #: cells comfortably covers real TPU pod meshes.
 MAX_MESH_DIM = 1024
+
+
+def mesh_dim_limit(domains: int) -> int:
+    """The bound on a coordinate when ``domains`` grids (the padded
+    domain count) share one shape: the cells of all of them together stay
+    within one ``MAX_MESH_DIM`` x ``MAX_MESH_DIM`` mesh, so one
+    mislabeled node cannot size every domain's grid — 1024 for one
+    domain, 64 for 256."""
+    return math.isqrt(MAX_MESH_DIM * MAX_MESH_DIM // max(domains, 1))
 
 
 def format_coord(row: int, col: int) -> str:

@@ -638,3 +638,18 @@ def test_the_mesh_demand_solve_compiles_for_four_v5e_chips_at_the_cells_size(v5e
     used = compiled.memory_analysis()
     assert used.argument_size_in_bytes + used.temp_size_in_bytes < 8 << 30
     assert "all-gather" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", [(2, 2), (2, 4), (4, 8), (8, 8)])
+def test_the_domain_slice_solve_compiles_for_a_v5e_at_the_fleets_size(
+    one_chip, shape
+):
+    """``tpu-v5e-fleet-12k``'s reservation: 199 ICI domains of 8 x 8 hosts,
+    padded to 256, one program an orientation; it returns four integers."""
+    from platform_aware_scheduling_tpu.ops import topology
+
+    free = jax.ShapeDtypeStruct((256, 8, 8), jnp.bool_, sharding=one_chip)
+    compiled = topology._domains_best_anchor.lower(free, *shape).compile()
+    used = compiled.memory_analysis()
+    assert used.argument_size_in_bytes == 256 * 8 * 8
+    assert used.temp_size_in_bytes < 1 << 20

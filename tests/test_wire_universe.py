@@ -235,7 +235,7 @@ class _StubGangs:
     def cache_token(self):
         return self.version, dict(self.held)
 
-    def filter_overlay(self, pod, clean):
+    def filter_overlay(self, pod, clean, span=None):
         failed = {
             node: shared_labels.gang_reserved_reason(gang_id)
             for node, gang_id in self.held.items()
@@ -243,7 +243,7 @@ class _StubGangs:
         }
         return failed, {}
 
-    def prioritize_overlay(self, pod, names):
+    def prioritize_overlay(self, pod, names, span=None):
         return None
 
 
