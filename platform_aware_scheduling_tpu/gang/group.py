@@ -49,7 +49,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Set, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Set, Tuple
 
 from platform_aware_scheduling_tpu.extender.types import HostPriority
 from platform_aware_scheduling_tpu.kube.objects import Pod
@@ -116,22 +116,28 @@ class GangSpec:
 
     @classmethod
     def from_pod(cls, pod: Pod) -> Optional["GangSpec"]:
-        """None unless the pod carries a well-formed gang demand.  The
+        """None unless the pod carries a well-formed gang demand."""
+        return cls.from_labels(pod.namespace, pod.name, pod.get_labels())
+
+    @classmethod
+    def from_labels(
+        cls, namespace: str, name: str, pod_labels: Dict[str, str]
+    ) -> Optional["GangSpec"]:
+        """None unless the labels carry a well-formed gang demand.  The
         validation lives in ONE place — utils/labels.gang_id_for (group
         + size labels, size >= 1, topology cell count == size) — so the
         scheduler and the gang-aware rebalance actuator can never
         disagree about membership.  A malformed demand fails open to
         non-gang semantics (logged) — a typo must not wedge scheduling."""
-        pod_labels = pod.get_labels()
-        gang_id = shared_labels.gang_id_for(pod.namespace, pod_labels)
+        gang_id = shared_labels.gang_id_for(namespace, pod_labels)
         if gang_id is None:
             if (
                 pod_labels.get(shared_labels.GROUP_LABEL)
                 and shared_labels.GANG_SIZE_LABEL in pod_labels
             ):
                 klog.v(2).info_s(
-                    f"malformed gang labels on pod {pod.namespace}/"
-                    f"{pod.name}; treating pod as non-gang",
+                    f"malformed gang labels on pod {namespace}/"
+                    f"{name}; treating pod as non-gang",
                     component="gang",
                 )
             return None
@@ -194,6 +200,25 @@ class _Gang:
         return out
 
 
+class MemberVerdict(NamedTuple):
+    """A gang member's Filter verdict in compact form: ``allowed`` (the
+    slice, while the gang holds one) passes where telemetry-clean; every
+    other clean candidate fails with the holder's reason where ``held``
+    names one, else with ``reason``.  Without a slice every clean
+    candidate fails with ``reason``.  ``held`` ({node: holding gang} at
+    ``version``) and ``allowed`` are shared: read them, never write."""
+
+    state: str
+    allowed: List[str]
+    reason: str
+    version: int
+    held: Dict[str, str]
+
+    @property
+    def holds_slice(self) -> bool:
+        return self.state in (STATE_RESERVED, STATE_BOUND)
+
+
 class GangTracker:
     """The gang ledger the TAS verbs consult: reservations, member
     lifecycle, and the Filter/Prioritize overlays.
@@ -232,6 +257,9 @@ class GangTracker:
         # cached verdict can never outlive the reservation state it
         # encoded (docs/gang.md)
         self._reservation_version = 0
+        # (version, {node: holding gang}) — the held map built once a
+        # reservation version, read by every Filter (_held_locked)
+        self._held_memo: Optional[Tuple[int, Dict[str, str]]] = None
         self._mesh: Optional[topology.MeshView] = None
         self._mesh_at: float = -float("inf")
         self._swept_at: float = -float("inf")
@@ -369,6 +397,16 @@ class GangTracker:
                     held[node] = gang.gang_id
         return held
 
+    def _held_locked(self) -> Dict[str, str]:
+        """The full held map, built once a reservation version: every
+        change of the held set bumps the version, so the memo can never
+        outlive the state it maps.  Shared — callers only read it."""
+        memo = self._held_memo
+        if memo is None or memo[0] != self._reservation_version:
+            memo = (self._reservation_version, self._reserved_map_locked())
+            self._held_memo = memo
+        return memo[1]
+
     def _prune_locked(self, now: float) -> int:
         """Reclaim expired reservations (gang re-forms) and drop gangs
         abandoned in forming for 10x the TTL.  Returns the number of
@@ -496,93 +534,151 @@ class GangTracker:
         concrete ``gang: node reserved by gang <id>`` reason
         (CODE_GANG_RESERVED).  Gang member: only the gang's reserved
         slice passes; with no reservable slice EVERY candidate fails
-        (CODE_GANG_INFEASIBLE) — the all-or-nothing invariant.  The
-        Filter that reserves records the stage ``gang_reserve`` on
-        ``span``: the held map, the free mask, the solve, the anchor and
-        its names."""
-        now = self._clock()
+        (CODE_GANG_INFEASIBLE) — the all-or-nothing invariant.  A
+        member's verdict is :meth:`member_verdict`'s, spelled out over
+        the candidates: the exact path, and the reference the native
+        encoder's answer is held to (tas/telemetryscheduler.py)."""
         spec = GangSpec.from_pod(pod)
-        self._sweep_dead_gangs(now)
-        mesh = None
-        if spec is not None and spec.topology is not None:
-            mesh = self._mesh_view(now)
-        expired = 0
-        reservations_created = 0
-        rejected_reason = None
         failed: Dict[str, str] = {}
         codes: Dict[str, int] = {}
-        with self._lock:
-            expired = self._prune_locked(now)
-            if spec is None:
-                held = self._reserved_map_locked()
-                for name in candidates:
-                    holder = held.get(name)
-                    if holder is not None:
-                        failed[name] = shared_labels.gang_reserved_reason(holder)
-                        codes[name] = decisions.CODE_GANG_RESERVED
+        if spec is None:
+            now = self._clock()
+            self._sweep_dead_gangs(now)
+            with self._lock:
+                expired = self._prune_locked(now)
+                held = self._held_locked()
                 gauges = self._publish_gauges_locked()
-            else:
-                gang = self._gangs.get(spec.gang_id)
-                if gang is None:
-                    gang = _Gang(spec, now)
-                    self._gangs[spec.gang_id] = gang
-                gang.last_seen = now
-                gang.members.add(f"{pod.namespace}/{pod.name}")
-                self._member_gang[f"{pod.namespace}/{pod.name}"] = (
-                    spec.gang_id
-                )
-                if gang.state == STATE_FORMING:
-                    with span.stage("gang_reserve"):
-                        rejected_reason = self._try_reserve_locked(
-                            gang, candidates, mesh, now
-                        )
-                    if rejected_reason is None:
-                        reservations_created = 1
-                if gang.state in (STATE_RESERVED, STATE_BOUND):
-                    if gang.state == STATE_RESERVED:
-                        # an actively scheduling gang keeps its hold
-                        gang.expires_at = now + self.ttl_s
-                    allowed = set(gang.reserved_nodes)
-                    held = self._reserved_map_locked(exclude=spec.gang_id)
-                    topo = spec.topology_label
-                    for name in candidates:
-                        if name in allowed:
-                            continue
-                        holder = held.get(name)
-                        if holder is not None:
-                            failed[name] = (
-                                shared_labels.gang_reserved_reason(holder)
-                            )
-                            codes[name] = decisions.CODE_GANG_RESERVED
-                        else:
-                            failed[name] = (
-                                f"gang {spec.gang_id}: node outside "
-                                f"reserved {topo} slice"
-                            )
-                            codes[name] = decisions.CODE_GANG_INFEASIBLE
+            for name in candidates:
+                holder = held.get(name)
+                if holder is not None:
+                    failed[name] = shared_labels.gang_reserved_reason(holder)
+                    codes[name] = decisions.CODE_GANG_RESERVED
+            self._count_expired(expired)
+            self._set_gauges(gauges)
+            self._journal_flush()  # no-op unless durable state moved
+            return failed, codes
+        verdict = self._member_verdict(
+            spec, f"{pod.namespace}/{pod.name}", lambda: candidates, span
+        )
+        if verdict.holds_slice:
+            allowed = set(verdict.allowed)
+            held = verdict.held
+            for name in candidates:
+                if name in allowed:
+                    continue
+                holder = held.get(name)
+                if holder is not None:
+                    failed[name] = shared_labels.gang_reserved_reason(holder)
+                    codes[name] = decisions.CODE_GANG_RESERVED
                 else:
-                    reason = (
-                        "no mesh coordinates available"
-                        if rejected_reason == "no_mesh"
-                        else f"no feasible {spec.topology_label} slice"
+                    failed[name] = verdict.reason
+                    codes[name] = decisions.CODE_GANG_INFEASIBLE
+        else:
+            for name in candidates:
+                failed[name] = verdict.reason
+                codes[name] = decisions.CODE_GANG_INFEASIBLE
+        return failed, codes
+
+    def member_verdict(
+        self,
+        namespace: str,
+        name: str,
+        pod_labels: Dict[str, str],
+        clean_names: Callable[[], List[str]],
+        span=trace.NULL_SPAN,
+    ) -> Optional[MemberVerdict]:
+        """A gang member's Filter verdict in compact form, or None for a
+        pod whose labels make it no member.  ``clean_names`` gives the
+        request's telemetry-clean candidates; it is called only when the
+        gang must reserve (the solve's free mask sees clean candidates
+        alone, so a gang can never reserve a slice it cannot bind)."""
+        spec = GangSpec.from_labels(namespace, name, pod_labels)
+        if spec is None:
+            return None
+        return self._member_verdict(
+            spec, f"{namespace}/{name}", clean_names, span
+        )
+
+    def _member_verdict(
+        self, spec: GangSpec, key: str, clean_names, span
+    ) -> MemberVerdict:
+        """Every side effect of a member's Filter — membership, the TTL
+        refresh of a held slice, the reservation of a forming gang (the
+        stage ``gang_reserve`` on ``span``: the held map, the free mask,
+        the solve, the anchor and its names), the counters, gauges and
+        journal — and its verdict, with no per-candidate work."""
+        now = self._clock()
+        self._sweep_dead_gangs(now)
+        mesh = self._mesh_view(now) if spec.topology is not None else None
+        reserved = False
+        rejected_reason = None
+        with self._lock:
+            tracked = len(self._gangs)
+            expired = self._prune_locked(now)
+            gang = self._gangs.get(spec.gang_id)
+            created = gang is None
+            if created:
+                gang = _Gang(spec, now)
+                self._gangs[spec.gang_id] = gang
+            gang.last_seen = now
+            gang.members.add(key)
+            self._member_gang[key] = spec.gang_id
+            if gang.state == STATE_FORMING:
+                candidates = clean_names()
+                with span.stage("gang_reserve"):
+                    rejected_reason = self._try_reserve_locked(
+                        gang, candidates, mesh, now
                     )
-                    for name in candidates:
-                        failed[name] = f"gang {spec.gang_id}: {reason}"
-                        codes[name] = decisions.CODE_GANG_INFEASIBLE
-                gauges = self._publish_gauges_locked()
-        if expired:
-            trace.COUNTERS.inc(
-                "pas_gang_reservation_expirations_total", expired
+                reserved = rejected_reason is None
+            state = gang.state
+            if state in (STATE_RESERVED, STATE_BOUND):
+                if state == STATE_RESERVED:
+                    # an actively scheduling gang keeps its hold
+                    gang.expires_at = now + self.ttl_s
+                allowed = gang.reserved_nodes
+                reason = (
+                    f"gang {spec.gang_id}: node outside reserved "
+                    f"{spec.topology_label} slice"
+                )
+            else:
+                allowed = []
+                reason = f"gang {spec.gang_id}: " + (
+                    "no mesh coordinates available"
+                    if rejected_reason == "no_mesh"
+                    else f"no feasible {spec.topology_label} slice"
+                )
+            verdict = MemberVerdict(
+                state, allowed, reason, self._reservation_version,
+                self._held_locked(),
             )
-        if reservations_created:
+            # the gauges count gangs by state and the hosts they hold: only
+            # a new gang, a reservation, an expiry or a drop moves them,
+            # and the walk over every gang is most of a verdict's cost
+            moved = (
+                created
+                or reserved
+                or expired
+                or len(self._gangs) != tracked
+            )
+            gauges = self._publish_gauges_locked() if moved else None
+        self._count_expired(expired)
+        if reserved:
             trace.COUNTERS.inc("pas_gang_reservations_total")
         if rejected_reason is not None:
             trace.COUNTERS.inc(
                 "pas_gang_rejected_total", labels={"reason": rejected_reason}
             )
-        self._set_gauges(gauges)
+        if gauges is not None:
+            self._set_gauges(gauges)
         self._journal_flush()  # no-op unless durable state moved
-        return failed, codes
+        return verdict
+
+    @staticmethod
+    def _count_expired(expired: int) -> None:
+        if expired:
+            trace.COUNTERS.inc(
+                "pas_gang_reservation_expirations_total", expired
+            )
 
     def prioritize_overlay(
         self, pod: Pod, candidates: List[str], span=trace.NULL_SPAN
@@ -599,15 +695,10 @@ class GangTracker:
         # Filter normally runs first and holds the reservation; this
         # degenerates to a lookup.  A Prioritize-first arrival drives the
         # same reservation path so the verbs cannot disagree.
-        self.filter_overlay(pod, candidates, span)
-        with self._lock:
-            gang = self._gangs.get(spec.gang_id)
-            reserved = (
-                list(gang.reserved_nodes)
-                if gang is not None
-                and gang.state in (STATE_RESERVED, STATE_BOUND)
-                else []
-            )
+        verdict = self._member_verdict(
+            spec, f"{pod.namespace}/{pod.name}", lambda: candidates, span
+        )
+        reserved = verdict.allowed if verdict.holds_slice else []
         in_request = set(candidates)
         ordered = [name for name in reserved if name in in_request]
         return [
@@ -1078,15 +1169,13 @@ class GangTracker:
         with self._lock:
             expired = self._prune_locked(now)
             version = self._reservation_version
-            held = self._reserved_map_locked()  # built fresh already
+            held = self._held_locked()
             # gauges only when something actually expired — this runs on
             # every non-gang Filter request, and the common no-expiry
             # case must not pay two all-gang walks under the lock
             gauges = self._publish_gauges_locked() if expired else None
         if expired:
-            trace.COUNTERS.inc(
-                "pas_gang_reservation_expirations_total", expired
-            )
+            self._count_expired(expired)
             self._set_gauges(gauges)
             self._journal_flush()
         return version, held
@@ -1106,10 +1195,7 @@ class GangTracker:
         with self._lock:
             expired = self._prune_locked(now)
             gauges = self._publish_gauges_locked()
-        if expired:
-            trace.COUNTERS.inc(
-                "pas_gang_reservation_expirations_total", expired
-            )
+        self._count_expired(expired)
         self._set_gauges(gauges)
         self._journal_flush()
         return expired

@@ -411,6 +411,11 @@ typedef struct {
     StrSlice pod_namespace;
     StrSlice policy_label; /* labels["telemetry-policy"] */
     int has_label;
+    /* raw byte span [start, end) of the pod's labels object, the last
+     * "labels" key of the last metadata (as the exact decode keeps it);
+     * -1 when absent or null — what a gang member's Filter reads its
+     * gang from (tas/telemetryscheduler.py) */
+    Py_ssize_t labels_start, labels_end;
     int nodes_present;     /* "Nodes" was a non-null object with items */
     StrSlice *names;       /* node name slices (Nodes.items[].metadata.name) */
     Py_ssize_t num_names;
@@ -461,6 +466,12 @@ static PyObject *ParsedArgs_get(ParsedArgs *self, void *closure) {
     if (strcmp(which, "policy_label") == 0) {
         if (!self->has_label) Py_RETURN_NONE;
         return slice_to_unicode(self->body, &self->policy_label);
+    }
+    if (strcmp(which, "pod_labels_span") == 0) {
+        if (self->labels_start < 0) Py_RETURN_NONE;
+        return PyBytes_FromStringAndSize(
+            PyBytes_AS_STRING(self->body) + self->labels_start,
+            self->labels_end - self->labels_start);
     }
     if (strcmp(which, "nodes_present") == 0)
         return PyBool_FromLong(self->nodes_present);
@@ -543,6 +554,8 @@ static PyGetSetDef ParsedArgs_getset[] = {
     {"pod_name", (getter)ParsedArgs_get, NULL, NULL, "pod_name"},
     {"pod_namespace", (getter)ParsedArgs_get, NULL, NULL, "pod_namespace"},
     {"policy_label", (getter)ParsedArgs_get, NULL, NULL, "policy_label"},
+    {"pod_labels_span", (getter)ParsedArgs_get, NULL, NULL,
+     "pod_labels_span"},
     {"nodes_present", (getter)ParsedArgs_get, NULL, NULL, "nodes_present"},
     {"num_nodes", (getter)ParsedArgs_get, NULL, NULL, "num_nodes"},
     {"node_names_present", (getter)ParsedArgs_get, NULL, NULL,
@@ -588,6 +601,7 @@ static int scan_pod_metadata(Scan *sc, ParsedArgs *pa) {
     memset(&pa->pod_namespace, 0, sizeof(StrSlice));
     memset(&pa->policy_label, 0, sizeof(StrSlice));
     pa->has_label = 0;
+    pa->labels_start = pa->labels_end = -1;
     if (sc->s[sc->i] == 'n') return skip_literal(sc, "null", 4);
     if (sc->s[sc->i] != '{') return fail("metadata not object");
     sc->i++;
@@ -624,12 +638,15 @@ static int scan_pod_metadata(Scan *sc, ParsedArgs *pa) {
                 return fail("pod namespace not string");
             }
         } else if (key.len == 6 && memcmp(kp, "labels", 6) == 0) {
-            /* scan the labels object for "telemetry-policy"; a repeated
-             * "labels" key replaces any label from an earlier occurrence */
+            /* scan the labels object for "telemetry-policy", keeping its
+             * span; a repeated "labels" key replaces any label and span
+             * from an earlier occurrence */
             memset(&pa->policy_label, 0, sizeof(StrSlice));
             pa->has_label = 0;
+            pa->labels_start = pa->labels_end = -1;
             skip_ws(sc);
             if (sc->i < sc->n && sc->s[sc->i] == '{') {
+                Py_ssize_t labels_start = sc->i;
                 sc->i++;
                 skip_ws(sc);
                 if (sc->i < sc->n && sc->s[sc->i] == '}') { sc->i++; }
@@ -677,6 +694,8 @@ static int scan_pod_metadata(Scan *sc, ParsedArgs *pa) {
                     if (sc->s[sc->i] == '}') { sc->i++; break; }
                     return fail("bad labels");
                 }
+                pa->labels_start = labels_start;
+                pa->labels_end = sc->i;
             } else if (sc->i < sc->n && sc->s[sc->i] == 'n') {
                 /* null labels: Go zero-value map (clears, no error) */
                 if (skip_literal(sc, "null", 4) < 0) return -1;
@@ -707,6 +726,7 @@ static int scan_pod(Scan *sc, ParsedArgs *pa) {
     memset(&pa->pod_namespace, 0, sizeof(StrSlice));
     memset(&pa->policy_label, 0, sizeof(StrSlice));
     pa->has_label = 0;
+    pa->labels_start = pa->labels_end = -1;
     if (sc->s[sc->i] != '{') return fail("Pod not object");
     sc->i++;
     skip_ws(sc);
@@ -1053,6 +1073,7 @@ static PyObject *wirec_parse_prioritize(PyObject *mod, PyObject *arg) {
     memset(&pa->pod_namespace, 0, sizeof(StrSlice));
     memset(&pa->policy_label, 0, sizeof(StrSlice));
     pa->has_label = 0;
+    pa->labels_start = pa->labels_end = -1;
     pa->nodes_present = 0;
     pa->names = NULL;
     pa->num_names = 0;
@@ -1521,12 +1542,20 @@ error:
  *
  * each <item> the passing candidate's own bytes, ``"items": null`` when
  * none passes, NodeNames closed by the "" the reference's split(" ")
- * leaves. */
+ * leaves.
+ *
+ * A failed row with no reason of its own takes ``dflt`` (``dflt_len``
+ * bytes, the ": " separator included): NODE_VIOLATES, the reference
+ * literal, for every caller but a gang member's Filter, whose rows
+ * outside its slice carry the member's own reason. */
+static const char NODE_VIOLATES[] = ": \"Node violates\"";
+
 static int emit_filter(Buf *out, const char *base, const StrSlice *cand,
                        Py_ssize_t num, const Py_ssize_t *rows,
                        const uint8_t *raw_ok, const char **enc_ptr,
                        const Py_ssize_t *enc_len, const uint8_t *vmask,
                        const char **reason_ptr, const Py_ssize_t *reason_len,
+                       const char *dflt, size_t dflt_len,
                        uint8_t *seen, const Py_ssize_t *item_spans,
                        Py_ssize_t *n_failed_out) {
     Py_ssize_t n_failed = 0;
@@ -1589,7 +1618,7 @@ static int emit_filter(Buf *out, const char *base, const StrSlice *cand,
             if (buf_put(out, ": ", 2) < 0 ||
                 buf_put(out, reason_ptr[row], (size_t)reason_len[row]) < 0)
                 return -1;
-        } else if (buf_put(out, ": \"Node violates\"", 17) < 0) {
+        } else if (buf_put(out, dflt, dflt_len) < 0) {
             return -1;
         }
     }
@@ -1633,12 +1662,22 @@ static int emit_filter(Buf *out, const char *base, const StrSlice *cand,
  * exact path writes json.dumps' own.  Returns None, and the exact path
  * answers, where the reference's ``available.split(" ")`` would not
  * give the names back one for one: a candidate named "" or holding a
- * space. */
+ * space.
+ *
+ * ``default`` (optional 5th arg, bytes, pre-JSON-encoded like a reason)
+ * takes the place of "Node violates" for a failed row the reason table
+ * leaves without one: a gang member's reason for every candidate outside
+ * its slice, one string for thousands of rows. */
 static PyObject *filter_encode_common(PyObject *args, int nodes_wire) {
     PyObject *parsed_obj, *table_obj, *mask_obj, *reasons_obj = Py_None;
-    if (!PyArg_ParseTuple(args, "OOO|O", &parsed_obj, &table_obj, &mask_obj,
-                          &reasons_obj))
+    PyObject *default_obj = Py_None;
+    if (!PyArg_ParseTuple(args, "OOO|OO", &parsed_obj, &table_obj, &mask_obj,
+                          &reasons_obj, &default_obj))
         return NULL;
+    if (default_obj != Py_None && !PyBytes_Check(default_obj)) {
+        PyErr_SetString(PyExc_TypeError, "default reason must be bytes");
+        return NULL;
+    }
     if (!PyObject_TypeCheck(parsed_obj, &ParsedArgs_Type)) {
         PyErr_SetString(PyExc_TypeError, "expected ParsedArgs");
         return NULL;
@@ -1686,11 +1725,24 @@ static PyObject *filter_encode_common(PyObject *args, int nodes_wire) {
     Buf out_buf = {NULL, 0, 0};
     Buf *out = &out_buf;
     int oom = 0;
+    /* the reason of a failed row with none in the table, chosen once */
+    char *dflt_buf = NULL;
+    const char *dflt = NODE_VIOLATES;
+    size_t dflt_len = sizeof(NODE_VIOLATES) - 1;
 
     rows = PyMem_Malloc((size_t)(num ? num : 1) * sizeof(Py_ssize_t));
     raw_ok = PyMem_Malloc((size_t)(num ? num : 1));
     seen = PyMem_Calloc((size_t)t->n_rows + 1, 1);
     if (!rows || !raw_ok || !seen) { PyErr_NoMemory(); goto done; }
+    if (default_obj != Py_None) {
+        size_t given = (size_t)PyBytes_GET_SIZE(default_obj);
+        dflt_buf = PyMem_Malloc(given + 2);
+        if (!dflt_buf) { PyErr_NoMemory(); goto done; }
+        memcpy(dflt_buf, ": ", 2);
+        memcpy(dflt_buf + 2, PyBytes_AS_STRING(default_obj), given);
+        dflt = dflt_buf;
+        dflt_len = given + 2;
+    }
 
     size_t span_bytes = 0;
     int split_unsafe = 0;  /* Nodes wire: a name split(" ") would break */
@@ -1781,12 +1833,16 @@ static PyObject *filter_encode_common(PyObject *args, int nodes_wire) {
 
     Py_BEGIN_ALLOW_THREADS
     /* "name", -> len+4 each; failed entry adds ': "Node violates"' (18)
-     * or ': ' + its pre-encoded reason bytes (accounted in reason_bytes) */
-    out_buf = pool_get(96 + span_bytes + (size_t)num * 24 + reason_bytes);
+     * or ': ' + its pre-encoded reason bytes (accounted in reason_bytes),
+     * or a longer default reason (its excess, a candidate) */
+    size_t dflt_extra = dflt_len > sizeof(NODE_VIOLATES) - 1
+        ? (dflt_len - (sizeof(NODE_VIOLATES) - 1)) * (size_t)num : 0;
+    out_buf = pool_get(96 + span_bytes + (size_t)num * 24 + reason_bytes
+                       + dflt_extra);
     if (!out_buf.data) oom = 1;
     if (!oom && emit_filter(out, body, cand, num, rows, raw_ok, enc_ptr,
-                            enc_len, vmask, reason_ptr, reason_len, seen,
-                            item_spans, &n_failed) < 0)
+                            enc_len, vmask, reason_ptr, reason_len, dflt,
+                            dflt_len, seen, item_spans, &n_failed) < 0)
         oom = 1;
     Py_END_ALLOW_THREADS
 
@@ -1812,6 +1868,7 @@ done:
     PyMem_Free(rows);
     PyMem_Free(raw_ok);
     PyMem_Free(seen);
+    PyMem_Free(dflt_buf);
     PyBuffer_Release(&viol);
     return res;
 }
@@ -1824,6 +1881,48 @@ static PyObject *wirec_filter_encode(PyObject *mod, PyObject *args) {
 static PyObject *wirec_filter_encode_nodes(PyObject *mod, PyObject *args) {
     (void)mod;
     return filter_encode_common(args, 1);
+}
+
+/* candidate_rows(parsed, table) -> bytes | None
+ *
+ * The table row of every NodeNames candidate in request order, as native
+ * int32 (-1: absent from the table): what a gang member's Filter counts
+ * its verdict's classes over and takes its slice's clean names from
+ * (tas/telemetryscheduler.py) before filter_encode answers it.  None
+ * where a row would not stand for its name one for one — a candidate
+ * named "", holding a space, or written with escapes (not resolved
+ * here) — and the exact path answers.  Runs under the GIL: one hash
+ * lookup a candidate. */
+static PyObject *wirec_candidate_rows(PyObject *mod, PyObject *args) {
+    (void)mod;
+    PyObject *parsed_obj, *table_obj;
+    if (!PyArg_ParseTuple(args, "OO", &parsed_obj, &table_obj)) return NULL;
+    if (!PyObject_TypeCheck(parsed_obj, &ParsedArgs_Type)) {
+        PyErr_SetString(PyExc_TypeError, "expected ParsedArgs");
+        return NULL;
+    }
+    if (!PyObject_TypeCheck(table_obj, &NameTable_Type)) {
+        PyErr_SetString(PyExc_TypeError, "expected NameTable");
+        return NULL;
+    }
+    ParsedArgs *pa = (ParsedArgs *)parsed_obj;
+    NameTable *t = (NameTable *)table_obj;
+    Py_ssize_t num = pa->num_nn_names;
+    const char *body = PyBytes_AS_STRING(pa->body);
+    PyObject *out = PyBytes_FromStringAndSize(
+        NULL, num * (Py_ssize_t)sizeof(int32_t));
+    if (!out) return NULL;
+    int32_t *rows = (int32_t *)PyBytes_AS_STRING(out);
+    for (Py_ssize_t k = 0; k < num; k++) {
+        const StrSlice *sl = &pa->nn_names[k];
+        if (sl->escaped || sl->len == 0 ||
+            memchr(body + sl->off, ' ', (size_t)sl->len)) {
+            Py_DECREF(out);
+            Py_RETURN_NONE;
+        }
+        rows[k] = (int32_t)table_lookup(t, body + sl->off, sl->len);
+    }
+    return out;
 }
 
 /* ------------------------------------------------------------------ */
@@ -2484,7 +2583,9 @@ static PyObject *wirec_filter_respond(PyObject *mod, PyObject *args) {
         if (!out_buf.data) oom = 1;
         if (!oom && emit_filter(out, span, u->slices, num, rows, u->raw_ok,
                                 enc_ptr, enc_len, vmask, reason_ptr,
-                                reason_len, seen, NULL, &n_failed) < 0)
+                                reason_len, NODE_VIOLATES,
+                                sizeof(NODE_VIOLATES) - 1, seen, NULL,
+                                &n_failed) < 0)
             oom = 1;
         if (oom) PyErr_NoMemory();
         else {
@@ -2917,7 +3018,11 @@ static PyMethodDef wirec_methods[] = {
     {"filter_encode", wirec_filter_encode, METH_VARARGS,
      "Assemble the NodeNames-mode FilterResult response from a parsed "
      "body, a name table, a per-row violation bitmask, and optional "
-     "per-row pre-encoded reason bytes; returns (bytes, n_failed)."},
+     "per-row pre-encoded reason bytes and default reason bytes; returns "
+     "(bytes, n_failed)."},
+    {"candidate_rows", wirec_candidate_rows, METH_VARARGS,
+     "The table row of every NodeNames candidate as int32 bytes (-1 = "
+     "absent), or None for a name that is empty, holds a space or escapes."},
     {"filter_encode_nodes", wirec_filter_encode_nodes, METH_VARARGS,
      "filter_encode for a request that carried Nodes: the passing items "
      "echoed as slices of the request's bytes; (bytes, n_failed), or None "

@@ -1158,6 +1158,126 @@ class PrioritizeFastPath:
             del self._gang_merged[self.RESPONSE_CACHE_SIZE :]
         return merged, merged_reasons, table
 
+    def gang_member_filter(
+        self,
+        wirec,
+        compiled: CompiledPolicy,
+        view: DeviceView,
+        policy_name: str,
+        explained,
+        parsed,
+        verdict_of,
+        span=trace.NULL_SPAN,
+    ):
+        """A gang member's NodeNames-wire Filter, natively: ``(body,
+        failed count, {reason code: count}, the first failed entries)``,
+        or None — before ``verdict_of`` is called — where the exact path
+        must answer (a name the rows will not vouch for, a candidate the
+        name table lacks: the exact overlay fails such a name, the
+        encoder would pass it), or where ``verdict_of`` found no member.
+
+        ``explained`` is :meth:`violation_reasons`' triple; ``verdict_of``
+        takes a function that materialises the telemetry-clean candidate
+        names and returns the tracker's compact verdict
+        (gang/group.py ``MemberVerdict``).  The verdict is three row sets
+        over one mask: the slice's clean rows pass; a violating row keeps
+        its telemetry reason; every other row fails with its holder's
+        reason where a gang holds it and the slice is held
+        (:meth:`gang_merged`'s table), else with the verdict's own reason
+        (the encoder's default).  Never stored in the response caches: a
+        member's verdict is its gang's, and its Filter has side effects."""
+        violations, reasons, _indexes = explained
+        table = self._table_for(view)
+        n_rows = len(table.node_names)
+        native = table.native(wirec)
+        rows_raw = wirec.candidate_rows(parsed, native)
+        if rows_raw is None:
+            return None
+        rows = np.frombuffer(rows_raw, dtype=np.int32)
+        if rows.size == 0 or int(rows.min()) < 0:
+            return None
+        violating = np.frombuffer(
+            self._violation_mask(violations, n_rows), dtype=np.uint8
+        )
+
+        def clean_names() -> List[str]:
+            names = parsed.node_names_list()
+            keep = np.flatnonzero(violating[rows] == 0).tolist()
+            return [names[k] for k in keep]
+
+        verdict = verdict_of(clean_names)
+        if verdict is None:
+            return None
+        mask = np.ones(n_rows, dtype=np.uint8)
+        if verdict.holds_slice:
+            merged, reason_of, reason_rows = self.gang_merged(
+                compiled, view, policy_name, violations, reasons,
+                verdict.held, verdict.version,
+            )
+            # rows failing with a reason of their own: violating or held
+            own_reason = np.frombuffer(
+                self._violation_mask(merged, n_rows), dtype=np.uint8
+            )
+            index = table.node_index
+            at = [
+                row
+                for row in (index.get(name) for name in verdict.allowed)
+                if row is not None
+            ]
+            mask[at] = violating[at]
+        else:
+            reason_of = reasons
+            own_reason = violating
+            rule_map = self.violation_rule_map(compiled, view)
+            reason_rows = (
+                self.reason_table(
+                    compiled, view, policy_name, violations, rule_map, n_rows
+                )
+                if rule_map is not None
+                else [None] * n_rows
+            )
+        # a violating row keeps its telemetry reason, never the default
+        bare = [row for row in violations if row < n_rows
+                and reason_rows[row] is None]
+        if bare:
+            reason_rows = list(reason_rows)
+            for row in bare:
+                reason_rows[row] = b'"Node violates"'
+        with span.stage("fencode", sampled=True):
+            body, n_failed = wirec.filter_encode(
+                parsed, native, mask, reason_rows,
+                json.dumps(verdict.reason).encode(),
+            )
+        # the decision record's exact counts, from the same partition
+        failed = np.zeros(n_rows, dtype=bool)
+        failed[rows] = True
+        failed &= mask.astype(bool)
+        n_violating = int(np.count_nonzero(failed & violating.astype(bool)))
+        n_own = int(np.count_nonzero(failed & own_reason.astype(bool)))
+        counts = {
+            code: count
+            for code, count in (
+                (decisions.CODE_RULE_VIOLATION, n_violating),
+                (decisions.CODE_GANG_RESERVED, n_own - n_violating),
+                (decisions.CODE_GANG_INFEASIBLE, int(n_failed) - n_own),
+            )
+            if count
+        }
+        # the exact record keeps a request's first failed entries
+        head: Dict[str, str] = {}
+        node_names = table.node_names
+        for row in rows[np.flatnonzero(mask[rows])].tolist():
+            if len(head) >= decisions.RETAIN_NODE_CAP:
+                break
+            name = node_names[row]
+            if name not in head:
+                head[name] = (
+                    reason_of.get(name, "Node violates")
+                    if own_reason[row]
+                    else verdict.reason
+                )
+        return body, int(n_failed), counts, head
+
     # -- filter response reuse -------------------------------------------------
 
     def filter_lookup(

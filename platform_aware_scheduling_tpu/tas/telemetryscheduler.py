@@ -44,6 +44,7 @@ order), which is within the reference's behavior envelope.
 
 from __future__ import annotations
 
+import json
 import time
 from typing import Dict, List, Optional
 
@@ -131,9 +132,12 @@ class MetricsExtender:
         # members Filter/Prioritize against their reserved slice, other
         # pods fail gang-held nodes, Bind promotes reservations, and the
         # front-ends serve GET /debug/gangs (404 while this is None).
-        # While set, the Filter response cache and the native Prioritize
-        # scanner are bypassed — the gang verdict is pod-label-dependent
-        # state the span-keyed caches cannot key (docs/gang.md)
+        # While set, a member's body bypasses the Filter response cache
+        # (the native encoder answers it from the tracker's compact
+        # verdict) and the native Prioritize scanner — the gang verdict
+        # is pod-label-dependent state the span-keyed caches cannot key;
+        # other pods' cached verdicts key on the reservation version
+        # (docs/gang.md)
         self.gangs = None
         # opt-in forecast.Forecaster, set by assembly when --forecast=on:
         # scheduleonmetric ranks on predicted-at-bind values through the
@@ -648,15 +652,26 @@ class MetricsExtender:
                     span.set("degraded", reason)
             probe = None
             if degraded_action is None:
-                # gang mode: the cache serves NON-gang pods, keyed on
-                # (gang reservation version, pod gang id) — any body
-                # that carries the gang group label at all may belong
-                # to a member (whose Filter has reservation side
-                # effects: TTL refresh, membership) and bypasses
+                # gang mode: the cache serves NON-gang pods, keyed on the
+                # gang reservation version; a body that mentions the gang
+                # SIZE label at all may be a member's, whose verdict is
+                # its gang's and whose Filter has side effects (TTL
+                # refresh, membership, the reservation): the native
+                # encoder answers it from the tracker's compact verdict,
+                # never from a cache
                 gang_token = None
+                member = False
                 if self.gangs is not None:
-                    gang_token = self._gang_cache_token(request)
-                if (
+                    member = (
+                        shared_labels.GANG_SIZE_LABEL.encode() in request.body
+                    )
+                    if not member:
+                        gang_token = self._gang_cache_token()
+                if member:
+                    if self.admission is None and self.shard is None:
+                        with span.stage("cache_probe", leaf=False):
+                            probe = self._gang_member_filter(request)
+                elif (
                     (self.gangs is None or gang_token is not None)
                     and self.admission is None
                     and (
@@ -937,25 +952,122 @@ class MetricsExtender:
             klog.error("shard prioritize failed open: %r", exc)
             return None
 
-    def _gang_cache_token(self, request: HTTPRequest):
-        """(reservation version, held map) when this request may use the
-        Filter response cache under gang mode; None bypasses.  A body
-        mentioning the GANG SIZE label at all may belong to a member —
-        the native wire view exposes no pod labels beyond the policy, and
-        a member's Filter has reservation side effects (TTL refresh,
-        membership) a cached response would skip — so only size-label-
-        free bodies are cacheable.  The key is ``pas-gang-size``, not
+    def _gang_cache_token(self):
+        """(reservation version, held map) for a Filter under gang mode
+        whose body never mentions the GANG SIZE label, and so is no
+        member's: the response cache keys its merged verdict on the
+        version.  The test is ``pas-gang-size``, not
         ``pas-workload-group``: gang membership requires BOTH
         (labels.gang_id_for), and the group label alone is the
         rebalancer's min-available grouping that ordinary non-gang
-        workloads carry — those must keep their cache hits.  Fails open
-        to a bypass on any trouble."""
+        workloads carry — those must keep their cache hits.  A body that
+        does mention it reads its labels from the native wire view
+        (``ParsedArgs.pod_labels_span``) in :meth:`_gang_member_filter`.
+        None bypasses, on any trouble."""
         try:
-            if shared_labels.GANG_SIZE_LABEL.encode() in request.body:
-                return None
             return self.gangs.cache_token()
         except Exception as exc:
             klog.error("gang cache token failed, cache bypass: %s", exc)
+            return None
+
+    def _gang_member_filter(
+        self, request: HTTPRequest
+    ) -> Optional[HTTPResponse]:
+        """A gang member's NodeNames-wire Filter answered by the native
+        encoder from the tracker's compact verdict (gang/group.py
+        ``member_verdict``; tas/fastpath.py ``gang_member_filter``), or
+        None and the exact path answers: no native scanner, the Nodes
+        wire, labels that make no member, a host-only policy, a name the
+        encoder will not vouch for or the name table lacks, any
+        exception.  Its bytes are the exact path's (tests/
+        test_gang_domains.py); it is counted a cache miss answered
+        natively, and in ``pas_gang_filter_native_total``."""
+        if self.fastpath is None:
+            return None
+        wirec = get_wirec()
+        if wirec is None or not hasattr(wirec, "candidate_rows"):
+            return None
+        span = trace.of(request)
+        try:
+            with span.stage("scan", sampled=True):
+                parsed = wirec.parse_prioritize(request.body)
+            with span.stage("policy", sampled=True):
+                if (
+                    not self.node_cache_capable
+                    or (parsed.nodes_present and parsed.num_nodes > 0)
+                    or not parsed.node_names_present
+                    or parsed.num_node_names == 0
+                ):
+                    return None
+                raw_labels = parsed.pod_labels_span
+                policy_name = parsed.policy_label
+                if raw_labels is None or policy_name is None:
+                    return None
+                # the exact decode's labels: a null value is Go's ""
+                pod_labels = {
+                    key: "" if value is None else value
+                    for key, value in json.loads(raw_labels).items()
+                }
+                namespace = parsed.pod_namespace or ""
+                name = parsed.pod_name or ""
+                try:
+                    policy = self.cache.read_policy(namespace, policy_name)
+                except Exception:
+                    return None
+                compiled, view = self._device_policy(policy)
+                if compiled is None or not self._device_filter_ok(compiled):
+                    return None
+                explained = self.fastpath.violation_reasons(
+                    compiled, view, policy.name
+                )
+                if explained is None:
+                    return None
+
+            def verdict_of(clean_names):
+                with span.stage("gang_overlay", leaf=False):
+                    return self.gangs.member_verdict(
+                        namespace, name, pod_labels, clean_names, span=span
+                    )
+
+            answer = self.fastpath.gang_member_filter(
+                wirec, compiled, view, policy.name, explained, parsed,
+                verdict_of, span=span,
+            )
+            if answer is None:
+                return None
+            body, n_failed, counts, head = answer
+            with span.stage("record", sampled=True):
+                span.set("filter_cache", "miss")
+                trace.COUNTERS.inc("pas_filter_cache_miss_total")
+                trace.COUNTERS.inc(
+                    "pas_filter_native_total", labels={"wire": "names"}
+                )
+                trace.COUNTERS.inc("pas_gang_filter_native_total")
+                span.set("pod", f"{namespace}/{name}")
+                if self.flight is not None:
+                    request.flight_universe = (None, parsed.num_node_names)
+                    request.flight_gang = int(
+                        pod_labels[shared_labels.GANG_SIZE_LABEL]
+                    )
+                if decisions.DECISIONS.enabled:
+                    decisions.DECISIONS.record_filter(
+                        request_id=span.trace_id,
+                        pod_namespace=namespace,
+                        pod_name=name,
+                        policy=policy_name,
+                        path="native",
+                        candidates=parsed.num_node_names,
+                        filtered=n_failed,
+                        violating=head,
+                        violating_total=n_failed,
+                        violating_scope="request",
+                        reason_counts=counts or None,
+                    )
+            return HTTPResponse.json(body)
+        except (ValueError, TypeError):
+            return None
+        except Exception as exc:
+            klog.error("gang member native filter failed, exact path: %s", exc)
             return None
 
     def _filter_cache_probe(self, request: HTTPRequest, gang_token=None):
@@ -1262,10 +1374,11 @@ class MetricsExtender:
         if self.gangs is not None and (
             shared_labels.GANG_SIZE_LABEL.encode() in request.body
         ):
-            # the parsed wire view exposes no pod gang labels, so the
-            # native scanner cannot tell a gang member apart — a body
-            # that mentions the gang SIZE label at all serves through
-            # the exact path, whose overlay can.  Size-label-free bodies
+            # a body that mentions the gang SIZE label at all serves
+            # through the exact path, whose overlay answers a member with
+            # its slice alone (a few hosts, not a ranking of the
+            # candidates: nothing there for the native path to save).
+            # Size-label-free bodies
             # are provably non-gang (membership requires pas-gang-size,
             # labels.gang_id_for — the group label alone is ordinary
             # rebalance grouping), and a non-gang pod's Prioritize never

@@ -246,6 +246,7 @@ class DecisionRecord:
         filtered: int = 0,
         violating: Optional[Mapping[str, str]] = None,
         violating_scope: str = "request",
+        violating_total: Optional[int] = None,
         metric: str = "",
         operator: str = "",
         score_head: Optional[List[Tuple[str, int]]] = None,
@@ -266,9 +267,13 @@ class DecisionRecord:
         self.filtered = filtered
         self.eligible = max(0, candidates - filtered)
         # shared, state-level reason map (device paths) or the request's
-        # own failed map (exact path) — ``violating_scope`` says which
+        # own failed map (exact path) — ``violating_scope`` says which;
+        # ``violating_total`` where a request's map came already cut to
+        # its first entries (a gang member's native Filter)
         violating = violating if violating is not None else {}
-        self.violating_total = len(violating)
+        self.violating_total = (
+            len(violating) if violating_total is None else violating_total
+        )
         if (
             violating_scope == "request"
             and len(violating) > RETAIN_NODE_CAP

@@ -269,6 +269,7 @@ declare("pas_decision_evicted_open_total", "counter", "Open decision records ove
 declare("pas_gang_reservations_total", "counter", "Gang slice reservations created (a feasible anchor found and its nodes held).")
 declare("pas_gang_reservation_expirations_total", "counter", "Gang reservations reclaimed after their TTL expired before the gang fully bound.")
 declare("pas_gang_admitted_total", "counter", "Gangs fully bound (every member landed on its reserved slice).")
+declare("pas_gang_filter_native_total", "counter", "Gang members' Filters answered by the native encoder from the tracker's compact verdict (each also counted in pas_filter_cache_miss_total and in pas_filter_native_total, wire names); over the Filters served it is the members' native share.")
 declare("pas_gang_rejected_total", "counter", "Gang Filter passes that found no feasible slice (label: reason in infeasible/no_mesh).")
 declare("pas_gang_active", "gauge", "Gangs currently tracked and not yet fully bound (forming or reserved).")
 declare("pas_gang_reserved_nodes", "gauge", "Nodes currently held by gang reservations (bound gangs included until released).")

@@ -21,7 +21,8 @@ Covers the whole forecasting layer:
     fallback returns;
   * /debug/forecast 200/404/405 on both front-ends + the /debug index;
   * the gang-mode Filter response cache restore: non-gang pods hit the
-    cache keyed on the reservation version, gang members still bypass.
+    cache keyed on the reservation version, gang members still bypass
+    it (the native encoder answers them from the tracker's verdict).
 """
 
 import json
@@ -937,17 +938,25 @@ class TestGangFilterCacheRestore:
         assert _counter("pas_filter_cache_bypass_total") == before_bypass
 
     def test_gang_members_still_bypass(self):
+        """A member's answer never comes from the response cache (its
+        verdict is its gang's, and its Filter has side effects): each is
+        a miss the native encoder answers from the tracker's verdict."""
         extender, _kube, names = build_mesh_service(4, 4, gang=True)
         before_bypass = _counter("pas_filter_cache_bypass_total")
         before_hit = _counter("pas_filter_cache_hit_total")
+        before_miss = _counter("pas_filter_cache_miss_total")
+        before_native = _counter("pas_gang_filter_native_total")
         gang_body = json.dumps(
             {"Pod": _gang_pod_obj("a-0", "gang-a", 8, "2x4"),
              "NodeNames": names}
         ).encode()
-        _post(extender, "filter", gang_body)
-        _post(extender, "filter", gang_body)
-        assert _counter("pas_filter_cache_bypass_total") == before_bypass + 2
+        first = _post(extender, "filter", gang_body)
+        second = _post(extender, "filter", gang_body)
+        assert first.body == second.body
         assert _counter("pas_filter_cache_hit_total") == before_hit
+        assert _counter("pas_filter_cache_miss_total") == before_miss + 2
+        assert _counter("pas_gang_filter_native_total") == before_native + 2
+        assert _counter("pas_filter_cache_bypass_total") == before_bypass
 
     def test_reservation_change_invalidates_cached_verdict(self):
         """A cached non-gang verdict must reflect every reservation

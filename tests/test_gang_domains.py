@@ -12,7 +12,11 @@
     Prioritize answer and every admitted slice equals the slice rule of
     the benchmark's plain reference (``perfbench/gang_world.py``), with
     every binding learned from the cluster's pods — no Bind verb;
-  * a device failure counted in ``pas_device_path_errors_total``.
+  * a device failure counted in ``pas_device_path_errors_total``;
+  * a member's Filter answered by the native encoder from the tracker's
+    compact verdict, byte for byte the exact path's on the same tracker
+    state, with the same side effects and decision counts, and the
+    requests it leaves to the exact path.
 """
 
 import json
@@ -30,6 +34,8 @@ from platform_aware_scheduling_tpu.ops.state import TensorStateMirror
 from platform_aware_scheduling_tpu.tas.cache import AutoUpdatingCache
 from platform_aware_scheduling_tpu.tas.metrics import NodeMetric
 from platform_aware_scheduling_tpu.tas.policy.v1alpha1 import TASPolicy
+from platform_aware_scheduling_tpu.tas import degraded as degraded_mode
+from platform_aware_scheduling_tpu.tas import telemetryscheduler
 from platform_aware_scheduling_tpu.tas.telemetryscheduler import MetricsExtender
 from platform_aware_scheduling_tpu.testing.builders import (
     make_gang_pod,
@@ -37,7 +43,7 @@ from platform_aware_scheduling_tpu.testing.builders import (
     make_node,
 )
 from platform_aware_scheduling_tpu.testing.fake_kube import FakeKubeClient
-from platform_aware_scheduling_tpu.utils import labels, trace
+from platform_aware_scheduling_tpu.utils import decisions, labels, trace
 from platform_aware_scheduling_tpu.utils.quantity import Quantity
 
 sys.path.insert(
@@ -424,3 +430,275 @@ def test_the_assembly_starts_the_pod_feed():
         assert extender.gangs is tracker and tracker._feed is not None
     finally:
         stop.set()
+
+
+# ---------------------------------------------------------------------------
+# a member's Filter: the native encoder against the exact path
+# ---------------------------------------------------------------------------
+
+
+class _Clock:
+    def __init__(self):
+        self.t = 1000.0
+
+    def __call__(self) -> float:
+        return self.t
+
+
+def member_service(clock, domains: int = 2, mesh: bool = True):
+    """(extender, host names) over ``domains`` 8x8 ICI domains, the gang
+    tracker on ``clock`` and fed no pods (bindings are told it); without
+    ``mesh`` the tracker sees no coordinates."""
+    kube = FakeKubeClient()
+    for node in fleet_nodes(domains):
+        kube.add_node(node)
+    names = sorted(n.name for n in kube.list_nodes())
+    cache = AutoUpdatingCache()
+    mirror = TensorStateMirror()
+    mirror.attach(cache)
+    cache.write_policy("default", POLICY, TASPolicy.from_obj(_policy_obj()))
+    extender = MetricsExtender(cache, mirror=mirror, node_cache_capable=True)
+    extender.gangs = GangTracker(
+        nodes_provider=kube.list_nodes if mesh else list, clock=clock)
+    set_hot(extender, names, ())
+    return extender, names
+
+
+def set_hot(extender, names, hot) -> None:
+    """Telemetry under which the ``hot`` hosts violate the policy."""
+    extender.cache.write_metric("mesh_metric", {
+        name: NodeMetric(value=Quantity(
+            2 * 10**9 if name in hot else len(names) - i))
+        for i, name in enumerate(names)
+    })
+
+
+def member_obj(name, group, size, topo=None):
+    obj = _gang_pod_obj(name, group, size, topo or "1x1")
+    if topo is None:
+        del obj["metadata"]["labels"][labels.GANG_TOPOLOGY_LABEL]
+    return obj
+
+
+def ledger(tracker):
+    """What a member's Filter may change in the tracker."""
+    with tracker._lock:
+        gangs = {
+            gid: (g.state, sorted(g.members), g.expires_at,
+                  list(g.reserved_nodes), dict(g.bound))
+            for gid, g in tracker._gangs.items()
+        }
+        return gangs, dict(tracker._member_gang), tracker._reservation_version
+
+
+REASONS = ("rule_violation", "gang_reserved", "gang_infeasible")
+COUNTED = ("pas_gang_filter_native_total", "pas_filter_cache_bypass_total",
+           "pas_filter_cache_miss_total")
+
+
+def counters():
+    out = {name: trace.COUNTERS.get(name, kind="counter") for name in COUNTED}
+    for reason in REASONS:
+        out[reason] = trace.COUNTERS.get(
+            "pas_decision_filtered_nodes_total", kind="counter",
+            labels={"reason": reason})
+    return out
+
+
+def moved(before):
+    after = counters()
+    return {k: after[k] - before[k] for k in after if after[k] != before[k]}
+
+
+def filter_both(twins, obj, candidates, monkeypatch):
+    """One Filter on each twin: the first as served, the second with the
+    native wire module gone (the exact path).  Returns both answers and
+    what each moved, after checking the trackers agree."""
+    (native, _), (exact, _) = twins
+    body = {"Pod": obj, "NodeNames": candidates}
+    before = counters()
+    got = _post(native, "filter", body)
+    got_moved = moved(before)
+    got_record = last_record()
+    before = counters()
+    with monkeypatch.context() as patch:
+        patch.setattr(telemetryscheduler, "get_wirec", lambda: None)
+        want = _post(exact, "filter", body)
+    want_moved = moved(before)
+    assert ledger(native.gangs) == ledger(exact.gangs)
+    assert native.gangs.reserved_nodes() == exact.gangs.reserved_nodes()
+    if decisions.DECISIONS.enabled:
+        want_record = last_record()
+        for record in (got_record, want_record):
+            for key in ("seq", "ts", "request_id", "path"):
+                record.pop(key)
+        assert got_record == want_record
+    return got, want, got_moved, want_moved
+
+
+def last_record():
+    return decisions.DECISIONS.snapshot(verb="filter", limit=1)["records"][0]
+
+
+def assert_native(got, want, got_moved, want_moved):
+    assert got.status == want.status == 200
+    assert got.body == want.body
+    assert got_moved.pop("pas_gang_filter_native_total") == 1
+    assert got_moved.pop("pas_filter_cache_miss_total") == 1
+    assert want_moved.pop("pas_filter_cache_bypass_total") == 1
+    assert got_moved == want_moved  # the same count under every reason
+    return json.loads(got.body)
+
+
+class TestMemberFilterNative:
+    """Every member state, each with violating hosts among the candidates
+    and hosts another gang holds; where the member's gang holds a slice,
+    a host of it violates too."""
+
+    @staticmethod
+    def twins(mesh=True):
+        clock = _Clock()
+        return (member_service(clock, mesh=mesh),
+                member_service(clock, mesh=mesh)), clock
+
+    @staticmethod
+    def hot_everywhere(twins, hot):
+        for extender, names in twins:
+            set_hot(extender, names, hot)
+
+    def held_by_other(self, twins, monkeypatch, topo="2x2"):
+        """Gang ``other`` holds four hosts, and three hosts violate."""
+        names = twins[0][1]
+        self.hot_everywhere(twins, {names[3], names[77], names[100]})
+        obj = member_obj("o-0", "other", 4, topo)
+        got = assert_native(*filter_both(twins, obj, names, monkeypatch))
+        assert len(got["NodeNames"]) == 4
+        return set(got["NodeNames"])
+
+    def test_the_reserving_call(self, monkeypatch):
+        twins, _clock = self.twins()
+        names = twins[0][1]
+        other = self.held_by_other(twins, monkeypatch)
+        got = assert_native(*filter_both(
+            twins, member_obj("j-0", "job", 8, "2x4"), names, monkeypatch))
+        assert len(got["NodeNames"]) == 8 and not other & set(got["NodeNames"])
+        reasons = set(got["FailedNodes"].values())
+        assert "gang: node reserved by gang default/other" in reasons
+        assert any("threshold" in r for r in reasons)
+
+    def test_a_reserved_member(self, monkeypatch):
+        twins, clock = self.twins()
+        names = twins[0][1]
+        self.held_by_other(twins, monkeypatch)
+        first = assert_native(*filter_both(
+            twins, member_obj("j-0", "job", 8, "2x4"), names, monkeypatch))
+        slice_hosts = first["NodeNames"]
+        self.hot_everywhere(
+            twins, {names[3], names[77], names[100], slice_hosts[2]})
+        clock.t += 5.0  # the member's Filter refreshes the TTL
+        got = assert_native(*filter_both(
+            twins, member_obj("j-1", "job", 8, "2x4"), names, monkeypatch))
+        assert got["NodeNames"] == [h for h in slice_hosts if h != slice_hosts[2]]
+        assert "threshold" in got["FailedNodes"][slice_hosts[2]]
+
+    def test_a_bound_member(self, monkeypatch):
+        twins, _clock = self.twins()
+        names = twins[0][1]
+        self.held_by_other(twins, monkeypatch)
+        members = [f"b-{m}" for m in range(4)]
+        slice_hosts = None
+        for pod in members:
+            got = assert_native(*filter_both(
+                twins, member_obj(pod, "bound", 4, "2x2"), names, monkeypatch))
+            slice_hosts = slice_hosts or got["NodeNames"]
+        for pod, host in zip(members, slice_hosts):
+            for extender, _names in twins:
+                extender.gangs.observe_bind("default", pod, host)
+        assert twins[0][0].gangs.gang_state("default/bound") == "bound"
+        self.hot_everywhere(
+            twins, {names[3], names[77], names[100], slice_hosts[1]})
+        got = assert_native(*filter_both(
+            twins, member_obj("b-0", "bound", 4, "2x2"), names, monkeypatch))
+        assert set(got["NodeNames"]) == set(slice_hosts) - {slice_hosts[1]}
+
+    def test_an_infeasible_gang(self, monkeypatch):
+        twins, _clock = self.twins()
+        names = twins[0][1]
+        self.held_by_other(twins, monkeypatch)
+        got = assert_native(*filter_both(
+            twins, member_obj("w-0", "wide", 256, "16x16"), names,
+            monkeypatch))
+        assert got["NodeNames"] == []
+        assert set(got["FailedNodes"]) == set(names)
+        assert "gang default/wide: no feasible 16x16 slice" in set(
+            got["FailedNodes"].values())
+
+    def test_no_mesh(self, monkeypatch):
+        twins, _clock = self.twins(mesh=False)
+        names = twins[0][1]
+        self.held_by_other(twins, monkeypatch, topo=None)  # no mesh needed
+        got = assert_native(*filter_both(
+            twins, member_obj("n-0", "nomesh", 4, "2x2"), names, monkeypatch))
+        assert got["NodeNames"] == []
+        values = set(got["FailedNodes"].values())
+        assert "gang default/nomesh: no mesh coordinates available" in values
+        assert any("threshold" in r for r in values)
+
+    def test_a_size_only_gang(self, monkeypatch):
+        twins, _clock = self.twins()
+        names = twins[0][1]
+        other = self.held_by_other(twins, monkeypatch)
+        got = assert_native(*filter_both(
+            twins, member_obj("s-0", "sized", 3), names, monkeypatch))
+        assert len(got["NodeNames"]) == 3 and not other & set(got["NodeNames"])
+        self.hot_everywhere(
+            twins, {names[3], names[77], names[100], got["NodeNames"][0]})
+        again = assert_native(*filter_both(
+            twins, member_obj("s-1", "sized", 3), names, monkeypatch))
+        assert again["NodeNames"] == got["NodeNames"][1:]
+
+
+class TestMemberFilterFallback:
+    """What the native member path will not vouch for takes the exact
+    path, and ``pas_gang_filter_native_total`` does not move."""
+
+    class _Degraded:
+        def __init__(self, action):
+            self.action = action
+
+        def filter_decision(self):
+            return self.action, "telemetry stale"
+
+    class _Admission:
+        def review(self, *_args, **_kwargs):
+            return None
+
+    @pytest.mark.parametrize("case", [
+        "unknown to the mirror", "an empty name", "a name with a space",
+        "degraded fail-open", "degraded fail-closed", "an admission plane",
+    ])
+    def test_the_exact_path_answers(self, case, monkeypatch):
+        twins, _clock = TestMemberFilterNative.twins()
+        names = twins[0][1]
+        candidates = list(names)
+        extra = {"unknown to the mirror": "ghost-host",
+                 "an empty name": "", "a name with a space": "host 00001"}
+        if case in extra:
+            candidates.insert(5, extra[case])
+        for extender, _names in twins:
+            if case.startswith("degraded"):
+                extender.degraded = self._Degraded(
+                    degraded_mode.ACTION_FAIL_OPEN if case.endswith("open")
+                    else degraded_mode.ACTION_FAIL_CLOSED)
+            elif case == "an admission plane":
+                extender.admission = self._Admission()
+        got, want, got_moved, want_moved = filter_both(
+            twins, member_obj("f-0", "fall", 4, "2x2"), candidates,
+            monkeypatch)
+        assert got.status == want.status == 200
+        assert got.body == want.body
+        assert "pas_gang_filter_native_total" not in got_moved
+        assert "pas_filter_cache_miss_total" not in got_moved
+        if not case.startswith("degraded"):
+            assert got_moved.get("pas_filter_cache_bypass_total") == 1
+        assert got_moved == want_moved

@@ -894,3 +894,126 @@ class TestAdvisorFindings:
         python = ext.prioritize(request_from(body))
         assert native.status == python.status
         assert native.body == python.body
+
+
+# ---------------------------------------------------------------------------
+# what a gang member's native Filter reads: the labels span, the candidate
+# rows, and the encoder's default reason
+# ---------------------------------------------------------------------------
+
+
+def labels_body(metadata: bytes, tail: bytes = b"") -> bytes:
+    return (b'{"Pod": {"metadata": ' + metadata + b"}" + tail
+            + b', "NodeNames": ["n1", "n2"]}')
+
+
+class TestPodLabelsSpan:
+    """``ParsedArgs.pod_labels_span`` against the exact decode's labels:
+    JSON-equal (a null value read as Go's ""), on every shape the two
+    agree on."""
+
+    CASES = {
+        "escapes": labels_body(json.dumps({
+            "name": "p", "labels": {
+                "telemetry-policy": "pol",
+                "note": 'q"uo\\te é ☃ \t',
+                "pas-gang-size": "4"}}).encode()),
+        "escapes kept as sent": labels_body(json.dumps({
+            "name": "p", "labels": {"telemetry-policy": "pol",
+                                    "note": "é☃"}},
+            ensure_ascii=False).encode()),
+        "a repeated labels key, the last wins": labels_body(
+            b'{"name": "p", "labels": {"a": "1", "telemetry-policy": "x"}, '
+            b'"labels": {"telemetry-policy": "pol", "b": "2"}}'),
+        "labels null": labels_body(b'{"name": "p", "labels": null}'),
+        "labels null after an object": labels_body(
+            b'{"name": "p", "labels": {"a": "1"}, "labels": null}'),
+        "no labels": labels_body(b'{"name": "p", "namespace": "ns"}'),
+        "empty labels": labels_body(b'{"name": "p", "labels": {}}'),
+        "a null label value": labels_body(
+            b'{"name": "p", "labels": {"telemetry-policy": "pol", "x": null}}'),
+        "metadata twice, the last has none": labels_body(
+            b'{"name": "p", "labels": {"a": "1"}}, "metadata": {"name": "q"}'),
+        "Pod null after an object": labels_body(
+            b'{"name": "p", "labels": {"a": "1"}}', b', "Pod": null'),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_the_span_is_the_exact_decodes_labels(self, case):
+        body = self.CASES[case]
+        span = wirec.parse_prioritize(body).pod_labels_span
+        got = {} if span is None else {
+            key: "" if value is None else value
+            for key, value in json.loads(span).items()
+        }
+        assert got == Args.from_json(body).pod.get_labels()
+        if case.startswith(("labels null", "no labels", "metadata twice")):
+            assert span is None
+        else:
+            assert span is not None and span in body  # a slice, as sent
+
+
+class TestMemberFilterPieces:
+    NAMES = ["n1", "n2", "n3", "n4"]
+
+    def test_candidate_rows(self):
+        table = wirec.build_table(self.NAMES)
+        parsed = wirec.parse_prioritize(nn_body(["n3", "ghost", "n1", "n3"]))
+        rows = np.frombuffer(wirec.candidate_rows(parsed, table), np.int32)
+        assert rows.tolist() == [2, -1, 0, 2]
+        empty = wirec.parse_prioritize(nn_body([]))
+        assert wirec.candidate_rows(empty, table) == b""
+
+    @pytest.mark.parametrize("name", ["", "n 1", "éscaped"])
+    def test_candidate_rows_will_not_vouch(self, name):
+        table = wirec.build_table(self.NAMES + [name])
+        parsed = wirec.parse_prioritize(nn_body(["n1", name]))
+        assert wirec.candidate_rows(parsed, table) is None
+
+    def test_a_default_reason_fills_rows_without_one(self):
+        table = wirec.build_table(self.NAMES)
+        parsed = wirec.parse_prioritize(nn_body(["n1", "n2", "n4", "n1", "n3"]))
+        own = "gang default/g: node outside reserved 2x2 slice"
+        body, n_failed = wirec.filter_encode(
+            parsed, table, b"\x01\x00\x01\x01", [b'"r1"', None, None, None],
+            json.dumps(own).encode())
+        assert n_failed == 3
+        assert body == FilterResult(
+            nodes=None, node_names=["n2"],
+            failed_nodes={"n1": "r1", "n4": own, "n3": own},
+        ).to_json()
+        with pytest.raises(TypeError):
+            wirec.filter_encode(parsed, table, b"\x00" * 4, None, own)
+
+    @pytest.mark.parametrize(
+        "case_idx",
+        [i for i, (_n, native) in enumerate(TestFilterNativeParity.CASES)
+         if native])
+    def test_the_default_argument_keeps_todays_bytes(
+        self, case_idx, monkeypatch
+    ):
+        """Every names-wire differential case: the encoder's bytes with no
+        default, an explicit None and the reference literal are the exact
+        path's."""
+        names, _native = TestFilterNativeParity.CASES[case_idx]
+        real = wirec.filter_encode
+        calls = TestFilterNativeParity._spy_filter_encode(monkeypatch)
+        build_filter_extender().filter(request_from(nn_body(names)))
+        (args,) = calls
+        monkeypatch.setenv("PAS_TPU_NO_NATIVE", "1")
+        python = build_filter_extender().filter(request_from(nn_body(names)))
+        for extra in ((), (None,), (b'"Node violates"',)):
+            body, _n_failed = real(*args, *extra)
+            assert body == python.body, (names, extra)
+
+    @pytest.mark.parametrize("case", sorted(TestFilterNodesWireParity.NATIVE))
+    def test_the_nodes_wire_keeps_todays_bytes(self, case):
+        body = TestFilterNodesWireParity.NATIVE[case]
+        parsed = wirec.parse_prioritize(body)
+        table = wirec.build_table(self.NAMES)
+        mask = b"\x01\x00\x00\x01"
+        reasons = [b'"r1"', None, None, None]
+        today = wirec.filter_encode_nodes(parsed, table, mask, reasons)
+        for extra in ((None,), (b'"Node violates"',)):
+            assert wirec.filter_encode_nodes(
+                parsed, table, mask, reasons, *extra) == today, case
