@@ -51,6 +51,8 @@ import threading
 import time
 from typing import Callable, Dict, List, NamedTuple, Optional, Set, Tuple
 
+import numpy as np
+
 from platform_aware_scheduling_tpu.extender.types import HostPriority
 from platform_aware_scheduling_tpu.kube.objects import Pod
 from platform_aware_scheduling_tpu.ops import topology
@@ -72,6 +74,9 @@ STATE_BOUND = "bound"
 #: drain is idle-dropped like an abandoned forming gang.
 STATE_DRAINING = "draining"
 STATE_RELEASED = "released"
+#: the states in which a gang holds its slice (bound gangs until released;
+#: draining victims while their pods terminate)
+HOLDING = (STATE_RESERVED, STATE_BOUND, STATE_DRAINING)
 
 #: seconds a gang's slice reservation holds without bind progress before
 #: it is reclaimed; each member Filter refreshes it
@@ -160,6 +165,7 @@ class _Gang:
         "members",
         "bound",
         "reserved_nodes",
+        "cells",
         "anchor",
         "domain",
         "created_at",
@@ -174,6 +180,9 @@ class _Gang:
         self.members: Set[str] = set()  # pod keys seen at Filter time
         self.bound: Dict[str, str] = {}  # pod key -> node
         self.reserved_nodes: List[str] = []  # row-major slice order
+        # int32 flat cells of reserved_nodes on the tracker's counting mesh
+        # while the gang holds them there (GangTracker._held_cells_locked)
+        self.cells: Optional[np.ndarray] = None
         self.anchor: Optional[Tuple[int, int, int, int]] = None  # i, j, h, w
         self.domain = ""  # the ICI domain the anchor lies in
         self.created_at = now
@@ -200,19 +209,47 @@ class _Gang:
         return out
 
 
+class HeldSet:
+    """The hosts every live reservation holds at one reservation version:
+    ``parts``, one ``(gang id, hosts)`` a holding gang in the tracker's
+    order, and :attr:`map`, ``{host: holding gang}`` (a host two gangs
+    hold maps to the later), made on its first read.  Built once a
+    version and shared: read it, never write."""
+
+    __slots__ = ("version", "parts", "_map")
+
+    def __init__(self, version: int, parts: Tuple[Tuple[str, List[str]], ...]):
+        self.version = version
+        self.parts = parts
+        self._map: Optional[Dict[str, str]] = None
+
+    def __bool__(self) -> bool:
+        return bool(self.parts)
+
+    @property
+    def map(self) -> Dict[str, str]:
+        held = self._map
+        if held is None:
+            held = {}
+            for gang_id, hosts in self.parts:
+                held.update(dict.fromkeys(hosts, gang_id))
+            self._map = held
+        return held
+
+
 class MemberVerdict(NamedTuple):
     """A gang member's Filter verdict in compact form: ``allowed`` (the
     slice, while the gang holds one) passes where telemetry-clean; every
     other clean candidate fails with the holder's reason where ``held``
     names one, else with ``reason``.  Without a slice every clean
-    candidate fails with ``reason``.  ``held`` ({node: holding gang} at
-    ``version``) and ``allowed`` are shared: read them, never write."""
+    candidate fails with ``reason``.  ``held`` (every reservation's hosts
+    at ``version``) and ``allowed`` are shared: read them, never write."""
 
     state: str
     allowed: List[str]
     reason: str
     version: int
-    held: Dict[str, str]
+    held: HeldSet
 
     @property
     def holds_slice(self) -> bool:
@@ -257,9 +294,14 @@ class GangTracker:
         # cached verdict can never outlive the reservation state it
         # encoded (docs/gang.md)
         self._reservation_version = 0
-        # (version, {node: holding gang}) — the held map built once a
-        # reservation version, read by every Filter (_held_locked)
-        self._held_memo: Optional[Tuple[int, Dict[str, str]]] = None
+        # the held set built once a reservation version, read by every
+        # Filter (_held_locked)
+        self._held_memo: Optional[HeldSet] = None
+        # how many live reservations hold each flat cell of _cell_mesh:
+        # kept as gangs reserve and release, under the lock, inside the
+        # same version bumps (_held_cells_locked)
+        self._cell_mesh: Optional[topology.MeshView] = None
+        self._cell_count: Optional[np.ndarray] = None
         self._mesh: Optional[topology.MeshView] = None
         self._mesh_at: float = -float("inf")
         self._swept_at: float = -float("inf")
@@ -302,6 +344,10 @@ class GangTracker:
             klog.error("gang mesh refresh failed: %s", exc)
             return mesh
         new = topology.MeshView(nodes)
+        if new.same_as(mesh):
+            # an unchanged cluster keeps its view: what is kept per view
+            # (the held cells, the Filter's row->cell map) stays valid
+            new = mesh
         with self._lock:
             self._mesh = new
             self._mesh_at = now
@@ -392,20 +438,78 @@ class GangTracker:
             # draining victims still hold: their pods are terminating on
             # the slice and the overlapping preemptor reservation relies
             # on nobody else slipping in (reservation-while-draining)
-            if gang.state in (STATE_RESERVED, STATE_BOUND, STATE_DRAINING):
+            if gang.state in HOLDING:
                 for node in gang.reserved_nodes:
                     held[node] = gang.gang_id
         return held
 
-    def _held_locked(self) -> Dict[str, str]:
-        """The full held map, built once a reservation version: every
-        change of the held set bumps the version, so the memo can never
-        outlive the state it maps.  Shared — callers only read it."""
+    def _held_locked(self) -> HeldSet:
+        """Every live reservation's hosts, built once a reservation
+        version — one step a holding gang: every change of the held set
+        bumps the version, so the memo can never outlive the state it
+        maps.  Shared — callers only read it."""
         memo = self._held_memo
-        if memo is None or memo[0] != self._reservation_version:
-            memo = (self._reservation_version, self._reserved_map_locked())
+        if memo is None or memo.version != self._reservation_version:
+            memo = HeldSet(
+                self._reservation_version,
+                tuple([
+                    (gang.gang_id, gang.reserved_nodes)
+                    for gang in self._gangs.values()
+                    if gang.state in HOLDING and gang.reserved_nodes
+                ]),
+            )
             self._held_memo = memo
-        return memo[1]
+        return memo
+
+    def _held_cells_locked(self, mesh: topology.MeshView) -> np.ndarray:
+        """int32 [``mesh.n_cells``]: how many live reservations hold each flat
+        cell of ``mesh``.  Kept as gangs reserve and release; counted anew
+        from every holding gang's hosts when ``mesh`` is not the view it
+        counts on (the first solve, a cluster whose nodes changed)."""
+        if mesh is not self._cell_mesh:
+            count = np.zeros(mesh.n_cells, dtype=np.int32)
+            for gang in self._gangs.values():
+                gang.cells = None
+                if gang.state in HOLDING and gang.reserved_nodes:
+                    cells = mesh.cells_of(gang.reserved_nodes)
+                    gang.cells = cells[cells >= 0]
+                    np.add.at(count, gang.cells, 1)
+            self._cell_mesh = mesh
+            self._cell_count = count
+        return self._cell_count
+
+    def _hold_cells_locked(
+        self, gang: _Gang, cells: Optional[np.ndarray] = None
+    ) -> None:
+        """``gang`` now holds ``gang.reserved_nodes``: count its cells
+        (``cells``, where the solve on the counting mesh knows them)."""
+        mesh = self._cell_mesh
+        gang.cells = None
+        if mesh is None:
+            return  # nothing is counted yet: the first solve counts all
+        if cells is None:
+            cells = mesh.cells_of(gang.reserved_nodes)
+            cells = cells[cells >= 0]
+        gang.cells = cells
+        np.add.at(self._cell_count, cells, 1)
+
+    def _unhold_cells_locked(self, gang: _Gang) -> None:
+        """``gang`` stops holding its hosts: uncount its cells."""
+        if gang.cells is not None:
+            np.subtract.at(self._cell_count, gang.cells, 1)
+            gang.cells = None
+
+    def _taken_cells_locked(
+        self, mesh: topology.MeshView, gang: _Gang
+    ) -> np.ndarray:
+        """bool [padded domains, rows, cols] of ``mesh``: the cells some
+        live reservation other than ``gang``'s holds — the cells of
+        ``_reserved_map_locked(exclude=gang)``'s hosts."""
+        count = self._held_cells_locked(mesh)
+        if gang.cells is not None:
+            count = count.copy()
+            np.subtract.at(count, gang.cells, 1)
+        return (count > 0).reshape(mesh.padded_domains, mesh.rows, mesh.cols)
 
     def _prune_locked(self, now: float) -> int:
         """Reclaim expired reservations (gang re-forms) and drop gangs
@@ -418,6 +522,7 @@ class GangTracker:
                 and gang.expires_at is not None
                 and gang.expires_at <= now
             ):
+                self._unhold_cells_locked(gang)
                 gang.state = STATE_FORMING
                 gang.reserved_nodes = []
                 gang.anchor = None
@@ -448,6 +553,7 @@ class GangTracker:
     def _drop_locked(self, gang_id: str) -> None:
         dropped = self._gangs.pop(gang_id, None)
         if dropped is not None:
+            self._unhold_cells_locked(dropped)
             if dropped.reserved_nodes:
                 self._reservation_version += 1  # its slice is free again
                 self._journal_gen += 1
@@ -469,7 +575,7 @@ class GangTracker:
         held = sum(
             len(gang.reserved_nodes)
             for gang in self._gangs.values()
-            if gang.state in (STATE_RESERVED, STATE_BOUND, STATE_DRAINING)
+            if gang.state in HOLDING
         )
         return float(active), float(held)
 
@@ -482,19 +588,27 @@ class GangTracker:
     def _try_reserve_locked(
         self,
         gang: _Gang,
-        candidates: List[str],
+        candidates: Optional[List[str]],
         mesh: Optional[topology.MeshView],
         now: float,
+        clean: Optional[np.ndarray] = None,
+        span=trace.NULL_SPAN,
     ) -> Optional[str]:
         """Attempt the all-or-nothing reservation for a forming gang over
-        this request's candidates.  Returns None on success (the gang
-        holds a slice) or the bounded rejection-reason label."""
+        this request's candidates: their names, or — for a gang with a
+        topology on a mesh — ``clean``, the bool mask of ``mesh``'s cells
+        whose nodes are candidates (``candidates`` is then unused).  Both
+        reserve the same slice.  The solve's steps are stages on ``span``
+        (ops/topology.py ``best_domain_anchor``).  Returns None on success
+        (the gang holds a slice) or the bounded rejection-reason label."""
         # gang.bound is always empty here: both paths into FORMING (new
         # gang, TTL expiry) clear it — abandoned-slice binds never leak
         # into a new solve (the straddling fix)
-        held = self._reserved_map_locked(exclude=gang.gang_id)
-        free = [name for name in candidates if name not in held]
         spec = gang.spec
+        cells = None
+        if clean is None:
+            held = self._reserved_map_locked(exclude=gang.gang_id)
+            free = [name for name in candidates if name not in held]
         if spec.topology is None:
             # size-only gang: any k nodes, chosen in sorted-name order
             # for determinism (no adjacency constraint, no mesh needed)
@@ -508,13 +622,24 @@ class GangTracker:
             if mesh is None or len(mesh) == 0:
                 return "no_mesh"
             # one device program per orientation over every ICI domain:
-            # a slice never spans two
-            found = topology.best_slice(
-                mesh, free, spec.topology, use_device=self.use_device
+            # a slice never spans two.  From cells, the free mask is the
+            # candidates' cells less the cells other gangs hold: a step a
+            # cell, never a step a host by name
+            found = topology.best_slice_in(
+                mesh,
+                mesh.free_masks(free)
+                if clean is None
+                else clean & ~self._taken_cells_locked(mesh, gang),
+                spec.topology,
+                use_device=self.use_device,
+                span=span,
             )
             if found is None:
                 return "infeasible"
-            gang.reserved_nodes, gang.anchor, gang.domain = found
+            gang.reserved_nodes, gang.anchor, gang.domain, cells = found
+        self._hold_cells_locked(
+            gang, cells if mesh is self._cell_mesh else None
+        )
         gang.state = STATE_RESERVED
         gang.expires_at = now + self.ttl_s
         self._reservation_version += 1
@@ -546,7 +671,7 @@ class GangTracker:
             self._sweep_dead_gangs(now)
             with self._lock:
                 expired = self._prune_locked(now)
-                held = self._held_locked()
+                held = self._held_locked().map
                 gauges = self._publish_gauges_locked()
             for name in candidates:
                 holder = held.get(name)
@@ -562,7 +687,7 @@ class GangTracker:
         )
         if verdict.holds_slice:
             allowed = set(verdict.allowed)
-            held = verdict.held
+            held = verdict.held.map
             for name in candidates:
                 if name in allowed:
                     continue
@@ -586,30 +711,42 @@ class GangTracker:
         pod_labels: Dict[str, str],
         clean_names: Callable[[], List[str]],
         span=trace.NULL_SPAN,
+        clean_cells: Optional[
+            Callable[[topology.MeshView], np.ndarray]
+        ] = None,
     ) -> Optional[MemberVerdict]:
         """A gang member's Filter verdict in compact form, or None for a
         pod whose labels make it no member.  ``clean_names`` gives the
         request's telemetry-clean candidates; it is called only when the
         gang must reserve (the solve's free mask sees clean candidates
-        alone, so a gang can never reserve a slice it cannot bind)."""
+        alone, so a gang can never reserve a slice it cannot bind).
+        ``clean_cells``, where given, gives the same candidates as a bool
+        mask of a mesh's cells, and a gang with a topology reserves from
+        it (``pas_gang_reserve_rows_total``) without their names."""
         spec = GangSpec.from_labels(namespace, name, pod_labels)
         if spec is None:
             return None
         return self._member_verdict(
-            spec, f"{namespace}/{name}", clean_names, span
+            spec, f"{namespace}/{name}", clean_names, span, clean_cells
         )
 
     def _member_verdict(
-        self, spec: GangSpec, key: str, clean_names, span
+        self, spec: GangSpec, key: str, clean_names, span, clean_cells=None
     ) -> MemberVerdict:
         """Every side effect of a member's Filter — membership, the TTL
         refresh of a held slice, the reservation of a forming gang (the
-        stage ``gang_reserve`` on ``span``: the held map, the free mask,
-        the solve, the anchor and its names), the counters, gauges and
-        journal — and its verdict, with no per-candidate work."""
+        stage ``gang_reserve`` on ``span``: the held cells or map, the
+        free mask, the solve — its stages ``gang_upload``,
+        ``gang_launch``, ``gang_fetch`` — the anchor and its names;
+        the candidates' names or cells are made before it), the counters,
+        gauges and journal — and its verdict, with no per-candidate
+        work."""
         now = self._clock()
         self._sweep_dead_gangs(now)
         mesh = self._mesh_view(now) if spec.topology is not None else None
+        from_cells = (
+            clean_cells is not None and mesh is not None and len(mesh) > 0
+        )
         reserved = False
         rejected_reason = None
         with self._lock:
@@ -624,10 +761,11 @@ class GangTracker:
             gang.members.add(key)
             self._member_gang[key] = spec.gang_id
             if gang.state == STATE_FORMING:
-                candidates = clean_names()
-                with span.stage("gang_reserve"):
+                candidates = None if from_cells else clean_names()
+                clean = clean_cells(mesh) if from_cells else None
+                with span.stage("gang_reserve", leaf=False):
                     rejected_reason = self._try_reserve_locked(
-                        gang, candidates, mesh, now
+                        gang, candidates, mesh, now, clean=clean, span=span
                     )
                 reserved = rejected_reason is None
             state = gang.state
@@ -664,6 +802,8 @@ class GangTracker:
         self._count_expired(expired)
         if reserved:
             trace.COUNTERS.inc("pas_gang_reservations_total")
+            if from_cells:
+                trace.COUNTERS.inc("pas_gang_reserve_rows_total")
         if rejected_reason is not None:
             trace.COUNTERS.inc(
                 "pas_gang_rejected_total", labels={"reason": rejected_reason}
@@ -911,8 +1051,10 @@ class GangTracker:
             gang.members.add(key)
             self._member_gang[key] = spec.gang_id
             gang.last_seen = now
+            self._unhold_cells_locked(gang)  # a reserved gang's old slice
             gang.state = STATE_RESERVED
             gang.reserved_nodes = list(nodes)
+            self._hold_cells_locked(gang)
             gang.anchor = tuple(anchor) if anchor is not None else None
             gang.bound = {}
             gang.expires_at = now + self.ttl_s
@@ -1136,6 +1278,7 @@ class GangTracker:
                     gang.state = STATE_RESERVED
                     gang.expires_at = now + self.ttl_s
                 self._gangs[gang_id] = gang
+                self._hold_cells_locked(gang)
                 for key in gang.members:
                     self._member_gang[key] = gang_id
                 restored += 1
@@ -1156,8 +1299,8 @@ class GangTracker:
 
     # -- introspection ---------------------------------------------------------
 
-    def cache_token(self) -> Tuple[int, Dict[str, str]]:
-        """(reservation version, {node: holding gang id}) for the Filter
+    def cache_token(self) -> Tuple[int, HeldSet]:
+        """(reservation version, the held set at it) for the Filter
         response cache (tas/telemetryscheduler._gang_cache_token): every
         reservation change bumps the version, so a cached response keyed
         on it can never outlive the state it encoded.  Prunes expired

@@ -228,18 +228,21 @@ def _anchor_of(best) -> Optional[Tuple[int, int, int, int]]:
     return None if score >= INFEASIBLE else (score, d, i, j)
 
 
+def _dispatch_anchor(free, h: int, w: int):
+    """The device program of one orientation over a bool [D, M, N] mask,
+    dispatched and not read back; None where the window cannot fit."""
+    _, m, n = free.shape
+    if h > m or w > n:
+        return None
+    return _domains_best_anchor(jnp.asarray(free, dtype=bool), int(h), int(w))
+
+
 def domains_anchor_device(free, h: int, w: int):
     """Device path: ``(score, domain, row, col)`` of the best anchor over
     a bool [D, M, N] mask (a NumPy array, or one already on the device),
     None when no window fits; one dispatch and a 16-byte readback."""
-    _, m, n = free.shape
-    if h > m or w > n:
-        return None
-    return _anchor_of(
-        np.asarray(
-            _domains_best_anchor(jnp.asarray(free, dtype=bool), int(h), int(w))
-        )
-    )
+    best = _dispatch_anchor(free, h, w)
+    return None if best is None else _anchor_of(np.asarray(best))
 
 
 def domains_anchor_host(free: np.ndarray, h: int, w: int):
@@ -259,18 +262,31 @@ def domains_anchor_host(free: np.ndarray, h: int, w: int):
 
 
 def best_domain_anchor(
-    free: np.ndarray, shapes: Sequence[Tuple[int, int]], use_device: bool = True
+    free: np.ndarray,
+    shapes: Sequence[Tuple[int, int]],
+    use_device: bool = True,
+    span=trace.NULL_SPAN,
 ) -> Optional[Tuple[int, int, int, int, int]]:
     """``(h, w, domain, row, col)`` of the best anchor of a bool [D, M, N]
     free mask over the orientations ``shapes`` (in preference order): the
     lowest (score, orientation, domain, row, col).  The mask goes up once
-    and each orientation is one program over every domain; device
-    trouble falls back to the host mirror, counted."""
+    and each orientation is one program over every domain, every one
+    dispatched before any is read back (one wait for the chip, not one an
+    orientation); device trouble falls back to the host mirror,
+    counted.  The three steps are the stages ``gang_upload``,
+    ``gang_launch`` and ``gang_fetch`` on ``span``."""
     found = None
     if use_device:
         try:
-            on_device = jnp.asarray(free, dtype=bool)
-            found = [domains_anchor_device(on_device, h, w) for h, w in shapes]
+            with span.stage("gang_upload"):
+                on_device = jnp.asarray(free, dtype=bool)
+            with span.stage("gang_launch"):
+                runs = [_dispatch_anchor(on_device, h, w) for h, w in shapes]
+            with span.stage("gang_fetch"):
+                read = iter(
+                    jax.device_get([r for r in runs if r is not None])
+                )
+            found = [None if r is None else _anchor_of(next(read)) for r in runs]
         except Exception:
             _count_fallback()
     if found is None:
@@ -455,9 +471,49 @@ class MeshView:
         self.name_at = name_at
         self.rows = max_row + 1
         self.cols = max_col + 1
+        #: {name: flat cell} — ``(domain * rows + row) * cols + col``, the
+        #: index of the node's cell in a flattened :meth:`free_masks`
+        self.cell_of: Dict[str, int] = {
+            name: (d * self.rows + i) * self.cols + j
+            for (d, i, j), name in name_at.items()
+        }
 
     def __len__(self) -> int:
         return len(self.coord_of)
+
+    def same_as(self, other: Optional["MeshView"]) -> bool:
+        """Whether ``other`` maps every name to the same cell of the same
+        grids (a rebuilt view of an unchanged cluster)."""
+        return (
+            other is not None
+            and other.domains == self.domains
+            and other.name_at == self.name_at
+        )
+
+    @property
+    def n_cells(self) -> int:
+        """Cells of the flattened ``[padded domains, rows, cols]`` grids."""
+        return self.padded_domains * self.rows * self.cols
+
+    def cells_of(self, names) -> np.ndarray:
+        """int32 flat cell of each name, -1 for a name off the mesh."""
+        get = self.cell_of.get
+        return np.fromiter((get(name, -1) for name in names), dtype=np.int32)
+
+    def mask_of(self, cells: np.ndarray) -> np.ndarray:
+        """bool [padded domains, rows, cols]: True at ``cells`` (flat; a
+        negative cell is no cell) — :meth:`free_masks` of the names the
+        cells belong to."""
+        mask = np.zeros(self.n_cells, dtype=bool)
+        mask[cells[cells >= 0]] = True
+        return mask.reshape(self.padded_domains, self.rows, self.cols)
+
+    def window_cells(self, domain: int, i: int, j: int, h: int, w: int):
+        """int32 flat cells of the ``h x w`` window at (i, j) of one domain,
+        in row-major slice order."""
+        rows = (domain * self.rows + i + np.arange(h, dtype=np.int32))
+        cols = j + np.arange(w, dtype=np.int32)
+        return (rows[:, None] * self.cols + cols[None, :]).reshape(-1)
 
     def free_masks(self, free_names) -> np.ndarray:
         """bool [padded domains, rows, cols]: a cell is free iff its node
@@ -503,12 +559,29 @@ def best_slice(
     orientation, over the free nodes of every ICI domain — one device
     program per orientation (:func:`best_domain_anchor`); None when no
     slice fits."""
+    found = best_slice_in(mesh, mesh.free_masks(free_names), shape, use_device)
+    return None if found is None else found[:3]
+
+
+def best_slice_in(
+    mesh: MeshView,
+    free: np.ndarray,
+    shape: Tuple[int, int],
+    use_device: bool = True,
+    span=trace.NULL_SPAN,
+):
+    """:func:`best_slice` over a ready bool ``[padded domains, rows, cols]``
+    free mask: ``(names, (row, col, h, w), domain label, int32 flat cells
+    of the slice)``, or None.  The solve's steps are stages on ``span``
+    (:func:`best_domain_anchor`)."""
     h, w = shape
     shapes = [(h, w)] if h == w else [(h, w), (w, h)]
-    found = best_domain_anchor(mesh.free_masks(free_names), shapes, use_device)
+    found = best_domain_anchor(free, shapes, use_device, span)
     if found is None:
         return None
     hh, ww, d, i, j = found
     # every cell of the window is free, so every one has its node
     names = mesh.names_for(slice_cells(i, j, hh, ww), domain=d)
-    return names, (i, j, hh, ww), mesh.domains[d]
+    return names, (i, j, hh, ww), mesh.domains[d], mesh.window_cells(
+        d, i, j, hh, ww
+    )

@@ -29,7 +29,8 @@ from __future__ import annotations
 import json
 import os
 import threading
-from typing import Dict, List, Optional, Tuple
+from collections.abc import Mapping
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -153,6 +154,57 @@ class _ViewTable:
         return table
 
 
+class _MergedReasons(Mapping):
+    """``{node: reason}`` of a merged verdict: the telemetry's reasons,
+    then every clean held node with its holder's reason, in the held
+    map's order — made on the first read of an entry.  A decision record
+    keeps it by reference and asks its length alone; only a reader of the
+    entries (``/debug/decisions``, a bind's outcome) pays the walk."""
+
+    __slots__ = ("base", "_held", "_index", "_violating", "_len", "_map")
+
+    def __init__(self, reasons, held, index, violating, length: int):
+        self.base = reasons  # the telemetry's own {node: reason}
+        self._held = held
+        self._index = index
+        self._violating = violating
+        self._len = length
+        self._map = None
+
+    def _built(self) -> Dict[str, str]:
+        merged = self._map
+        if merged is None:
+            merged = dict(self.base)
+            index, violating = self._index, self._violating
+            for node, gang_id in self._held.map.items():
+                row = index.get(node)
+                if row is not None and not violating[row]:
+                    merged[node] = shared_labels.gang_reserved_reason(gang_id)
+            self._map = merged
+        return merged
+
+    def __getitem__(self, node: str) -> str:
+        return self._built()[node]
+
+    def __iter__(self):
+        return iter(self._built())
+
+    def __len__(self) -> int:
+        return self._len
+
+
+class GangMerged(NamedTuple):
+    """A merged (telemetry + gang-held) Filter verdict at one reservation
+    version (:meth:`PrioritizeFastPath.gang_merged`), over the name
+    table's rows."""
+
+    violations: frozenset  # violating or held-clean rows
+    reasons: "_MergedReasons"  # {node: reason} of those rows
+    table: list  # JSON reason bytes a row, for the native encoder
+    holder_at: np.ndarray  # int32 a row: its holder in held_reasons, or -1
+    held_reasons: list  # a holding gang's reason, in the held set's order
+
+
 class PrioritizeFastPath:
     """Caches global rankings + violation sets per state version and
     answers verbs with numpy selections over them."""
@@ -217,10 +269,13 @@ class PrioritizeFastPath:
         self._prioritize_skeletons: List[list] = []
         # merged (telemetry + gang reservation) Filter verdicts, one per
         # (violation-set identity, reservation version, policy):
-        # [violations, version, policy, merged frozenset, merged reasons,
-        #  merged reason-bytes table] — MRU, shared by every non-gang
-        # request at one (state, reservation) generation
+        # [violations, version, policy, GangMerged] — MRU, shared by every
+        # non-gang request at one (state, reservation) generation
         self._gang_merged: List[list] = []
+        # {gang id: its hosts' rows and reason} while it holds (_gang_part)
+        self._gang_parts: Dict[str, tuple] = {}
+        # (name table, mesh view, row -> flat cell) (_mesh_rows)
+        self._row_cells = None
         # [ranked, table, top-K (name, score) head] — the shared
         # prioritize score breakdown decision records reference
         self._explain_heads: List[list] = []
@@ -1099,19 +1154,22 @@ class PrioritizeFastPath:
         policy_name: str,
         violations: frozenset,
         reasons: Dict[str, str],
-        held: Dict[str, str],
+        held,
         version: int,
         span=trace.NULL_SPAN,
-    ) -> Tuple[frozenset, Dict[str, str], list]:
-        """The non-gang-pod Filter verdict under active reservations:
-        ``(merged violating rows, merged {node: reason}, merged per-row
-        reason-bytes table)`` — telemetry violations plus gang-held
+    ) -> "GangMerged":
+        """The non-gang-pod Filter verdict under active reservations
+        (:class:`GangMerged`) — telemetry violations plus gang-held
         nodes, with the telemetry reason winning a collision exactly like
         the exact path's overlay merge (the overlay only ever fails
-        telemetry-CLEAN candidates).  Memoized per (violation-set
-        identity, reservation version, policy) so every cached request at
-        one generation shares the same objects.  A new version's build is
-        the stage ``gang_merge`` on ``span``."""
+        telemetry-CLEAN candidates).  ``held`` is the tracker's
+        ``HeldSet`` at ``version`` (gang/group.py).  Memoized per
+        (violation-set identity, reservation version, policy) so every
+        cached request at one generation shares the same objects.
+
+        A new version's build is the stage ``gang_merge`` on ``span``:
+        whole-array steps over every holding gang's table rows, with no
+        step a held host by name (:meth:`_gang_part`)."""
         with self._lock:
             for idx, entry in enumerate(self._gang_merged):
                 if (
@@ -1123,40 +1181,11 @@ class PrioritizeFastPath:
                         self._gang_merged.insert(
                             0, self._gang_merged.pop(idx)
                         )
-                    return entry[3], entry[4], entry[5]
+                    return entry[3]
         with span.stage("gang_merge"):
-            return self._gang_merge(
-                compiled, view, policy_name, violations, reasons, held, version
+            merged = self._gang_merge(
+                compiled, view, policy_name, violations, reasons, held
             )
-
-    def _gang_merge(
-        self, compiled, view, policy_name, violations, reasons, held, version
-    ):
-        index = view.node_index
-        gang_rows: Dict[int, Tuple[str, str]] = {}
-        for node, gang_id in held.items():
-            row = index.get(node)
-            if row is not None and row not in violations:
-                gang_rows[row] = (node, gang_id)
-        merged = frozenset(violations | set(gang_rows))
-        merged_reasons = dict(reasons)
-        n_rows = len(view.node_names)
-        rule_map = self.violation_rule_map(compiled, view)
-        if rule_map is not None:
-            base = self.reason_table(
-                compiled, view, policy_name, violations, rule_map, n_rows
-            )
-            table = list(base[:n_rows])
-            table += [None] * (n_rows - len(table))
-        else:
-            table = [None] * n_rows
-        for row, (node, gang_id) in gang_rows.items():
-            reason = shared_labels.gang_reserved_reason(gang_id)
-            merged_reasons[node] = reason
-            if row < n_rows:
-                table[row] = json.dumps(reason).encode()
-        self._violation_mask(merged, n_rows)  # the encoder's mask, memoized
-        entry = [violations, version, policy_name, merged, merged_reasons, table]
         with self._lock:
             for existing in self._gang_merged:
                 if (
@@ -1164,10 +1193,106 @@ class PrioritizeFastPath:
                     and existing[1] == version
                     and existing[2] == policy_name
                 ):
-                    return existing[3], existing[4], existing[5]
-            self._gang_merged.insert(0, entry)
+                    return existing[3]  # another thread built it first
+            self._gang_merged.insert(
+                0, [violations, version, policy_name, merged]
+            )
             del self._gang_merged[self.RESPONSE_CACHE_SIZE :]
-        return merged, merged_reasons, table
+        return merged
+
+    def _gang_merge(
+        self, compiled, view, policy_name, violations, reasons, held
+    ) -> "GangMerged":
+        index = view.node_index
+        n_rows = len(view.node_names)
+        violating = np.frombuffer(
+            self._violation_mask(violations, n_rows), dtype=np.uint8
+        )
+        known, kept = self._gang_parts, {}
+        for gang_id, hosts in held.parts:
+            part = known.get(gang_id)
+            if part is None or part[0] is not hosts or part[1] is not index:
+                part = self._gang_part(index, gang_id, hosts)
+            kept[gang_id] = part
+        self._gang_parts = kept  # gangs that no longer hold fall out
+        parts = list(kept.values())
+        rows = (
+            np.concatenate([part[2] for part in parts])
+            if parts
+            else np.zeros(0, dtype=np.int32)
+        )
+        holder = np.repeat(
+            np.arange(len(parts), dtype=np.int32),
+            [len(part[2]) for part in parts],
+        )
+        held_rows = np.zeros(n_rows, dtype=bool)
+        held_rows[rows] = True
+        if np.count_nonzero(held_rows) < len(rows):
+            # a host two gangs hold takes the later one's reason, as the
+            # held map's last writer: each row's last occurrence is kept
+            last = len(rows) - 1 - np.unique(rows[::-1], return_index=True)[1]
+            rows, holder = rows[last], holder[last]
+        # a violating row keeps its telemetry reason
+        clean = violating[rows] == 0
+        rows, holder = rows[clean], holder[clean]
+        rule_map = self.violation_rule_map(compiled, view)
+        if rule_map is not None:
+            base = self.reason_table(
+                compiled, view, policy_name, violations, rule_map, n_rows
+            )
+            table = np.fromiter(base[:n_rows], dtype=object, count=n_rows)
+        else:
+            table = np.full(n_rows, None, dtype=object)
+        table[rows] = np.array([part[4] for part in parts], dtype=object)[
+            holder
+        ]
+        holder_at = np.full(n_rows, -1, dtype=np.int32)
+        holder_at[rows] = holder
+        merged = violations.union(rows.tolist())
+        mask = violating | held_rows
+        self._seed_mask(merged, n_rows, mask.view(np.uint8).tobytes())
+        return GangMerged(
+            merged,
+            _MergedReasons(
+                reasons, held, index, violating,
+                len(reasons) + len(merged) - len(violations),
+            ),
+            table.tolist(),
+            holder_at,
+            [part[3] for part in parts],
+        )
+
+    def _gang_part(self, index, gang_id: str, hosts: List[str]) -> tuple:
+        """``(hosts, index, int32 table rows of its hosts, its reason, the
+        reason's JSON bytes)`` of one holding gang — made once its hold
+        (or the name table) is new and kept while it lasts (``_gang_merge``):
+        the only step a host by name is here, a gang's hosts once."""
+        at = [index.get(host) for host in hosts]
+        reason = shared_labels.gang_reserved_reason(gang_id)
+        return (
+            hosts,
+            index,
+            np.array([r for r in at if r is not None], dtype=np.int32),
+            reason,
+            json.dumps(reason).encode(),
+        )
+
+    def _seed_mask(
+        self, violations: frozenset, n_rows: int, mask_bytes: bytes
+    ) -> None:
+        """Give :meth:`_violation_mask` a set's mask made another way."""
+        with self._lock:
+            self._viol_masks.insert(0, [violations, n_rows, mask_bytes])
+            del self._viol_masks[self.RESPONSE_CACHE_SIZE :]
+
+    def _mesh_rows(self, table: _ViewTable, mesh) -> np.ndarray:
+        """int32 flat mesh cell of each of ``table``'s rows, -1 for a node
+        off the mesh: built once a (name table, mesh view) pair."""
+        memo = self._row_cells
+        if memo is None or memo[0] is not table or memo[1] is not mesh:
+            memo = (table, mesh, mesh.cells_of(table.node_names))
+            self._row_cells = memo
+        return memo[2]
 
     def gang_member_filter(
         self,
@@ -1188,8 +1313,10 @@ class PrioritizeFastPath:
         encoder would pass it), or where ``verdict_of`` found no member.
 
         ``explained`` is :meth:`violation_reasons`' triple; ``verdict_of``
-        takes a function that materialises the telemetry-clean candidate
-        names and returns the tracker's compact verdict
+        takes two functions — one that materialises the telemetry-clean
+        candidate names, one that gives them as a bool mask of a mesh
+        view's cells (a scatter of the candidate rows' cells:
+        :meth:`_mesh_rows`) — and returns the tracker's compact verdict
         (gang/group.py ``MemberVerdict``).  The verdict is three row sets
         over one mask: the slice's clean rows pass; a violating row keeps
         its telemetry reason; every other row fails with its holder's
@@ -1216,18 +1343,26 @@ class PrioritizeFastPath:
             keep = np.flatnonzero(violating[rows] == 0).tolist()
             return [names[k] for k in keep]
 
-        verdict = verdict_of(clean_names)
+        def clean_cells(mesh) -> np.ndarray:
+            return mesh.mask_of(
+                self._mesh_rows(table, mesh)[rows[violating[rows] == 0]]
+            )
+
+        verdict = verdict_of(clean_names, clean_cells)
         if verdict is None:
             return None
         mask = np.ones(n_rows, dtype=np.uint8)
+        merged = None
         if verdict.holds_slice:
-            merged, reason_of, reason_rows = self.gang_merged(
+            merged = self.gang_merged(
                 compiled, view, policy_name, violations, reasons,
                 verdict.held, verdict.version, span,
             )
+            reason_rows = merged.table
             # rows failing with a reason of their own: violating or held
             own_reason = np.frombuffer(
-                self._violation_mask(merged, n_rows), dtype=np.uint8
+                self._violation_mask(merged.violations, n_rows),
+                dtype=np.uint8,
             )
             index = table.node_index
             at = [
@@ -1237,7 +1372,6 @@ class PrioritizeFastPath:
             ]
             mask[at] = violating[at]
         else:
-            reason_of = reasons
             own_reason = violating
             rule_map = self.violation_rule_map(compiled, view)
             reason_rows = (
@@ -1282,11 +1416,12 @@ class PrioritizeFastPath:
                 break
             name = node_names[row]
             if name not in head:
-                head[name] = (
-                    reason_of.get(name, "Node violates")
-                    if own_reason[row]
-                    else verdict.reason
-                )
+                if violating[row]:
+                    head[name] = reasons.get(name, "Node violates")
+                elif own_reason[row]:
+                    head[name] = merged.held_reasons[merged.holder_at[row]]
+                else:
+                    head[name] = verdict.reason
         return body, int(n_failed), counts, head
 
     # -- filter response reuse -------------------------------------------------

@@ -1023,10 +1023,11 @@ class MetricsExtender:
                 if explained is None:
                     return None
 
-            def verdict_of(clean_names):
+            def verdict_of(clean_names, clean_cells):
                 with span.stage("gang_overlay", leaf=False):
                     return self.gangs.member_verdict(
-                        namespace, name, pod_labels, clean_names, span=span
+                        namespace, name, pod_labels, clean_names, span=span,
+                        clean_cells=clean_cells,
                     )
 
             answer = self.fastpath.gang_member_filter(
@@ -1155,11 +1156,12 @@ class MetricsExtender:
                     # merge the reservation overlay into the verdict the
                     # cached bytes will encode (non-gang pods fail
                     # gang-held nodes with the concrete gang reason)
+                    merged = self.fastpath.gang_merged(
+                        compiled, view, policy.name, violations,
+                        reasons, held, gang_version, span,
+                    )
                     violations, reasons, reason_table = (
-                        self.fastpath.gang_merged(
-                            compiled, view, policy.name, violations,
-                            reasons, held, gang_version, span,
-                        )
+                        merged.violations, merged.reasons, merged.table
                     )
             candidates = (
                 parsed.num_node_names if use_node_names else parsed.num_nodes

@@ -180,7 +180,10 @@ declare("pas_stage_handle_seconds_total", "counter", "Seconds of those handle st
 declare("pas_stage_scan_total", "counter", "Sampled scan stages recorded on verb spans (Filter's native scan in the probe).")
 declare("pas_stage_scan_seconds_total", "counter", "Seconds of those scan stages.")
 declare("pas_stage_gang_reserve_total", "counter", "Verb spans that recorded the stage gang_reserve: a gang member's Filter (or Prioritize) that solved for a slice.")
-declare("pas_stage_gang_reserve_seconds_total", "counter", "Seconds of those gang_reserve stages: held map, free mask, the solve over every ICI domain, anchor and names.")
+declare("pas_stage_gang_reserve_seconds_total", "counter", "Seconds of those gang_reserve stages: held cells or map, free mask, the solve over every ICI domain, anchor and names.")
+declare("pas_stage_gang_upload_seconds_total", "counter", "Seconds of the stages gang_upload inside gang_reserve: the solve's free mask put on the device (over pas_stage_gang_reserve_total, its share of a reservation).")
+declare("pas_stage_gang_launch_seconds_total", "counter", "Seconds of the stages gang_launch inside gang_reserve: every orientation's program of the solve dispatched.")
+declare("pas_stage_gang_fetch_seconds_total", "counter", "Seconds of the stages gang_fetch inside gang_reserve: the orientations' anchors read back, the one wait for the device and for the interpreter after it.")
 declare("pas_stage_gang_overlay_total", "counter", "Verb spans that recorded the stage gang_overlay: the gang tracker's Filter or Prioritize overlay, on the exact path.")
 declare("pas_stage_gang_overlay_seconds_total", "counter", "Seconds of those gang_overlay stages.")
 declare("pas_stage_gang_merge_total", "counter", "Verb spans that recorded the stage gang_merge: a Filter that built the merged (telemetry + gang-held) verdict of a new reservation version.")
@@ -269,6 +272,7 @@ declare("pas_decision_evicted_open_total", "counter", "Open decision records ove
 # gang & topology-aware scheduling (gang/group.py + ops/topology.py:
 # atomic multi-host slice placement with TTL reservations; docs/gang.md)
 declare("pas_gang_reservations_total", "counter", "Gang slice reservations created (a feasible anchor found and its nodes held).")
+declare("pas_gang_reserve_rows_total", "counter", "Of those, reservations whose free mask came from the Filter's candidate rows (a scatter of their mesh cells, less the held cells), not from the candidates' names.")
 declare("pas_gang_reservation_expirations_total", "counter", "Gang reservations reclaimed after their TTL expired before the gang fully bound.")
 declare("pas_gang_admitted_total", "counter", "Gangs fully bound (every member landed on its reserved slice).")
 declare("pas_gang_filter_native_total", "counter", "Gang members' Filters answered by the native encoder from the tracker's compact verdict (each also counted in pas_filter_cache_miss_total and in pas_filter_native_total, wire names); over the Filters served it is the members' native share.")
@@ -995,6 +999,9 @@ VERB_FAMILIES = (
     "pas_stage_gang_overlay_seconds_total",
     "pas_stage_gang_merge_total",
     "pas_stage_gang_merge_seconds_total",
+    "pas_stage_gang_upload_seconds_total",
+    "pas_stage_gang_launch_seconds_total",
+    "pas_stage_gang_fetch_seconds_total",
 )
 _VERB_SPANS = "POST /scheduler/"  # the name of a served verb's span begins so
 _at = VERB_FAMILIES.index
@@ -1028,6 +1035,10 @@ _FOLDED_STAGES = {
         _at("pas_stage_gang_merge_total"),
         _at("pas_stage_gang_merge_seconds_total"),
     ),
+    # the solve's steps inside gang_reserve: counted by gang_reserve's count
+    "gang_upload": (-1, _at("pas_stage_gang_upload_seconds_total")),
+    "gang_launch": (-1, _at("pas_stage_gang_launch_seconds_total")),
+    "gang_fetch": (-1, _at("pas_stage_gang_fetch_seconds_total")),
 }
 
 

@@ -22,6 +22,7 @@
 import json
 import os
 import sys
+import threading
 import time
 
 import numpy as np
@@ -29,6 +30,7 @@ import pytest
 
 from benchmarks.gang_load import POLICY, _gang_pod_obj, _policy_obj, _post
 from platform_aware_scheduling_tpu.gang import GangTracker
+from platform_aware_scheduling_tpu.gang.group import DEFAULT_TTL_S
 from platform_aware_scheduling_tpu.ops import topology
 from platform_aware_scheduling_tpu.ops.state import TensorStateMirror
 from platform_aware_scheduling_tpu.tas.cache import AutoUpdatingCache
@@ -702,3 +704,559 @@ class TestMemberFilterFallback:
         if not case.startswith("degraded"):
             assert got_moved.get("pas_filter_cache_bypass_total") == 1
         assert got_moved == want_moved
+
+
+# ---------------------------------------------------------------------------
+# a reservation version's held-host work: mesh cells and table rows
+# ---------------------------------------------------------------------------
+
+
+def holey_fleet(seed: int, domains: int):
+    """Hosts of ``domains`` 8x8 ICI domains, one in twenty coordinates a
+    hole, and five hosts with no coordinate at all."""
+    gen = np.random.default_rng(seed)
+    nodes = []
+    for d in range(domains):
+        for r in range(8):
+            for c in range(8):
+                if gen.random() < 0.05:
+                    continue
+                nodes.append(make_node(
+                    f"host-{(d * 8 + r) * 8 + c:05d}",
+                    labels={
+                        labels.TPU_DOMAIN_LABEL: f"pod-{d:03d}",
+                        labels.TPU_COORD_LABEL: labels.format_coord(r, c),
+                    },
+                ))
+    return nodes + [make_node(f"plain-{i}") for i in range(5)]
+
+
+def hold_random(tracker, names, gen, gangs: int):
+    """``gangs`` gangs reserve random hosts (preemption's reserve_slice:
+    any names, on or off the mesh, overlaps with another gang's left
+    alone); every third is then bound, every fifth draining."""
+    for g in range(gangs):
+        hosts = list(gen.choice(names, size=int(gen.integers(1, 9)),
+                                replace=False))
+        pod = make_gang_pod(f"r{g}-0", f"r{g}", len(hosts))
+        assert tracker.reserve_slice(pod, hosts)
+        if g % 3 == 0:
+            for m, host in enumerate(hosts):
+                with tracker._lock:
+                    tracker._member_gang[f"default/r{g}-{m}"] = f"default/r{g}"
+                tracker.observe_bind("default", f"r{g}-{m}", host)
+        if g % 5 == 0:
+            tracker.mark_draining(f"default/r{g}")
+
+
+def counted_cells(tracker, mesh):
+    """What every holding gang's hosts put on ``mesh``'s cells, counted
+    from the names alone: the held-cell count's reference."""
+    count = np.zeros(mesh.n_cells, dtype=np.int32)
+    with tracker._lock:
+        for gang in tracker._gangs.values():
+            if gang.state in ("reserved", "bound", "draining"):
+                for host in set(gang.reserved_nodes):
+                    cell = mesh.cell_of.get(host)
+                    if cell is not None:
+                        count[cell] += 1
+    return count
+
+
+def assert_cells_agree(tracker, mesh):
+    """The held-cell count equals the holders' hosts counted anew, and
+    its mask is ``free_masks`` of the {node: gang} map's hosts."""
+    with tracker._lock:
+        count = tracker._held_cells_locked(mesh).copy()
+        held = tracker._reserved_map_locked()
+        version = tracker._reservation_version
+    assert np.array_equal(count, counted_cells(tracker, mesh))
+    assert np.array_equal(
+        (count > 0).reshape(mesh.padded_domains, mesh.rows, mesh.cols),
+        mesh.free_masks(held))
+    # the held set's map is the exact path's, order and last writer too
+    assert list(tracker.cache_token()[1].map.items()) == list(held.items())
+    return version
+
+
+class TestRowsAndCells:
+    @pytest.mark.parametrize("domains", [1, 6, 199])
+    def test_the_row_built_free_mask_is_the_names_free_mask(self, domains):
+        gen = np.random.default_rng(domains)
+        nodes = holey_fleet(domains, domains)
+        tracker = GangTracker(nodes_provider=lambda: nodes)
+        names = [n.name for n in nodes]
+        hold_random(tracker, names, gen, gangs=max(4, len(names) // 40))
+        # the reserving gang holds hosts of its own (reserve_slice): they
+        # are no other gang's, and free to it
+        own_hosts = list(gen.choice(names, size=8, replace=False))
+        assert tracker.reserve_slice(
+            make_gang_pod("own-0", "own", 8), own_hosts)
+        mesh = tracker.mesh()
+        # the name table: every host in a shuffled order, and names that
+        # no node carries
+        table = list(gen.permutation(names + ["ghost-a", "ghost-b"]))
+        row_cells = mesh.cells_of(table)
+        for trial in range(4):
+            rows = gen.integers(0, len(table), size=len(table) // 2,
+                                dtype=np.int32)
+            violating = (gen.random(len(table)) < 0.1).astype(np.uint8)
+            clean = rows[violating[rows] == 0]
+            with tracker._lock:
+                own = tracker._gangs["default/own"]
+                taken = tracker._taken_cells_locked(mesh, own)
+                held = tracker._reserved_map_locked(exclude="default/own")
+            got = mesh.mask_of(row_cells[clean]) & ~taken
+            want = mesh.free_masks(
+                [table[r] for r in clean.tolist() if table[r] not in held])
+            assert got.shape == want.shape == (
+                mesh.padded_domains, mesh.rows, mesh.cols)
+            assert np.array_equal(got, want), trial
+            assert got[len(mesh.domains):].sum() == 0  # padding domains
+
+    def test_cells_agree_with_the_held_map_after_every_transition(self):
+        clock = _Clock()
+        nodes = holey_fleet(7, 6)
+        names = [n.name for n in nodes]
+        tracker = GangTracker(nodes_provider=lambda: nodes, clock=clock)
+        mesh = tracker.mesh()
+        seen = [assert_cells_agree(tracker, mesh)]
+
+        def step():
+            seen.append(assert_cells_agree(tracker, mesh))
+
+        def reserve(group, size, topo=None):
+            """A member's verdict from cells, as the native Filter asks."""
+            verdict = tracker.member_verdict(
+                "default", f"{group}-0",
+                make_gang_pod(f"{group}-0", group, size, topo or "")
+                .get_labels(),
+                lambda: names,
+                clean_cells=(
+                    (lambda view: view.mask_of(view.cells_of(names)))
+                    if topo else None
+                ))
+            assert verdict.holds_slice, group
+            return list(verdict.allowed)
+
+        a = reserve("a", 8, "2x4")
+        step()
+        b = reserve("b", 16, "4x4")
+        step()
+        s = reserve("s", 3)  # size-only: from names, cells counted too
+        step()
+        for m, host in enumerate(a):  # bind: the gang holds on
+            with tracker._lock:
+                tracker._member_gang[f"default/a-{m}"] = "default/a"
+            tracker.observe_bind("default", f"a-{m}", host)
+        assert tracker.gang_state("default/a") == "bound"
+        step()
+        clock.t += DEFAULT_TTL_S + 1  # b and s expire: their cells free
+        tracker.prune()
+        assert tracker.gang_state("default/b") == "forming"
+        step()
+        b = reserve("b", 16, "4x4")  # b again, elsewhere or not
+        step()
+        assert tracker.mark_draining("default/a")
+        step()
+        # the preemptor's slice overlaps the draining victim's
+        assert tracker.reserve_slice(
+            make_gang_pod("p-0", "p", 8, "2x4"), a)
+        step()
+        assert tracker.reserve_slice(  # a reserved gang's slice moves
+            make_gang_pod("p-1", "p", 8, "2x4"), a[:4] + b[:4])
+        step()
+        assert tracker.release("default/a")  # the overlap left: one holder
+        step()
+        reserve("gone", 4, "2x2")
+        assert tracker.mark_draining("default/gone")
+        step()
+        clock.t += 10 * DEFAULT_TTL_S + 1  # a wedged drain is dropped
+        tracker.prune()
+        assert tracker.gang_state("default/gone") is None
+        step()
+        assert len(set(seen)) > 8  # every step moved the version
+        # recover(): a journal's reservations restored on a tracker that
+        # already counts cells
+        journal = _MemJournal()
+        tracker.journal = journal
+        reserve("j", 4, "2x2")
+        snapshot = journal.saved[-1]
+        fresh = GangTracker(nodes_provider=lambda: nodes, clock=clock,
+                            pods_provider=list)
+        fresh_mesh = fresh.mesh()
+        assert_cells_agree(fresh, fresh_mesh)  # the count is made first
+        fresh.journal = _MemJournal(snapshot)
+        assert fresh.recover() == len(snapshot["gangs"]) > 0
+        assert_cells_agree(fresh, fresh_mesh)
+        # the mesh was listed anew past its age, and kept: nothing moved
+        assert tracker.mesh() is mesh
+
+
+class _MemJournal:
+    def __init__(self, data=None):
+        self.saved = [data] if data else []
+
+    def save(self, snapshot):
+        self.saved.append(snapshot)
+        return True
+
+    def load(self):
+        return self.saved[-1] if self.saved else None
+
+
+def merged_reference(fastpath, compiled, view, policy_name, violations,
+                     reasons, held):
+    """The merged verdict built a held host at a time, as the fast path
+    built it before the per-gang parts: ``(merged rows, {node: reason},
+    reason-bytes table)``."""
+    index = view.node_index
+    gang_rows = {}
+    for node, gang_id in held.items():
+        row = index.get(node)
+        if row is not None and row not in violations:
+            gang_rows[row] = (node, gang_id)
+    merged = frozenset(violations | set(gang_rows))
+    merged_reasons = dict(reasons)
+    n_rows = len(view.node_names)
+    rule_map = fastpath.violation_rule_map(compiled, view)
+    if rule_map is not None:
+        base = fastpath.reason_table(
+            compiled, view, policy_name, violations, rule_map, n_rows)
+        table = list(base[:n_rows])
+        table += [None] * (n_rows - len(table))
+    else:
+        table = [None] * n_rows
+    for row, (node, gang_id) in gang_rows.items():
+        reason = labels.gang_reserved_reason(gang_id)
+        merged_reasons[node] = reason
+        table[row] = json.dumps(reason).encode()
+    return merged, merged_reasons, table
+
+
+class TestMergedVerdict:
+    def test_built_from_gangs_equals_built_from_hosts(self, monkeypatch):
+        clock = _Clock()
+        extender, names = member_service(clock, domains=6)
+        tracker, fastpath = extender.gangs, extender.fastpath
+        policy = extender.cache.read_policy("default", POLICY)
+        built = []
+        real = fastpath._gang_merge
+
+        def counted(*args):
+            built.append(args[-1])
+            return real(*args)
+
+        monkeypatch.setattr(fastpath, "_gang_merge", counted)
+
+        def check():
+            compiled, view = extender._device_policy(policy)
+            violations, reasons, _ = fastpath.violation_reasons(
+                compiled, view, policy.name)
+            version, held = tracker.cache_token()
+            got = fastpath.gang_merged(
+                compiled, view, policy.name, violations, reasons, held,
+                version)
+            want = merged_reference(fastpath, compiled, view, policy.name,
+                                    violations, reasons, held.map)
+            n_rows = len(view.node_names)
+            assert got.violations == want[0]
+            assert got.table == want[2]
+            assert len(got.reasons) == len(want[1])  # the record's count
+            assert list(got.reasons.items()) == list(want[1].items())
+            mask = np.zeros(n_rows, dtype=np.uint8)
+            mask[[r for r in want[0] if r < n_rows]] = 1
+            assert fastpath._violation_mask(got.violations, n_rows) == (
+                mask.tobytes())
+            for row in np.flatnonzero(mask).tolist():
+                name = view.node_names[row]
+                if row not in violations:
+                    assert got.held_reasons[got.holder_at[row]] == want[1][name]
+            # the same objects for the same version
+            assert fastpath.gang_merged(
+                compiled, view, policy.name, violations, reasons, held,
+                version) is got
+
+        def member(group, m, size, topo):
+            return make_gang_pod(f"{group}-{m}", group, size, topo,
+                                 policy=POLICY)
+
+        def reserve(group, size, topo):
+            tracker.filter_overlay(member(group, 0, size, topo), names)
+
+        hot = {names[5], names[70], names[200]}
+        set_hot(extender, names, hot)
+        check()
+        shapes = [(8, "2x4"), (4, "2x2"), (16, "4x4"), (8, "4x2"), (3, "")]
+        for g, (size, topo) in enumerate(shapes * 3):
+            reserve(f"g{g}", size, topo)
+            check()
+        # a held host turns hot: the telemetry's reason wins, a new lineage
+        held_host = tracker.reserved_nodes()
+        set_hot(extender, names, hot | set(list(held_host)[:3]))
+        check()
+        tracker.release("default/g1")
+        check()
+        clock.t += DEFAULT_TTL_S + 1  # every reservation expires at once
+        tracker.prune()
+        check()
+        for g in range(4):
+            reserve(f"h{g}", 8, "2x4")
+            check()
+        victim = tracker._gangs["default/h0"]
+        assert tracker.mark_draining("default/h0")
+        check()
+        # the preemptor's slice overlaps the victim's: its rows two holds
+        assert tracker.reserve_slice(member("pre", 0, 8, "2x4"),
+                                     list(victim.reserved_nodes))
+        check()
+        tracker.release("default/h0")
+        check()
+        tracker.release("default/pre")
+        check()
+        # an earlier gang's slice moves onto a later gang's hosts: the later
+        # holder's reason stays, whichever moved last
+        later = list(tracker._gangs["default/h3"].reserved_nodes)
+        assert tracker.reserve_slice(member("h1", 1, 8, "2x4"), later)
+        check()
+        tracker.release("default/h3")
+        check()
+        # a build for every new version or violation set
+        assert len(built) >= 20, len(built)
+
+    def test_verb_threads_build_while_gangs_move(self):
+        """Verb threads build merged verdicts of whatever version they
+        read while gangs reserve and release: every one equals the
+        reference of its own held set."""
+        clock = _Clock()
+        extender, names = member_service(clock, domains=4)
+        tracker, fastpath = extender.gangs, extender.fastpath
+        set_hot(extender, names, {names[1], names[40]})
+        policy = extender.cache.read_policy("default", POLICY)
+        compiled, view = extender._device_policy(policy)
+        violations, reasons, _ = fastpath.violation_reasons(
+            compiled, view, policy.name)
+        done = threading.Event()
+        wrong = []
+
+        def verb():
+            while not done.is_set():
+                version, held = tracker.cache_token()
+                got = fastpath.gang_merged(
+                    compiled, view, policy.name, violations, reasons, held,
+                    version)
+                want = merged_reference(fastpath, compiled, view,
+                                        policy.name, violations, reasons,
+                                        held.map)
+                if got.violations != want[0] or got.table != want[2] or (
+                        dict(got.reasons) != want[1]):
+                    wrong.append(version)
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        threads = [threading.Thread(target=verb) for _ in range(6)]
+        try:
+            for thread in threads:
+                thread.start()
+            deadline = time.monotonic() + 2.0
+            g = 0
+            while time.monotonic() < deadline:
+                tracker.filter_overlay(make_gang_pod(
+                    f"t{g}-0", f"t{g}", 4, "2x2", policy=POLICY), names)
+                if g % 2:
+                    tracker.release(f"default/t{g - 1}")
+                g += 1
+        finally:
+            done.set()
+            sys.setswitchinterval(interval)
+            for thread in threads:
+                thread.join(timeout=10)
+        assert not any(thread.is_alive() for thread in threads)
+        assert g > 10 and not wrong
+
+    def test_the_stage_and_its_counters(self):
+        clock = _Clock()
+        extender, names = member_service(clock, domains=2)
+        tracker, fastpath = extender.gangs, extender.fastpath
+        tracker.filter_overlay(
+            make_gang_pod("a-0", "a", 8, "2x4", policy=POLICY), names)
+        policy = extender.cache.read_policy("default", POLICY)
+        compiled, view = extender._device_policy(policy)
+        violations, reasons, _ = fastpath.violation_reasons(
+            compiled, view, policy.name)
+        version, held = tracker.cache_token()
+        span = trace.Span("POST /scheduler/filter")
+        fastpath.gang_merged(compiled, view, policy.name, violations,
+                             reasons, held, version, span)
+        again = trace.Span("POST /scheduler/filter")
+        fastpath.gang_merged(compiled, view, policy.name, violations,
+                             reasons, held, version, again)
+        assert [s[0] for s in span.stages] == ["gang_merge"]
+        assert again.stages == []  # the version's entry: no build
+        ring = trace.TraceBuffer()
+        ring.add(span)
+        ring.add(again)
+        tally = dict(zip(trace.VERB_FAMILIES, ring._verbs))
+        assert tally["pas_stage_gang_merge_total"] == 1
+        assert tally["pas_stage_gang_merge_seconds_total"] > 0
+
+
+def plain_obj():
+    return {"metadata": {"name": "plain", "namespace": "default",
+                         "labels": {"telemetry-policy": POLICY}}}
+
+
+class TestReserveFromRows:
+    """The native member Filter reserves from its candidate rows' cells,
+    the exact path from names: the same slice, the same bytes."""
+
+    def test_reserve_release_reserve(self, monkeypatch):
+        twins, clock = TestMemberFilterNative.twins()
+        names = twins[0][1]
+        TestMemberFilterNative.hot_everywhere(
+            twins, {names[0], names[9], names[100]})
+
+        def rows_moved(step):
+            before = trace.COUNTERS.get("pas_gang_reserve_rows_total")
+            out = step()
+            return out, (
+                trace.COUNTERS.get("pas_gang_reserve_rows_total") - before)
+
+        def reserve(group, size, topo):
+            (got, moved) = rows_moved(lambda: assert_native(*filter_both(
+                twins, member_obj(f"{group}-0", group, size, topo), names,
+                monkeypatch)))
+            assert moved == 1  # the native twin's, from rows
+            assert got["NodeNames"], group
+            return got["NodeNames"]
+
+        def plain_both():
+            body = {"Pod": plain_obj(), "NodeNames": names}
+            got = _post(twins[0][0], "filter", body)
+            with monkeypatch.context() as patch:
+                patch.setattr(telemetryscheduler, "get_wirec", lambda: None)
+                want = _post(twins[1][0], "filter", body)
+            assert got.status == want.status == 200
+            assert got.body == want.body
+            return json.loads(got.body)
+
+        first = reserve("a", 8, "2x4")
+        plain_both()
+        second = reserve("b", 8, "2x4")
+        assert not set(first) & set(second)
+        reserve("c", 4, "2x2")
+        held = plain_both()["FailedNodes"]
+        assert held[first[0]] == "gang: node reserved by gang default/a"
+        # a's slice was the best anchor; its release gives it back
+        for extender, _names in twins:
+            assert extender.gangs.release("default/a")
+        assert first[0] not in plain_both()["FailedNodes"]
+        again = reserve("d", 8, "2x4")
+        assert again == first
+        # a member of a reserved gang: no solve, no rows counted
+        clock.t += 1.0
+        _got, moved = rows_moved(lambda: assert_native(*filter_both(
+            twins, member_obj("d-1", "d", 8, "2x4"), names, monkeypatch)))
+        assert moved == 0
+        # a release and an expiry between two Filters of one member
+        for extender, _names in twins:
+            assert extender.gangs.release("default/b")
+        clock.t += DEFAULT_TTL_S + 1
+        assert reserve("e", 16, "4x4")
+        plain_both()
+        # a gang with no topology reserves from names on either twin
+        _got, moved = rows_moved(lambda: assert_native(*filter_both(
+            twins, member_obj("s-0", "sized", 3), names, monkeypatch)))
+        assert moved == 0
+
+
+def sequential_best(free, shapes, device=True):
+    """The best anchor over the orientations, one read back before the
+    next is dispatched: the lowest (score, orientation, domain, row,
+    col)."""
+    best = None
+    for index, (h, w) in enumerate(shapes):
+        anchor = (topology.domains_anchor_device(free, h, w) if device
+                  else topology.domains_anchor_host(free, h, w))
+        if anchor is not None and (best is None or (anchor[0], index) < best[0]):
+            best = ((anchor[0], index), (h, w) + anchor[1:])
+    return None if best is None else best[1]
+
+
+class TestOrientationsDispatched:
+    @pytest.mark.parametrize("pair", [[(2, 4), (4, 2)], [(4, 8), (8, 4)],
+                                      [(3, 5), (5, 3)], [(4, 4)], [(9, 1), (1, 9)]])
+    def test_the_answer_is_the_sequential_ones(self, pair):
+        for seed in range(10):
+            free = random_masks(seed, 1 + seed % 6)
+            want = sequential_best(free, pair)
+            assert want == sequential_best(free, pair, device=False)
+            assert topology.best_domain_anchor(free, pair) == want
+            assert topology.best_domain_anchor(free, pair, False) == want
+
+    def test_a_tie_goes_to_the_first_orientation(self):
+        free = np.zeros((1, 8, 8), dtype=bool)
+        free[0] = True  # a 2x4 and a 4x2 in the corner strand 7 each
+        assert topology.best_domain_anchor(free, [(2, 4), (4, 2)]) == (
+            2, 4, 0, 0, 0)
+        assert topology.best_domain_anchor(free, [(4, 2), (2, 4)]) == (
+            4, 2, 0, 0, 0)
+
+    @pytest.mark.parametrize("device", [True, False])
+    def test_the_solve_steps_are_stages_inside_the_reservation(self, device):
+        clock = _Clock()
+        extender, names = member_service(clock, domains=2)
+        extender.gangs.use_device = device
+        span = trace.Span("POST /scheduler/filter")
+        extender.gangs.filter_overlay(
+            make_gang_pod("a-0", "a", 8, "2x4", policy=POLICY), names,
+            span=span)
+        steps = ("gang_upload", "gang_launch", "gang_fetch")
+        got = {name: (start, start + dur)
+               for name, start, dur in span.stages}
+        assert "gang_reserve" in got
+        if not device:  # the host mirror: no crossing to time
+            assert not set(steps) & set(got)
+            return
+        # in order, each inside the reservation
+        reserve = got["gang_reserve"]
+        ends = [reserve[0]]
+        for name in steps:
+            assert ends[-1] <= got[name][0] and got[name][1] <= reserve[1]
+            ends.append(got[name][1])
+        ring = trace.TraceBuffer()
+        ring.add(span)
+        tally = dict(zip(trace.VERB_FAMILIES, ring._verbs))
+        assert tally["pas_stage_gang_reserve_total"] == 1
+        for name in steps:
+            seconds = tally[f"pas_stage_{name}_seconds_total"]
+            assert seconds == pytest.approx(got[name][1] - got[name][0])
+
+    @pytest.mark.parametrize("where", ["second dispatch", "readback"])
+    def test_a_failure_after_the_first_dispatch_is_counted_once(
+            self, where, monkeypatch):
+        free = random_masks(5, 4)
+        shapes = [(2, 4), (4, 2)]
+        want = sequential_best(free, shapes, device=False)
+        real = topology._domains_best_anchor
+        calls = []
+
+        def second_fails(*args, **kwargs):
+            calls.append(args[1:])
+            if len(calls) == 2:
+                raise RuntimeError("device lost")
+            return real(*args, **kwargs)
+
+        def lost(_arrays):
+            raise RuntimeError("device lost")
+
+        if where == "second dispatch":
+            monkeypatch.setattr(topology, "_domains_best_anchor", second_fails)
+        else:
+            monkeypatch.setattr(topology.jax, "device_get", lost)
+        before = trace.COUNTERS.get(
+            "pas_device_path_errors_total", labels={"site": "gang_topology"})
+        assert topology.best_domain_anchor(free, shapes) == want
+        after = trace.COUNTERS.get(
+            "pas_device_path_errors_total", labels={"site": "gang_topology"})
+        assert after - before == 1

@@ -24,6 +24,7 @@ import pytest
 
 from benchmarks.http_load import build_extender, make_bodies
 from platform_aware_scheduling_tpu.extender.server import HTTPRequest
+from platform_aware_scheduling_tpu.gang.group import HeldSet
 from platform_aware_scheduling_tpu.native import get_wirec
 from platform_aware_scheduling_tpu.tas import telemetryscheduler
 from platform_aware_scheduling_tpu.tas.metrics import NodeMetric
@@ -233,7 +234,10 @@ class _StubGangs:
         self.held = {}
 
     def cache_token(self):
-        return self.version, dict(self.held)
+        hosts = {}
+        for node, gang_id in self.held.items():
+            hosts.setdefault(gang_id, []).append(node)
+        return self.version, HeldSet(self.version, tuple(hosts.items()))
 
     def filter_overlay(self, pod, clean, span=None):
         failed = {
