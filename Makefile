@@ -1,5 +1,5 @@
 # Build/test fan-out (capability parity: reference top-level Makefile:1-9).
-.PHONY: all test e2e e2e-kind chip-smoke bench bench-http bench-gas bench-gang bench-configs bench-serving bench-rebalance bench-chaos bench-decisions bench-forecast bench-ha bench-twin bench-shard test-serving test-obs test-rebalance test-faults test-decisions test-gang test-forecast test-ha test-slo test-shard test-record test-control test-admission test-explain test-solveobs bench-control bench-admission bench-replay bench-ledger test-fuzz fuzz-smoke test-wirec trace-lint pascheck obs-smoke lint image clean dryrun
+.PHONY: all test e2e e2e-kind chip-smoke bench-http bench-gas bench-gang bench-configs bench-serving bench-rebalance bench-chaos bench-decisions bench-forecast bench-shard test-serving test-obs test-rebalance test-faults test-decisions test-gang test-forecast test-ha test-slo test-shard test-record test-control test-admission test-explain test-solveobs test-fuzz fuzz-smoke test-wirec trace-lint pascheck obs-smoke lint image clean dryrun
 
 all: test
 
@@ -25,16 +25,11 @@ e2e-kind:
 chip-smoke:
 	python chip_smoke.py
 
-# every section is a child process, one at a time (one process per chip);
-# exits non-zero when any section failed or was not run
-bench:
-	python bench.py
-
-# north-star serving A/B alone (faster than the full bench)
+# north-star serving A/B: threaded and async front-ends, host vs device
 bench-http:
 	python -m benchmarks.http_load
 
-# GAS wire A/B alone
+# GAS wire A/B
 bench-gas:
 	python -m benchmarks.gas_load
 
@@ -53,7 +48,7 @@ test-serving:
 test-rebalance:
 	python -m pytest tests/test_rebalance.py -q
 
-# rebalance convergence A/B alone: synthetic churn, active vs label-only
+# rebalance convergence A/B: synthetic churn, active vs label-only
 bench-rebalance:
 	python -m benchmarks.rebalance_load
 
@@ -64,7 +59,7 @@ bench-rebalance:
 test-faults:
 	python -m pytest tests/test_faults.py -q
 
-# chaos A/B alone: availability + p99 through the live front-end under a
+# chaos A/B: availability + p99 through the live front-end under a
 # scripted 10% metrics-API error rate vs a clean baseline
 bench-chaos:
 	python -m benchmarks.chaos_load
@@ -85,7 +80,7 @@ bench-decisions:
 test-gang:
 	python -m pytest tests/test_gang.py tests/test_binpack_edges.py -q
 
-# gang A/B alone: competing gangs (gang-on admits both, gang-off
+# gang A/B: competing gangs (gang-on admits both, gang-off
 # deadlocks half-placed) + 10k-node reservation throughput
 bench-gang:
 	python -m benchmarks.gang_load
@@ -97,7 +92,7 @@ bench-gang:
 test-forecast:
 	python -m pytest tests/test_forecast.py -q
 
-# forecast A/B alone: trending violated-at-bind + transient-spike
+# forecast A/B: trending violated-at-bind + transient-spike
 # eviction suppression + forecaster on-vs-off p99 (skip the 10k-node
 # overhead tier with the scenario functions directly)
 bench-forecast:
@@ -109,21 +104,12 @@ bench-forecast:
 test-ha:
 	python -m pytest tests/test_lease.py tests/test_ha.py -q
 
-# HA A/B alone: c=8 spread over 3 replicas vs 1 + leader-kill failover
-bench-ha:
-	python -m benchmarks.ha_load
-
 # SLO engine + digital-twin suite (docs/observability.md "SLOs & error
 # budgets"): burn-rate window math on fake clocks, bucket quantile
 # interpolation, /debug/slo + off-path pins, and the scenario matrix
 # incl. the metric-storm page -> recover acceptance over real sockets
 test-slo:
 	python -m pytest tests/test_slo.py tests/test_twin.py -q -m 'not slow'
-
-# digital-twin scenario matrix alone: every default scenario at 10k
-# nodes, verdicts = the SLO engine's judgment (testing/twin.py)
-bench-twin:
-	python -m benchmarks.twin_load
 
 # partition plane suite (docs/sharding.md): partition math +
 # rendezvous determinism, journaled/fenced ownership incl. heartbeat
@@ -133,7 +119,7 @@ bench-twin:
 test-shard:
 	python -m pytest tests/test_shard.py -q -m 'not slow'
 
-# sharded scale-out A/B alone: 4 partition-owner subprocesses vs one
+# sharded scale-out A/B: 4 partition-owner subprocesses vs one
 # full-world replica — aggregate Filter rps and the measured ~1/P
 # per-replica refresh cut (benchmarks/shard_load.py); exits nonzero
 # unless both halves of the bet hold
@@ -154,12 +140,6 @@ test-record:
 test-control:
 	python -m pytest tests/test_control.py -q -m 'not slow'
 
-# the controller's head-to-head A/B alone: final error-budget ledgers
-# static vs self-tuning on both programs + the quiet-day null
-# (benchmarks/control_load.py); exits nonzero unless strictly better
-bench-control:
-	python -m benchmarks.control_load
-
 # priority-aware admission plane suite (docs/admission.md): class
 # ladder + bounded queue semantics, backfill/fairness, gang-atomic
 # preemption with fenced-refusal containment, flag fail-fast,
@@ -178,25 +158,10 @@ test-explain:
 # solve observatory suite (docs/observability.md "Solve observatory"):
 # per-stage attribution sums to the measured total, churn edge cases
 # (first pass, delete, byte-identical refresh), /debug/solve codes on
-# both front-ends, off-path byte-identity, the recompile-watch twin
-# gate, and the perf-ledger anchor round trip
+# both front-ends, off-path byte-identity, and the recompile-watch twin
+# gate
 test-solveobs:
 	python -m pytest tests/test_solveobs.py -q -m 'not slow'
-
-# perf-regression ledger: fresh per-stage solve floors + warm-verb
-# floor vs the COMMITTED anchor (benchmarks/perf_anchor.json), plus the
-# observatory instrumented-vs-off pin.  Report-only (shared runners
-# jitter); add --strict to gate, --write to re-anchor after an
-# intentional perf change (benchmarks/perf_ledger.py)
-bench-ledger:
-	python -m benchmarks.perf_ledger
-
-# the admission plane's head-to-head alone: preemption cascade ON vs
-# OFF through the real verbs + the quiet-diurnal null + gate overhead
-# (benchmarks/admission_load.py); exits nonzero unless ON is strictly
-# better and the quiet day stays silent
-bench-admission:
-	python -m benchmarks.admission_load
 
 # adversarial scenario search suite (docs/robustness.md "Adversarial
 # scenario search"): seeded-LCG determinism + the pinned draw values,
@@ -216,12 +181,6 @@ test-fuzz:
 # find on the healthy tree is a real bug and is printed, never swallowed
 fuzz-smoke:
 	env JAX_PLATFORMS=cpu python -m benchmarks.fuzz_load
-
-# replay throughput (legacy vs vectorized twin load model) + the
-# what-if demo: 2x load must degrade the availability verdict a 1x
-# replay keeps green (testing/replay.py)
-bench-replay:
-	python -c "import json; from benchmarks.twin_load import replay_report; print(json.dumps(replay_report(), indent=2))"
 
 # native wire-path sanitizer gate (docs/architecture.md "The wire
 # path"): compile _wirec with -fsanitize=address,undefined and run the
@@ -270,7 +229,7 @@ test-obs:
 obs-smoke:
 	python -m benchmarks.obs_smoke
 
-# BASELINE configs #2/#3/#4/#5 + solver surface + mesh checks alone
+# BASELINE configs #2/#3/#4/#5 + solver surface + mesh checks
 bench-configs:
 	python -m benchmarks.configs
 
@@ -281,7 +240,7 @@ dryrun:
 	python __graft_entry__.py 8
 
 lint:
-	python -m compileall -q platform_aware_scheduling_tpu tests benchmarks bench.py chip_smoke.py __graft_entry__.py
+	python -m compileall -q platform_aware_scheduling_tpu tests benchmarks chip_smoke.py __graft_entry__.py
 
 image:
 	docker build -f deploy/images/Dockerfile.tas -t pas-tpu-tas .

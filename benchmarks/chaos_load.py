@@ -16,8 +16,8 @@ Two things live here:
   * ``run()`` — the bench: p99 + availability through a LIVE threaded
     front-end while the telemetry refresh loop runs against a metrics
     client with a scripted, seeded 10% error rate, vs the same service
-    on a clean client.  Feeds the ``chaos`` section of bench.py's line
-    and the BENCH_DETAIL artifact: the robustness claim in numbers —
+    on a clean client (``make bench-chaos``): the robustness claim in
+    numbers —
     fault-tolerant retries + degraded modes keep the serving path's
     latency and availability flat through a flaky control plane.
 """
@@ -356,7 +356,7 @@ def _drive_side(error_rate: float, num_nodes: int, requests: int) -> Dict:
 
 
 def run(num_nodes: int = 256, requests: int = 400) -> Dict:
-    """The ``chaos`` bench section: clean baseline vs scripted 10%
+    """The chaos A/B: clean baseline vs scripted 10%
     metrics-API error rate through the same live service, plus the
     multi-replica leader-kill failover scenario."""
     out: Dict = {"num_nodes": num_nodes, "requests": requests}

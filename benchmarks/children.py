@@ -8,7 +8,7 @@ two kinds and never both:
     the platform it found into its JSON; or
   * it **launches** — it never initializes a JAX backend
     (:func:`assert_launcher`), and runs the processes that do, one at a
-    time, through :func:`run_child` (a finished bench entry) or its own
+    time, through :func:`run_child` (a child that prints its JSON) or its own
     ``Popen`` of a service that calls :func:`hold_chip`.
 
 ``platform`` defaults to ``"tpu"`` everywhere; the Tier-1 harness tests

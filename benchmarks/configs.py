@@ -30,8 +30,8 @@ beside it (no extrapolation):
     8-device virtual CPU mesh (subprocess).
 
 On-device timings use K solves chained inside ONE compiled program, one
-readback, so the per-dispatch host round trip is amortized over K (same
-method as bench.py's headline; chip_smoke.py prints the round trip itself).
+readback, so the per-dispatch host round trip is amortized over K
+(chip_smoke.py prints the round trip itself).
 
 Process model (benchmarks/children.py): the configs that compute on the
 device (:data:`DEVICE_CONFIGS`) and the ones that launch services or

@@ -26,8 +26,7 @@ Three measurements, all hermetic and driven through the REAL verbs:
     bar is that the off path is unchanged and the on path stays within
     a few percent (fits run off the request path).
 
-``run()`` feeds the ``forecast`` section of bench.py's line +
-BENCH_DETAIL artifact.
+``make bench-forecast`` runs ``run()`` and prints its result.
 """
 
 from __future__ import annotations

@@ -20,9 +20,9 @@ four properties every time CI runs:
      scale), so the search stays a real search and not three
      ceremonial runs.
 
-``run()`` is the compact bench section (bench.py's ``fuzz`` key):
-candidates/s, corpus size, coverage signal count, and finds from a
-short budgeted run.  Exits nonzero from the CLI when any gate fails.
+``run()`` (``--bench``) is the compact report: candidates/s, corpus
+size, coverage signal count, and finds from a short budgeted run.
+Exits nonzero from the CLI when any gate fails.
 """
 
 from __future__ import annotations
@@ -201,8 +201,8 @@ def smoke(seed: int = 7, budget_s: float = DEFAULT_BUDGET_S) -> Dict:
 
 
 def run(seed: int = 7, budget_s: float = 8.0) -> Dict:
-    """The bench.py ``fuzz`` section: a short budgeted search plus the
-    reproducibility pin (cheap enough to run every bench round)."""
+    """The ``--bench`` report: a short budgeted search plus the
+    reproducibility pin."""
     started = time.monotonic()
     repro = reproducibility_gate(seed=seed)
     search = throughput_run(seed=seed, budget_s=budget_s)
@@ -230,7 +230,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser.add_argument(
         "--bench",
         action="store_true",
-        help="emit the compact bench section instead of the smoke gates",
+        help="emit the compact report instead of the smoke gates",
     )
     args = parser.parse_args(argv)
     if args.bench:

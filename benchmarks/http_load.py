@@ -335,7 +335,7 @@ def scrape_stage_breakdown(port: int) -> Dict:
     """Per-stage latency attribution from the live service's
     ``/debug/traces`` ring (utils/trace.py): mean/total milliseconds per
     stage name over the recent completed traces, plus the trace count.
-    This is what gives the BENCH_DETAIL artifact per-stage attribution —
+    This is what gives the A/B's result per-stage attribution —
     'where did the p99 go' (read/queue_wait/coalesce/decode/kernel/
     encode/write) instead of one opaque number."""
     _status, payload = http_get(port, "/debug/traces")
@@ -369,9 +369,9 @@ def scrape_observability(port: int) -> Dict:
     """Control-plane & device health from the live service: readiness
     state + flap count (/readyz, pas_ready_transitions_total) and the
     device memory watermark / kernel-cost gauges from /metrics
-    (utils/devicewatch.py).  Rides the BENCH_DETAIL artifact next to the
-    stage breakdowns: a bench round that ran against a not-ready or
-    memory-pressured service says so in its own artifact."""
+    (utils/devicewatch.py).  Rides the result next to the stage
+    breakdowns: a run against a not-ready or memory-pressured service
+    says so in its own result."""
     from platform_aware_scheduling_tpu.utils import trace
 
     out: Dict = {}
@@ -512,7 +512,7 @@ def _spawn_service(
         stdout=subprocess.PIPE,
         text=True,
         # resolve `-m benchmarks.*` from the repo root regardless of the
-        # caller's cwd (bench.py supports being launched anywhere)
+        # caller's cwd
         cwd=children.REPO_ROOT,
     )
     line = proc.stdout.readline().strip()
@@ -925,7 +925,7 @@ def decision_overhead(
     NodeNames hit tier (where per-request cost is smallest and relative
     overhead therefore largest).  Also scrapes the ON side's
     placement-quality surface: pas_decision_* families after a bind
-    burst, plus a /debug/decisions summary — so BENCH_DETAIL shows the
+    burst, plus a /debug/decisions summary — so the result shows the
     feedback loop actually closing, not just costing nothing."""
     from platform_aware_scheduling_tpu.utils import trace
 
@@ -1024,7 +1024,7 @@ def record_overhead(
     filter at c=1 on the primary NodeNames hit tier (smallest
     per-request cost, therefore the harshest relative-overhead lens,
     exactly like the decision-provenance A/B above).  The ON side also
-    scrapes GET /debug/record so BENCH_DETAIL shows the ring actually
+    scrapes GET /debug/record so the result shows the ring actually
     captured the driven traffic, not just that it cost nothing.
 
     Unlike the decision A/B, the repeat loop is OUTSIDE the spawn: a

@@ -15,8 +15,8 @@ pod onto its planned target node, and per-node load is simply
 
 Measured per mode: cycles-to-zero-violations, evictions executed, and
 plan latency (mean + p99 across planning cycles, first-cycle compile
-included in the max).  ``run()`` feeds the ``rebalance`` section of
-bench.py's line + BENCH_DETAIL artifact.
+included in the max).  ``make bench-rebalance`` runs ``run()`` and
+prints its result.
 """
 
 from __future__ import annotations

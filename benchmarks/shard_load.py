@@ -34,9 +34,8 @@ Journaled ownership, handoff, and fencing are proved by the HA harness
 and the twin's ``partition_handoff`` scenario (tests/test_ha.py,
 testing/twin.py); THIS bench isolates the steady-state scale-out claim.
 
-Feeds the ``shard`` section of bench.py's line and the BENCH_DETAIL
-artifact; ``make bench-shard`` runs it alone and exits nonzero when
-either half of the bet fails.
+``make bench-shard`` (a CI step) runs it and exits nonzero when either
+half of the bet fails.
 """
 
 from __future__ import annotations

@@ -896,7 +896,7 @@ static int push_nn_name(Scan *sc, ParsedArgs *pa, Py_ssize_t *cap,
  *
  * Per-name scan_string pays ~2x the structural cost in validation
  * bookkeeping; at 10k names that is most of the request's parse floor
- * (BENCH_r05 filter_floor_breakdown: parse 173 us).  Here names are
+ * (benchmarks/http_load.filter_floor_breakdown).  Here names are
  * recorded by bare memchr quote pairs and validated by ONE SWAR sweep
  * over the whole array span at the end: a clean sweep (no control
  * bytes, no backslash, no >= 0x80 — exactly scan_string's special set)

@@ -1,7 +1,7 @@
 """Solve observatory: per-stage device-solve attribution + refresh churn
 (docs/observability.md "Solve observatory").
 
-The device solve has sat at ~1.3 ms since BENCH_r01 while everything
+The device solve sat at ~1.3 ms through the early rounds while everything
 around it got 4x faster, and ROADMAP item 4's incremental solve cannot
 be designed — or gated — without knowing WHERE those microseconds go and
 HOW MUCH of the world actually changes per refresh.  Neither was

@@ -1011,7 +1011,7 @@ class FuzzEngine:
         stop_on_find: bool = False,
     ) -> Dict:
         """Run candidates until the budget (wall clock and/or count) is
-        spent.  Returns the summary the bench line reports."""
+        spent.  Returns the summary fuzz_load reports."""
         if time_budget_s is None and max_candidates is None:
             raise ValueError("need a time budget or a candidate cap")
         started = clock()

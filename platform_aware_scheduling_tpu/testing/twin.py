@@ -27,9 +27,8 @@ them into ONE replayable simulator:
     tick, and renders a verdict whose checks are EXACTLY the SLO
     engine's judgment (plus scenario-specific invariants like "zero
     evictions while telemetry was stale");
-  * :func:`run_matrix` — the scenario matrix the bench's ``twin``
-    section reports (benchmarks/twin_load.py): every future PR's
-    BENCH_DETAIL shows the regression surface per scenario.
+  * :func:`run_matrix` — the scenario matrix tests/test_twin.py runs:
+    the regression surface per scenario.
 
 Everything is deterministic: one fake clock, seeded fault plans, no real
 sleeping.  Heavy imports (jax via the mirror) stay lazy so this module
@@ -269,8 +268,8 @@ class TwinCluster(HAHarness):
             )
         #: vectorized per-tick load model (numpy bincount over interned
         #: node ordinals + memoized NodeMetric publication); the legacy
-        #: dict path stays selectable so benchmarks/twin_load.py can
-        #: report the before/after ticks-per-second honestly
+        #: dict path stays selectable for tests/test_record.py's parity
+        #: cases
         self.vectorized = vectorized
         self._node_ordinal: Dict[str, int] = (
             {} if gang else {f"node-{i}": i for i in range(num_nodes)}
@@ -2727,8 +2726,7 @@ def run_matrix(
     wire_slo_us: float = 500.0,
     scenarios: Tuple[Scenario, ...] = DEFAULT_SCENARIOS,
 ) -> Dict:
-    """Run every scenario at the given scale; the bench's ``twin``
-    section (benchmarks/twin_load.py) reports this matrix.  Fresh
+    """Run every scenario at the given scale (tests/test_twin.py).  Fresh
     scenario INSTANCES per run — scenario objects carry per-run state.
     ``wire_slo_us`` tunes the diurnal wire-floor latency gate (0
     disables it)."""

@@ -21,10 +21,10 @@ it.  Readiness is a conjunction of named conditions:
 ``/readyz`` answers 200 with the condition list when all hold, 503 with
 the same list (failing conditions carry their reason) otherwise.  Each
 evaluation updates the ``pas_ready`` gauge and counts ready <-> unready
-flips on ``pas_ready_transitions_total`` — the flap count the bench
-harvests into BENCH_DETAIL.  Both endpoints bypass the async admission
-queue, same bar as /metrics: they must stay readable exactly when the
-queue is saturated.
+flips on ``pas_ready_transitions_total`` — the flap count
+benchmarks/http_load.py scrapes into its A/B result.  Both endpoints
+bypass the async admission queue, same bar as /metrics: they must stay
+readable exactly when the queue is saturated.
 
 This module must stay importable without jax (the host layer's rule).
 """

@@ -6,7 +6,7 @@ metric is p99 Prioritize latency, latency histograms are built in: every
 extender verb records into a :class:`LatencyRecorder`, and serving-layer
 counters live in :class:`CounterSet`, both exposed as real Prometheus
 text exposition (``# HELP``/``# TYPE``, ``_bucket``/``_sum``/``_count``
-histogram series) on ``/metrics`` and consumed by bench.py.
+histogram series) on ``/metrics``.
 
 The request-level span model, the trace ring buffer, and the metric-name
 inventory build on these in utils/trace.py (docs/observability.md).

@@ -1,6 +1,6 @@
 """benchmarks/configs.py — the BASELINE config benches must run and agree
-with host semantics at tiny shapes (the full shapes run in bench.py on
-real hardware; these tests pin correctness, not performance)."""
+with host semantics at tiny shapes (these tests pin correctness, not
+performance)."""
 
 from benchmarks import configs
 

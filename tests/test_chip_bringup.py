@@ -4,14 +4,14 @@
   * the compile cache goes where JAX_COMPILATION_CACHE_DIR says, else to
     one fixed path inside the checkout;
   * chip_smoke.py refuses a CPU by name and prints no result;
-  * bench.py's launcher — and a process spawning a device service — never
-    initializes a JAX backend, and a device service refuses another
-    platform than the one it was told;
+  * perfbench's generator child and the drivers it loads — and a process
+    spawning a device service — never initialize a JAX backend, and a
+    device service refuses another platform than the one it was told;
   * every device failure the served path catches is counted;
   * the Pallas-or-scan choice reads the operands, not the host's chip count.
 """
 
-import importlib.util
+import glob
 import json
 import os
 import subprocess
@@ -116,20 +116,37 @@ class TestNoSilentCpu:
         assert value == len(jax.devices())
 
 
+#: perfbench's generator child and every driver it loads by name: the
+#: processes that drive a chip-holding service and must never import JAX
+PERFBENCH = os.path.join(REPO, "perfbench")
+GENERATOR_MODULES = [os.path.join(PERFBENCH, "generator.py")] + sorted(
+    glob.glob(os.path.join(PERFBENCH, "drivers", "*.py"))
+)
+
+
 class TestOneProcessPerChip:
-    def test_bench_launcher_stays_off_jax(self):
-        """Importing bench.py, its section table and everything the
-        launcher imports to assemble its line initializes no backend."""
+    @pytest.mark.parametrize(
+        "path", GENERATOR_MODULES,
+        ids=[os.path.relpath(p, PERFBENCH) for p in GENERATOR_MODULES],
+    )
+    def test_generator_side_stays_off_jax(self, path):
+        """Loading the module as the benchmark does, from its file with
+        ``perfbench/`` on the path, imports neither JAX nor any module of
+        the package: a generator that did would hold the chip its
+        service needs."""
         proc = run_python(["-c", (
-            "import bench\n"
-            "from benchmarks import children, control_load, admission_load\n"
-            "assert len(bench.SECTIONS) == 18, len(bench.SECTIONS)\n"
-            "line, detail = bench.assemble_line({'metric': 'm'}, None, None)\n"
-            "children.assert_launcher('bench.py')\n"
-            "print('launcher clean')\n"
+            "import importlib.util, sys\n"
+            f"sys.path.insert(0, {PERFBENCH!r})\n"
+            f"spec = importlib.util.spec_from_file_location('under_test', {path!r})\n"
+            "spec.loader.exec_module(importlib.util.module_from_spec(spec))\n"
+            "assert 'jax' not in sys.modules\n"
+            "held = [m for m in sys.modules\n"
+            "        if m.split('.')[0] == 'platform_aware_scheduling_tpu']\n"
+            "assert not held, held\n"
+            "print('generator clean')\n"
         )])
-        assert proc.returncode == 0, proc.stderr
-        assert "launcher clean" in proc.stdout
+        assert proc.returncode == 0, proc.stderr[-800:]
+        assert "generator clean" in proc.stdout
 
     def test_no_module_takes_the_chip_at_import(self):
         """A device value at module level (``X = jnp.int32(-1)``)
@@ -140,7 +157,7 @@ class TestOneProcessPerChip:
         may have touched a backend."""
         proc = run_python(["-c", (
             "import importlib, pkgutil\n"
-            "import bench, chip_smoke, __graft_entry__\n"
+            "import chip_smoke, __graft_entry__\n"
             "import benchmarks, platform_aware_scheduling_tpu as pas\n"
             "from platform_aware_scheduling_tpu.utils import backend\n"
             "for pkg in (pas, benchmarks):\n"
@@ -160,18 +177,6 @@ class TestOneProcessPerChip:
         assert backend.backend_initialized()  # this test process computes
         with pytest.raises(RuntimeError, match="initialized a JAX backend"):
             http_load._spawn_service(16, device=True)
-
-    def test_failed_or_not_run_sections_are_named(self):
-        spec = importlib.util.spec_from_file_location(
-            "bench", os.path.join(REPO, "bench.py")
-        )
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        assert bench.section_problems({"speedup": 2.0}) == []
-        assert bench.section_problems({"not_run": "needs 5 chips"})
-        assert bench.section_problems({"a": {"error": "boom"}, "b": {}}) == [
-            "a: boom"
-        ]
 
 
 def _post(path, body):

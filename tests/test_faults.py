@@ -805,9 +805,9 @@ class TestAssemblyWiring:
         assert len(constants) == 26
         assert [n for n in constants if "--" + n in mains] == []
 
-        # other programs' flags the docs also name: pascheck's
-        # (docs/analysis.md) and benchmarks/perf_ledger.py's
-        elsewhere = {"--root", "--write-baseline", "--write"}
+        # another program's flags the docs also name: pascheck's
+        # (docs/analysis.md)
+        elsewhere = {"--root", "--write-baseline"}
         known = mains | accepted(whatif.build_arg_parser()) | elsewhere
         root = pathlib.Path(__file__).resolve().parents[1]
         named = {}

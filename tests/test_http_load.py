@@ -1,9 +1,9 @@
 """benchmarks/http_load.py harness correctness at tiny shapes.
 
-The full-scale A/B runs in bench.py on real hardware; these tests pin the
-harness itself: alias derivation from the actual sweep (the round-4 judge
-hit a KeyError driving ``concurrency_sweep=(1,)``), the repeat-spread
-field, and the >=100-request control sample.
+These tests pin the harness itself: alias derivation from the actual
+sweep (the round-4 judge hit a KeyError driving
+``concurrency_sweep=(1,)``), the repeat-spread field, and the
+>=100-request control sample.
 
 Every spawned service is told ``platform="cpu"``: the harness default is
 ``"tpu"`` and a service that finds another platform than the one named

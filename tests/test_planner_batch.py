@@ -154,8 +154,8 @@ def test_padded_solve_is_the_unpadded_one_and_the_plain_loop(seed, size, assigne
 
 
 def test_one_rule_list_for_every_pod_is_the_case_of_one_policy():
-    """bench.py, the mesh dry run and the benches solve one rule list over
-    every pod: D = 1 and a zero ``policy``, no form of its own."""
+    """The mesh dry run and the benches solve one rule list over every
+    pod: D = 1 and a zero ``policy``, no form of its own."""
     state, pods = batch_scheduler.example_inputs(num_nodes=32, num_pods=8)
     assert state.dontschedule.active.shape == (1, 2)
     assert not np.asarray(pods.policy).any()

@@ -15,8 +15,6 @@
     front-ends;
   * recompile watch — a diurnal twin run recompiles NOTHING after a
     full-period warmup (pas_xla_compiles_total flat);
-  * perf ledger — measure -> anchor -> drift round-trips and a
-    synthetic 20% stage regression is flagged;
   * causal spine — churn/solve events join /debug/explain chains by
     tick as "the world changed under you" context, and churn passes
     export anonymized into the flight recorder (format /3).
@@ -464,62 +462,6 @@ class TestRecompileWatch:
         assert churn["world"] == 8
         assert 0.0 <= churn["fraction_mean"] <= 1.0
         assert churn["last_pass"]["total_rows"] >= 0
-
-
-class TestPerfLedger:
-    def test_round_trip_and_synthetic_regression_flagged(self, tmp_path):
-        from benchmarks import perf_ledger
-
-        measurement = perf_ledger.measure(
-            num_nodes=48, solve_reps=6, verb_reps=40
-        )
-        entries = measurement["entries"]
-        assert "solve_execute" in entries
-        assert "warm_filter_verb" in entries
-        for entry in entries.values():
-            assert entry["floor_us"] > 0
-            assert (
-                perf_ledger.TOL_MIN_PCT
-                <= entry["tolerance_pct"]
-                <= perf_ledger.TOL_MAX_PCT
-            )
-        anchor_path = tmp_path / "anchor.json"
-        anchor = perf_ledger.write_anchor(measurement, anchor_path)
-        assert perf_ledger.load_anchor(anchor_path) == anchor
-        # a measurement drifts zero against itself
-        rows = perf_ledger.drift(measurement, anchor)
-        assert rows and not any(r["flagged"] for r in rows)
-        # a synthetic 20% regression on any one stage must flag: the
-        # tolerance cap (15%) sits below it by construction
-        import copy
-
-        current = copy.deepcopy(measurement)
-        current["entries"]["solve_execute"]["floor_us"] *= 1.20
-        rows = perf_ledger.drift(current, anchor)
-        flagged = [r["name"] for r in rows if r["flagged"]]
-        assert flagged == ["solve_execute"]
-
-    def test_one_sided_entries_never_flag(self):
-        from benchmarks import perf_ledger
-
-        anchor = {
-            "entries": {"gone": {"floor_us": 10.0, "tolerance_pct": 10.0}}
-        }
-        current = {
-            "entries": {"new": {"floor_us": 99.0, "tolerance_pct": 10.0}}
-        }
-        rows = perf_ledger.drift(current, anchor)
-        assert {r["name"]: r["flagged"] for r in rows} == {
-            "gone": False,
-            "new": False,
-        }
-
-    def test_committed_anchor_is_loadable(self):
-        from benchmarks import perf_ledger
-
-        anchor = perf_ledger.load_anchor()
-        assert anchor is not None, "benchmarks/perf_anchor.json missing"
-        assert anchor["entries"], "committed anchor has no entries"
 
 
 class TestCausalSpine:
